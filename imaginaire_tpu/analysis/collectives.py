@@ -15,11 +15,21 @@ import numpy as np
 from . import hlo_audit
 from .jaxpr_audit import iter_eqns
 
-# explicit collective primitives at the jaxpr level
-JAXPR_COLLECTIVE_PRIMS = (
-    "psum", "pmax", "pmin", "pmean", "all_gather", "all_to_all",
-    "ppermute", "pshuffle", "psum_scatter", "reduce_scatter",
-)
+# explicit collective primitives at the jaxpr level, as jax 0.9.0 names
+# them (read off ``jax._src.lax.parallel``), each under the name the
+# ledger reports it by. ``pmean`` traces to psum + div, ``pshuffle`` to
+# ppermute and ``psum_scatter`` to reduce_scatter; inside a ``shard_map``
+# with ``check_vma`` a psum of a varying value is ``psum_invariant``.
+JAXPR_COLLECTIVE_PRIMS = {
+    "psum": "psum", "psum_invariant": "psum", "unreduced_psum": "psum",
+    "pmax": "pmax", "pmin": "pmin",
+    "all_gather": "all_gather", "all_gather_invariant": "all_gather",
+    "all_gather_reduced": "all_gather",
+    "reduce_scatter": "reduce_scatter",
+    "unreduced_reduce_scatter": "reduce_scatter",
+    "all_to_all": "all_to_all", "ragged_all_to_all": "all_to_all",
+    "ppermute": "ppermute", "psend": "psend", "precv": "precv",
+}
 
 
 def _outvar_bytes(eqn):
@@ -45,11 +55,8 @@ def jaxpr_collectives(closed_jaxpr):
     """prim -> {count, bytes} of explicit collective equations."""
     stats = {}
     for _, eqn in iter_eqns(closed_jaxpr):
-        prim = eqn.primitive.name
-        # jax's efficient-transpose rewrite renamed psum -> psum2 (and
-        # may do the same to others); normalize so both spellings count
-        name = prim[:-1] if prim.endswith("2") else prim
-        if name in JAXPR_COLLECTIVE_PRIMS:
+        name = JAXPR_COLLECTIVE_PRIMS.get(eqn.primitive.name)
+        if name is not None:
             entry = stats.setdefault(name, {"count": 0, "bytes": 0})
             entry["count"] += 1
             entry["bytes"] += _outvar_bytes(eqn)

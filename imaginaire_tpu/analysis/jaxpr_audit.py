@@ -34,9 +34,10 @@ F64_DTYPES = ("float64", "complex128")
 MAX_PER_RULE = 16
 DEFAULT_CONST_BYTES_LIMIT = 4 << 20  # 4 MiB
 
-# host-callback primitive names across jax versions
+# host-callback primitives as jax 0.9.0 names them (jax.debug.print
+# traces to ``debug_print``)
 _CALLBACK_PRIMS = ("pure_callback", "io_callback", "debug_callback",
-                   "outside_call", "host_callback_call")
+                   "debug_print")
 
 
 @dataclass
@@ -145,7 +146,7 @@ def audit_jaxpr(program, closed_jaxpr, *,
     for path, eqn in iter_eqns(jaxpr):
         stats["eqns"] += 1
         prim = eqn.primitive.name
-        if prim in _CALLBACK_PRIMS or "callback" in prim:
+        if prim in _CALLBACK_PRIMS:
             stats["callback_eqns"] += 1
             stack = _name_stack(eqn)
             add("host_callback", path,

@@ -282,16 +282,6 @@ def default_config():
             mem_budget_frac=0.9,  # check_run_health watermark gate
             census_top=20,  # live-array census rows kept in reports
             oom_report=True,  # RESOURCE_EXHAUSTED forensics dump
-            # Persistent-compile-cache guard (ISSUE 8 satellite): the
-            # PR-7 bisect pinned a flaky NaN/SIGSEGV on executables
-            # DESERIALIZED from the jax persistent compile cache during
-            # warm-cache *resume* runs (fresh compiles never fail).
-            # off_on_resume (default) disables the cache only when the
-            # run restores a checkpoint — cold runs keep their compile
-            # amortization; 'off' always disables; 'on' never touches
-            # the configured cache. Tripping emits an
-            # xla/persistent_cache_disabled meta event.
-            persistent_cache="off_on_resume",  # on | off | off_on_resume
             # Graph audit (imaginaire_tpu/analysis, ISSUE 12): every
             # ledgered compile statically checks its closed jaxpr + the
             # optimized HLO (host callbacks, f64 leaks, bf16 casts

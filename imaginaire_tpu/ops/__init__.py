@@ -28,37 +28,26 @@ won the race; this bit the memory autotuner once already). The rules:
 
 Each op has a pure-jnp implementation (differentiable; XLA autodiff turns
 the gather-style forward into the scatter-add backward the CUDA code does
-with atomicAdd) and a Pallas TPU kernel reachable via
-``implementation='pallas'``. ``implementation='auto'`` follows measured
-dispatch (OPSBENCH.json, scripts/opsbench.py): resample2d and
-channelnorm pin to the jnp/XLA path (XLA beat or outlived the
-hand-written kernels at every production shape); correlation pins to the
-'mxu' formulation — the cost volume recast as per-displacement-row
-matmuls plus a strided band-gather, 2.1x the scan path at FlowNetC's
-full shape — with the scan path covering general kernel sizes;
-spade_modulation pins to 'fused' (the custom_vjp residual-trimming path,
-currently CPU-measured / chip-pending).
+with atomicAdd). channelnorm and spade_modulation also have a Pallas TPU
+kernel reachable via ``implementation='pallas'``; both compile for a TPU
+v5e (tests/test_tpu_compile.py). ``implementation='auto'`` resolves to
+each module's ``AUTO_IMPLEMENTATION``: resample2d and channelnorm to the
+jnp/XLA path, correlation to the 'mxu' formulation — the cost volume
+recast as per-displacement-row matmuls plus a strided band-gather — with
+the scan path covering general kernel sizes, spade_modulation to 'fused'
+(the custom_vjp residual-trimming path).
 
-auto decision-table refresh protocol
-------------------------------------
-Each op module carries an ``AUTO_IMPLEMENTATION`` constant that MUST be
-backed by an OPSBENCH.json row, never asserted by fiat. To refresh:
-
-  1. run ``python scripts/opsbench.py`` (optionally ``--ops <op,...>``)
-     on the target hardware; residual-policy ops (spade_modulation)
-     are benched on the grad path and their rows carry the grad
-     program's AOT ``temp_bytes`` — the winner for such ops orders by
-     (temp bytes, then latency), since identical forward math makes
-     latency alone noise;
-  2. on a real chip (platform 'tpu') the run is authoritative: it
-     rewrites the decision table and may change any pin;
-  3. off-chip runs (CPU containers) MERGE instead: their rows land
-     tagged ``chip_pending: true`` and may only pin ops the chip has
-     never measured — a CPU row never overwrites a chip-measured
-     winner (scripts/opsbench.py ``merge_report``);
-  4. update the op's ``AUTO_IMPLEMENTATION`` + dispatch comment to cite
-     the new row, and keep ``tests/test_spade_modulation.py``'s
-     pin-vs-OPSBENCH consistency check passing.
+auto pins
+---------
+Every ``AUTO_IMPLEMENTATION`` is pinned to the XLA formulation; not
+measured on this installation. ``python scripts/opsbench.py`` (optionally
+``--ops <op,...>``) times each implementation on the device it runs on
+and prints the rows and the winner per op; residual-policy ops
+(spade_modulation) are benched on the grad path and their rows carry the
+grad program's AOT ``temp_bytes`` — the winner for such ops orders by
+(temp bytes, then latency), since identical forward math makes latency
+alone noise. A pin changes only on a chip run's rows, with the op's
+dispatch comment saying which.
 """
 
 # module aliases FIRST (while the package attributes still point at the

@@ -15,8 +15,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-# The measured 'auto' pin (TPU v5e, OPSBENCH.json) — see the dispatch
-# comment below; bench legs record this via ops.resolved_implementations().
+# 'auto' is pinned to the XLA formulation; not measured on this
+# installation. Bench legs record it via ops.resolved_implementations().
 AUTO_IMPLEMENTATION = "jnp"
 
 
@@ -30,11 +30,9 @@ def _channelnorm_jnp(x, p):
 def channelnorm(x, p=2, implementation="auto"):
     """L-p norm over the trailing channel axis of an NHWC tensor -> (B,H,W,1)."""
     if implementation == "auto":
-        # Measured on-chip (TPU v5e): the jnp path never lost to the
-        # pallas kernel at any probed shape — XLA already fuses square,
-        # reduce and sqrt, while the kernel's (N, C) layout idles
-        # 128-wide lanes at the common C=2-3. Numbers live in
-        # OPSBENCH.json; re-run scripts/opsbench.py before changing this.
+        # XLA already fuses square, reduce and sqrt, while the
+        # kernel's (N, C) layout idles 128-wide lanes at the common
+        # C=2-3
         implementation = AUTO_IMPLEMENTATION
     if implementation == "jnp":
         return _channelnorm_jnp(x, p)

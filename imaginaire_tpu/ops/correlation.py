@@ -14,9 +14,8 @@ weights port directly.
 
 TPU notes: the displacement loop is a ``lax.scan`` over a static grid
 (one compiled slice+dot per step, compiler-friendly), and the reduction
-over channels is a contraction XLA can fuse; the Pallas kernel version
-tiles (H, W) blocks into VMEM and walks the displacement window there,
-turning the channel dot into an MXU matmul.
+over channels is a contraction XLA can fuse; the 'mxu' formulation turns
+the channel dot into per-displacement-row MXU matmuls plus a band gather.
 """
 
 from __future__ import annotations
@@ -25,10 +24,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-# The measured 'auto' pin (TPU v5e, OPSBENCH.json) for the FlowNetC
-# configuration; shapes the mxu band grid cannot represent fall back to
-# 'jnp' in the dispatch below. Bench legs record this via
-# ops.resolved_implementations().
+# 'auto' is pinned to the XLA 'mxu' formulation for the FlowNetC
+# configuration; not measured on this installation. Shapes the mxu band
+# grid cannot represent take 'jnp' in the dispatch below. Bench legs
+# record it via ops.resolved_implementations().
 AUTO_IMPLEMENTATION = "mxu"
 
 
@@ -119,12 +118,9 @@ def correlation(
     if pad_size < max_displacement:
         raise ValueError("pad_size must cover max_displacement")
     if implementation == "auto":
-        # Measured on-chip (TPU v5e, OPSBENCH.json round 5): the 'mxu'
-        # matmul+band-gather formulation beats the 441-pass lax.scan at
-        # both FlowNetC operating shapes — 0.89ms vs 1.84ms at
-        # (1,64,128,256) and 0.15ms vs 0.98ms at (1,32,64,256) — so it
-        # is the pinned default for the FlowNetC configuration; the scan
-        # path serves general kernel_size/stride1.
+        # the 'mxu' matmul+band-gather formulation serves the FlowNetC
+        # configuration; the scan path serves general
+        # kernel_size/stride1
         implementation = AUTO_IMPLEMENTATION \
             if (kernel_size == 1 and stride1 == 1
                 and max_displacement % stride2 == 0) \
@@ -142,16 +138,4 @@ def correlation(
         return _correlation_mxu(x1, x2, pad_size, max_displacement, stride2)
     if implementation == "jnp":
         return _correlation_jnp(x1, x2, pad_size, kernel_size, max_displacement, stride1, stride2)
-    if implementation in ("pallas", "pallas_interpret"):
-        from imaginaire_tpu.ops.pallas.correlation_kernel import correlation_pallas
-
-        return correlation_pallas(
-            x1,
-            x2,
-            pad_size=pad_size,
-            kernel_size=kernel_size,
-            max_displacement=max_displacement,
-            stride2=stride2,
-            interpret=(implementation == "pallas_interpret"),
-        )
     raise ValueError(f"unknown implementation {implementation!r}")

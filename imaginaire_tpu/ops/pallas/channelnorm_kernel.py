@@ -1,11 +1,12 @@
 """Fused channel L-p norm Pallas kernel.
 
 One VMEM pass per row-block: |x|^p, channel reduction and the p-th root
-fused. Rows = flattened B*H*W, lanes = C. Measured on a real v5e chip
-(OPSBENCH.json) the jnp path — which XLA fuses into neighboring ops —
-never lost to this kernel at any probed shape (lanes mostly idle at
-C=2-3), so ``channelnorm(implementation='auto')`` always picks jnp; the
-kernel is retained for parity testing and as a fusion example.
+fused. Rows = flattened B*H*W, lanes = C, mostly idle at the common
+C=2-3, while XLA fuses the jnp path into neighboring ops — so
+``channelnorm(implementation='auto')`` picks jnp (not measured on this
+installation). The kernel compiles for a TPU v5e
+(tests/test_tpu_compile.py) and is kept for parity testing and as a
+fusion example.
 """
 
 from __future__ import annotations

@@ -46,8 +46,8 @@ def _stats_kernel(n_sb, inv_s, eps, x_ref, mean_ref, rstd_ref):
         rstd_ref[...] = jnp.zeros_like(rstd_ref)
 
     x = x_ref[0].astype(jnp.float32)
-    mean_ref[...] += jnp.sum(x, axis=0, keepdims=True)
-    rstd_ref[...] += jnp.sum(x * x, axis=0, keepdims=True)
+    mean_ref[0] += jnp.sum(x, axis=0, keepdims=True)
+    rstd_ref[0] += jnp.sum(x * x, axis=0, keepdims=True)
 
     @pl.when(sb == n_sb - 1)
     def _finalize():
@@ -65,7 +65,7 @@ def _apply_kernel(n_pairs, *refs):
     beta_refs = refs[1 + n_pairs : 1 + 2 * n_pairs]
     mean_ref, rstd_ref, o_ref = refs[1 + 2 * n_pairs :]
     x = x_ref[0].astype(jnp.float32)
-    xhat = (x - mean_ref[...]) * rstd_ref[...]
+    xhat = (x - mean_ref[0]) * rstd_ref[0]
     gs = jnp.float32(1.0)
     for g_ref in gamma_refs:
         gs = gs + g_ref[0].astype(jnp.float32)
@@ -103,7 +103,10 @@ def spade_modulation_fwd_pallas(x, gammas, betas, eps=1e-5,
     b3 = tuple(_pad2(t.reshape(b, s, c), s_pad, c_pad) for t in betas)
 
     row_spec = pl.BlockSpec((1, bs_, bc), lambda bi, ci, si: (bi, si, ci))
-    stat_spec = pl.BlockSpec((1, bc), lambda bi, ci, si: (bi, ci))
+    # a unit axis between batch and channels: the chip's compiler wants
+    # the last two dims of a block divisible by (8, 128) or equal to the
+    # array's own, and a (1, bc) block over (B, C) is neither
+    stat_spec = pl.BlockSpec((1, 1, bc), lambda bi, ci, si: (bi, 0, ci))
 
     with islands.scope("norm_stats"):
         mean, rstd = pl.pallas_call(
@@ -111,8 +114,8 @@ def spade_modulation_fwd_pallas(x, gammas, betas, eps=1e-5,
             grid=(b, n_cb, n_sb),
             in_specs=[row_spec],
             out_specs=(stat_spec, stat_spec),
-            out_shape=(jax.ShapeDtypeStruct((b, c_pad), jnp.float32),
-                       jax.ShapeDtypeStruct((b, c_pad), jnp.float32)),
+            out_shape=(jax.ShapeDtypeStruct((b, 1, c_pad), jnp.float32),
+                       jax.ShapeDtypeStruct((b, 1, c_pad), jnp.float32)),
             interpret=interpret,
         )(x3)
         islands.guard("norm_stats", mean=mean, rstd=rstd)
@@ -127,6 +130,6 @@ def spade_modulation_fwd_pallas(x, gammas, betas, eps=1e-5,
     )(x3, *g3, *b3, mean, rstd)
 
     out = out[:, :s, :c].reshape(b, h, w, c)
-    mean = mean[:, :c].reshape(b, 1, 1, c)
-    rstd = rstd[:, :c].reshape(b, 1, 1, c)
+    mean = mean[:, :, :c].reshape(b, 1, 1, c)
+    rstd = rstd[:, :, :c].reshape(b, 1, 1, c)
     return out, mean, rstd

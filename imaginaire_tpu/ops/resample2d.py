@@ -17,19 +17,15 @@ flow[..., 1] = vertical displacement (+y down).
 
 The backward pass of the CUDA op scatters gradients with atomicAdd
 (resample2d_kernel.cu:122-125). Here the jnp forward is built from
-gathers, so jax autodiff produces exactly that scatter-add under XLA; the
-Pallas forward kernel is tied to the same backward through custom_vjp.
+gathers, so jax autodiff produces exactly that scatter-add under XLA.
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 
-# The measured 'auto' pin (TPU v5e, OPSBENCH.json) — see the dispatch
-# comment below; bench legs record this via ops.resolved_implementations().
+# 'auto' is pinned to the XLA formulation; not measured on this
+# installation. Bench legs record it via ops.resolved_implementations().
 AUTO_IMPLEMENTATION = "jnp"
 
 
@@ -68,44 +64,15 @@ def _bilinear_warp(x, flow):
     return out.astype(x.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _resample2d_pallas(x, flow, interpret):
-    from imaginaire_tpu.ops.pallas.resample2d_kernel import resample2d_fwd_pallas
-
-    return resample2d_fwd_pallas(x, flow, interpret=interpret)
-
-
-def _pallas_fwd(x, flow, interpret):
-    return _resample2d_pallas(x, flow, interpret), (x, flow)
-
-
-def _pallas_bwd(interpret, res, g):
-    x, flow = res
-    _, vjp = jax.vjp(_bilinear_warp, x, flow)
-    return vjp(g)
-
-
-_resample2d_pallas.defvjp(_pallas_fwd, _pallas_bwd)
-
-
 def resample2d(x, flow, implementation="auto"):
     """Warp ``x`` backward by ``flow`` (NHWC).
 
-    implementation: 'jnp' | 'pallas' | 'pallas_interpret' | 'auto'
+    implementation: 'jnp' | 'auto'
     """
     if x.ndim != 4 or flow.ndim != 4 or flow.shape[-1] != 2:
         raise ValueError(f"resample2d expects NHWC x and (B,H,W,2) flow, got {x.shape}, {flow.shape}")
     if implementation == "auto":
-        # Measured on-chip (TPU v5e): XLA's gather lowering beats the
-        # scalar-loop pallas kernel severalfold at every shape it even
-        # compiles at, and the kernel fails to compile (VMEM) at vid2vid
-        # warp shapes — jnp is the winner everywhere. Numbers live in
-        # OPSBENCH.json; re-run scripts/opsbench.py before changing this.
         implementation = AUTO_IMPLEMENTATION
     if implementation == "jnp":
         return _bilinear_warp(x, flow)
-    if implementation == "pallas":
-        return _resample2d_pallas(x, flow, False)
-    if implementation == "pallas_interpret":
-        return _resample2d_pallas(x, flow, True)
     raise ValueError(f"unknown implementation {implementation!r}")

@@ -33,7 +33,7 @@ implementations:
                      (ops/pallas/spade_modulation_kernel.py) + the same
                      hand-written backward
   'pallas_interpret' the kernel in interpret mode (CPU testing)
-  'auto'             the measured pin, see AUTO_IMPLEMENTATION below
+  'auto'             the pin, see AUTO_IMPLEMENTATION below
 """
 
 from __future__ import annotations
@@ -45,20 +45,12 @@ import jax.numpy as jnp
 
 from imaginaire_tpu.analysis import islands
 
-# The production pin for implementation='auto'. Decision table:
-# OPSBENCH.json (scripts/opsbench.py --ops spade_modulation), benched
-# on the TRAINING path (grad of the op wrt every input) with each
-# row's AOT grad-program temp bytes recorded — the decision axis for a
-# residual-policy op whose forward math is identical across 'jnp' and
-# 'fused'. Current rows are CPU-measured (chip_pending: the container
-# has no TPU): 'fused' halves grad temp at every probed SPADE shape
-# (49152 vs 98304 B at (4,32,32,1024); 16384 vs 32768 B at the
-# 2-condition (4,64,64,512) case) and also wins grad latency at 3 of
-# the 4 shapes (e.g. 372ms vs 476ms at the deep block). The
-# non-interpret pallas kernel cannot compile on CPU (error rows);
-# re-run on a real chip before promoting it — the refresh protocol
-# (ops/__init__.py) never lets a CPU run overwrite a chip-measured
-# winner.
+# 'auto' is pinned to the XLA 'fused' formulation (the custom_vjp
+# residual-trimming path); not measured on this installation. The
+# decision axis for a residual-policy op whose forward math is identical
+# across 'jnp' and 'fused' is what the backward keeps: scripts/opsbench.py
+# benches it on the TRAINING path and records each row's AOT
+# grad-program temp bytes.
 AUTO_IMPLEMENTATION = "fused"
 
 _SPATIAL_AXES = (1, 2)  # NHWC instance-norm reduction axes

@@ -9,12 +9,7 @@ all-reduce) during SPMD partitioning, riding ICI within a host/pod slice
 and DCN across hosts.
 """
 
-# jax moved shard_map from jax.experimental to the top level; support
-# both so the sharded layers/dryrun run on either side of the move
-try:
-    from jax import shard_map
-except ImportError:  # older jax: the experimental home
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from imaginaire_tpu.parallel.mesh import (
     create_mesh,

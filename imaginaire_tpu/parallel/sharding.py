@@ -103,14 +103,27 @@ def place_committed_batch(batch, mesh=None, axis=DATA_AXIS):
     if jax.process_count() > 1:
         return place_process_local_batch(batch, mesh, axis)
     shardings = batch_pytree_shardings(batch, mesh, axis)
-    specs = jax.tree_util.tree_leaves(
-        shardings, is_leaf=lambda s: isinstance(s, NamedSharding))
-    if not any(len(s.spec) and s.spec[0] == axis for s in specs):
+    if not _any_leaf_shards(shardings, axis):
         # nothing actually shards (batch dim indivisible everywhere):
         # committing replicated arrays would only drag every consumer
         # program onto the full mesh — keep the uncommitted placement
         return to_device(batch)
     return jax.device_put(batch, shardings)
+
+
+def _any_leaf_shards(shardings, axis):
+    specs = jax.tree_util.tree_leaves(
+        shardings, is_leaf=lambda s: isinstance(s, NamedSharding))
+    return any(len(s.spec) and s.spec[0] == axis for s in specs)
+
+
+def batch_commits(batch, axis=DATA_AXIS):
+    """Whether ``place_committed_batch`` would commit this (single-
+    process) batch: a process mesh is set and the ``axis`` size divides
+    some leaf's leading dim."""
+    mesh = peek_mesh()
+    return mesh is not None and _any_leaf_shards(
+        batch_pytree_shardings(batch, mesh, axis), axis)
 
 
 def place_process_local_batch(batch, mesh, axis=DATA_AXIS):

@@ -167,8 +167,8 @@ def force_teardown():
     process topology (``jax.process_count`` would otherwise keep
     reporting the dead world)."""
     import jax
+    import jax.extend.backend
     from jax._src import distributed
-    from jax._src import xla_bridge
 
     gs = distributed.global_state
     old_client, old_service = gs.client, gs.service
@@ -189,18 +189,12 @@ def force_teardown():
                          name="elastic-old-client-shutdown").start()
     if old_client is not None or old_service is not None:
         _LEAKED.append((old_client, old_service))
-    xla_bridge._clear_backends()
-    for fn in (jax.process_count, jax.process_index, jax.device_count,
-               jax.local_device_count):
-        cache_clear = getattr(fn, "cache_clear", None)
-        if cache_clear is not None:
-            cache_clear()
-    # jitted executables baked device ids of the dead world into their
-    # bindings — anything cached at the jax level must go too
-    try:
-        jax.clear_caches()
-    except Exception as e:  # noqa: BLE001 — best-effort on older jax
-        logger.debug("elastic: jax.clear_caches failed: %s", e)
+    # every backend client goes, and with it what jax caches per world:
+    # the process topology (``jax.process_count`` would otherwise keep
+    # reporting the dead one) and the jitted executables, which baked
+    # the dead world's device ids into their bindings
+    jax.extend.backend.clear_backends()
+    jax.clear_caches()
 
 
 # ------------------------------------------------------------ the plan

@@ -110,6 +110,22 @@ class CheckpointIntegrityError(RuntimeError):
     """A restored checkpoint's bytes do not match its saved checksums."""
 
 
+def _host_copy(leaf):
+    """The leaf's bytes on the host, without leaving them there: jax
+    caches a fetched value on the array it was fetched from, and the
+    leaves of a live train state stay alive — a checksum pass over a
+    zoo-width SPADE state left 6.8 GiB of host copies behind (PR 22,
+    TPU v5e). Fetch through a second array over the same device buffers
+    instead; the copy dies with it."""
+    import jax
+
+    if isinstance(leaf, jax.Array) and not leaf.is_deleted():
+        leaf = jax.make_array_from_single_device_arrays(
+            leaf.shape, leaf.sharding,
+            [s.data for s in leaf.addressable_shards])
+    return np.asarray(jax.device_get(leaf))
+
+
 def _leaf_record(leaf):
     """(record dict, skip reason). Non-addressable / object leaves are
     skipped with a reason instead of forcing a gather — EXCEPT fully
@@ -136,9 +152,7 @@ def _leaf_record(leaf):
                 return None, "not_fully_addressable"
         return None, "not_fully_addressable"
     try:
-        import jax
-
-        arr = np.asarray(jax.device_get(leaf))
+        arr = _host_copy(leaf)
     except Exception:  # noqa: BLE001 — fall back to a plain asarray
         try:
             arr = np.asarray(leaf)

@@ -46,37 +46,34 @@ from imaginaire_tpu.config import cfg_get
 
 logger = logging.getLogger(__name__)
 
-# bf16 peak FLOP/s per chip by device kind (prefix-matched). The
-# fallback assumes the target chip of this repo's PROFILE.md numbers;
-# override with telemetry.peak_flops for other hardware.
+# bf16 peak FLOP/s per chip by device kind (prefix-matched), each with
+# the source of the figure. A device that is not here has no peak, and
+# a run on it no ``perf/mfu``: set telemetry.peak_flops to give one.
+_CLOUD_TPU_DOCS = "Google Cloud TPU documentation, system architecture"
 _PEAK_FLOPS_BY_KIND = (
-    ("TPU v6", 918e12),
-    ("TPU v5p", 459e12),
-    ("TPU v5 lite", 197e12),
-    ("TPU v5e", 197e12),
-    ("TPU v4", 275e12),
+    ("TPU v6", 918e12, f"{_CLOUD_TPU_DOCS}, 'TPU v6e'"),
+    ("TPU v5p", 459e12, f"{_CLOUD_TPU_DOCS}, 'TPU v5p'"),
+    # a v5e chip reports device_kind 'TPU v5 lite'
+    ("TPU v5 lite", 197e12, f"{_CLOUD_TPU_DOCS}, 'TPU v5e'"),
+    ("TPU v5e", 197e12, f"{_CLOUD_TPU_DOCS}, 'TPU v5e'"),
+    ("TPU v4", 275e12, f"{_CLOUD_TPU_DOCS}, 'TPU v4'"),
 )
-_FALLBACK_PEAK_FLOPS = 197e12
 
 
 def resolve_peak_flops(override=None):
-    """(peak_flops, source) — config override > device-kind table >
-    assumed-v5e fallback (flagged so MFU numbers are never silently
-    wrong on unknown hardware)."""
+    """(peak_flops, source) — config override > the table row of the
+    device's own ``device_kind``. A kind the table does not know (the
+    CPU among them) gets ``(None, why)``: no peak is assumed for it."""
     if override:
         return float(override), "config:telemetry.peak_flops"
-    try:
-        import jax
+    import jax
 
-        kind = jax.devices()[0].device_kind
-        for prefix, peak in _PEAK_FLOPS_BY_KIND:
-            if str(kind).startswith(prefix):
-                return peak, f"device_kind:{kind}"
-    except Exception:  # noqa: BLE001 — no backend yet
-        kind = "unknown"
-    return _FALLBACK_PEAK_FLOPS, (
-        f"assumed_v5e_peak (device_kind={kind}; set telemetry.peak_flops "
-        "to override)")
+    kind = str(jax.devices()[0].device_kind)
+    for prefix, peak, source in _PEAK_FLOPS_BY_KIND:
+        if kind.startswith(prefix):
+            return peak, f"device_kind:{kind} ({source})"
+    return None, (f"no peak known for device_kind={kind}: perf/mfu not "
+                  "computed (set telemetry.peak_flops to give one)")
 
 
 class _NullSpan:

@@ -1020,10 +1020,11 @@ def render_report(path_or_events):
                 lines.append(f"- {name}: {value:.4g} (step {step})")
     flops_meta = s["meta"].get("step_flops")
     if flops_meta:
+        peak = flops_meta.get("peak_flops")
         lines.append(f"- step_flops: {flops_meta.get('flops'):.4g} "
-                     f"({flops_meta.get('source')}, peak "
-                     f"{flops_meta.get('peak_flops'):.4g} FLOP/s via "
-                     f"{flops_meta.get('peak_source')})")
+                     f"({flops_meta.get('source')}, "
+                     + (f"peak {peak:.4g} FLOP/s via " if peak else "")
+                     + f"{flops_meta.get('peak_source')})")
     lines.extend(_health_section(s))
     lines.extend(_xla_section(s))
     lines.extend(_graph_section(s))

@@ -137,59 +137,6 @@ def xla_obs_settings(cfg):
     }
 
 
-def apply_persistent_cache_policy(cfg, resuming=False):
-    """Guard the known-bad persistent-compile-cache deserialize path
-    (ISSUE 8 satellite). The PR-7 chaos-leg bisect reproduced flaky NaN
-    losses / SIGSEGV when the spade step executables were DESERIALIZED
-    from the jax persistent compile cache during a warm-cache resume —
-    fresh compiles never failed (clean HEAD, ~20-run bisect; see
-    CHANGES.md PR 7). Until the upstream deserialize bug is fixed, a
-    resumed run must not pay a crash lottery for compile amortization.
-
-    ``cfg.xla_obs.persistent_cache``:
-      - ``on``            — never touch the configured cache
-      - ``off``           — always disable it
-      - ``off_on_resume`` — (default) disable only when ``resuming``
-
-    Call BEFORE the first compile. Returns True when the cache was
-    disabled; emits an ``xla/persistent_cache_disabled`` meta event so
-    the run's jsonl records why its compiles were cold."""
-    import jax
-
-    ocfg = cfg_get(cfg or {}, "xla_obs", None) or {}
-    mode = str(cfg_get(ocfg, "persistent_cache",
-                       "off_on_resume")).lower()
-    if mode not in ("on", "off", "off_on_resume"):
-        logger.warning("unknown xla_obs.persistent_cache=%r; treating "
-                       "as off_on_resume", mode)
-        mode = "off_on_resume"
-    trip = mode == "off" or (mode == "off_on_resume" and bool(resuming))
-    if not trip:
-        return False
-    import os as _os
-
-    previous = (jax.config.jax_compilation_cache_dir
-                or _os.environ.get("JAX_COMPILATION_CACHE_DIR"))
-    jax.config.update("jax_compilation_cache_dir", None)
-    # the env var re-arms the cache in child processes this run spawns
-    # (dryrun legs, pod launchers) — scrub it too
-    _os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
-    if previous:
-        from imaginaire_tpu import telemetry
-
-        tm = telemetry.get()
-        if tm.enabled:
-            tm.meta("xla/persistent_cache_disabled", mode=mode,
-                    resuming=bool(resuming), previous_dir=str(previous))
-        logger.warning(
-            "persistent compile cache DISABLED (%s, resuming=%s): "
-            "executables deserialized from the cache are flaky on "
-            "resume (NaN/SIGSEGV — PR-7 bisect); compiles run cold. "
-            "Set xla_obs.persistent_cache: on to override.",
-            mode, resuming)
-    return True
-
-
 # ------------------------------------------------------------ fingerprints
 
 
@@ -477,6 +424,21 @@ def _telemetry():
 # --------------------------------------------------------- wrapped programs
 
 
+def trim_host_heap():
+    """Hand the heap's freed pages back to the OS (glibc only; a no-op
+    elsewhere). The compiler allocates and frees gigabytes of host
+    memory per step program and glibc keeps them, so the next compile's
+    peak lands on top of the last one's leftovers: at zoo-width SPADE
+    the G-step compile peaked at 44 GiB resident on a TPU v5e host that
+    has 40 (PR 22), with 6 GiB of the D step's still held."""
+    import ctypes
+
+    try:
+        ctypes.CDLL("libc.so.6").malloc_trim(0)
+    except (OSError, AttributeError):
+        pass
+
+
 class CompiledProgram:
     """Ledger-dispatching drop-in for ``jax.jit(fn)``.
 
@@ -509,6 +471,11 @@ class CompiledProgram:
     # jax.jit surface the rest of the repo relies on
     def lower(self, *args, **kwargs):
         return self._jit.lower(*args, **kwargs)
+
+    def executables(self):
+        """The AOT executables compiled so far (``as_text()``,
+        ``memory_analysis()``), in compile order."""
+        return list(self._executables.values())
 
     def _cache_size(self):
         if self._passthrough:
@@ -623,16 +590,13 @@ class CompiledProgram:
             is_recompile = True
         counted = is_recompile and reason is None
         _LEDGER.begin(self.label)
+        trim_host_heap()
         try:
             t0 = time.perf_counter()
             # trace explicitly so the graph auditor gets the closed
             # jaxpr the lowering consumed — lower() alone discards it
-            traced = None
-            try:
-                traced = self._jit.trace(*args)
-                lowered = traced.lower()
-            except AttributeError:  # jax without .trace
-                lowered = self._jit.lower(*args)
+            traced = self._jit.trace(*args)
+            lowered = traced.lower()
             t1 = time.perf_counter()
             compiled = lowered.compile()
             t2 = time.perf_counter()

@@ -227,10 +227,10 @@ class BaseTrainer:
                 vars_G["params"], vars_G.get("spectral"),
                 remove_sn=self.model_average_remove_sn)
             state["num_ema_updates"] = jnp.zeros((), jnp.int32)
-        self.state = self._place_state(state)
+        self.state = self._place_state(state, data)
         return self.state
 
-    def _place_state(self, state):
+    def _place_state(self, state, data=None):
         """Commit the state pytree under the partition plan's shardings
         (no-op without an active plan): params model-sharded per the
         rules, optimizer/EMA trees cross-replica sharded over 'data',
@@ -257,7 +257,31 @@ class BaseTrainer:
 
             return assemble_global(state,
                                    NamedSharding(get_mesh(), P()))
+        if data is not None and self._feed_commits_batches(data):
+            # the steps will come back from the first call with the
+            # state committed (replicated) to the mesh their batches are
+            # committed to: place it there now, or every step program
+            # compiles twice, once for the uncommitted state and once
+            # for the committed one (the ledger's ``sharding_commit``;
+            # a zoo-width SPADE D step is minutes and gigabytes of host
+            # memory to compile, PR 22)
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            from imaginaire_tpu.parallel.mesh import peek_mesh
+
+            return jax.device_put(state, NamedSharding(peek_mesh(), P()))
         return state
+
+    def _feed_commits_batches(self, data):
+        """Whether the training loop will hand the steps committed,
+        sharded batches: a train loader behind the device prefetcher,
+        and a batch the process mesh's data axis divides."""
+        from imaginaire_tpu.data.device_prefetch import prefetch_settings
+        from imaginaire_tpu.parallel.sharding import batch_commits
+
+        return (self.train_data_loader is not None
+                and prefetch_settings(self.cfg)[0]
+                and batch_commits(data))
 
     def _constrain_state(self, state):
         """Pin a step program's output state to the placement layout

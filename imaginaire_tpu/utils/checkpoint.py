@@ -46,7 +46,6 @@ Fault tolerance (ISSUE 7, ``resilience/``):
 
 from __future__ import annotations
 
-import contextlib
 import os
 import re
 
@@ -67,44 +66,36 @@ _ASYNC_CKPT = None
 _POINTER_THREAD = None
 
 
+# How many GB of a checkpoint may be in flight on the host at once
+# (device-to-host copies, encode/decode buffers). Orbax's default is the
+# whole tree at once: saving a zoo-width SPADE state (6.8 GiB) then took
+# another 10.3 GiB of host memory on the TPU v5e host, and restoring it
+# 7.3 (PR 22, measured), on a host whose 40 GiB the compiles of that
+# width had already come within reach of.
+_HOST_IN_FLIGHT_GB = 1
+
+
+def _pytree_handler(**kwargs):
+    return ocp.PyTreeCheckpointHandler(
+        save_concurrent_gb=_HOST_IN_FLIGHT_GB,
+        save_device_host_concurrent_gb=_HOST_IN_FLIGHT_GB,
+        restore_concurrent_gb=_HOST_IN_FLIGHT_GB, **kwargs)
+
+
 def _async_checkpointer():
     global _ASYNC_CKPT
     if _ASYNC_CKPT is None:
-        _ASYNC_CKPT = ocp.AsyncCheckpointer(ocp.PyTreeCheckpointHandler())
+        # Directories are created inside ``save`` (behind orbax's
+        # fixed-name barriers), not on a background thread: orbax's
+        # asynchronous directory creation signals peers through KV keys
+        # that embed a PER-PROCESS operation counter, and an elastic
+        # joiner (ISSUE 13) has a shorter save history than the
+        # survivors, so it would wait on a key nobody sets.
+        _ASYNC_CKPT = ocp.AsyncCheckpointer(
+            _pytree_handler(),
+            async_options=ocp.options.AsyncOptions(
+                create_directories_asynchronously=False))
     return _ASYNC_CKPT
-
-
-def _align_orbax_barrier_counters():
-    """Pin orbax's per-process barrier counters before a collective save.
-
-    Orbax suffixes its internal barrier keys (``create_tmp_directory:…``,
-    async-save finalization, …) with PER-PROCESS ``itertools.count()``
-    values from ``orbax.checkpoint.multihost.counters`` and asserts via
-    ``sync_global_devices`` that every process computed the same key.
-    That assumes uniform save history — which elastic membership breaks
-    (ISSUE 13): a host that rejoined mid-run has saved fewer checkpoints
-    than the survivors, so at the next collective save its counter (say
-    ``.1``) disagrees with theirs (``.4``) and the whole pod dies with
-    ``sync_global_devices name mismatch``.
-
-    The counters carry no information for us: saves are already
-    serialized pod-wide by the named ``ckpt_enter``/``ckpt_commit``
-    timed barriers, each save targets a unique directory name, and
-    ``wait_for_pending_checkpoint`` drains any in-flight async commit
-    before the next dispatch — so resetting the counters between saves
-    cannot collide two concurrent barriers. Resetting (rather than
-    patching the accessors) keeps orbax's own uniqueness-within-a-save
-    behavior intact while making the sequence identical everywhere."""
-    import itertools
-
-    try:
-        from orbax.checkpoint.multihost import counters as _counters
-    except Exception:  # pragma: no cover — older orbax layouts
-        return
-    for attr in ("_tmp_directory_counter", "_async_save_counter",
-                 "_composite_save_counter"):
-        if hasattr(_counters, attr):
-            setattr(_counters, attr, itertools.count())
 
 
 def checkpoint_name(epoch, iteration):
@@ -174,10 +165,6 @@ def save_checkpoint(logdir, state, epoch, iteration, max_to_keep=None,
     from imaginaire_tpu.resilience import cluster
 
     cluster.timed_barrier("ckpt_enter", tag=name)
-    # Everyone is now entering THIS save together — align orbax's
-    # per-process barrier counters so elastic members with different
-    # save histories derive identical collective keys (ISSUE 13).
-    _align_orbax_barrier_counters()
 
     def _write_pointer():
         if is_master():
@@ -286,7 +273,7 @@ def save_checkpoint(logdir, state, epoch, iteration, max_to_keep=None,
         _POINTER_THREAD.start()
     else:
         with telemetry.span("ckpt"):
-            with ocp.PyTreeCheckpointer() as ckpt:
+            with ocp.Checkpointer(_pytree_handler()) as ckpt:
                 ckpt.save(path, state)
         _after_commit()
         telemetry.get().heartbeat()
@@ -539,39 +526,30 @@ def gc_checkpoints(logdir, max_to_keep, protect=()):
 # -------------------------------------------------------------- restore
 
 
-@contextlib.contextmanager
-def _no_restore_barrier():
-    """Suppress orbax's end-of-restore process sync for the duration.
+def _restore_checkpointer():
+    """A PyTree checkpointer whose restore syncs with no other process.
 
     ``Checkpointer.restore`` closes with ``sync_global_processes`` — an
-    UNTIMED ``sync_global_devices`` psum over every global device
-    through the CPU gloo layer. In an elastic pod (ISSUE 13) restores
-    are legitimately asymmetric: a joiner restores the published
-    checkpoint at startup while the survivors re-commit their live
-    state and never touch orbax, so the joiner's barrier waits 30s for
-    gloo contexts no peer will ever create and the restore dies with
-    ``DEADLINE_EXCEEDED`` — and even when every member restores, a
-    fallback scan that walks a different number of candidates on one
-    host leaves that host's collective sequence offset from its peers,
-    which surfaces later as a wedged/aborted all-device sync at the
-    next checkpoint save. Restore is read-only, so the barrier guards
-    nothing; pod-wide resume agreement is the KV-store consensus vote
-    (timed, and it NAMES the absent process). Saves keep their sync:
-    the pre-finalize barrier is what stops the primary from renaming
-    the tmp directory while peers are still writing."""
-    from orbax.checkpoint import checkpointer as _ocp_checkpointer
+    UNTIMED all-device sync. In an elastic pod (ISSUE 13) restores are
+    legitimately asymmetric: a joiner restores the published checkpoint
+    at startup while the survivors re-commit their live state and never
+    touch orbax, so the joiner's barrier waits for peers that never
+    arrive — and a fallback scan that walks a different number of
+    candidates on one host leaves its collective sequence offset from
+    its peers'. Restore is read-only, so the barrier guards nothing;
+    pod-wide resume agreement is the KV-store consensus vote (timed,
+    and it NAMES the absent process). Naming this process as the only
+    active one is orbax's own way to say so. Saves keep their sync: the
+    pre-finalize barrier is what stops the primary from renaming the
+    tmp directory while peers are still writing."""
+    import jax
 
-    mh = _ocp_checkpointer.multihost
-    orig = mh.sync_global_processes
-
-    def _skip(name, **kwargs):
-        return None
-
-    mh.sync_global_processes = _skip
-    try:
-        yield
-    finally:
-        mh.sync_global_processes = orig
+    me = jax.process_index()
+    opts = ocp.options.MultiprocessingOptions(primary_host=me,
+                                              active_processes={me})
+    return ocp.Checkpointer(
+        _pytree_handler(multiprocessing_options=opts),
+        multiprocessing_options=opts)
 
 
 def _host_template(target):
@@ -617,8 +595,7 @@ def load_checkpoint(path, target=None, verify=True):
 
         verify_files(os.path.abspath(path),
                      (integrity or {}).get("files"), context=str(path))
-    with telemetry.span("ckpt_load"), _no_restore_barrier(), \
-            ocp.PyTreeCheckpointer() as ckpt:
+    with telemetry.span("ckpt_load"), _restore_checkpointer() as ckpt:
         if target is not None:
             # force host-numpy restore here too (ISSUE 11): without
             # restore args orbax replays the SAVED shardings from the
@@ -657,7 +634,7 @@ def load_checkpoint(path, target=None, verify=True):
             # under their own shardings.
             import numpy as np
 
-            meta = ckpt.metadata(os.path.abspath(path))
+            meta = ckpt.metadata(os.path.abspath(path)).item_metadata.tree
             restore_args = jax.tree_util.tree_map(
                 lambda m: (ocp.RestoreArgs(restore_type=np.ndarray)
                            if hasattr(m, "shape") else ocp.RestoreArgs()),
@@ -738,15 +715,19 @@ def load_latest_verified(logdir, target=None, verify=True):
             fallbacks += 1
             _note_fallback(tm, cand, fallbacks, str(e))
             continue
+        except (ImportError, AttributeError, TypeError, NameError):
+            # a fault of the program (a moved import, a changed call
+            # signature), not evidence about THIS checkpoint's bytes:
+            # quarantining on it would rename every healthy candidate
+            # `.corrupt` in turn. Leave the checkpoints where they are.
+            raise
         except Exception as e:  # noqa: BLE001 — truncated/unrestorable
             if type(e).__name__ in ("XlaRuntimeError",
                                     "JaxRuntimeError"):
-                # runtime/collective infrastructure failure, not
-                # evidence about THIS checkpoint's bytes: quarantining
-                # here would walk the fallback scan through every
-                # candidate and condemn a healthy logdir (ISSUE 13:
-                # seen as gloo context timeouts when a resize left the
-                # pod's collective layer wedged). Fail the restore
+                # runtime/collective infrastructure failure, no more
+                # telling about the bytes than a program fault (ISSUE
+                # 13: seen as gloo context timeouts when a resize left
+                # the pod's collective layer wedged). Fail the restore
                 # loudly and leave the checkpoints alone.
                 raise
             errors.append(f"{cand}: {type(e).__name__}: {e}")

@@ -14,13 +14,13 @@ from imaginaire_tpu import telemetry
 from imaginaire_tpu.config import Config, cfg_get
 from imaginaire_tpu.data import get_test_dataloader
 from imaginaire_tpu.parallel.mesh import (
-    honor_platform_env,
     master_only_print as print,  # noqa: A001
     maybe_init_distributed_from_env,
     mesh_from_config,
     set_mesh,
 )
 from imaginaire_tpu.registry import resolve
+from imaginaire_tpu.utils import compile_cache
 from imaginaire_tpu.utils.logging_utils import init_logging, make_logging_dir
 
 
@@ -41,7 +41,7 @@ def parse_args():
 
 
 def main():
-    honor_platform_env()
+    compile_cache.configure()
     maybe_init_distributed_from_env()
     args = parse_args()
     cfg = Config(args.config)

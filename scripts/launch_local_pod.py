@@ -504,9 +504,7 @@ def main(argv=None):
         return 0 if ok else 1
     if args.bench:
         # clean throughput leg: children run without drill scaffolding
-        # (inherited elastic env) and without the persistent compile
-        # cache (the deserialize path is the known-bad NaN/SIGSEGV
-        # lottery, PR-7 bisect); every JSON line they print folds into
+        # (inherited elastic env); every JSON line they print folds into
         # ONE leg-summary JSON here
         sink = []
         codes, wall, timed_out = launch_pod(
@@ -514,7 +512,7 @@ def main(argv=None):
             devices_per_process=args.devices_per_process,
             timeout=args.timeout, coordinator_port=args.coordinator_port,
             log_dir=args.child_log_dir, bare_output=True, json_sink=sink,
-            scrub_env=("IMAGINAIRE_ELASTIC*", "JAX_COMPILATION_CACHE_DIR"))
+            scrub_env=("IMAGINAIRE_ELASTIC*",))
         summary = {
             "pod_bench": {
                 "process_count": args.num_processes,

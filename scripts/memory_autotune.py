@@ -487,8 +487,9 @@ def main(argv=None):
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_test_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    from imaginaire_tpu.utils import compile_cache
+
+    compile_cache.configure()
     import numpy as np
 
     from imaginaire_tpu.parallel.mesh import create_mesh, set_mesh

@@ -21,16 +21,13 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-# TPU v5e (v5 lite): 197 TFLOP/s bf16 peak per chip
-V5E_PEAK_FLOPS = 197e12
+from imaginaire_tpu.telemetry import resolve_peak_flops
 
 
 def fence(tree):
-    leaf = jax.tree_util.tree_leaves(tree)[0]
-    return float(jnp.sum(leaf.astype(jnp.float32)))
+    jax.block_until_ready(tree)
 
 
 def time_step(trainer, data, iters=8):
@@ -108,11 +105,13 @@ def main():
                 fl = step_flops(trainer, data)
                 if all(v is not None for v in fl.values()):
                     total = sum(fl.values())
-                    mfu = total / dt / V5E_PEAK_FLOPS
+                    peak, source = resolve_peak_flops()
                     print(f"  flops: dis={fl['dis']:.3e} "
                           f"gen={fl['gen']:.3e} "
-                          f"total={total:.3e}/step -> MFU={mfu * 100:.1f}% "
-                          f"of {V5E_PEAK_FLOPS / 1e12:.0f} TF/s", flush=True)
+                          f"total={total:.3e}/step -> "
+                          + (f"MFU={total / dt / peak * 100:.1f}% of "
+                             f"{peak / 1e12:.0f} TF/s ({source})"
+                             if peak else source), flush=True)
             results.append(row)
         except Exception as e:  # noqa: BLE001
             print(f"bs={bs}: failed ({e!s:.120})", flush=True)

@@ -627,65 +627,6 @@ class TestLoaderEqualShards:
         assert sorted(seen) == [0, 1, 2, 3]  # item 4 dropped evenly
 
 
-# -------------------------------- persistent compile-cache guard
-
-
-class TestPersistentCachePolicy:
-    def _apply(self, mode, resuming, monkeypatch, tmp_path):
-        from imaginaire_tpu.telemetry import xla_obs
-
-        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
-        try:
-            cfg = {"xla_obs": {"persistent_cache": mode}}
-            return xla_obs.apply_persistent_cache_policy(
-                cfg, resuming=resuming), \
-                jax.config.jax_compilation_cache_dir
-        finally:
-            jax.config.update("jax_compilation_cache_dir",
-                              "/tmp/jax_test_cache")
-
-    def test_off_on_resume_trips_only_on_resume(self, monkeypatch,
-                                                tmp_path):
-        tripped, cache = self._apply("off_on_resume", True,
-                                     monkeypatch, tmp_path)
-        assert tripped and cache is None
-        tripped, cache = self._apply("off_on_resume", False,
-                                     monkeypatch, tmp_path)
-        assert not tripped and cache == str(tmp_path)
-
-    def test_off_always_trips(self, monkeypatch, tmp_path):
-        tripped, cache = self._apply("off", False, monkeypatch,
-                                     tmp_path)
-        assert tripped and cache is None
-
-    def test_on_never_trips(self, monkeypatch, tmp_path):
-        tripped, cache = self._apply("on", True, monkeypatch, tmp_path)
-        assert not tripped and cache == str(tmp_path)
-
-    def test_trip_emits_meta_event(self, monkeypatch, tmp_path):
-        from imaginaire_tpu import telemetry
-        from imaginaire_tpu.telemetry import xla_obs
-        from imaginaire_tpu.telemetry.report import load_events
-
-        logdir = tmp_path / "logs"
-        tm = telemetry.configure(logdir=str(logdir), enabled=True,
-                                 sinks=("jsonl",))
-        jax.config.update("jax_compilation_cache_dir",
-                          str(tmp_path / "cache"))
-        try:
-            xla_obs.apply_persistent_cache_policy(
-                {"xla_obs": {"persistent_cache": "off"}},
-                resuming=False)
-        finally:
-            jax.config.update("jax_compilation_cache_dir",
-                              "/tmp/jax_test_cache")
-        tm.shutdown()
-        events = load_events(str(logdir / "telemetry.jsonl"))
-        metas = [e for e in events
-                 if e.get("name") == "xla/persistent_cache_disabled"]
-        assert metas and metas[0]["mode"] == "off"
-
-
 # -------------------------------------------- heartbeat epoch scoping
 
 

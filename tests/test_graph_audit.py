@@ -65,9 +65,7 @@ class TestJaxprRules:
         assert "eqns[" in v.path
 
     def test_f64_leak_named(self):
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        with jax.enable_x64():
             def bad(x):
                 return jnp.sum(x.astype(jnp.float64))
 

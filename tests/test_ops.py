@@ -49,11 +49,10 @@ def np_correlation(x1, x2, pad, md, s2):
     return out
 
 
-@pytest.mark.parametrize("impl", ["jnp", "pallas_interpret"])
-def test_resample2d_matches_reference(rng, impl):
+def test_resample2d_matches_reference(rng):
     x = rng.randn(2, 5, 6, 3).astype(np.float32)
     flow = (rng.randn(2, 5, 6, 2) * 2).astype(np.float32)
-    got = np.asarray(resample2d(jnp.asarray(x), jnp.asarray(flow), implementation=impl))
+    got = np.asarray(resample2d(jnp.asarray(x), jnp.asarray(flow), implementation="jnp"))
     want = np_resample2d(x, flow)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
@@ -75,17 +74,6 @@ def test_resample2d_grad_is_scatter_add(rng):
     np.testing.assert_allclose(np.asarray(g)[0, 0, :, 0], [2.0, 0.0, 1.0])
 
 
-def test_resample2d_pallas_vjp_matches_jnp(rng):
-    x = jnp.asarray(rng.randn(1, 4, 5, 2).astype(np.float32))
-    flow = jnp.asarray((rng.randn(1, 4, 5, 2) * 1.5).astype(np.float32))
-    g1 = jax.grad(lambda a, f: resample2d(a, f, implementation="jnp").sum(), argnums=(0, 1))(x, flow)
-    g2 = jax.grad(
-        lambda a, f: resample2d(a, f, implementation="pallas_interpret").sum(), argnums=(0, 1)
-    )(x, flow)
-    for a, b in zip(g1, g2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)
-
-
 @pytest.mark.parametrize("impl", ["jnp", "pallas_interpret"])
 @pytest.mark.parametrize("p", [1, 2])
 def test_channelnorm(rng, impl, p):
@@ -97,7 +85,7 @@ def test_channelnorm(rng, impl, p):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("impl", ["jnp", "mxu", "pallas_interpret"])
+@pytest.mark.parametrize("impl", ["jnp", "mxu"])
 def test_correlation(rng, impl):
     x1 = rng.randn(2, 6, 7, 4).astype(np.float32)
     x2 = rng.randn(2, 6, 7, 4).astype(np.float32)

@@ -243,46 +243,73 @@ class TestFallback:
                                           target=_state())
         assert not any(".corrupt" in n for n in os.listdir(tmp_path))
 
-    def test_restore_suppresses_orbax_process_sync(self):
-        # elastic restores are asymmetric (a joiner restores while the
-        # survivors re-commit live state) — orbax's untimed end-of-
-        # restore all-device sync must be neutered for the duration and
-        # restored after
-        from orbax.checkpoint import checkpointer as ocp_checkpointer
+    @pytest.mark.parametrize("fault", [ImportError, AttributeError,
+                                       TypeError, NameError])
+    def test_program_fault_raises_without_quarantine(self, tmp_path,
+                                                     monkeypatch, fault):
+        # a moved import or a changed signature inside the restore path
+        # is the program's fault and says nothing about the bytes: the
+        # scan must not rename healthy checkpoints `.corrupt` over it
+        for it in (1, 2):
+            ckpt_lib.save_checkpoint(str(tmp_path), _state(it), 0, it)
 
-        orig = ocp_checkpointer.multihost.sync_global_processes
-        with ckpt_lib._no_restore_barrier():
-            patched = ocp_checkpointer.multihost.sync_global_processes
-            assert patched is not orig
-            patched("any_barrier_name", processes={0, 1})  # no-op
-        assert ocp_checkpointer.multihost.sync_global_processes is orig
+        def _boom(path, target=None, verify=True):
+            raise fault("cannot import name 'checkpointer'")
 
-    def test_save_aligns_orbax_barrier_counters(self):
-        # orbax suffixes barrier keys with per-process save counters; an
-        # elastic joiner has a shorter save history than the survivors,
-        # so without re-alignment the counters diverge and the collective
-        # save dies with "sync_global_devices name mismatch"
-        from orbax.checkpoint.multihost import counters
+        monkeypatch.setattr(ckpt_lib, "load_checkpoint", _boom)
+        with pytest.raises(fault):
+            ckpt_lib.load_latest_verified(str(tmp_path), target=_state())
+        assert not any(".corrupt" in n for n in os.listdir(tmp_path))
+        assert len(ckpt_lib.scan_checkpoints(str(tmp_path))) == 2
 
-        # burn a few ticks to simulate a process with prior saves
-        for _ in range(3):
-            counters.tmp_directory_counter()
-        assert counters.tmp_directory_counter() != "0"
-        ckpt_lib._align_orbax_barrier_counters()
-        assert counters.tmp_directory_counter() == "0"
-        # uniqueness WITHIN a save sequence is preserved
-        assert counters.tmp_directory_counter() == "1"
-        if hasattr(counters, "async_save_counter"):
-            ckpt_lib._align_orbax_barrier_counters()
-            assert counters.async_save_counter() == "0"
-
-    def test_save_path_invokes_counter_alignment(self, tmp_path,
+    def test_restore_syncs_with_no_other_process(self, tmp_path,
                                                  monkeypatch):
-        calls = []
-        monkeypatch.setattr(ckpt_lib, "_align_orbax_barrier_counters",
-                            lambda: calls.append(1))
-        ckpt_lib.save_checkpoint(str(tmp_path), _state(1), 0, 1)
-        assert calls == [1]
+        # elastic restores are asymmetric (a joiner restores while the
+        # survivors re-commit live state): a restore that waited on
+        # orbax's untimed end-of-restore all-device sync would hang on
+        # peers that never arrive. Pretend to be one of two processes
+        # and make the sync fatal — the restore must never reach it.
+        from jax.experimental import multihost_utils
+
+        ckpt_lib.save_checkpoint(str(tmp_path), _state(), 0, 1)
+        path = ckpt_lib.latest_checkpoint_path(str(tmp_path))
+
+        def _boom(*a, **k):
+            raise AssertionError("restore entered a cross-process sync")
+
+        monkeypatch.setattr(jax, "process_count", lambda *a, **k: 2)
+        monkeypatch.setattr(multihost_utils, "sync_global_devices", _boom)
+        for target in (_state(), None):
+            payload = ckpt_lib.load_checkpoint(path, target=target)
+            np.testing.assert_array_equal(
+                np.asarray(payload["state"]["w"]), _state()["state"]["w"])
+
+    def test_async_save_sets_no_per_process_signal_key(self, tmp_path):
+        # orbax's asynchronous directory creation signals peers through
+        # KV keys suffixed with a PER-PROCESS operation counter; an
+        # elastic joiner has a shorter save history than the survivors
+        # and would wait on a key nobody sets. The async save must not
+        # depend on any such key, however many saves came before.
+        from orbax.checkpoint._src.futures import signaling_client
+
+        client = signaling_client.get_signaling_client()
+        keys = []
+        orig = type(client).key_value_set
+
+        def _record(self, key, value, **kw):
+            keys.append(key)
+            return orig(self, key, value, **kw)
+
+        type(client).key_value_set = _record
+        try:
+            for it in (1, 2):
+                ckpt_lib.save_checkpoint(str(tmp_path), _state(it), 0, it,
+                                         async_save=True)
+                ckpt_lib.wait_for_pending_checkpoint()
+        finally:
+            type(client).key_value_set = orig
+        assert keys == []
+        assert len(ckpt_lib.scan_checkpoints(str(tmp_path))) == 2
 
 
 # ------------------------------------------------------------- retention

@@ -9,8 +9,6 @@ checkpoint bytes, and the refusal cases (masked partial path, non-
 instance base, broadcast maps) fall back to the reference composition.
 """
 
-import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -27,8 +25,8 @@ from imaginaire_tpu.ops import spade_modulation
 from imaginaire_tpu.ops.spade_modulation import AUTO_IMPLEMENTATION
 
 # downscaled-channel stand-ins for the spade-128/256/512 pyramid levels
-# (full-channel operating points are OPSBENCH's job); the last is the
-# multi-cond accumulation case (seg + edge + prior-frame maps)
+# (full-channel operating points are scripts/opsbench.py's job); the last
+# is the multi-cond accumulation case (seg + edge + prior-frame maps)
 SHAPES = [((2, 32, 32, 8), 1),    # spade-128 deep block
           ((2, 16, 16, 12), 2),   # spade-256 deep block, 2 conditions
           ((1, 64, 64, 4), 3)]    # spade-512 mid block, 3 conditions
@@ -241,30 +239,6 @@ def test_adaptive_norm_conv_fuses_linear_refuses(rng, key):
 
 
 # ------------------------------------------------- decision-table pins
-
-
-def test_auto_pin_backed_by_opsbench():
-    """AUTO_IMPLEMENTATION constants must agree with the committed
-    OPSBENCH.json decision table (the refresh protocol in
-    ops/__init__.py) — and the spade pin must be backed by clean
-    measured rows, not asserted by fiat."""
-    from imaginaire_tpu import ops
-
-    path = os.path.join(os.path.dirname(__file__), "..", "OPSBENCH.json")
-    with open(path) as f:
-        table = json.load(f)
-    resolved = ops.resolved_implementations()
-    for op, impl in resolved.items():
-        assert table["winners"].get(op) == impl, (
-            f"{op}: AUTO_IMPLEMENTATION={impl!r} but OPSBENCH winner is "
-            f"{table['winners'].get(op)!r} — re-run scripts/opsbench.py "
-            f"and update the pin together")
-    rows = [c for c in table["cases"]
-            if c["op"] == "spade_modulation"
-            and c["impl"] == resolved["spade_modulation"]]
-    assert rows and all("ms" in r for r in rows)
-    # the spade rows carry the decision axis for a residual-policy op
-    assert all("temp_bytes" in r for r in rows)
 
 
 def test_auto_dispatch_resolves(rng):

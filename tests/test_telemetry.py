@@ -426,7 +426,9 @@ def test_trainer_step_emits_spans_counters_and_mfu(tm_sandbox, tmp_path):
     assert {"data_wait", "dis_step", "gen_step"} <= names
     counters = {e["name"] for e in events if e["kind"] == "counter"}
     assert "perf/imgs_per_sec" in counters
-    assert "perf/mfu" in counters  # XLA cost analysis worked on CPU
+    # the CPU has no row in the peak table: no peak is assumed for it,
+    # so no MFU is computed, and the step_flops meta says why
+    assert "perf/mfu" not in counters
     assert any(c.startswith("xla/compile/gen_step/") for c in counters)
     spans = [e for e in events if e["kind"] == "span"
              and e["name"] == "gen_step"]
@@ -434,6 +436,29 @@ def test_trainer_step_emits_spans_counters_and_mfu(tm_sandbox, tmp_path):
     meta = next(e for e in events if e["kind"] == "meta"
                 and e["name"] == "step_flops")
     assert meta["flops"] > 0
+    assert meta["peak_flops"] is None
+    assert "device_kind=cpu" in meta["peak_source"]
+
+
+@pytest.mark.parametrize("kind,peak", [("TPU v5 lite", 197e12),
+                                       ("TPU v5e", 197e12),
+                                       ("TPU v4", 275e12),
+                                       ("cpu", None),
+                                       ("TPU v9x", None)])
+def test_peak_flops_come_from_the_device_kinds_own_row(monkeypatch, kind,
+                                                       peak):
+    import jax
+    from types import SimpleNamespace
+
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [SimpleNamespace(device_kind=kind)])
+    got, source = telemetry.resolve_peak_flops()
+    assert got == peak
+    assert kind in source
+    if peak is None:
+        assert "not computed" in source
+    # an explicit override still wins, on any device
+    assert telemetry.resolve_peak_flops(3e12)[0] == 3e12
 
 
 def test_span_overhead_stays_negligible(tm_sandbox):

@@ -1,0 +1,179 @@
+"""Compile for a described TPU v5e, without the chip (section 2 of the
+on-chip-measurement guide): the Pallas kernels that stay selectable, the
+XLA formulations every op's ``auto`` resolves to, and the zoo-width SPADE
+serving forward, each at its production shape. Nothing runs; what the
+chip's compiler refuses fails here, at no chip time.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU's library, and every worker imports this file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HBM_BYTES = 16 * 10 ** 9  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """An executable compiled for a described chip is written to the
+    persistent cache but cannot be read back: switch it off around
+    these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def topo(no_persistent_cache):
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    # lint: allow(bare-jit) -- compile-only probe, nothing is dispatched
+    return jax.jit(fn).lower(*args).compile()
+
+
+# ------------------------------------------------------- Pallas kernels
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_channelnorm_kernel_compiles(one_chip, dtype):
+    from imaginaire_tpu.ops.pallas.channelnorm_kernel import (
+        channelnorm_pallas,
+    )
+
+    compiled = _compile(channelnorm_pallas,
+                        _sds((2, 512, 1024, 2), dtype, one_chip))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("shape", [(4, 32, 32, 1024), (4, 256, 256, 128)])
+def test_spade_modulation_kernel_compiles(one_chip, shape, dtype):
+    from imaginaire_tpu.ops.pallas.spade_modulation_kernel import (
+        spade_modulation_fwd_pallas,
+    )
+
+    x = _sds(shape, dtype, one_chip)
+    compiled = _compile(
+        lambda x, g, b: spade_modulation_fwd_pallas(x, (g,), (b,)),
+        x, x, x)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# -------------------------------------------- what ``auto`` resolves to
+
+
+def test_correlation_auto_compiles(one_chip):
+    from imaginaire_tpu.ops import correlation, correlation_mod
+
+    assert correlation_mod.AUTO_IMPLEMENTATION == "mxu"
+    x = _sds((1, 64, 128, 256), jnp.float32, one_chip)
+    compiled = _compile(
+        lambda a, b: correlation(a, b, implementation="auto"), x, x)
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_resample2d_auto_compiles(one_chip):
+    from imaginaire_tpu.ops import resample2d
+
+    compiled = _compile(
+        lambda x, f: resample2d(x, f, implementation="auto"),
+        _sds((2, 512, 1024, 3), jnp.float32, one_chip),
+        _sds((2, 512, 1024, 2), jnp.float32, one_chip))
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_spade_modulation_auto_value_and_grad_compiles(one_chip):
+    from imaginaire_tpu.ops import spade_modulation, spade_modulation_mod
+
+    assert spade_modulation_mod.AUTO_IMPLEMENTATION == "fused"
+
+    def loss(x, g, b):
+        out = spade_modulation(x, (g,), (b,), implementation="auto")
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    x = _sds((4, 256, 256, 128), jnp.bfloat16, one_chip)
+    compiled = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                        x, x, x)
+    assert compiled.memory_analysis().temp_size_in_bytes < HBM_BYTES
+
+
+# ------------------------------------------- the serving forward, bs 4
+
+
+def test_zoo_spade_serving_forward_fits_one_chip(one_chip):
+    """configs/projects/spade/cocostuff/base128_bs4.yaml as shipped (nf
+    128, 256x256, 185 label channels), the forward inference.py serves,
+    from ``jax.eval_shape`` shapes: no weight is materialized."""
+    from imaginaire_tpu.config import Config, cfg_get
+    from imaginaire_tpu.registry import resolve
+    from imaginaire_tpu.utils.data import (
+        get_paired_input_label_channel_number,
+    )
+
+    cfg = Config(os.path.join(ROOT, "configs", "projects", "spade",
+                              "cocostuff", "base128_bs4.yaml"))
+    cfg.trainer.perceptual_loss.allow_random_init = True
+    cfg.trainer.perceptual_loss.pop("weights_path", None)
+    trainer = resolve(cfg.trainer.type, "Trainer")(cfg)
+    bs = int(cfg.data.val.batch_size)
+    n_lab = get_paired_input_label_channel_number(cfg.data)
+    assert (bs, n_lab, int(cfg.gen.num_filters)) == (4, 185, 128)
+    batch = {"images": jax.ShapeDtypeStruct((bs, 256, 256, 3), np.float32),
+             "label": jax.ShapeDtypeStruct((bs, 256, 256, n_lab),
+                                           np.float32)}
+
+    def variables_of(key, data):
+        trainer.init_state(key, data)
+        return trainer.inference_params()
+
+    variables = jax.eval_shape(
+        variables_of, jax.ShapeDtypeStruct((2,), np.uint32), batch)
+    trainer.state = None  # eval_shape left shapes there
+    inference_args = dict(cfg_get(cfg, "inference_args", None) or {})
+    net = trainer.net_G
+
+    def forward(variables, data, rng):
+        return net.apply(variables, data, training=False,
+                         rngs={"noise": rng}, method=net.inference,
+                         **inference_args)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+
+    compiled = _compile(forward, on_chip(variables), on_chip(batch),
+                        _sds((2,), np.uint32, one_chip))
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert 0 < total < HBM_BYTES, total

@@ -23,8 +23,7 @@ def _test_env():
     return dict(os.environ,
                 JAX_PLATFORMS="cpu",
                 XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=8"),
-                JAX_COMPILATION_CACHE_DIR="/tmp/jax_test_cache")
+                           + " --xla_force_host_platform_device_count=8"))
 
 
 def _run_train(config, logdir, max_iter=2):

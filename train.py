@@ -20,13 +20,13 @@ from imaginaire_tpu.data import get_train_and_val_dataloader
 from imaginaire_tpu.parallel.mesh import (
     create_mesh,
     fit_mesh_shape,
-    honor_platform_env,
     master_only_print as print,  # noqa: A001
     maybe_init_distributed_from_env,
     mesh_from_config,
     set_mesh,
 )
 from imaginaire_tpu.registry import resolve
+from imaginaire_tpu.utils import compile_cache
 from imaginaire_tpu.utils.logging_utils import init_logging, make_logging_dir
 
 
@@ -73,7 +73,7 @@ def _maybe_elastic_join():
 
 
 def main():
-    honor_platform_env()
+    compile_cache.configure()
     # elastic joiner rendezvous must precede distributed init: it is
     # what PRODUCES the IMAGINAIRE_DIST_* contract for a joining host
     _maybe_elastic_join()
@@ -108,17 +108,6 @@ def main():
     # the configured sinks (<logdir>/telemetry.jsonl by default); the
     # watchdog/trace knobs ride the same cfg section
     tm = telemetry.configure(cfg, logdir=logdir)
-    # persistent-compile-cache guard (ISSUE 8 satellite): a warm-cache
-    # RESUME rides the known-bad executable-deserialize path (flaky
-    # NaN/SIGSEGV, PR-7 bisect) — off_on_resume (default) disables the
-    # cache exactly when a checkpoint will be restored. Must run before
-    # the first compile.
-    from imaginaire_tpu.telemetry import xla_obs
-    from imaginaire_tpu.utils import checkpoint as ckpt_lib
-
-    resuming = bool(args.checkpoint) \
-        or ckpt_lib.latest_checkpoint_path(logdir) is not None
-    xla_obs.apply_persistent_cache_policy(cfg, resuming=resuming)
     # fault-tolerance layer (resilience/): retry policy + chaos
     # injection singleton, the SIGTERM preemption guard that drains the
     # in-flight step into an emergency checkpoint (ISSUE 7), and the
@@ -369,9 +358,15 @@ def main():
                         sys.exit(resilience.EXIT_PREEMPTED)
                     if current_iteration >= max_iter:
                         print("Done with training!!!")
+                        # deterministic producer shutdown (as in the
+                        # drain path): the prefetcher's thread otherwise
+                        # stays blocked on its queue, holding device
+                        # batches and the trainer, for the life of a
+                        # process that goes on after main() returns
+                        timed_feed.close()
                         trainer.save_checkpoint(epoch, current_iteration)
                         _finalize_run(trainer)
-                        return
+                        return trainer
                 if data is None:
                     # resumed exactly at an epoch boundary: every batch
                     # of this epoch was consumed before the kill —
@@ -380,7 +375,7 @@ def main():
                 trainer.end_of_epoch(data, epoch, current_iteration)
             print("Done with training!!!")
             _finalize_run(trainer)
-            return
+            return trainer
         except elastic.ElasticResize as resize:
             plan = resize.plan
         except cluster.ClusterDesyncError as desync:
