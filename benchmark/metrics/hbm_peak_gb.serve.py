@@ -1,0 +1,7 @@
+"""Peak bytes in use on the fullest chip after the window
+(`memory_stats()["peak_bytes_in_use"]`), in GB."""
+
+
+def read(observed):
+    peak = observed.get("memory_peak_bytes")
+    return peak / 1e9 if peak else None
