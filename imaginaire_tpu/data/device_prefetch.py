@@ -18,9 +18,9 @@ jit. A bounded queue keeps up to ``depth`` batches resident on device
 ahead of the consumer.
 
 Observability: per-batch ``data/host_wait_ms`` (producer blocked on the
-host loader), ``data/transfer_ms`` (device_put dispatch) and
-``data/queue_depth`` (ready batches at consume time) accumulate in a
-lock-guarded buffer; ``drain_stats()`` hands them to the trainer's
+host loader), ``data/transfer_ms`` (placement, to the batch being on the
+device) and ``data/queue_depth`` (ready batches at consume time)
+accumulate in a lock-guarded buffer; ``drain_stats()`` hands them to the trainer's
 meters, flushed on ``logging_iter`` with the loss meters — nothing here
 ever blocks the step loop on a device sync.
 
@@ -147,17 +147,22 @@ class DevicePrefetcher:
 
     def _transfer(self, batch):
         """Split host-only leaves out, commit the numeric remainder as
-        sharded device arrays, re-merge. Non-dict batches place whole."""
+        sharded device arrays, re-merge. Non-dict batches place whole.
+        Returns once the batch IS on the device: the producer thread
+        waits, off the step path, so a queued batch is a resident one
+        and ``prefetch_transfer`` is the H2D time."""
+        import jax
+
         from imaginaire_tpu.parallel.sharding import place_committed_batch
         from imaginaire_tpu.utils.misc import merge_host_leaves, \
             split_host_leaves
 
         if not isinstance(batch, dict):
-            return place_committed_batch(batch, mesh=self.mesh,
-                                         axis=self.axis)
+            return jax.block_until_ready(place_committed_batch(
+                batch, mesh=self.mesh, axis=self.axis))
         numeric, host = split_host_leaves(batch)
-        placed = place_committed_batch(numeric, mesh=self.mesh,
-                                       axis=self.axis)
+        placed = jax.block_until_ready(place_committed_batch(
+            numeric, mesh=self.mesh, axis=self.axis))
         return PrefetchedBatch(merge_host_leaves(placed, host))
 
     def __iter__(self):
@@ -175,9 +180,10 @@ class DevicePrefetcher:
 
         def produce():
             # producer-side telemetry spans (prefetch_host / _preprocess
-            # / _transfer) are tagged with this thread's name — the hang
-            # watchdog's stack dump and the phase table both show where
-            # the pipeline actually spends its time, off the step path
+            # / _transfer / _put) are tagged with this thread's name: the
+            # hang watchdog's stack dump and the phase table both show
+            # where the pipeline actually spends its time, off the step
+            # path
             from imaginaire_tpu import telemetry
 
             tm = telemetry.get()
@@ -207,7 +213,8 @@ class DevicePrefetcher:
                         batch = self._transfer(batch)
                     self._record("data/transfer_ms",
                                  (time.perf_counter() - t1) * 1e3)
-                    put(batch)
+                    with tm.span("prefetch_put"):
+                        put(batch)
                     index += 1
             except BaseException as e:  # forwarded to the consumer
                 put(e)

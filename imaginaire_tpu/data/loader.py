@@ -26,6 +26,7 @@ import logging
 
 import numpy as np
 
+from imaginaire_tpu import telemetry
 from imaginaire_tpu.config import cfg_get
 from imaginaire_tpu.parallel.mesh import get_rank, get_world_size
 from imaginaire_tpu.registry import resolve
@@ -108,7 +109,10 @@ class DataLoader:
             chaos.get().maybe_io_error("loader")
             return self.dataset[int(idx)]
 
-        return retry_call(_read, label="loader")
+        # one sample's read, decode, augment and label encoding, on the
+        # worker thread that runs it
+        with telemetry.span("loader_fetch"):
+            return retry_call(_read, label="loader")
 
     def __len__(self):
         if self.global_batch_size and self.shard_by_process:
@@ -209,19 +213,25 @@ class DataLoader:
 
         def produce():
             try:
-                with ThreadPoolExecutor(self.num_workers) as pool:
+                with ThreadPoolExecutor(
+                        self.num_workers,
+                        thread_name_prefix="loader-worker") as pool:
                     for idxs in batches:
                         if stop.is_set():
                             return
                         futures = [pool.submit(self._fetch, int(i))
                                    for i in idxs]
-                        put(self._collate([f.result() for f in futures]))
+                        items = [f.result() for f in futures]
+                        with telemetry.span("loader_collate"):
+                            batch = self._collate(items)
+                        put(batch)
             except BaseException as e:  # forwarded to the consumer
                 put(e)
             finally:
                 put(sentinel)
 
-        producer = threading.Thread(target=produce, daemon=True)
+        producer = threading.Thread(target=produce, daemon=True,
+                                    name="loader-producer")
         producer.start()
         try:
             while True:

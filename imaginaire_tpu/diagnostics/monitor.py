@@ -154,10 +154,16 @@ class HealthMonitor:
     # --------------------------------------------------------- processing
 
     def _check(self, trainer, entry):
+        from imaginaire_tpu import telemetry
+
         h = entry["health"]
-        # lint: allow(host-sync) -- reads the PREVIOUS step's flags, one step behind the dispatch frontier
-        finite, audited = (bool(x) for x in jax.device_get(
-            (h["finite"], h["audited"])))
+        # the one place the loop thread blocks on the device: the span's
+        # step is the iteration that polls, the flags the program's
+        # before it
+        with telemetry.span("health_poll", step=trainer.current_iteration):
+            # lint: allow(host-sync) -- reads the PREVIOUS step's flags, one step behind the dispatch frontier
+            finite, audited = (bool(x) for x in jax.device_get(
+                (h["finite"], h["audited"])))
         if audited:
             self._ingest(entry, finite=finite)
             if finite and self.on_nonfinite == "rollback":

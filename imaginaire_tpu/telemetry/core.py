@@ -7,13 +7,42 @@ throughput, no per-step phase attribution, no way to tell a hung
 prefetcher from a slow compile. This module is the process-wide event
 bus the whole stack reports into:
 
-- ``span(name)``       — context manager timing one phase of one step
-  (``data_wait`` / ``dis_step`` / ``gen_step`` / ``ckpt`` / ``eval`` ...).
-  Span durations are *dispatch* times on an async backend: the step loop
-  is never fenced per step. A ``block_until_ready`` fence runs only at
-  the flush interval (``step_complete(..., fence=...)``), so window
-  wall-clock — and therefore imgs/sec and MFU — is device-true while
-  per-step overhead stays at two ``perf_counter`` calls per span.
+- ``span(name)``       — context manager timing one phase of one step.
+  Every span is also a ``jax.profiler.TraceAnnotation`` named
+  ``imaginaire/<name>`` on the thread it runs on: a flag test while no
+  profiler session is active, and while one is (``trace_at_step``, a
+  benchmark's own ``start_trace``) an event on the host plane of the
+  same ``.xplane.pb`` that holds the device's operations, on the
+  profiler's clock, so a device gap can be laid against what the host
+  was in. The training path's spans, by layer boundary; the spans of one
+  iteration on the loop thread carry the iteration ``start_of_iteration``
+  was given:
+
+  - ``data_wait`` (loop thread): blocked in ``next(feed)``, and nothing
+    else.
+  - ``start_of_iteration`` (loop): host hook and placement of a batch
+    that was not prefetched; near zero for one that was.
+  - ``dis_step`` / ``gen_step`` (loop): the host's time to ENQUEUE the
+    step program, pytree fingerprint included: the dispatch, not the
+    step's time on the device.
+  - ``health_poll`` (loop): blocked on the device for the previous
+    program's flags, the one place the loop thread waits for the device.
+  - ``end_of_iteration`` (loop): meters, ``step_complete``, the flush
+    and its fence when due; ``ckpt`` and ``eval`` nest under it.
+  - ``prefetch_host`` / ``prefetch_preprocess`` / ``prefetch_transfer``
+    / ``prefetch_put`` (``device-prefetch`` thread): waiting for the
+    loader's next batch; the trainer's host hook; placement, to the
+    batch being ON the device (the H2D time); blocked on a full queue
+    (the feed is ahead).
+  - ``loader_fetch`` (a loader worker, one per sample): read, decode,
+    augment, label encoding. ``loader_collate`` (the loader's producer
+    thread): stacking one batch.
+  - ``init_state`` (caller): building and placing the train state.
+
+  A ``block_until_ready`` fence runs only at the flush interval
+  (``step_complete(..., fence=...)``), so window wall-clock — and
+  therefore imgs/sec and MFU — is device-true while per-span overhead
+  stays at two ``perf_counter`` calls and one inactive annotation.
 - derived counters     — imgs/sec over the fenced window, step-time EWMA
   and p50/p99 over a bounded ring buffer, and MFU from the XLA cost
   analysis registered once at jit time
@@ -24,7 +53,8 @@ bus the whole stack reports into:
   producer and checkpoint pointer thread included) is dumped to the
   sinks and stderr (see ``watchdog.py``).
 - on-demand tracing    — ``telemetry.trace_at_step`` captures a
-  ``jax.profiler`` trace for steps ``[N, N + trace_num_steps)``.
+  ``jax.profiler`` trace for steps ``[N, N + trace_num_steps)`` into
+  ``<logdir>/trace``: the one profiler starter the program has.
 
 The module-level singleton starts disabled (a no-op whose ``span`` hands
 back a shared null context manager); entry points opt in via
@@ -90,9 +120,13 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+# every span's name on the profiler's host plane
+ANNOTATION_PREFIX = "imaginaire/"
+
 
 class _Span:
-    __slots__ = ("_tm", "name", "step", "parent", "_t0", "_wall")
+    __slots__ = ("_tm", "name", "step", "parent", "_t0", "_wall",
+                 "_annotation")
 
     def __init__(self, tm, name, step):
         self._tm = tm
@@ -111,12 +145,18 @@ class _Span:
             with self._tm._lock:
                 self._tm._exempt_depth += 1
             self._tm.last_heartbeat = self._tm._clock()
+        import jax
+
+        self._annotation = jax.profiler.TraceAnnotation(
+            ANNOTATION_PREFIX + self.name)
+        self._annotation.__enter__()
         self._wall = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur_s = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
         stack = self._tm._span_stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -211,9 +251,9 @@ class Telemetry:
         }
         with self._lock:
             self._events.append(event)
-            # a span nested under a same-named span (e.g. data_wait
-            # wrapping start_of_iteration which spans data_wait itself)
-            # must not double-count in the phase totals
+            # a span nested under a same-named span is the same wall
+            # time measured twice: it must not double-count in the
+            # phase totals
             if span.parent != span.name:
                 phase = self._phases.get(span.name)
                 if phase is None:

@@ -35,10 +35,10 @@ def summarize(events):
     """Aggregate events into {phases, counters, meta, hangs, wall_s}.
 
     Span events nested under a same-named parent are skipped (they are
-    the same wall time measured twice — e.g. a caller's ``data_wait``
-    wrapping ``start_of_iteration``'s own). Phases can still legitimately
+    the same wall time measured twice). Phases can still legitimately
     nest under *different* names (vid2vid's per-frame ``dis_step`` runs
-    inside ``gen_step``), so phase shares may sum past 100%.
+    inside ``gen_step``, ``ckpt`` inside ``end_of_iteration``), so phase
+    shares may sum past 100%.
     """
     phases = {}
     counters = {}

@@ -247,7 +247,6 @@ class CompileLedger:
     def begin(self, label):
         with self._lock:
             self._active.append((label, time.time()))
-        _telemetry().meta("compiling", label=label)
 
     def end(self, label):
         with self._lock:
@@ -344,9 +343,7 @@ class CompileLedger:
         with self._lock:
             recompiles = self.recompiles
             hits = dict(self.cache_hits)
-            total = len(self.records)
         tm.counter("xla/recompiles", recompiles, step=step)
-        tm.counter("xla/compiles_total", total, step=step)
         tm.counter("xla/graph_violations", self._graph_totals()[0],
                    step=step)
         for label, count in hits.items():

@@ -185,9 +185,14 @@ class BaseTrainer:
         return {"fake_images": jnp.zeros_like(data["images"])}
 
     def init_state(self, key, data):
-        """Build the full train-state pytree from one example batch.
+        """Build and place the full train-state pytree from one example
+        batch, under the ``init_state`` span (a large part of set-up at
+        zoo width). Families override ``_init_state``."""
+        with telemetry.span("init_state"):
+            return self._init_state(key, data)
 
-        The Flax inits run under jit: eager init dispatches every op
+    def _init_state(self, key, data):
+        """The Flax inits run under jit: eager init dispatches every op
         separately (minutes on CPU for a full generator); one traced
         program initializes in seconds.
         """
@@ -471,6 +476,8 @@ class BaseTrainer:
         from imaginaire_tpu.utils.misc import numeric_only
 
         batch = numeric_only(data)
+        # the span is the DISPATCH: the host's time to fingerprint the
+        # arguments and enqueue the program, not its time on the device
         with telemetry.span("gen_step", step=self.current_iteration):
             self.state, losses, health = self._jit_gen_step(self.state,
                                                             batch)
@@ -521,17 +528,15 @@ class BaseTrainer:
     def start_of_iteration(self, data, current_iteration):
         from imaginaire_tpu.data.device_prefetch import PrefetchedBatch
 
-        # the data_wait span covers the host hook + H2D transfer (the
-        # per-step input cost this process pays; the feed wait itself is
-        # a sibling span in the train loop). Near-zero for prefetched
-        # batches — exactly what the phase table should show.
-        with telemetry.span("data_wait", step=current_iteration):
+        # host hook + H2D placement for a batch that was not prefetched;
+        # near zero for one that was. The wait on the feed is the train
+        # loop's own ``data_wait`` span, around ``next(feed)``.
+        with telemetry.span("start_of_iteration", step=current_iteration):
             prefetched = isinstance(data, PrefetchedBatch)
             if not prefetched:
                 data = self._start_of_iteration(data, current_iteration)
             self.current_iteration = current_iteration
             self.start_iteration_time = time.time()
-            self._maybe_profile(current_iteration)
             if prefetched:
                 # a DevicePrefetcher already ran the host hook and
                 # committed the numeric leaves as sharded device arrays
@@ -593,67 +598,51 @@ class BaseTrainer:
 
         return place_committed_batch(self._start_of_iteration(data, -1))
 
-    def _maybe_profile(self, current_iteration):
-        """XLA profiler trace window (the jax-native replacement for the
-        reference's speed_benchmark nvprof runs, SURVEY §5.1): configure
-        cfg.trainer.profile = {start_iteration: N, num_iterations: K} to
-        capture steps [N, N+K) into <logdir>/profile for perfetto/xprof."""
-        pcfg = cfg_get(cfg_get(self.cfg, "trainer", {}) or {}, "profile",
-                       None)
-        if pcfg is None:
-            return
-        start = cfg_get(pcfg, "start_iteration", 10)
-        num = cfg_get(pcfg, "num_iterations", 5)
-        if current_iteration == start and not getattr(self, "_profiling",
-                                                      False):
-            path = os.path.join(cfg_get(self.cfg, "logdir", "."), "profile")
-            jax.profiler.start_trace(path)
-            self._profiling = True
-            print(f"jax.profiler trace started -> {path}")
-        elif getattr(self, "_profiling", False) and \
-                current_iteration >= start + num:
-            jax.profiler.stop_trace()
-            self._profiling = False
-            print("jax.profiler trace stopped")
-
     def end_of_iteration(self, data, current_epoch, current_iteration):
         """(ref: base.py:294-373)."""
-        self.current_epoch = current_epoch
-        self.current_iteration = current_iteration
-        self._end_of_iteration(data, current_epoch, current_iteration)
-        self.time_iteration = time.time() - self.start_iteration_time
-        tm = telemetry.get()
-        if tm.enabled:
-            self._register_step_flops(data)
-            # heartbeat + ring-buffer accounting; the fence only runs at
-            # the flush interval (never a per-step device sync)
-            tm.step_complete(
-                current_iteration, items=self._batch_items(data),
-                dur_s=self.time_iteration,
-                # lint: allow(host-sync) -- heartbeat fence, runs only at the telemetry flush interval
-                fence=lambda: jax.block_until_ready(self.state))
-            # pod digest (podview.py, ISSUE 17): publish/aggregate at
-            # the digest cadence; inert null object single-process
-            podview.get().on_step(current_iteration)
-        cfg = self.cfg
-        if current_iteration % cfg_get(cfg, "logging_iter", 100) == 0:
-            self._meter("time/iteration").write(self.time_iteration)
-            self._flush_meters(current_iteration)
-            if cfg_get(cfg.trainer, "log_weight_stats", False):
-                self._write_weight_stats(current_iteration)
-        if current_iteration % cfg_get(cfg, "snapshot_save_iter", 10000) == 0:
-            self.save_checkpoint(current_epoch, current_iteration)
-            self.write_metrics()
-        if current_iteration % cfg_get(cfg, "image_save_iter", 10000) == 0:
-            self.save_image(self._image_path(current_iteration), data)
-        # continuous eval (ISSUE 18): mid-training FID/KID sweeps at the
-        # cfg.evaluation.every_n_iter cadence, through the sharded plane
-        # + reference store — quality lands in the same jsonl the
-        # throughput counters do
-        eval_every = cfg_get(cfg_get(cfg, "evaluation", {}) or {},
-                             "every_n_iter", None)
-        if eval_every and current_iteration % int(eval_every) == 0:
-            self.continuous_eval(current_iteration)
+        # the span carries the iteration the batch was started with (the
+        # callers count one further before they call), so one
+        # iteration's spans share a step
+        with telemetry.span("end_of_iteration",
+                            step=self.current_iteration):
+            self.current_epoch = current_epoch
+            self.current_iteration = current_iteration
+            self._end_of_iteration(data, current_epoch, current_iteration)
+            self.time_iteration = time.time() - self.start_iteration_time
+            tm = telemetry.get()
+            if tm.enabled:
+                self._register_step_flops(data)
+                # heartbeat + ring-buffer accounting; the fence only runs
+                # at the flush interval (never a per-step device sync)
+                tm.step_complete(
+                    current_iteration, items=self._batch_items(data),
+                    dur_s=self.time_iteration,
+                    # lint: allow(host-sync) -- heartbeat fence, runs only at the telemetry flush interval
+                    fence=lambda: jax.block_until_ready(self.state))
+                # pod digest (podview.py, ISSUE 17): publish/aggregate at
+                # the digest cadence; inert null object single-process
+                podview.get().on_step(current_iteration)
+            cfg = self.cfg
+            if current_iteration % cfg_get(cfg, "logging_iter", 100) == 0:
+                self._meter("time/iteration").write(self.time_iteration)
+                self._flush_meters(current_iteration)
+                if cfg_get(cfg.trainer, "log_weight_stats", False):
+                    self._write_weight_stats(current_iteration)
+            if current_iteration % cfg_get(cfg, "snapshot_save_iter",
+                                           10000) == 0:
+                self.save_checkpoint(current_epoch, current_iteration)
+                self.write_metrics()
+            if current_iteration % cfg_get(cfg, "image_save_iter",
+                                           10000) == 0:
+                self.save_image(self._image_path(current_iteration), data)
+            # continuous eval (ISSUE 18): mid-training FID/KID sweeps at
+            # the cfg.evaluation.every_n_iter cadence, through the sharded
+            # plane + reference store — quality lands in the same jsonl
+            # the throughput counters do
+            eval_every = cfg_get(cfg_get(cfg, "evaluation", {}) or {},
+                                 "every_n_iter", None)
+            if eval_every and current_iteration % int(eval_every) == 0:
+                self.continuous_eval(current_iteration)
 
     def end_of_epoch(self, data, current_epoch, current_iteration):
         """(ref: base.py:375-405)."""
@@ -1488,7 +1477,7 @@ class BaseTrainer:
             if it >= n_iters:
                 break
             # side-effect-free preprocessing: start_of_iteration would
-            # reset timers / re-trigger the profiler window mid-metrics
+            # reset the iteration's timers mid-metrics
             data = to_device(self._start_of_iteration(
                 data, self.current_iteration))
             _, new_mut = self._apply_G(ema_vars, numeric_only(data),

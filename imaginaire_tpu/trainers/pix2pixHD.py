@@ -72,11 +72,11 @@ class Trainer(SPADETrainer):
         return (enc_cfg is not None and self.contain_instance_map
                 and cfg_get(enc_cfg, "num_feat_channels", 0) > 0)
 
-    def init_state(self, key, data):
+    def _init_state(self, key, data):
         """Reserve the cluster-center leaf up front so the state pytree
         structure never changes mid-training (a late insert would force the
         jitted steps to recompile and break orbax resume targets)."""
-        state = super().init_state(key, data)
+        state = super()._init_state(key, data)
         if self._has_encoder():
             from imaginaire_tpu.utils.data import (
                 get_paired_input_label_channel_number,

@@ -253,7 +253,7 @@ class Trainer(BaseTrainer):
             images = images[:, 0]
         return {"label": label, "image": images}
 
-    def init_state(self, key, data):
+    def _init_state(self, key, data):
         """All generator submodules (temporal path included) and all
         temporal discriminator scales materialize here — the curriculum
         only flips static flags later."""

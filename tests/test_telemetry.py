@@ -10,6 +10,7 @@ import sys
 import threading
 import time
 
+import numpy as np
 import pytest
 
 from imaginaire_tpu import telemetry
@@ -64,11 +65,15 @@ def test_jsonl_sink_roundtrip(tm_sandbox, tmp_path):
     events = _read_jsonl(str(tmp_path / "telemetry.jsonl"))
     kinds = {e["kind"] for e in events}
     assert {"span", "counter", "meta"} <= kinds
-    span = next(e for e in events if e["kind"] == "span")
-    assert span["name"] == "gen_step" and span["step"] == 7
+    # by name: `configure` replays the process-wide compile ledger into a
+    # new instance's sinks first, so whatever an earlier test of this
+    # worker compiled (xla/compile/* counters, xla_compile/* metas) leads
+    span, = [e for e in events
+             if e["kind"] == "span" and e["name"] == "gen_step"]
+    assert span["step"] == 7
     assert span["dur_ms"] >= 0 and span["thread"]
-    counter = next(e for e in events if e["kind"] == "counter")
-    assert counter["name"] == "loss/total"
+    counter, = [e for e in events
+                if e["kind"] == "counter" and e["name"] == "loss/total"]
     assert counter["value"] == 1.25 and counter["step"] == 7
 
 
@@ -100,6 +105,73 @@ def test_same_name_nested_span_not_double_counted(tm_sandbox):
             time.sleep(0.001)
     phases = tm.window_summary()["phases"]
     assert phases["data_wait"]["count"] == 1
+
+
+class _FakeAnnotation:
+    """Stands in for `jax.profiler.TraceAnnotation`: records, in order,
+    what was opened and closed on which thread."""
+
+    log = []
+
+    def __init__(self, name, **kwargs):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("open", self.name,
+                         threading.current_thread().name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("close", self.name,
+                         threading.current_thread().name))
+        return False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    import jax
+
+    monkeypatch.setattr(_FakeAnnotation, "log", [])
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
+    return _FakeAnnotation.log
+
+
+def _nested(tm):
+    with tm.span("outer", step=1):
+        with tm.span("inner", step=1):
+            pass
+    me = threading.current_thread().name
+    return [("open", "imaginaire/outer", me), ("open", "imaginaire/inner", me),
+            ("close", "imaginaire/inner", me),
+            ("close", "imaginaire/outer", me)]
+
+
+def _second_thread(tm):
+    def work():
+        with tm.span("prefetch_host"):
+            pass
+
+    worker = threading.Thread(target=work, name="device-prefetch")
+    worker.start()
+    worker.join(timeout=10)
+    assert not worker.is_alive()
+    return [("open", "imaginaire/prefetch_host", "device-prefetch"),
+            ("close", "imaginaire/prefetch_host", "device-prefetch")]
+
+
+@pytest.mark.parametrize("drive", [_nested, _second_thread])
+def test_span_is_one_profiler_annotation_on_its_thread(
+        tm_sandbox, annotations, drive):
+    tm = telemetry.configure(enabled=True, sinks=[],
+                             flush_every_n_steps=0)
+    assert drive(tm) == annotations
+
+
+def test_disabled_telemetry_opens_no_annotation(annotations):
+    tm = tcore.Telemetry(enabled=False)
+    with tm.span("gen_step", step=1):
+        pass
+    assert annotations == []
 
 
 def test_disabled_singleton_is_noop(tmp_path):
@@ -292,10 +364,10 @@ def test_trace_at_step_knob(tm_sandbox, monkeypatch):
     assert [c[0] for c in calls] == ["start", "stop"]
     assert calls[0][1].endswith("/trace")
     # started exactly at step 3, stopped once step 3+2 was reached
-    spans = [e for e in tm._events if e["kind"] == "meta"]
-    steps = {e["name"]: e["step"] for e in spans}
-    assert steps["trace_started"] == 3
-    assert steps["trace_stopped"] == 5
+    # by name: the replayed compile ledger's metas carry no step
+    steps = {e["name"]: e["step"] for e in tm._events
+             if e["kind"] == "meta" and e["name"].startswith("trace_")}
+    assert steps == {"trace_started": 3, "trace_stopped": 5}
 
 
 def test_window_summary_data_wait_share(tm_sandbox):
@@ -412,11 +484,16 @@ def test_trainer_step_emits_spans_counters_and_mfu(tm_sandbox, tmp_path):
     tm = telemetry.configure(logdir=str(tmp_path), enabled=True,
                              sinks=["jsonl"], flush_every_n_steps=2)
     trainer.init_state(jax.random.PRNGKey(0), batch)
-    for i in range(3):
-        data = trainer.start_of_iteration(batch, i)
+    for i, data in enumerate(tm.timed_iter([batch] * 3, "data_wait")):
+        data = trainer.start_of_iteration(data, i)
         trainer.dis_update(data)
         trainer.gen_update(data)
         trainer.end_of_iteration(data, 0, i + 1)
+    # the feed wait alone is `data_wait`: once for each batch and once
+    # for the end of the feed, not a second time in start_of_iteration
+    phases = tm.window_summary()["phases"]
+    assert phases["data_wait"]["count"] == 4
+    assert phases["start_of_iteration"]["count"] == 3
     tm.shutdown()
 
     events = _read_jsonl(str(tmp_path / "telemetry.jsonl"))
@@ -438,6 +515,114 @@ def test_trainer_step_emits_spans_counters_and_mfu(tm_sandbox, tmp_path):
     assert meta["flops"] > 0
     assert meta["peak_flops"] is None
     assert "device_kind=cpu" in meta["peak_source"]
+
+
+class _ArrayDataset:
+    def __len__(self):
+        return 8
+
+    def __getitem__(self, idx):
+        rng = np.random.RandomState(idx)
+        return {"images": rng.rand(8, 3).astype(np.float32)}
+
+
+@pytest.fixture(scope="module")
+def fed_loop_spans(tmp_path_factory):
+    """The span events of `train.py`'s loop body at a tiny size: a
+    worker-threaded loader behind the trainer's device prefetcher, three
+    iterations. One run for all the cases below."""
+    import jax
+
+    from imaginaire_tpu.data.loader import DataLoader
+
+    old = tcore._TELEMETRY
+    sink = CaptureSink()
+    logdir = str(tmp_path_factory.mktemp("fed_loop"))
+    try:
+        tm = telemetry.configure(logdir=logdir, enabled=True, sinks=[sink],
+                                 flush_every_n_steps=0)
+        trainer = _tiny_trainer(logdir)
+        dataset = _ArrayDataset()
+        loader = DataLoader(dataset, 2, shuffle=False, num_workers=2)
+        trainer.init_state(jax.random.PRNGKey(0),
+                           loader._collate([dataset[0], dataset[1]]))
+        feed = trainer.data_prefetcher(loader, iteration_of=lambda i: i)
+        iteration = 0
+        for data in tm.timed_iter(feed, "data_wait", step_of=lambda i: i):
+            data = trainer.start_of_iteration(data, iteration)
+            trainer.dis_update(data)
+            trainer.gen_update(data)
+            iteration += 1
+            trainer.end_of_iteration(data, 0, iteration)
+        trainer.diag.drain(trainer)
+        tm.flush()
+    finally:
+        tcore._TELEMETRY.shutdown()
+        tcore._TELEMETRY = old
+    assert iteration == 4
+    return {"loop_thread": threading.current_thread().name,
+            "spans": sink.of_kind("span")}
+
+
+@pytest.mark.parametrize("name,thread,count", [
+    ("init_state", "loop", 1),
+    ("health_poll", "loop", 8),        # after each program but the first,
+                                       # and the drain
+    ("end_of_iteration", "loop", 4),
+    ("prefetch_put", "device-prefetch", 4),
+    ("loader_fetch", "loader-worker", 8),
+    ("loader_collate", "loader-producer", 4),
+])
+def test_layer_boundary_spans_of_a_fed_loop(fed_loop_spans, name, thread,
+                                            count):
+    spans = [e for e in fed_loop_spans["spans"] if e["name"] == name]
+    assert len(spans) == count
+    if thread == "loop":
+        thread = fed_loop_spans["loop_thread"]
+    assert all(e["thread"].startswith(thread) for e in spans)
+    # each opens at a layer boundary, under no other span
+    assert all(e["parent"] is None for e in spans)
+
+
+def test_one_iterations_loop_spans_share_its_step(fed_loop_spans):
+    by_step = {}
+    for e in fed_loop_spans["spans"]:
+        if e["thread"] == fed_loop_spans["loop_thread"] \
+                and e["name"] != "init_state":
+            by_step.setdefault(e["step"], []).append(e["name"])
+    assert by_step[1] == ["data_wait", "start_of_iteration", "dis_step",
+                          "health_poll", "gen_step", "health_poll",
+                          "end_of_iteration"]
+
+
+def test_prefetch_transfer_ends_when_the_batch_is_on_the_device(
+        tm_sandbox, monkeypatch):
+    import jax
+
+    from imaginaire_tpu.data.device_prefetch import DevicePrefetcher
+
+    ready_at = []
+    wait_for = jax.block_until_ready
+
+    def slow_ready(tree):
+        time.sleep(0.03)
+        out = wait_for(tree)
+        ready_at.append(time.time())
+        return out
+
+    monkeypatch.setattr(jax, "block_until_ready", slow_ready)
+    sink = CaptureSink()
+    tm = telemetry.configure(enabled=True, sinks=[sink],
+                             flush_every_n_steps=0)
+    batches = [{"images": np.ones((2, 4), np.float32), "key": ["a", "b"]}]
+    out = list(DevicePrefetcher(batches, depth=1))
+    tm.flush()
+    assert isinstance(out[0]["images"], jax.Array)
+    span, = [e for e in sink.of_kind("span")
+             if e["name"] == "prefetch_transfer"]
+    assert len(ready_at) == 1
+    assert span["t"] + span["dur_ms"] / 1e3 >= ready_at[0] - 1e-3
+    assert span["dur_ms"] >= 30.0
 
 
 @pytest.mark.parametrize("kind,peak", [("TPU v5 lite", 197e12),
