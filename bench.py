@@ -1414,7 +1414,6 @@ def _pipeline_cfg(bs=None):
     cfg = Config(ZOO_CONFIG)
     cfg.trainer.perceptual_loss.allow_random_init = True
     cfg.trainer.perceptual_loss.pop("weights_path", None)
-    cfg.data.one_hot_on_device = True
     for split in ("train", "val"):
         cfg.data[split].roots = [packed]
         cfg.data[split].is_packed = True
@@ -1542,10 +1541,10 @@ def run_pipeline_fed():
     alongside as the before/after evidence for the transfer overlap.
 
     Uses the zoo config's own data section (8 workers, is_packed,
-    resize/scale/flip/crop augmentations) plus ``one_hot_on_device``:
-    the host ships (B,256,256) int seg maps + (B,256,256,1) edge maps
-    and the device one-hot expands (the 48MB/img host one-hot transfer
-    would otherwise dominate the host-to-device link). A second bs8 leg
+    resize/scale/flip/crop augmentations): the dataset ships (B,256,256)
+    int seg maps + (B,256,256,1) edge maps and the feed one-hot expands
+    on the device (the 48MB/img host one-hot would otherwise dominate
+    the loader and the host-to-device link). A second bs8 leg
     records the pipeline-fed number at the throughput-optimum batch
     (PROFILE.md round 4); its failure (compiler cap) degrades to the
     bs4-only record rather than failing the bench."""

@@ -81,9 +81,9 @@ def require_tpu(n_chips):
 def derive_config(base_yaml, out_dir, seed, mesh_devices, name,
                   global_batch=None, same_samples=False, n_imgs=64):
     """Write the fixture and a config that differs from ``base_yaml``
-    only in where the data is (``data.*.roots``, ``is_packed``,
-    ``one_hot_on_device``, a ``test_data`` block that is the file's own
-    val split), ``perceptual_loss.allow_random_init``, the
+    only in where the data is (``data.*.roots``, ``is_packed``, a
+    ``test_data`` block that is the file's own val split),
+    ``perceptual_loss.allow_random_init``, the
     iteration/snapshot counters and a mesh of ``mesh_devices`` devices.
     ``global_batch`` and ``same_samples`` are the four-chip comparison's:
     both of its runs train at one global batch, on the file's own
@@ -103,7 +103,6 @@ def derive_config(base_yaml, out_dir, seed, mesh_devices, name,
     packed = make_packed_cocostuff_fixture(
         os.path.join(out_dir, "data"), n_imgs=n_imgs, seed=seed,
         n_classes=n_classes)
-    data["one_hot_on_device"] = True
     for split in ("train", "val"):
         data[split]["roots"] = [packed]
         data[split]["is_packed"] = True
@@ -113,10 +112,8 @@ def derive_config(base_yaml, out_dir, seed, mesh_devices, name,
     if same_samples:
         data["train"]["augmentations"] = copy.deepcopy(
             data["val"]["augmentations"])
-    # the test loop one-hot expands on the host, as the shipped file
-    # does: the inference forward takes the expanded label stack
     test_data = {k: copy.deepcopy(v) for k, v in data.items()
-                 if k not in ("train", "val", "one_hot_on_device")}
+                 if k not in ("train", "val")}
     test_data["test"] = copy.deepcopy(data["val"])
     cfg["test_data"] = test_data
     perceptual = cfg["trainer"]["perceptual_loss"]

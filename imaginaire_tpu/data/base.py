@@ -7,7 +7,9 @@ interpolator / use_dont_care / is_mask / pre+post aug ops
   - 1-channel label maps one-hot expanded to num_channels (+1 dont-care
     channel kept when use_dont_care, ref: base.py:272-298),
   - all ``input_labels`` types concatenated into ``data['label']``
-    (ref: paired_videos.py:276-283).
+    (ref: paired_videos.py:276-283); an image dataset with one leading
+    mask label ships it as its int32 index map instead and the feed
+    builds the same stack on the device (``index_map_label``).
 """
 
 from __future__ import annotations
@@ -74,25 +76,27 @@ class BaseDataset:
             self.is_mask[name] = cfg_get(info, "is_mask", False)
             self.pre_aug_ops[name] = _parse_ops(cfg_get(info, "pre_aug_ops", "None"))
             self.post_aug_ops[name] = _parse_ops(cfg_get(info, "post_aug_ops", "None"))
-        # TPU-native label path: ship (H,W) int index maps to the device
-        # and one-hot there (trainers/base._expand_labels) instead of
-        # building ~num_channels x float32 one-hot tensors on the host —
-        # for COCO-Stuff's 183 classes that is a 0.3MB vs 48MB per-image
-        # host->device transfer (SURVEY.md §7 hard-part #6).
-        self.one_hot_on_device = bool(
-            cfg_get(self.cfgdata, "one_hot_on_device", False))
-        if self.one_hot_on_device and (
-                self.supports_temporal_stride
-                or "video" in str(cfg_get(self.cfgdata, "type", ""))):
-            # video trainers fold past labels into channels on the host
-            # (trainers/vid2vid._start_of_iteration) — int maps would
-            # silently skip that path, so refuse rather than mis-train.
-            # The type-name check also catches video datasets that don't
-            # implement temporal striding (paired_few_shot_videos_native).
-            raise ValueError(
-                "one_hot_on_device is implemented for image datasets "
-                "only; drop the knob for video dataset types")
         self.input_labels = list(cfg_get(self.cfgdata, "input_labels", None) or [])
+        # The mask label type that crosses the host as its (H,W) int32
+        # index map (0.26 MB at 256x256 against 48.5 MB of float32
+        # one-hot for COCO-Stuff's 183 classes); the feed expands it on
+        # the device (``device_prefetch.expand_index_labels``, called by
+        # ``BaseTrainer._on_device``) to exactly the stack
+        # ``_encode_onehot`` builds. Decided from what the dataset sees
+        # of itself: an image dataset whose labels hold exactly one mask
+        # type, first in the list (mask channels lead the stack). Video
+        # types fold past labels into channels on the host
+        # (trainers/vid2vid._start_of_iteration; the type-name test also
+        # catches paired_few_shot_videos_native, which has no temporal
+        # stride) and every other layout encodes on the host.
+        mask_labels = [t for t in self.input_labels
+                       if self.is_mask.get(t, False)]
+        is_video = (self.supports_temporal_stride
+                    or "video" in str(cfg_get(self.cfgdata, "type", "")))
+        self.index_map_label = (
+            mask_labels[0]
+            if (len(mask_labels) == 1 and not is_video
+                and mask_labels[0] == self.input_labels[0]) else None)
         self.input_image = list(cfg_get(self.cfgdata, "input_image", None) or [])
         self.keypoint_data_types = list(
             cfg_get(self.cfgdata, "keypoint_data_types", None) or [])
@@ -250,8 +254,7 @@ class BaseDataset:
                                        and arr.shape[-1] == 1
                                        and self.num_channels[t] > 1
                                        and not vis_output):
-                    if self.one_hot_on_device and self.is_mask[t] \
-                            and t in self.input_labels:
+                    if t == self.index_map_label:
                         arr = self._encode_index_map(
                             arr, self.num_channels[t])
                     else:
@@ -294,26 +297,15 @@ class BaseDataset:
     def concat_labels(self, out, squeeze_time=False):
         """(ref: paired_videos.py:276-283).
 
-        With ``one_hot_on_device`` the single mask label type stays an
-        int index map under ``label`` (channel dim dropped; the trainer
-        one-hot expands it on device) and any remaining float label
-        types concatenate under ``label_float`` — the trainer appends
-        them after the device-side one-hot, preserving the reference's
-        label channel order (mask channels first)."""
-        if self.input_labels and self.one_hot_on_device:
-            mask_types = [t for t in self.input_labels if self.is_mask[t]]
-            if len(mask_types) != 1:
-                raise ValueError(
-                    "one_hot_on_device needs exactly one mask label type, "
-                    f"got {mask_types} — disable the knob for this config")
-            if mask_types[0] != self.input_labels[0]:
-                raise ValueError(
-                    "one_hot_on_device requires the mask label type first "
-                    "in input_labels (channel-order contract)")
-            idx = out.pop(mask_types[0])
-            out["label"] = idx[..., 0]  # (T,H,W) int32
-            floats = [out.pop(t) for t in self.input_labels
-                      if t != mask_types[0]]
+        Where the dataset ships its mask label as an index map
+        (``index_map_label``), ``label`` is that int32 map (channel dim
+        dropped) and the remaining float label types concatenate under
+        ``label_float``: the feed appends them after the device-side
+        one-hot, which keeps the reference's channel order (mask
+        channels first)."""
+        if self.index_map_label is not None:
+            out["label"] = out.pop(self.index_map_label)[..., 0]  # (T,H,W)
+            floats = [out.pop(t) for t in self.input_labels[1:]]
             if floats:
                 out["label_float"] = np.concatenate(floats, axis=-1)
         elif self.input_labels:
@@ -322,8 +314,9 @@ class BaseDataset:
         if squeeze_time:
             for k in list(out.keys()):
                 v = out[k]
-                min_ndim = 3 if (k == "label" and self.one_hot_on_device) \
-                    else 4  # int index maps carry no channel dim
+                # the index map carries no channel dim
+                min_ndim = 3 if (k == "label" and self.index_map_label) \
+                    else 4
                 if isinstance(v, np.ndarray) and v.ndim >= min_ndim:
                     out[k] = v[0] if v.shape[0] == 1 else v
         return out

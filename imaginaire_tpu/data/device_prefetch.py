@@ -17,10 +17,17 @@ laid out for the SPMD step program — no post-hoc redistribution inside
 jit. A bounded queue keeps up to ``depth`` batches resident on device
 ahead of the consumer.
 
+A dataset's mask label crosses the host as its int32 index map
+(``BaseDataset.index_map_label``); ``expand_index_labels`` turns it into
+the float32 channel stack on the device. The trainer hands it in as
+``on_device`` (``BaseTrainer._on_device``) and the producer enqueues it
+right after the placement, without waiting for it.
+
 Observability: per-batch ``data/host_wait_ms`` (producer blocked on the
 host loader), ``data/transfer_ms`` (placement, to the batch being on the
-device) and ``data/queue_depth`` (ready batches at consume time)
-accumulate in a lock-guarded buffer; ``drain_stats()`` hands them to the trainer's
+device), ``data/h2d_mb`` (what the placement was handed) and
+``data/queue_depth`` (ready batches at consume time) accumulate in a
+lock-guarded buffer; ``drain_stats()`` hands them to the trainer's
 meters, flushed on ``logging_iter`` with the loss meters — nothing here
 ever blocks the step loop on a device sync.
 
@@ -60,6 +67,67 @@ class PrefetchedBatch(dict):
     preprocess + transfer (``BaseTrainer.start_of_iteration`` does)."""
 
 
+_EXPAND_PROGRAMS = {}
+
+
+def expand_index_labels(data, num_label_channels):
+    """A placed batch whose ``label`` is the dataset's integer index map
+    -> the same batch with ``label`` the float32 (B,H,W,C) stack of
+    ``num_label_channels`` the host encoding builds: the one-hot of the
+    mask channels (exactly ``BaseDataset._encode_onehot``'s 0.0 / 1.0;
+    an index past the last channel, the dropped dont-care, is
+    ``one_hot``'s zero row) followed by ``label_float``, which leaves
+    the batch. Any other batch comes back as it is.
+
+    One small device program, enqueued and not waited for. Its output's
+    sharding is stated: the batch dimension over the axis the placed
+    label is laid on, as ``place_committed_batch`` lays a host-encoded
+    stack; an uncommitted label (``to_device``) gives an uncommitted
+    stack. One ledgered program per layout, so an evaluation batch after
+    a training batch is not read as a recompile."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    label = data.get("label") if isinstance(data, dict) else None
+    if label is None or not jnp.issubdtype(label.dtype, jnp.integer):
+        return data
+    floats = data.get("label_float")
+    num_label_channels = int(num_label_channels)
+    num_mask_channels = num_label_channels - (
+        0 if floats is None else floats.shape[-1])
+    sharding = None
+    if isinstance(getattr(label, "sharding", None), NamedSharding):
+        lead = tuple(label.sharding.spec)[:1]
+        sharding = NamedSharding(
+            label.sharding.mesh,
+            P(*lead, *([None] * label.ndim)) if lead else P())
+    key = (num_label_channels, num_mask_channels, sharding)
+    program = _EXPAND_PROGRAMS.get(key)
+    if program is None:
+        from imaginaire_tpu.telemetry import xla_obs
+
+        def expand(label, floats):
+            # written into the whole stack's buffer: a concatenate keeps
+            # the one-hot as a second, temporary stack (193 MB at
+            # 4x256x256x184, the v5e compiler's memory analysis). The
+            # dropped dont-care index is the first float channel's: set
+            # over it
+            stack = jax.nn.one_hot(label, num_label_channels,
+                                   dtype=jnp.float32)
+            if floats is not None:
+                stack = stack.at[..., num_mask_channels:].set(
+                    floats.astype(jnp.float32))
+            return stack
+
+        program = _EXPAND_PROGRAMS.setdefault(key, xla_obs.compiled_program(
+            "expand_labels", expand, allow_shape_growth=True,
+            out_shardings=sharding))
+    out = dict(data, label=program(label, floats))
+    out.pop("label_float", None)
+    return out
+
+
 def prefetch_settings(cfg):
     """(enabled, depth) from the ``data.device_prefetch`` config knob.
 
@@ -88,6 +156,10 @@ class DevicePrefetcher:
             host-side ``_start_of_iteration`` hook. ``index`` counts
             batches within the current iteration pass, so callers can
             derive the consuming iteration number.
+        on_device: optional ``fn(batch) -> batch`` run on the placed
+            numeric leaves: device programs (the trainer's
+            ``_on_device``: the label expansion), enqueued behind
+            whatever step is running and not waited for.
         depth: number of batches kept resident on device ahead of the
             consumer (the queue bound).
         mesh: mesh for the committed batch sharding; defaults to the
@@ -96,9 +168,10 @@ class DevicePrefetcher:
     """
 
     def __init__(self, loader, host_preprocess=None, depth=2, mesh=None,
-                 axis="data"):
+                 axis="data", on_device=None):
         self.loader = loader
         self.host_preprocess = host_preprocess
+        self.on_device = on_device
         self.depth = max(int(depth), 1)
         self.mesh = mesh
         self.axis = axis
@@ -146,23 +219,35 @@ class DevicePrefetcher:
     # ------------------------------------------------------------ pipeline
 
     def _transfer(self, batch):
-        """Split host-only leaves out, commit the numeric remainder as
-        sharded device arrays, re-merge. Non-dict batches place whole.
-        Returns once the batch IS on the device: the producer thread
-        waits, off the step path, so a queued batch is a resident one
-        and ``prefetch_transfer`` is the H2D time."""
+        """Split host-only leaves out and commit the numeric remainder
+        as sharded device arrays. Non-dict batches place whole. Returns
+        (placed, host) once the batch IS on the device: the producer
+        thread waits, off the step path, so a queued batch is a resident
+        one and ``prefetch_transfer`` is the H2D time."""
         import jax
 
         from imaginaire_tpu.parallel.sharding import place_committed_batch
-        from imaginaire_tpu.utils.misc import merge_host_leaves, \
-            split_host_leaves
+        from imaginaire_tpu.utils.misc import split_host_leaves
 
-        if not isinstance(batch, dict):
-            return jax.block_until_ready(place_committed_batch(
-                batch, mesh=self.mesh, axis=self.axis))
         numeric, host = split_host_leaves(batch)
-        placed = jax.block_until_ready(place_committed_batch(
-            numeric, mesh=self.mesh, axis=self.axis))
+        self._record("data/h2d_mb", sum(
+            getattr(x, "nbytes", 0)
+            for x in jax.tree_util.tree_leaves(numeric)) / 1e6)
+        return jax.block_until_ready(place_committed_batch(
+            numeric, mesh=self.mesh, axis=self.axis)), host
+
+    def _finish(self, placed, host, tm):
+        """The placed leaves through ``on_device`` (enqueued, not waited
+        for: the device runs it in order before the step that reads its
+        output, and waiting here would tie the feed's period to the
+        device's backlog), then re-merged with the host-only leaves."""
+        from imaginaire_tpu.utils.misc import merge_host_leaves
+
+        if not isinstance(placed, dict):
+            return placed
+        if self.on_device is not None:
+            with tm.span("prefetch_expand"):
+                placed = self.on_device(placed)
         return PrefetchedBatch(merge_host_leaves(placed, host))
 
     def __iter__(self):
@@ -180,10 +265,10 @@ class DevicePrefetcher:
 
         def produce():
             # producer-side telemetry spans (prefetch_host / _preprocess
-            # / _transfer / _put) are tagged with this thread's name: the
-            # hang watchdog's stack dump and the phase table both show
-            # where the pipeline actually spends its time, off the step
-            # path
+            # / _transfer / _expand / _put) are tagged with this thread's
+            # name: the hang watchdog's stack dump and the phase table
+            # both show where the pipeline actually spends its time, off
+            # the step path
             from imaginaire_tpu import telemetry
 
             tm = telemetry.get()
@@ -210,9 +295,10 @@ class DevicePrefetcher:
                             batch = self.host_preprocess(batch, index)
                     t1 = time.perf_counter()
                     with tm.span("prefetch_transfer"):
-                        batch = self._transfer(batch)
+                        placed, host = self._transfer(batch)
                     self._record("data/transfer_ms",
                                  (time.perf_counter() - t1) * 1e3)
+                    batch = self._finish(placed, host, tm)
                     with tm.span("prefetch_put"):
                         put(batch)
                     index += 1

@@ -451,19 +451,27 @@ class CompiledProgram:
     """
 
     def __init__(self, label, fn, donate_argnums=(),
-                 allow_shape_growth=False):
-        import jax
-
+                 allow_shape_growth=False, out_shardings=None):
         self.label = label
         self._fn = fn
         self._donate_argnums = donate_argnums
-        self._jit = jax.jit(fn, donate_argnums=donate_argnums)
+        self._out_shardings = out_shardings
+        self._jit = self._build_jit()
         self._allow_shape_growth = bool(allow_shape_growth)
         self._executables = {}
         self._fingerprints = {}
         self._last_fp = None
         self._pending_reason = None
         self._passthrough = not _SETTINGS.enabled
+
+    def _build_jit(self):
+        import jax
+
+        # out_shardings=None is jit's own default: the compiler decides
+        kwargs = {} if self._out_shardings is None else {
+            "out_shardings": self._out_shardings}
+        return jax.jit(self._fn, donate_argnums=self._donate_argnums,
+                       **kwargs)
 
     # jax.jit surface the rest of the repo relies on
     def lower(self, *args, **kwargs):
@@ -497,10 +505,7 @@ class CompiledProgram:
         except Exception as e:  # noqa: BLE001 — older jax spellings
             logger.warning("retrace(%s): clear_cache failed (%s); "
                            "rebuilding the jit wrapper", self.label, e)
-            import jax
-
-            self._jit = jax.jit(self._fn,
-                                donate_argnums=self._donate_argnums)
+            self._jit = self._build_jit()
         _telemetry().meta("xla_retrace", label=self.label, reason=reason)
 
     def aot_compile(self, *args):
@@ -646,12 +651,14 @@ class CompiledProgram:
 
 
 def compiled_program(label, fn, donate_argnums=(),
-                     allow_shape_growth=False):
+                     allow_shape_growth=False, out_shardings=None):
     """Register ``fn`` as the labeled program ``label`` (see
     ``CompiledProgram``). The drop-in for ``jax.jit(fn,
-    donate_argnums=...)`` at every named compile site."""
+    donate_argnums=..., out_shardings=...)`` at every named compile
+    site."""
     return CompiledProgram(label, fn, donate_argnums=donate_argnums,
-                           allow_shape_growth=allow_shape_growth)
+                           allow_shape_growth=allow_shape_growth,
+                           out_shardings=out_shardings)
 
 
 def _run_audit(label, traced, lowered, compiled):

@@ -28,6 +28,7 @@ TPU-first redesign:
 
 from __future__ import annotations
 
+import functools
 import math
 import os
 import time
@@ -175,9 +176,12 @@ class BaseTrainer:
         return {}
 
     def _init_data(self, data):
-        """Hook: device-side data prep applied before module init (e.g.
-        int-label one-hot expansion). Default: identity."""
-        return data
+        """The example batch as the modules' inits take it: on the
+        device, through ``_on_device`` (a no-op for a batch
+        ``start_of_iteration`` already returned)."""
+        from imaginaire_tpu.utils.misc import to_device
+
+        return self._on_device(to_device(dict(data)))
 
     def _fake_output_for_init(self, data):
         """Shape-example generator output used to init the discriminator
@@ -538,14 +542,40 @@ class BaseTrainer:
             self.current_iteration = current_iteration
             self.start_iteration_time = time.time()
             if prefetched:
-                # a DevicePrefetcher already ran the host hook and
+                # a DevicePrefetcher already ran the host hook,
                 # committed the numeric leaves as sharded device arrays
-                # — re-running either would drag them back through the
-                # host
+                # and ran ``_on_device`` — re-running any of them would
+                # drag them back through the host
                 return data
             from imaginaire_tpu.utils.misc import to_device
 
-            return to_device(data)
+            return self._on_device(to_device(data))
+
+    def _on_device(self, data):
+        """What every dataset batch passes right after it is placed,
+        whichever path placed it (the prefetcher, the synchronous arm,
+        evaluation, the inits, the visualizations): a dataset's index
+        map ``label`` becomes the float32 channel stack, so the step
+        programs, the inference forward and the serving engine take the
+        arrays a host-encoded batch gives. Device work only, enqueued
+        and not waited for; a trainer whose hook reads the stack's
+        channels extends this (pix2pixHD)."""
+        from imaginaire_tpu.data.device_prefetch import expand_index_labels
+
+        # a config without label types has no stack to build
+        channels = self._label_channels
+        return expand_index_labels(data, channels) if channels else data
+
+    @functools.cached_property
+    def _label_channels(self):
+        """Channels of the label stack the config's data section names
+        (0 without label types); read once, ``_on_device`` runs on every
+        batch."""
+        from imaginaire_tpu.utils.data import (
+            get_paired_input_label_channel_number,
+        )
+
+        return get_paired_input_label_channel_number(self.cfg.data)
 
     def data_prefetcher(self, loader, iteration_of=None):
         """Wrap ``loader`` in a DevicePrefetcher honoring the
@@ -573,7 +603,7 @@ class BaseTrainer:
             return self._start_of_iteration(batch, it)
 
         return DevicePrefetcher(loader, host_preprocess=host_preprocess,
-                                depth=depth)
+                                depth=depth, on_device=self._on_device)
 
     def write_data_meters(self, stats):
         """Record drained DevicePrefetcher stats ({meter: [floats]}) —
@@ -596,7 +626,8 @@ class BaseTrainer:
             return data
         from imaginaire_tpu.parallel.sharding import place_committed_batch
 
-        return place_committed_batch(self._start_of_iteration(data, -1))
+        return self._on_device(
+            place_committed_batch(self._start_of_iteration(data, -1)))
 
     def end_of_iteration(self, data, current_epoch, current_iteration):
         """(ref: base.py:294-373)."""
@@ -1478,8 +1509,8 @@ class BaseTrainer:
                 break
             # side-effect-free preprocessing: start_of_iteration would
             # reset the iteration's timers mid-metrics
-            data = to_device(self._start_of_iteration(
-                data, self.current_iteration))
+            data = self._on_device(to_device(self._start_of_iteration(
+                data, self.current_iteration)))
             _, new_mut = self._apply_G(ema_vars, numeric_only(data),
                                        jax.random.fold_in(rng, it),
                                        training=True)

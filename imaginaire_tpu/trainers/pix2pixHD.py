@@ -8,9 +8,9 @@ checkpoint is written, instance features are K-means clustered so
 multi-modal inference can sample cluster centers
 (ref: pix2pixHD.py:159-173, model_utils/pix2pixHD.py:17-71).
 
-TPU-first: the edge map is pure jnp shifts (no host loop), computed in
-``_start_of_iteration`` alongside the device upload; the cluster pass
-reuses the jitted encoder apply.
+TPU-first: the edge map is pure jnp shifts (no host loop), computed on
+the device right after the feed has placed the batch and expanded its
+label (``_on_device``); the cluster pass reuses the jitted encoder apply.
 """
 
 from __future__ import annotations
@@ -58,12 +58,10 @@ class Trainer(SPADETrainer):
                                         axis=-1)
         return data
 
-    def _init_data(self, data):
-        return self.pre_process(super()._init_data(data))
-
-    def _start_of_iteration(self, data, current_iteration):
-        return self.pre_process(
-            super()._start_of_iteration(data, current_iteration))
+    def _on_device(self, data):
+        # reads the stack's channels, so it follows the expansion; the
+        # host hook stays SPADE's crop, on the compact batch
+        return self.pre_process(super()._on_device(data))
 
     # --------------------------------------------------------- checkpoints
 

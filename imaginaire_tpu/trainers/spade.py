@@ -63,49 +63,13 @@ class Trainer(BaseTrainer):
 
     # ------------------------------------------------------------ forwards
 
-    def _expand_labels(self, data):
-        """On-device one-hot for integer label maps (traced under jit).
-
-        TPU-idiomatic data path: the host ships (B,H,W) int labels
-        (~KB) instead of (B,H,W,C) one-hot floats (~C× more H2D
-        bandwidth — at COCO's 184 classes that is the difference between
-        a 0.3MB and a 48MB transfer per image). Float label tensors pass
-        through untouched (the reference's host-side one-hot,
-        ref: datasets/base.py:272).
-        """
-        label = data.get("label")
-        if label is None or not jnp.issubdtype(label.dtype, jnp.integer):
-            return data
-        from imaginaire_tpu.utils.data import get_paired_input_label_channel_number
-
-        n = get_paired_input_label_channel_number(self.cfg.data)
-        extra = data.get("label_float")
-        if extra is not None:
-            # datasets with one_hot_on_device ship non-mask label types
-            # (e.g. COCO edge maps) separately; they occupy the trailing
-            # channels, mask one-hot first (data/base.concat_labels)
-            n = n - extra.shape[-1]
-        onehot = jax.nn.one_hot(label, n, dtype=self.compute_dtype)
-        if extra is not None:
-            onehot = jnp.concatenate(
-                [onehot, extra.astype(onehot.dtype)], axis=-1)
-        out = dict(data, label=onehot)
-        out.pop("label_float", None)
-        return out
-
-    def _init_data(self, data):
-        return self._expand_labels(
-            to_device(dict(data)))
-
     def _apply_G(self, vars_G, data, rng, training, random_style=False):
-        data = self._expand_labels(data)
         out, new_mut = self.net_G.apply(
             vars_G, data, training=training, random_style=random_style,
             rngs={"noise": rng}, mutable=list(MUTABLE))
         return out, new_mut
 
     def _apply_D(self, vars_D, data, net_G_output, training, mutable=False):
-        data = self._expand_labels(data)
         if mutable:
             return self.net_D.apply(vars_D, data, net_G_output,
                                     training=training, mutable=list(MUTABLE))
@@ -186,8 +150,8 @@ class Trainer(BaseTrainer):
 
         base = self.base
         out = dict(data)
-        # label_float rides alongside int label maps (one_hot_on_device
-        # datasets) and must stay spatially aligned for the device concat
+        # label_float rides alongside the dataset's index-map label and
+        # must stay spatially aligned for the feed's device concat
         for key in ("label", "images", "label_float"):
             if key in out:
                 arr = np.asarray(out[key])
@@ -264,8 +228,7 @@ class Trainer(BaseTrainer):
     def _get_visualizations(self, data):
         """(input, label-viz, fake, [ema-fake]) strip
         (ref: trainers/spade.py:189-215)."""
-        data = self._expand_labels(
-            to_device(dict(data)))
+        data = self._on_device(to_device(dict(data)))
         rng = jax.random.PRNGKey(0)
         out, _ = self._apply_G(self.state["vars_G"], data, rng,
                                training=False, random_style=True)

@@ -25,27 +25,48 @@ def cfg():
     return c
 
 
+def _host_encoding(ds):
+    """``ds`` made to one-hot encode on the host, as every dataset did
+    before the index map: what the fed batch is compared against. No
+    config selects this."""
+    ds.index_map_label = None
+    return ds
+
+
 class TestPairedImages:
     def test_item_shapes_and_ranges(self, cfg):
         ds = PairedImages(cfg)
         assert len(ds) == 3
         item = ds[0]
-        # 12 seg + 1 dont-care + 1 edge = 14 label channels.
-        assert item["label"].shape == (256, 256, 14)
+        # the one mask label leads the list: it ships as its index map
+        # (12 seg classes + dont-care = index 12), the edge map beside it
+        assert item["label"].shape == (256, 256)
+        assert item["label"].dtype == np.int32
+        assert item["label"].min() >= 0 and item["label"].max() <= 12
+        assert item["label_float"].shape == (256, 256, 1)
         assert item["images"].shape == (256, 256, 3)
         assert item["images"].min() >= -1.0 and item["images"].max() <= 1.0
+        assert item["key"].startswith("seq0001/")
+
+    def test_host_encoded_item_is_one_hot(self, cfg):
+        ds = _host_encoding(PairedImages(cfg))
+        item = ds[0]
+        # 12 seg + 1 dont-care + 1 edge = 14 label channels.
+        assert item["label"].shape == (256, 256, 14)
+        assert "label_float" not in item
         # one-hot: each pixel's seg channels sum to 1
         seg = item["label"][..., :13]
         np.testing.assert_allclose(seg.sum(-1), 1.0)
-        assert item["key"].startswith("seq0001/")
 
     def test_dont_care_encoding(self, cfg):
         ds = PairedImages(cfg)
         # fixture writes 255 into the top-left corner -> dont-care channel 12
-        cfg.data.val.augmentations = {"center_crop_h_w": "256, 256"}
+        cfg.data.val.augmentations = {"resize_h_w": "256, 256"}
         ds_val = PairedImages(cfg, is_inference=True)
-        item = ds_val[0]
+        assert ds_val[0]["label"][0, 0] == 12
+        item = _host_encoding(ds_val)[0]
         assert item["label"].shape[-1] == 14
+        assert item["label"][0, 0, 12] == 1.0
 
     def test_label_lengths(self, cfg):
         ds = PairedImages(cfg)
@@ -63,7 +84,8 @@ class TestLoader:
         train, val = get_train_and_val_dataloader(cfg)
         batch = next(iter(train))
         assert batch["images"].shape == (1, 256, 256, 3)
-        assert batch["label"].shape == (1, 256, 256, 14)
+        assert batch["label"].shape == (1, 256, 256)
+        assert batch["label_float"].shape == (1, 256, 256, 1)
         assert len(train) == 3
 
     def test_epoch_reshuffle(self, cfg):
@@ -325,51 +347,96 @@ class TestTemporalStride:
 
 
 class TestOneHotOnDevice:
-    """one_hot_on_device: the host ships int index maps + float extras
-    and the trainer's device-side one-hot must reproduce the host
-    encoding exactly (data/base.py::_encode_index_map,
-    trainers/spade.py::_expand_labels)."""
+    """The dataset ships its one leading mask label as an int32 index
+    map plus float extras, and the feed's device-side one-hot must
+    reproduce the host encoding exactly (data/base.py::_encode_index_map,
+    data/device_prefetch.py::expand_index_labels behind
+    BaseTrainer._on_device)."""
 
     def _pair(self, cfg):
-        cfg.data.val.augmentations = {"center_crop_h_w": "256, 256"}
-        host = PairedImages(cfg, is_inference=True)
-        cfg.data.one_hot_on_device = True
+        # no crop: the fixture's out-of-range corner (255) stays in view
+        cfg.data.val.augmentations = {"resize_h_w": "256, 256"}
+        host = _host_encoding(PairedImages(cfg, is_inference=True))
         dev = PairedImages(cfg, is_inference=True)
         return host[0], dev[0]
 
     def test_matches_host_onehot(self, cfg):
-        import jax.numpy as jnp
-
+        """No knob: the unit-test config as shipped emits the index map."""
         a, b = self._pair(cfg)
         assert b["label"].dtype == np.int32
         assert b["label"].shape == (256, 256)
         assert b["label_float"].shape == (256, 256, 1)
-        # device-side expansion: 13 = 12 seg + dont-care
-        onehot = np.asarray(jnp.asarray(
-            np.eye(13, dtype=np.float32)[b["label"]]))
+        # 13 = 12 seg + dont-care
+        onehot = np.eye(13, dtype=np.float32)[b["label"]]
         recombined = np.concatenate([onehot, b["label_float"]], axis=-1)
         np.testing.assert_array_equal(recombined, a["label"])
 
-    def test_trainer_expand_labels_parity(self, cfg):
-        """End-to-end through the SPADE trainer's _expand_labels."""
-        import jax
+    @pytest.mark.parametrize("use_dont_care", [True, False])
+    def test_fed_label_is_the_host_stack_bit_for_bit(self, cfg,
+                                                     use_dont_care):
+        """Through the trainer's feed: float32, the host encoding's
+        shape and values exactly, out-of-range indices included (the
+        fixture writes 255 into the top-left corner)."""
+        from imaginaire_tpu.data.base import BaseDataset
         from imaginaire_tpu.registry import resolve
 
+        for spec in cfg.data.input_types:
+            if "seg_maps" in spec:
+                spec["seg_maps"]["use_dont_care"] = use_dont_care
         a, b = self._pair(cfg)
+        assert b["label"][0, 0] == 12  # out of range -> dont-care index
+        want = np.concatenate(
+            [BaseDataset._encode_onehot(b["label"][..., None], 12,
+                                        use_dont_care), b["label_float"]],
+            axis=-1)
+        assert want.shape[-1] == (14 if use_dont_care else 13)
+        np.testing.assert_array_equal(want, a["label"])
         trainer = resolve(cfg.trainer.type, "Trainer")(cfg)
-        data = {"label": jax.numpy.asarray(b["label"][None]),
-                "label_float": jax.numpy.asarray(b["label_float"][None])}
-        out = trainer._expand_labels(data)
+        out = trainer.start_of_iteration(
+            {"images": b["images"][None], "label": b["label"][None],
+             "label_float": b["label_float"][None]}, 0)
         assert "label_float" not in out
-        np.testing.assert_allclose(np.asarray(out["label"]),
-                                   a["label"][None], atol=1e-6)
+        assert out["label"].dtype == np.float32
+        assert out["label"].shape == (1,) + want.shape
+        np.testing.assert_array_equal(np.asarray(out["label"]), want[None])
+        if not use_dont_care:
+            assert not np.asarray(out["label"])[0, 0, 0].any()
 
-    def test_video_types_refuse_knob(self):
+    def test_video_types_encode_on_the_host(self):
+        """A video dataset folds past labels into channels on the host:
+        it keeps the one-hot stack, and no longer raises."""
         from imaginaire_tpu.data.paired_videos import Dataset as PairedVideos
 
         cfg = Config(os.path.join(os.path.dirname(__file__), "..", "configs",
                                   "unit_test", "vid2vid_street.yaml"))
         cfg.data.train.roots = [FIXTURES]
-        cfg.data.one_hot_on_device = True
-        with pytest.raises(ValueError, match="image datasets only"):
-            PairedVideos(cfg)
+        ds = PairedVideos(cfg)
+        assert [t for t in ds.input_labels if ds.is_mask[t]]
+        assert ds.index_map_label is None
+        item = ds[0]
+        assert item["label"].dtype == np.float32
+        assert item["label"].ndim == 4  # (T, H, W, C)
+        assert item["label"].shape[-1] == sum(
+            ds.get_label_lengths().values())
+        assert "label_float" not in item
+
+    @pytest.mark.parametrize("layout", ["two_masks", "mask_not_first"])
+    def test_other_label_layouts_encode_on_the_host(self, cfg, layout):
+        """Two mask types, or the mask not leading the list: the channel
+        order is not the index map's, so the dataset encodes as before."""
+        if layout == "two_masks":
+            for spec in cfg.data.input_types:
+                if "edge_maps" in spec:
+                    spec["edge_maps"]["is_mask"] = True
+        else:
+            cfg.data.input_labels = ["edge_maps", "seg_maps"]
+        ds = PairedImages(cfg)
+        assert ds.index_map_label is None
+        item = ds[0]
+        assert item["label"].dtype == np.float32
+        assert item["label"].shape == (256, 256, 14)
+        assert "label_float" not in item
+        # the seg one-hot (12 + dont-care) sits where the list puts it
+        seg = item["label"][..., 1:] if layout == "mask_not_first" \
+            else item["label"][..., :13]
+        np.testing.assert_allclose(seg.sum(-1), 1.0)

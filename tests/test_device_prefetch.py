@@ -7,6 +7,7 @@ crop-barrier ordering when stacked on a worker-threaded loader
 (mirrors tests/test_person_crop_pipeline.py::TestFirstWindowBarrier at
 prefetch depth > 1)."""
 
+import os
 import threading
 import time
 
@@ -356,3 +357,192 @@ class TestFirstWindowBarrierThroughPrefetch:
         assert n == 2 and len(used_coords) == t
         assert len(set(used_coords)) == 1, \
             f"every frame must reuse frame 0's bbox, got {set(used_coords)}"
+
+
+# ------------------------------------------------- index-map labels (feed)
+
+_CFGS = os.path.join(os.path.dirname(__file__), "..", "configs", "unit_test")
+_FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures", "spade", "raw")
+
+
+def _image_cfg(name, batch_size=8):
+    from imaginaire_tpu.config import Config
+
+    cfg = Config(os.path.join(_CFGS, name))
+    for split in ("train", "val"):
+        cfg.data[split].roots = [_FIXTURES]
+        cfg.data[split].batch_size = batch_size
+    cfg.data.val.augmentations = {"resize_h_w": "64, 64"}
+    return cfg
+
+
+def _val_loaders(cfg, batch_size=8):
+    """(compact, host): the config's validation loader as the dataset
+    ships it (index-map label) and made to one-hot encode on the host,
+    as every dataset did before; the 3 fixture frames cycle to a full
+    batch."""
+    from imaginaire_tpu.data.loader import DataLoader
+    from imaginaire_tpu.data.paired_images import Dataset
+
+    class Cycled(Dataset):
+        def __len__(self):
+            return 2 * batch_size
+
+    compact, host = (Cycled(cfg, is_inference=True) for _ in range(2))
+    assert compact.index_map_label == "seg_maps"
+    host.index_map_label = None
+    return tuple(DataLoader(ds, batch_size, shuffle=False)
+                 for ds in (compact, host))
+
+
+def _through(trainer, loader, path):
+    """The loader's first batch as ``path`` hands it to a step."""
+    if path == "prefetcher":
+        feed = iter(trainer.data_prefetcher(loader))
+        try:
+            return trainer.start_of_iteration(next(feed), 0)
+        finally:
+            feed.close()
+    batch = next(iter(loader))
+    if path == "synchronous":
+        return trainer.start_of_iteration(batch, 0)
+    from imaginaire_tpu.utils.misc import numeric_only
+
+    return trainer._eval_preprocess(numeric_only(batch))
+
+
+def _leaves(batch):
+    from imaginaire_tpu.utils.misc import numeric_only
+
+    return {jax.tree_util.keystr(path): leaf for path, leaf in
+            jax.tree_util.tree_flatten_with_path(numeric_only(batch))[0]}
+
+
+def _assert_same_leaves(got, want):
+    """Names, shapes, dtypes, shardings and values: what a step
+    program's signature and result are made of."""
+    from imaginaire_tpu.telemetry.xla_obs import fingerprint
+
+    got, want = _leaves(got), _leaves(want)
+    assert fingerprint(got)[1] == fingerprint(want)[1]
+    for name in want:
+        assert got[name].sharding == want[name].sharding, name
+        assert got[name].committed == want[name].committed, name
+        np.testing.assert_array_equal(np.asarray(got[name]),
+                                      np.asarray(want[name]), err_msg=name)
+
+
+class TestIndexMapLabelsThroughTheFeed:
+    @pytest.mark.parametrize("path", ["prefetcher", "synchronous",
+                                      "evaluation"])
+    def test_fed_batch_is_the_host_encoded_batch(self, data_mesh, path):
+        """SPADE: whichever path places the batch, the steps get the
+        leaves a host-encoded batch gives, so their signatures (and
+        cache entries) are unchanged."""
+        from imaginaire_tpu.registry import resolve
+
+        cfg = _image_cfg("spade.yaml")
+        trainer = resolve(cfg.trainer.type, "Trainer")(cfg)
+        compact, host = _val_loaders(cfg)
+        raw = next(iter(compact))
+        assert raw["label"].dtype == np.int32 and "label_float" in raw
+        got = _through(trainer, compact, path)
+        want = _through(trainer, host, path)
+        assert sorted(_leaves(got)) == ["['images']", "['is_flipped']",
+                                        "['label']"]
+        assert got["label"].dtype == np.float32
+        assert got["label"].shape == (8, 64, 64, 14)
+        if path != "synchronous":
+            assert got["label"].sharding == NamedSharding(
+                data_mesh, P("data", None, None, None))
+        _assert_same_leaves(got, want)
+
+    @pytest.mark.parametrize("path", ["prefetcher", "synchronous"])
+    def test_pix2pixhd_pre_process_follows_the_expansion(self, data_mesh,
+                                                         path):
+        """pix2pixHD's hook reads the stack's channels: same ``label``
+        (edges in the last channel) and ``instance_maps`` as from a
+        host-encoded batch."""
+        from imaginaire_tpu.registry import resolve
+
+        cfg = _image_cfg("pix2pixHD.yaml")
+        trainer = resolve(cfg.trainer.type, "Trainer")(cfg)
+        compact, host = _val_loaders(cfg)
+        got = _through(trainer, compact, path)
+        want = _through(trainer, host, path)
+        assert got["instance_maps"].dtype == np.int32
+        assert got["label"].shape == (8, 64, 64, 9)
+        _assert_same_leaves(got, want)
+        # what the parent's host hook built: pre_process on the host
+        # stack, before the placement
+        old = trainer.pre_process(
+            trainer._resize_data(next(iter(host))))
+        for name in ("label", "instance_maps"):
+            np.testing.assert_array_equal(np.asarray(got[name]),
+                                          np.asarray(old[name]))
+
+    def test_expansion_is_enqueued_in_the_producer_and_metered(
+            self, data_mesh):
+        """One ``prefetch_expand`` span a batch on the producer thread,
+        ``data/h2d_mb`` the compact bytes, and one ledgered program."""
+        from imaginaire_tpu import telemetry
+        from imaginaire_tpu.registry import resolve
+        from imaginaire_tpu.telemetry import xla_obs
+
+        cfg = _image_cfg("spade.yaml")
+        trainer = resolve(cfg.trainer.type, "Trainer")(cfg)
+        compact, host = _val_loaders(cfg)
+        spans = []
+        tm = telemetry.get()
+        real_span = tm.span
+
+        def span(name, **kw):
+            spans.append((name, threading.current_thread().name))
+            return real_span(name, **kw)
+
+        tm.span = span
+        mark = xla_obs.ledger().snapshot()
+        try:
+            feed = trainer.data_prefetcher(compact)
+            batches = list(feed)
+        finally:
+            del tm.span
+        assert len(batches) == 2
+        assert [s for s in spans if s[0] == "prefetch_expand"] == \
+            [("prefetch_expand", "device-prefetch")] * 2
+        order = [s[0] for s in spans if s[0].startswith("prefetch_")][:5]
+        assert order == ["prefetch_host", "prefetch_preprocess",
+                         "prefetch_transfer", "prefetch_expand",
+                         "prefetch_put"]
+        stats = feed.drain_stats()
+        compact_mb = sum(v.nbytes for v in next(iter(compact)).values()
+                         if isinstance(v, np.ndarray)) / 1e6
+        host_mb = sum(v.nbytes for v in next(iter(host)).values()
+                      if isinstance(v, np.ndarray)) / 1e6
+        assert stats["data/h2d_mb"] == pytest.approx([compact_mb] * 2)
+        assert host_mb > 3 * compact_mb
+        delta = xla_obs.snapshot_delta(mark)
+        assert delta["recompiles"] == 0
+
+    def test_inference_path_gives_the_same_images(self, data_mesh,
+                                                  tmp_path):
+        """``BaseTrainer.test`` (``inference.py``'s loop) on the
+        unit-test SPADE config: the compact dataset gives the images a
+        host-encoded one gives, byte for byte."""
+        from imaginaire_tpu.registry import resolve
+
+        cfg = _image_cfg("spade.yaml")
+        trainer = resolve(cfg.trainer.type, "Trainer")(cfg)
+        compact, host = _val_loaders(cfg)
+        sample = trainer.start_of_iteration(next(iter(compact)), 0)
+        trainer.init_state(jax.random.PRNGKey(0), sample)
+        written = {}
+        for name, loader in (("compact", compact), ("host", host)):
+            out = tmp_path / name
+            trainer.test(loader, str(out))
+            written[name] = {
+                os.path.relpath(os.path.join(d, f), out):
+                    open(os.path.join(d, f), "rb").read()
+                for d, _, files in os.walk(out) for f in files}
+        assert len(written["compact"]) == 3  # the fixture's 3 frames
+        assert written["compact"] == written["host"]
