@@ -107,3 +107,13 @@ register("loss_accumulation",
          "loss totals and grad/param health norms accumulate in fp32 "
          "(tree_norm, audit guard) so the finite-check is trustworthy",
          where="imaginaire_tpu/diagnostics/audit.py")
+register("router_scores",
+         "an expert router's scores, its top-k choice and the selected "
+         "weights' normalization run in fp32; a bf16 score flips choices "
+         "between near-tied experts",
+         where="imaginaire_tpu/models/generators/hybrid_lm.py")
+register("ssm_scan",
+         "a state-space scan's step sizes (softplus), decays exp(dt A), "
+         "their cumulative sums and the state carried across chunks stay "
+         "fp32; bf16 decays compound over thousands of steps",
+         where="imaginaire_tpu/models/generators/hybrid_lm.py")

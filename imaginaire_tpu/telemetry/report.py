@@ -982,6 +982,28 @@ def render_serving_report(path_or_events):
     return "\n".join(lines)
 
 
+def _experts_section(s):
+    """Routing of a token model's expert layers: the latest
+    ``moe/<layer>/*`` counters (``trainers/lm.py``'s flush hook)."""
+    layers = {}
+    for name, (value, _) in s["counters"].items():
+        parts = name.split("/")
+        if len(parts) == 3 and parts[0] == "moe":
+            layers.setdefault(parts[1], {})[parts[2]] = value
+    if not layers:
+        return []
+    lines = ["", "## experts",
+             "| layer | held assignments | fullest over mean "
+             "| buffer occupancy |", "|---|---|---|---|"]
+    for layer in sorted(layers, key=lambda k: (len(k), k)):
+        row = layers[layer]
+        lines.append(
+            f"| {layer} | {row.get('held_assignments', float('nan')):.0f} "
+            f"| {row.get('load_max_over_mean', float('nan')):.2f} "
+            f"| {row.get('buffer_occupancy', float('nan')) * 100:.1f}% |")
+    return lines
+
+
 def render_report(path_or_events):
     """Markdown-ish report (the PROFILE.md table format) for a
     telemetry.jsonl path or a pre-loaded event list."""
@@ -1025,6 +1047,7 @@ def render_report(path_or_events):
                      f"({flops_meta.get('source')}, "
                      + (f"peak {peak:.4g} FLOP/s via " if peak else "")
                      + f"{flops_meta.get('peak_source')})")
+    lines.extend(_experts_section(s))
     lines.extend(_health_section(s))
     lines.extend(_xla_section(s))
     lines.extend(_graph_section(s))

@@ -78,7 +78,9 @@ class BaseTrainer:
         iters_per_epoch = len(train_data_loader) if train_data_loader is not None else 1
         self.tx_G = get_optimizer_for_params(
             cfg.gen_opt, get_scheduler(cfg.gen_opt, iters_per_epoch))
-        self.tx_D = get_optimizer_for_params(
+        # no discriminator, no optimizer for one (a config without ``dis``
+        # need not carry a ``dis_opt``)
+        self.tx_D = None if net_D is None else get_optimizer_for_params(
             cfg.dis_opt, get_scheduler(cfg.dis_opt, iters_per_epoch))
 
         tcfg = cfg_get(cfg, "trainer", None) or {}
@@ -692,8 +694,10 @@ class BaseTrainer:
 
     @staticmethod
     def _batch_items(data):
-        """Samples in a batch (imgs/sec accounting): leading dim of the
-        first array leaf; video batches count frames (B*T)."""
+        """Samples in a batch (``perf/imgs_per_sec`` accounting): leading
+        dim of the first array leaf; video batches count frames (B*T). A
+        token batch (B, L) counts its B packed sequences, not their
+        tokens (``perf/tokens_per_sec`` is the token trainer's own)."""
         try:
             leaves = [v for v in (data or {}).values()
                       if hasattr(v, "shape") and getattr(v, "ndim", 0) >= 1]

@@ -1,0 +1,103 @@
+"""Token-model trainer: one step program, no discriminator.
+
+``gen_forward`` is the mean next-token cross-entropy the model returns
+over its vocabulary slice; the step is ``BaseTrainer._gen_step_fn`` as it
+is. The expert layers' routing counts ride the step's losses, so the
+loop reads them without a device sync of its own: telemetry's flush hook
+turns the newest step's into the ``moe/<layer>/*`` counters and the
+window's tokens into ``perf/tokens_per_sec``.
+"""
+
+from __future__ import annotations
+
+import time
+
+import jax
+import jax.numpy as jnp
+
+from imaginaire_tpu import telemetry
+from imaginaire_tpu.config import as_attrdict, cfg_get
+from imaginaire_tpu.trainers.base import BaseTrainer
+
+COUNTERS = ("held_assignments", "load_max_over_mean", "buffer_occupancy")
+
+
+class Trainer(BaseTrainer):
+    def __init__(self, cfg, *args, **kwargs):
+        cfg = as_attrdict(cfg)
+        # the default tree's dummy discriminator: a token model has none
+        cfg["dis"] = None
+        mp = cfg_get(cfg.trainer, "mixed_precision", None) or {}
+        cfg.gen["compute_dtype"] = (
+            str(cfg_get(mp, "compute_dtype", "bfloat16"))
+            if cfg_get(mp, "enabled", False)
+            else str(cfg_get(cfg.trainer, "compute_dtype", "float32")))
+        super().__init__(cfg, *args, **kwargs)
+        self._last_losses = None
+        self._tokens_since_flush = 0
+        self._flush_t0 = None
+        tm = telemetry.get()
+        if tm.enabled:
+            tm.flush_hooks.append(self._flush_counters)
+
+    def _init_loss(self, cfg):
+        self.weights["lm"] = 1.0
+
+    def _to_compute_dtype(self, tree):
+        """Nothing is cast here: the model casts each layer's kernels
+        where it uses them (``gen.compute_dtype``, set above from
+        ``trainer.mixed_precision``), and token ids have no float."""
+        return tree
+
+    def _audit_health(self, ok, grad_norm, step_counter, grads, params,
+                      updates, spectral=None, ema=None):
+        """The base's health summary with its norms taken every step and
+        zeroed off the cadence, not under ``lax.cond``: a cond's operands
+        are materialized, and at two thirds of a billion parameters the
+        ``updates`` tree is 2.7 GB that otherwise fuses into the Adam
+        pass (the compiler's memory analysis: 7.3 GB of temporaries
+        against 4.9). The norms read what that pass reads anyway."""
+        if ok is None:
+            return {}
+        from imaginaire_tpu.diagnostics import audit
+
+        pred = (step_counter % self.diag.every_n) == 0
+        health = {k: jnp.where(pred, v, 0.0) for k, v in audit.module_health(
+            grads, params, updates, grad_norm_total=grad_norm).items()}
+        health.update(finite=ok, audited=pred, rng_step=step_counter)
+        return health
+
+    def gen_forward(self, vars_G, vars_D, loss_params, data, rng,
+                    training=True):
+        out = self.net_G.apply(vars_G, data, training=training)
+        overflow = sum(v for k, v in out.items() if k.endswith("/overflow"))
+        # an assignment the buffer had no row for fails the step's finite
+        # flag (the update does not land) rather than vanishing
+        losses = {"lm": out["loss"] + jnp.where(overflow > 0, jnp.nan, 0.0)}
+        losses.update({k: v for k, v in out.items() if k.startswith("moe/")})
+        return losses, {}
+
+    def gen_update(self, data):
+        if self._flush_t0 is None:
+            self._flush_t0 = time.perf_counter()
+        losses = super().gen_update(data)
+        self._last_losses = losses
+        self._tokens_since_flush += int(data["tokens"].size)
+        return losses
+
+    def _flush_counters(self, tm, step):
+        """At telemetry's flush, behind its fence: tokens a second over
+        the flush window, and the newest step's routing counts."""
+        now = time.perf_counter()
+        if self._tokens_since_flush and now > (self._flush_t0 or now):
+            tm.counter("perf/tokens_per_sec",
+                       self._tokens_since_flush / (now - self._flush_t0),
+                       step=step)
+        self._flush_t0, self._tokens_since_flush = now, 0
+        if self._last_losses is None:
+            return
+        wanted = {k: v for k, v in self._last_losses.items()
+                  if k.rsplit("/", 1)[-1] in COUNTERS}
+        # lint: allow(host-sync) -- flush cadence, behind the flush's own fence
+        for name, value in jax.device_get(wanted).items():
+            tm.counter(name, float(value), step=step)

@@ -1,0 +1,60 @@
+"""Shared by the hybrid token model's tests: the tiny preset's sizes, the
+seed's weights as the reference names them, and the program's tree."""
+
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+TINY_YAML = os.path.join(ROOT, "configs", "unit_test", "hybrid_lm.yaml")
+
+
+def tiny_cfg(**gen):
+    """The tiny preset's config in float32 compute (the tests compare
+    with the float32 reference), with `gen` written over its gen section."""
+    from imaginaire_tpu.config import Config
+
+    cfg = Config(TINY_YAML)
+    cfg.gen.update(gen)
+    return cfg
+
+
+def sizes_of(cfg):
+    """The reference's `sizes` of a program config."""
+    sizes = dict(cfg.gen)
+    sizes["experts_held"] = dict(sizes["experts_held"])
+    return sizes
+
+
+def unflatten(flat):
+    """{"a/b": x} -> {"a": {"b": x}}: the program's tree of the
+    reference's names."""
+    tree = {}
+    for name, value in flat.items():
+        node = tree
+        *parents, leaf = name.split("/")
+        for part in parents:
+            node = node.setdefault(part, {})
+        node[leaf] = value
+    return tree
+
+
+def seeded(cfg, seed):
+    """(reference module, sizes, trainable, buffers) for `cfg`."""
+    from benchmark.lib import lm_weights
+    from benchmark.reference import nemotron_h_train as reference
+
+    sizes = sizes_of(cfg)
+    train, buffers = reference.split(
+        lm_weights.make(reference.spec(sizes), seed))
+    return reference, sizes, train, buffers
+
+
+def layer_params(flat, index):
+    """The program's parameters of layer `index`'s mixer, from the
+    reference's flat names."""
+    prefix = f"layer_{index}/mixer/"
+    return {k[len(prefix):]: v for k, v in flat.items()
+            if k.startswith(prefix)}

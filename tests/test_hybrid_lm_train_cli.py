@@ -1,0 +1,98 @@
+"""`train.py` on the tiny token-model preset (ISSUE 27 (e)): two
+iterations, a checkpoint and a resume, through `train.main()` itself in
+this process; the spans and the new counters in the jsonl and in the
+report, no recompile, a clean graph audit."""
+
+import json
+import os
+import signal
+import sys
+
+import pytest
+
+from hybrid_lm_util import ROOT, TINY_YAML
+
+from imaginaire_tpu.parallel import mesh as mesh_mod
+from imaginaire_tpu.telemetry import core as tcore
+from imaginaire_tpu.telemetry import xla_obs
+
+
+@pytest.fixture
+def entry_point_sandbox():
+    """train.main() installs process-wide state (the telemetry
+    singleton, the compile ledger, the mesh, signal handlers); put back
+    what later tests of this worker expect."""
+    old_tm, old_mesh = tcore._TELEMETRY, mesh_mod._GLOBAL_MESH
+    handlers = {s: signal.getsignal(s)
+                for s in (signal.SIGTERM, signal.SIGINT)}
+    xla_obs._reset_for_tests()
+    yield
+    tcore._TELEMETRY.shutdown()
+    tcore._TELEMETRY = old_tm
+    mesh_mod._GLOBAL_MESH = old_mesh
+    for s, h in handlers.items():
+        signal.signal(s, h)
+    xla_obs._reset_for_tests()
+
+
+def _train(monkeypatch, logdir, max_iter):
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
+    import train
+
+    monkeypatch.setattr(sys, "argv", [
+        "train.py", "--config", TINY_YAML, "--logdir", logdir,
+        "--max_iter", str(max_iter), "--seed", "0"])
+    return train.main()
+
+
+def _events(logdir):
+    with open(os.path.join(logdir, "telemetry.jsonl")) as f:
+        return [json.loads(line) for line in f if line.strip()]
+
+
+def test_train_py_trains_checkpoints_and_resumes(entry_point_sandbox,
+                                                 monkeypatch, tmp_path,
+                                                 capsys):
+    logdir = str(tmp_path / "log")
+    trainer = _train(monkeypatch, logdir, 2)
+    assert trainer.current_iteration == 2
+    assert os.path.exists(os.path.join(logdir, "latest_checkpoint.txt"))
+    events = _events(logdir)
+    spans = {e["name"] for e in events if e["kind"] == "span"}
+    assert {"data_wait", "gen_step", "health_poll", "init_state",
+            "start_of_iteration", "end_of_iteration", "prefetch_host",
+            "prefetch_transfer", "prefetch_put"} <= spans
+    assert "dis_step" not in spans
+    counters = {e["name"]: e["value"] for e in events
+                if e["kind"] == "counter"}
+    assert counters["perf/tokens_per_sec"] > 0
+    assert counters["perf/imgs_per_sec"] > 0
+    for layer in (1, 4):
+        assert counters[f"moe/{layer}/held_assignments"] > 0
+        assert counters[f"moe/{layer}/load_max_over_mean"] >= 1
+        assert 0 < counters[f"moe/{layer}/buffer_occupancy"] <= 1
+    assert counters["xla/recompiles"] == 0
+    assert counters["xla/graph_violations"] == 0
+    assert "expand_labels" not in {
+        e.get("label") for e in events if e["kind"] == "meta"}
+    metas = {e["name"] for e in events if e["kind"] == "meta"}
+    assert "step_flops" in metas and "xla_compile/gen_step" in metas
+
+    from imaginaire_tpu.telemetry.report import render_report
+
+    report = render_report(os.path.join(logdir, "telemetry.jsonl"))
+    assert "## experts" in report and "perf/tokens_per_sec" in report
+    assert "gen_step: 0 violation(s)" in report
+
+    # the resume leg: restores iteration 2 and trains on to 3
+    capsys.readouterr()
+    xla_obs._reset_for_tests()
+    resumed = _train(monkeypatch, logdir, 3)
+    out = capsys.readouterr().out
+    assert "Done with loading the checkpoint (resume=True)" in out
+    assert "Done with training!!!" in out
+    assert resumed.current_iteration == 3
+    steps = [e["step"] for e in _events(logdir)
+             if e["kind"] == "span" and e["name"] == "gen_step"]
+    assert steps == [0, 1, 2]

@@ -1,0 +1,168 @@
+"""The hybrid token model through the trainer, against the plain
+reference (ISSUE 27 (b), (d), (f)): the whole model's loss and gradients,
+two `gen_update` steps against the reference's Adam steps, an overfull
+expert buffer failing the step's health flag, and a token batch passing
+the feed's index-map rule untouched."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from hybrid_lm_util import seeded, tiny_cfg, unflatten
+
+from imaginaire_tpu.registry import resolve
+
+
+def _tokens(cfg, seed=1, batch=2):
+    rng = np.random.RandomState(seed)
+    return rng.randint(0, cfg.gen.vocab_slice,
+                       (batch, cfg.data.seq_len)).astype(np.int32)
+
+
+def _trainer(cfg, train, buffers):
+    from benchmark.drivers import train_lm
+
+    trainer = resolve(cfg.trainer.type, "Trainer")(cfg)
+    data = {"tokens": jnp.asarray(_tokens(cfg))}
+    trainer.init_state(jax.random.PRNGKey(0), data)
+    # copies: the step donates its state, and the tests read the seed's
+    # arrays again
+    train_lm.install_weights(trainer, {
+        k: jnp.array(v, copy=True) for k, v in {**train, **buffers}.items()})
+    return trainer, data
+
+
+def _worst(ours, theirs):
+    return max(float(jnp.linalg.norm(ours[k] - theirs[k])
+                     / (jnp.linalg.norm(theirs[k]) + 1e-12)) for k in theirs)
+
+
+def test_model_loss_and_gradients_follow_the_reference():
+    from benchmark.lib import program
+    from imaginaire_tpu.models.generators import hybrid_lm
+
+    cfg = tiny_cfg()
+    reference, sizes, train, buffers = seeded(cfg, 7)
+    tokens = jnp.asarray(_tokens(cfg))
+    net = hybrid_lm.Generator(cfg.gen, cfg.data)
+
+    def ours(train):
+        out = net.apply({"params": unflatten(train),
+                         "buffers": unflatten(buffers)}, {"tokens": tokens})
+        return out["loss"], out
+
+    def theirs(train):
+        return reference.loss(train, buffers, sizes, tokens)
+
+    (l_ours, out), g_ours = jax.jit(jax.value_and_grad(
+        ours, has_aux=True))(train)
+    (l_theirs, aux), g_theirs = jax.jit(jax.value_and_grad(
+        theirs, has_aux=True))(train)
+    assert abs(float(l_ours) - float(l_theirs)) < 1e-5 * float(l_theirs)
+    assert _worst(g_ours, g_theirs) < 1e-4
+    for layer, counts in aux.items():
+        assert float(out[f"moe/{layer}/held_assignments"]) == float(
+            counts["held_assignments"])
+    # the seam's names are the program's own paths
+    assert set(program.flatten(unflatten(train))) == set(train)
+
+
+def test_two_trainer_steps_follow_the_reference_adam():
+    cfg = tiny_cfg()
+    reference, sizes, train, buffers = seeded(cfg, 11)
+    trainer, data = _trainer(cfg, train, buffers)
+    assert trainer.net_D is None and trainer.tx_D is None
+    assert "opt_D" not in trainer.state and trainer.dis_update(data) is None
+    losses = [float(trainer.gen_update(data)["total"]) for _ in range(2)]
+
+    from benchmark.lib import program
+
+    @jax.jit
+    def step(train, mu, nu, count):
+        (loss, _), grads = jax.value_and_grad(reference.loss, has_aux=True)(
+            train, buffers, sizes, data["tokens"])
+        return (loss,) + reference.adam(
+            train, grads, mu, nu, count, cfg.gen_opt.lr,
+            cfg.gen_opt.adam_beta1, cfg.gen_opt.adam_beta2)
+
+    mu = {k: jnp.zeros_like(v) for k, v in train.items()}
+    nu = dict(mu)
+    ref_losses = []
+    for count in range(2):
+        loss, train, mu, nu = step(train, mu, nu, count)
+        ref_losses.append(float(loss))
+    np.testing.assert_allclose(losses, ref_losses, rtol=1e-5)
+    ours = program.flatten(trainer.state["vars_G"]["params"])
+    assert _worst(ours, train) < 1e-5
+    # the score-correction bias is a buffer: nothing moved it
+    for name, value in program.flatten(
+            trainer.state["vars_G"]["buffers"]).items():
+        np.testing.assert_array_equal(np.asarray(value),
+                                      np.asarray(buffers[name]))
+
+
+def test_an_overfull_expert_buffer_fails_the_health_flag():
+    """ISSUE 27 (d): 16 rows for some 140 held assignments: the step's
+    finite flag fails, the update does not land, the monitor counts it."""
+    cfg = tiny_cfg(expert_buffer_rows=16)
+    cfg.diagnostics.on_nonfinite = "skip"
+    _, _, train, buffers = seeded(cfg, 13)
+    trainer, data = _trainer(cfg, train, buffers)
+    trainer.diag._triaged = True   # no eager triage pass in a unit test
+    before = jax.tree_util.tree_map(np.asarray,
+                                    trainer.state["vars_G"]["params"])
+    losses = trainer.gen_update(data)
+    assert float(losses["moe/1/overflow"]) > 0
+    assert not np.isfinite(float(losses["total"]))
+    trainer.diag.drain(trainer)
+    assert trainer.diag.nonfinite_events == 1
+    after = trainer.state["vars_G"]["params"]
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_array_equal(a, np.asarray(b)),
+        before, after)
+
+
+def test_index_map_rule_engages_for_images_only():
+    """ISSUE 27 (f): an image dataset's batch and a token batch through
+    one `DevicePrefetcher` hook: the label's index map becomes the
+    float32 stack, the tokens stay the int32 they were, and no expansion
+    program is built for them."""
+    from imaginaire_tpu.data import device_prefetch
+
+    def on_device(batch):
+        return device_prefetch.expand_index_labels(batch, 6)
+
+    rng = np.random.RandomState(0)
+    images = {"images": rng.rand(2, 8, 8, 3).astype(np.float32),
+              "label": rng.randint(0, 5, (2, 8, 8)).astype(np.int32),
+              "label_float": rng.rand(2, 8, 8, 1).astype(np.float32)}
+    tokens = {"tokens": rng.randint(0, 256, (2, 64)).astype(np.int32)}
+    feed = device_prefetch.DevicePrefetcher([images, tokens, tokens],
+                                            on_device=on_device)
+    first, second, third = list(feed)
+    assert first["label"].shape == (2, 8, 8, 6)
+    assert first["label"].dtype == jnp.float32
+    assert "label_float" not in first
+    programs = len(device_prefetch._EXPAND_PROGRAMS)
+    for batch in (second, third):
+        assert set(batch) == {"tokens"}
+        assert batch["tokens"].dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(batch["tokens"]),
+                                      tokens["tokens"])
+    assert len(device_prefetch._EXPAND_PROGRAMS) == programs
+
+
+def test_packed_token_dataset_reads_its_shards(tmp_path):
+    from imaginaire_tpu.data import get_train_and_val_dataloader
+
+    cfg = tiny_cfg()
+    train, _ = get_train_and_val_dataloader(cfg, seed=0)
+    batch = next(iter(train))
+    assert set(batch) == {"tokens"} and batch["tokens"].shape == (2, 64)
+    assert batch["tokens"].dtype == np.int32
+    assert getattr(train.dataset, "index_map_label", None) is None
+    np.save(tmp_path / "bad.npy", np.zeros((4, 32), np.int32))
+    cfg.data.train.roots = [str(tmp_path)]
+    with pytest.raises(ValueError, match="seq_len"):
+        get_train_and_val_dataloader(cfg, seed=0)
