@@ -18,9 +18,13 @@ the vocabulary held here: ids, logits and loss are over the slice.
 The numerics are plain ``jax.numpy``/``lax``: the chunked state-space
 dual form of the Mamba-2 recurrence (``ssd_scan``), causal attention by
 query blocks (``causal_attention``), and dropless routing with static
-shapes (``route_held``: sort the assignments by expert, keep the held
-ones in a buffer of ``expert_buffer_rows`` rows, two grouped products by
-``lax.ragged_dot``, scatter back weighted). The fp32 islands (router
+shapes (``route_held``: sort the assignments by expert, the held ones
+first, into a buffer of ``expert_buffer_rows`` rows, two grouped products
+by ``lax.ragged_dot``, scatter back weighted). ``expert_buffer_rows`` is
+the buffer's capacity, what the largest routing may hold; a step computes
+the filled prefix of it (``on_filled_prefix``: a row a token where its
+held assignments fit that, the whole buffer otherwise, the same
+arithmetic either way). The fp32 islands (router
 scores, the scan's step sizes, decays and carried state, RMS statistics,
 the loss) are declared in ``analysis/islands.py``.
 
@@ -35,6 +39,7 @@ parameters) is never cast.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -335,6 +340,78 @@ def route_held(experts, weights, first, count, rows):
     return token, weight, valid, group_sizes, stats
 
 
+def held_experts_part(x, w_up, w_down, weight, token, valid, group_sizes,
+                      rows):
+    """The held experts' part of the layer's result, computed on the first
+    ``rows`` rows of ``route_held``'s buffer: all of it where the step
+    holds no more than ``rows`` assignments, since the rows past the held
+    ones are masked to zero wherever they are read. ``x`` (T, hidden) and
+    the kernels (count, hidden, width), (count, width, hidden) in the
+    compute dtype."""
+    token, weight = token[:rows], weight[:rows]
+    # a row past the groups' end is not the grouped products' to write,
+    # forward or backward: whatever stands there is masked on the way in
+    # (its gradient is the first product's), between the two and on the
+    # way out
+    mask = valid[:rows, None]
+    with jax.named_scope("lm/moe/dispatch"):
+        filled = jnp.where(mask, x[token], 0)
+    with jax.named_scope("lm/moe/experts"):
+        up = lax.ragged_dot(filled, w_up, group_sizes)
+        act = relu2(jnp.where(mask, up, 0))
+        out = lax.ragged_dot(act, w_down, group_sizes)
+        out = jnp.where(mask, out, 0)
+    with jax.named_scope("lm/moe/combine"):
+        out = out.astype(jnp.float32) * weight[:, None]
+        routed = jnp.zeros(x.shape, jnp.float32).at[token].add(out)
+        return routed.astype(x.dtype)
+
+
+def _tier(tiers, n_held):
+    """The first of the ascending ``tiers`` with ``n_held`` rows or more
+    (the last, if none has)."""
+    return sum((n_held > rows).astype(jnp.int32) for rows in tiers[:-1])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def on_filled_prefix(tiers, n_held, x, w_up, w_down, weight, *placed):
+    """``held_experts_part`` on the shortest of the static, ascending
+    ``tiers`` of rows that holds the step's ``n_held`` assignments, by
+    ``lax.switch``. Its gradient is each tier's own, recomputed inside
+    the backward branch: differentiating through the switch instead hands
+    every tier's intermediates from a forward conditional to a backward
+    one, and a step on the short tier would write the long tier's as
+    zeros (1.5 GB a layer at the published widths)."""
+    return lax.switch(
+        _tier(tiers, n_held),
+        [functools.partial(held_experts_part, rows=rows) for rows in tiers],
+        x, w_up, w_down, weight, *placed)
+
+
+def _on_filled_prefix_fwd(tiers, n_held, *operands):
+    return on_filled_prefix(tiers, n_held, *operands), (n_held, operands)
+
+
+def _on_filled_prefix_bwd(tiers, saved, ct):
+    n_held, (*floats, token, valid, group_sizes) = saved
+
+    def back(rows):
+        part = functools.partial(held_experts_part, token=token, valid=valid,
+                                 group_sizes=group_sizes, rows=rows)
+        return lambda ct, *floats: jax.vjp(part, *floats)[1](ct)
+
+    grads = lax.switch(_tier(tiers, n_held), [back(rows) for rows in tiers],
+                       ct, *floats)
+    # the kernels' gradients leave the switch in the compute dtype: left
+    # to itself the compiler moves their casts to float32 into the
+    # branches, and eight leaves of twice the size stand until the
+    # optimizer's pass (2 GB of temporaries at the published widths)
+    return (None, *lax.optimization_barrier(grads), None, None, None)
+
+
+on_filled_prefix.defvjp(_on_filled_prefix_fwd, _on_filled_prefix_bwd)
+
+
 class MoEMixer(nn.Module):
     cfg: Any
 
@@ -364,25 +441,22 @@ class MoEMixer(nn.Module):
             experts, weights = route(
                 x.astype(jnp.float32), w_router.astype(jnp.float32),
                 score_bias, g.num_experts_per_tok, g.routed_scaling_factor)
+        capacity = g.expert_buffer_rows
+        # the held assignments sort first, so they fill the buffer's
+        # prefix: a step that holds no more than a row a token computes
+        # on that prefix, any other on the whole buffer
+        tiers = tuple(sorted({min(x.shape[0], capacity), capacity}))
         with jax.named_scope("lm/moe/dispatch"):
             token, weight, valid, group_sizes, stats = route_held(
-                experts, weights, g.held_first, g.held_count,
-                g.expert_buffer_rows)
-            # a row past the groups' end is not the grouped products' to
-            # write, forward or backward: whatever stands there is masked
-            # on the way in (its gradient is the first product's), between
-            # the two and on the way out
-            mask = valid[:, None]
-            rows = jnp.where(mask, x[token], 0)
+                experts, weights, g.held_first, g.held_count, capacity)
+            n_held = stats["held_assignments"]
+            stats["compact"] = (n_held <= tiers[0]).astype(jnp.float32)
+        # the switch stands under no scope: its branches' operations
+        # carry their own
         with jax.named_scope("lm/moe/experts"):
-            up = lax.ragged_dot(rows, w_up.astype(dtype), group_sizes)
-            act = relu2(jnp.where(mask, up, 0))
-            out = lax.ragged_dot(act, w_down.astype(dtype), group_sizes)
-            out = jnp.where(mask, out, 0)
-        with jax.named_scope("lm/moe/combine"):
-            out = out.astype(jnp.float32) * weight[:, None]
-            routed = jnp.zeros(x.shape, jnp.float32).at[token].add(out)
-            routed = routed.astype(dtype)
+            w_up, w_down = w_up.astype(dtype), w_down.astype(dtype)
+        routed = on_filled_prefix(tiers, n_held, x, w_up, w_down, weight,
+                                  token, valid, group_sizes)
         with jax.named_scope("lm/moe/shared"):
             shared = relu2(x @ s_up.astype(dtype)) @ s_down.astype(dtype)
         return (routed + shared).reshape(*lead, hidden), stats

@@ -44,6 +44,7 @@ def summarize(events):
     counters = {}
     health_series = {}
     flow_cache_series = {}
+    compact_series = {}
     nonfinite_events = []
     recompile_events = []
     oom_events = []
@@ -96,6 +97,13 @@ def summarize(events):
                     [ev.get("step"), ev.get("value")])
             elif str(ev["name"]).startswith("flow_cache/"):
                 flow_cache_series.setdefault(ev["name"], []).append(
+                    float(ev.get("value") or 0.0))
+            elif (str(ev["name"]).startswith("moe/")
+                  and str(ev["name"]).endswith("/compact")):
+                # full series: each flush carries its newest step's 0 or
+                # 1, and the table shows the share of them on the prefix
+                compact_series.setdefault(
+                    ev["name"].split("/")[1], []).append(
                     float(ev.get("value") or 0.0))
             elif ev["name"] == "pod/step_skew_ms":
                 # full series: the gate thresholds the p50, not the
@@ -490,7 +498,10 @@ def summarize(events):
             "hangs": hangs, "wall_s": wall_s, "health": health,
             "flow_cache": flow_cache, "xla": xla,
             "resilience": resilience, "graph": graph, "pod": pod,
-            "quality": quality, "serving": serving}
+            "quality": quality, "serving": serving,
+            "experts_compact_share": {
+                layer: sum(series) / len(series)
+                for layer, series in compact_series.items()}}
 
 
 def _trend(series):
@@ -984,7 +995,10 @@ def render_serving_report(path_or_events):
 
 def _experts_section(s):
     """Routing of a token model's expert layers: the latest
-    ``moe/<layer>/*`` counters (``trainers/lm.py``'s flush hook)."""
+    ``moe/<layer>/*`` counters (``trainers/lm.py``'s flush hook), and of
+    the flushes' newest steps the share that computed on the filled
+    prefix of the buffer (``moe/<layer>/compact``; "n/a" in a run from
+    before the counter)."""
     layers = {}
     for name, (value, _) in s["counters"].items():
         parts = name.split("/")
@@ -992,15 +1006,18 @@ def _experts_section(s):
             layers.setdefault(parts[1], {})[parts[2]] = value
     if not layers:
         return []
+    share = s.get("experts_compact_share") or {}
     lines = ["", "## experts",
              "| layer | held assignments | fullest over mean "
-             "| buffer occupancy |", "|---|---|---|---|"]
+             "| buffer occupancy | on the prefix |", "|---|---|---|---|---|"]
     for layer in sorted(layers, key=lambda k: (len(k), k)):
         row = layers[layer]
         lines.append(
             f"| {layer} | {row.get('held_assignments', float('nan')):.0f} "
             f"| {row.get('load_max_over_mean', float('nan')):.2f} "
-            f"| {row.get('buffer_occupancy', float('nan')) * 100:.1f}% |")
+            f"| {row.get('buffer_occupancy', float('nan')) * 100:.1f}% "
+            + (f"| {share[layer] * 100:.0f}% |" if layer in share
+               else "| n/a |"))
     return lines
 
 

@@ -19,7 +19,8 @@ from imaginaire_tpu import telemetry
 from imaginaire_tpu.config import as_attrdict, cfg_get
 from imaginaire_tpu.trainers.base import BaseTrainer
 
-COUNTERS = ("held_assignments", "load_max_over_mean", "buffer_occupancy")
+COUNTERS = ("held_assignments", "load_max_over_mean", "buffer_occupancy",
+            "compact")
 
 
 class Trainer(BaseTrainer):

@@ -3,7 +3,12 @@ and gradients, at the tiny preset's sizes on the CPU (ISSUE 27 (a), (c),
 (d)): the chunked state-space scan against the step-by-step recurrence,
 also at a length the chunk does not divide; causal grouped-query attention
 by query blocks; dropless routing over a held share of the experts; the
-shares adding up to the whole layer; skew and an overfull buffer."""
+shares adding up to the whole layer; skew and an overfull buffer; the
+expert layer on the filled prefix of its buffer against the whole buffer
+(ISSUE 28)."""
+
+import functools
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -159,6 +164,110 @@ def test_an_overfull_buffer_is_counted_not_silent():
     assert float(stats["overflow"]) == 32
     assert int(group_sizes.sum()) == 96 and int(valid.sum()) == 96
     assert float(stats["held_assignments"]) == 128
+
+
+# --- the filled prefix (ISSUE 28): 128 tokens, top 2, experts 0 to 3 of 8
+# held, a buffer of 256 rows; the short tier is 128 rows, a row a token
+
+
+def _steered(held):
+    """A router kernel and an input whose first eight features name each
+    token's two experts, so that exactly `held` of the 256 assignments
+    land on the held experts; feature 8 is one everywhere."""
+    cfg = tiny_cfg()
+    hidden = cfg.gen.hidden_size
+    pairs = []
+    for t in range(128):
+        if 2 * t + 1 < held:
+            pairs.append((t % 4, (t + 1) % 4))          # both held
+        elif 2 * t < held:
+            pairs.append((t % 4, 4 + t % 4))            # one held
+        else:
+            pairs.append((4 + t % 4, 4 + (t + 1) % 4))  # none held
+    u = np.array(_inputs(cfg, 64)).reshape(128, hidden)
+    u[:, :8] = -1.0
+    for t, pair in enumerate(pairs):
+        u[t, list(pair)] = 1.0
+    u[:, 8] = 1.0
+    router = 0.02 * np.array(jax.random.normal(
+        jax.random.PRNGKey(4), (hidden, 8)))
+    router[:8] = 6.0 * np.eye(8)
+    return jnp.asarray(router), jnp.asarray(u.reshape(2, 64, hidden))
+
+
+@functools.lru_cache(maxsize=None)
+def _prefix_and_whole_buffer():
+    """The layer as the model runs it and its single-tier twin (the same
+    module computing on the whole buffer, whatever it holds), each with
+    value, stats and gradients in one program, compiled once for the
+    four cases."""
+    cfg = tiny_cfg()
+    g = hybrid_lm.model_settings(cfg.gen)
+    module = hybrid_lm.MoEMixer(g)
+
+    def layer(params, buffers, u):
+        return module.apply({"params": params, "buffers": buffers}, u)
+
+    def whole_buffer(tiers, n_held, *operands):
+        return hybrid_lm.held_experts_part(*operands, rows=tiers[-1])
+
+    def run(params, buffers, u):
+        out, stats = layer(params, buffers, u)
+        weights = jax.random.normal(jax.random.PRNGKey(9), out.shape)
+        return jnp.sum(out * weights), (out, stats)
+
+    _, _, train, buffers = seeded(cfg, 5)
+    params, buffers = layer_params(train, 1), layer_params(buffers, 1)
+    u = _inputs(cfg, 64)
+    programs = []
+    for tier_rule in (hybrid_lm.on_filled_prefix, whole_buffer):
+        with mock.patch.object(hybrid_lm, "on_filled_prefix", tier_rule):
+            programs.append(jax.jit(jax.value_and_grad(
+                run, argnums=(0, 2), has_aux=True)).lower(
+                    params, buffers, u).compile())
+    return (*programs, params, buffers, g)
+
+
+@pytest.mark.parametrize("held,compact", [
+    (40, 1.0), (128, 1.0), (129, 0.0), (256, 0.0)],
+    ids=["well_under", "exactly_a_row_a_token", "one_more", "every_one"])
+def test_the_filled_prefix_is_the_whole_buffer(held, compact):
+    """Output, gradients to the input and to every kernel, and all stats
+    equal the single-tier layer's on both sides of the threshold;
+    `compact` says which tier ran; nothing overflows and no assignment is
+    lost where the whole buffer is needed."""
+    tiered, whole, params, buffers, g = _prefix_and_whole_buffer()
+    router, u = _steered(held)
+    params = dict(params, router=router)
+    (_, (out, stats)), grads = tiered(params, buffers, u)
+    (_, (out_w, stats_w)), grads_w = whole(params, buffers, u)
+    assert float(stats["compact"]) == compact
+    assert float(stats["held_assignments"]) == held
+    assert float(stats["overflow"]) == 0
+    assert {k: float(v) for k, v in stats.items()} == {
+        k: float(v) for k, v in stats_w.items()}
+    # to rounding: XLA:CPU's products sum in another order at another
+    # number of rows (a lost assignment would show at 1e-2)
+    _close(out, out_w, tol=2e-6)
+    _close(grads[1], grads_w[1], tol=2e-6)
+    for name in params:
+        _close(grads[0][name], grads_w[0][name], tol=2e-6)
+    assert float(jnp.abs(grads[0]["experts_up"]).max()) > 0
+    # every held expert a constant map of feature 8, the shared expert
+    # silent: a token's result is 0.25 times the routing weights that
+    # reached it, and their sum is the router's over the held experts
+    probe = dict(
+        params, shared_up=jnp.zeros_like(params["shared_up"]),
+        experts_up=jnp.zeros_like(params["experts_up"]).at[:, 8].set(0.5),
+        experts_down=jnp.full_like(params["experts_down"],
+                                   1.0 / g.moe_intermediate_size))
+    (_, (out, _)), _ = tiered(probe, buffers, u)
+    experts, weights = hybrid_lm.route(
+        u.reshape(128, -1), router, buffers["score_bias"],
+        g.num_experts_per_tok, g.routed_scaling_factor)
+    sent = float(jnp.where(experts < g.held_count, weights, 0.0).sum())
+    reached = float(out.sum()) / (0.25 * g.hidden_size)
+    assert abs(reached - sent) <= 1e-4 * sent and sent > 0
 
 
 def test_bad_held_share_and_pattern_fail_loudly():

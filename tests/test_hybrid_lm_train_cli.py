@@ -72,6 +72,7 @@ def test_train_py_trains_checkpoints_and_resumes(entry_point_sandbox,
         assert counters[f"moe/{layer}/held_assignments"] > 0
         assert counters[f"moe/{layer}/load_max_over_mean"] >= 1
         assert 0 < counters[f"moe/{layer}/buffer_occupancy"] <= 1
+        assert 0 <= counters[f"moe/{layer}/compact"] <= 1
     assert counters["xla/recompiles"] == 0
     assert counters["xla/graph_violations"] == 0
     assert "expand_labels" not in {

@@ -408,6 +408,35 @@ def test_report_renders_phase_table(tm_sandbox, tmp_path):
     assert not summary["hangs"]
 
 
+@pytest.mark.parametrize("compact,column", [
+    ([1.0, 1.0, 0.0, 1.0], "| 75% |"), (None, "| n/a |")],
+    ids=["with_compact", "from_before_the_counter"])
+def test_report_renders_the_experts_table(compact, column):
+    """The latest `moe/<layer>/*` counters a layer, layers in numeric
+    order, and the share of the flushes' steps on the filled prefix (the
+    whole series of `moe/<layer>/compact`, where a run has it)."""
+    events = []
+    for flush in range(4):
+        for layer, held in (("10", 5000 + flush), ("3", 900 + flush)):
+            stats = {"held_assignments": held, "load_max_over_mean": 2.5,
+                     "buffer_occupancy": held / 49152}
+            if compact is not None:
+                stats["compact"] = compact[flush] if layer == "3" else 1.0
+            events += [{"kind": "counter", "name": f"moe/{layer}/{k}",
+                        "value": v, "step": flush, "t": float(flush)}
+                       for k, v in stats.items()]
+    lines = render_report(events).splitlines()
+    start = lines.index("## experts")
+    assert lines[start + 1].endswith("| buffer occupancy | on the prefix |")
+    rows = lines[start + 3:start + 5]
+    assert rows[0].startswith("| 3 | 903 | 2.50 | 1.8% ")
+    assert rows[0].endswith(column)
+    assert rows[1].startswith("| 10 | 5003 | 2.50 | 10.2% ")
+    assert rows[1].endswith("| 100% |" if compact else "| n/a |")
+    assert "## experts" not in render_report(
+        [e for e in events if not e["name"].startswith("moe/")])
+
+
 def test_telemetry_report_cli(tm_sandbox, tmp_path):
     import subprocess
 
