@@ -137,8 +137,8 @@ def _video_data_t(trainer, data):
         data_t["past_stacks"] = trainer._past_stacks(past_real, past_fake)
     else:
         data_t["past_stacks"] = {}
-    return ({k: v for k, v in data_t.items()
-             if not str(k).startswith("_")}, t_steady)
+    return {k: v for k, v in data_t.items()
+            if not str(k).startswith("_")}
 
 
 def trace_family_programs(family, logdir=None):
@@ -148,37 +148,13 @@ def trace_family_programs(family, logdir=None):
     batch = family_batch(family)
     traced = []
     if family in VIDEO_FAMILIES:
-        data_t, t_steady = _video_data_t(trainer, batch)
+        data_t = _video_data_t(trainer, batch)
         state = _sds(_state_sds(trainer, batch))
         args = (state, _sds(data_t))
         traced.append(("vid_dis_step",
                        trainer._jit_vid_dis._jit.trace(*args)))
         traced.append(("vid_gen_step",
                        trainer._jit_vid_gen._jit.trace(*args)))
-        tail_len = batch["images"].shape[1] - t_steady
-        if family == "vid2vid" and tail_len >= 1:
-            n_prev = trainer.num_frames_G - 1
-            scales = trainer.num_temporal_scales
-            b, _, h, w, _ = batch["images"].shape
-            n_lab = batch["label"].shape[-1]
-            t_dis = trainer.num_frames_D
-            max_prev = (t_dis ** max(scales - 1, 0)) * (t_dis - 1)
-            buffers = (
-                np.zeros((b, max(n_prev, 1), h, w, n_lab), np.float32),
-                np.zeros((b, max(n_prev, 1), h, w, 3), np.float32),
-                np.zeros((b, max_prev, h, w, 3), np.float32)
-                if scales > 0 else None,
-                np.zeros((b, max_prev, h, w, 3), np.float32)
-                if scales > 0 else None)
-            tail = {"label": batch["label"][:, t_steady:],
-                    "image": batch["images"][:, t_steady:],
-                    "real_prev_image":
-                        batch["images"][:, t_steady - 1:-1]}
-            constants = trainer._rollout_scan_constants(batch)
-            traced.append(("rollout_tail",
-                           trainer._jit_rollout_tail._jit.trace(
-                               state, _sds(buffers), _sds(tail),
-                               _sds(constants))))
         if family == "wc_vid2vid" and trainer.single_image_model \
                 is not None:
             import jax

@@ -170,14 +170,6 @@ def default_config():
             # off, since jax_debug_nans re-runs ops against buffers
             # donation already invalidated
             donate_step_buffers=True,
-            # software-pipelined rollout dispatch (parallel/pipeline.py,
-            # ISSUE 14): defer the health monitor's one-behind finite
-            # polls by `depth` frames so the host issues frame t+1 while
-            # frame t's programs and gradient all-reduce are in flight.
-            # Bit-identical to the sequential loop; depth=0 or
-            # enabled=False restores it exactly.
-            pipeline=AttrDict(enabled=True, depth=2,
-                              overlap_collectives=True),
         ),
         gen=AttrDict(type="imaginaire_tpu.models.generators.dummy"),
         dis=AttrDict(type="imaginaire_tpu.models.discriminators.dummy"),
@@ -342,8 +334,8 @@ def default_config():
         ),
         # -- fault tolerance (resilience/, ISSUE 7). checksum: per-leaf
         # crc32 checksums of the saved state ride the checkpoint sidecar
-        # (one device_get of the addressable leaves per save — see
-        # PROFILE.md for the cost); verify_on_load replays them on
+        # (one device_get of the addressable leaves per save);
+        # verify_on_load replays them on
         # restore and a mismatch quarantines the checkpoint (*.corrupt)
         # and falls back to the newest verifiable one.
         # emergency_checkpoint arms the SIGTERM preemption guard in

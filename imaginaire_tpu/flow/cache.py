@@ -4,8 +4,9 @@ flow supervision (ISSUE 4 tentpole).
 The vid2vid FlowLoss teacher only ever sees *real* frames — its
 ``(flow, conf)`` output is a pure function of the data batch — yet the
 reference (and our in-graph port) recomputes it inside the
-differentiated step program, identically every epoch, at 52.2 ms/frame
-(23% of the gen step, PROFILE.md). This module moves the teacher OFF
+differentiated step program, identically every epoch (about a quarter
+of the gen step on an earlier installation; not measured on this
+one). This module moves the teacher OFF
 the step's critical path, in two layers:
 
 1. **Off-step execution** (``TeacherFlowCache.attach``): the teacher

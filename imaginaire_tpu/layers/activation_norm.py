@@ -40,8 +40,9 @@ def _fusable_modulation(impl, base_norm, x, pairs, masked=False):
 
 def default_fused_modulation(anp, remat):
     """Generator-side default for the epilogue-fusion knob, given the
-    model's remat policy. Measured (PROFILE.md ISSUE-16, spade-512
-    bs4): ``custom_vjp`` residuals are OPAQUE to ``jax.checkpoint``, so
+    model's remat policy. From a CPU memory analysis on an earlier
+    installation at spade-512 bs 4 (not measured on this one):
+    ``custom_vjp`` residuals are OPAQUE to ``jax.checkpoint``, so
     inside a rematted block the fused op pins (x, γ, stats) residuals
     the block policy would otherwise discard and recompute — fusion
     and block-remat are alternative mechanisms for the same residuals,

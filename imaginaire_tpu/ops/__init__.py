@@ -40,14 +40,7 @@ the scan path covering general kernel sizes, spade_modulation to 'fused'
 auto pins
 ---------
 Every ``AUTO_IMPLEMENTATION`` is pinned to the XLA formulation; not
-measured on this installation. ``python scripts/opsbench.py`` (optionally
-``--ops <op,...>``) times each implementation on the device it runs on
-and prints the rows and the winner per op; residual-policy ops
-(spade_modulation) are benched on the grad path and their rows carry the
-grad program's AOT ``temp_bytes`` — the winner for such ops orders by
-(temp bytes, then latency), since identical forward math makes latency
-alone noise. A pin changes only on a chip run's rows, with the op's
-dispatch comment saying which.
+measured on this installation.
 """
 
 # module aliases FIRST (while the package attributes still point at the
@@ -72,8 +65,7 @@ OP_MODULES = {
 def resolved_implementations():
     """{op: implementation} each op's ``implementation='auto'`` resolves
     to — the single source is each module's ``AUTO_IMPLEMENTATION``
-    constant. Bench legs record this map so BENCH rows are attributable
-    to kernel choices (ISSUE 16)."""
+    constant."""
     return {op: mod.AUTO_IMPLEMENTATION for op, mod in OP_MODULES.items()}
 
 

@@ -15,8 +15,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-# 'auto' is pinned to the XLA formulation; not measured on this
-# installation. Bench legs record it via ops.resolved_implementations().
+# pinned to the XLA formulation; not measured on this installation
 AUTO_IMPLEMENTATION = "jnp"
 
 

@@ -26,8 +26,7 @@ from jax import lax
 
 # 'auto' is pinned to the XLA 'mxu' formulation for the FlowNetC
 # configuration; not measured on this installation. Shapes the mxu band
-# grid cannot represent take 'jnp' in the dispatch below. Bench legs
-# record it via ops.resolved_implementations().
+# grid cannot represent take 'jnp' in the dispatch below.
 AUTO_IMPLEMENTATION = "mxu"
 
 

@@ -6,8 +6,8 @@ with per-condition spatial γ/β maps (ref: layers/activation_norm.py:109-234
 ``SpatiallyAdaptiveNorm``). Left to autodiff, that composition saves
 ``norm(x)`` AND the summed γ map as full B×H×W×C residuals for the
 backward pass — at spade-512 that is the synthesis hot path's largest
-activation cost after the segmap-embed conv scratch (PROFILE.md
-ISSUE-9/10).
+activation cost after the segmap-embed conv scratch (an earlier
+installation's CPU memory analysis; not measured on this one).
 
 This op computes the whole epilogue in one differentiable call:
 
@@ -45,12 +45,9 @@ import jax.numpy as jnp
 
 from imaginaire_tpu.analysis import islands
 
-# 'auto' is pinned to the XLA 'fused' formulation (the custom_vjp
-# residual-trimming path); not measured on this installation. The
-# decision axis for a residual-policy op whose forward math is identical
-# across 'jnp' and 'fused' is what the backward keeps: scripts/opsbench.py
-# benches it on the TRAINING path and records each row's AOT
-# grad-program temp bytes.
+# pinned to the XLA 'fused' formulation (the custom_vjp
+# residual-trimming path); not measured on this installation. 'jnp' and
+# 'fused' share their forward math: what differs is what the backward keeps.
 AUTO_IMPLEMENTATION = "fused"
 
 _SPATIAL_AXES = (1, 2)  # NHWC instance-norm reduction axes

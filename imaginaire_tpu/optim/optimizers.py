@@ -133,8 +133,8 @@ def init_optimizer_state(tx, params, plan=None):
     cross-replica update-state specs (arXiv:2004.13336): every moment
     leaf is *born* as its 1/N data-axis shard (+ model-axis channel
     shard where the rules match), so the full replicated moment tree —
-    2x param bytes for adam, the single biggest state entry in
-    PROFILE.md's budget — never exists on any chip, not even
+    2x param bytes for adam, the single biggest entry of the train
+    state — never exists on any chip, not even
     transiently at init. Scalar bookkeeping leaves (adam ``count``,
     madam ``step``/``p_max``) resolve to replicated. Without a plan
     this is exactly ``tx.init(params)``.

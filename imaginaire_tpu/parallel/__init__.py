@@ -29,13 +29,6 @@ from imaginaire_tpu.parallel.partition import (
     per_device_tree_bytes,
     state_bytes_report,
 )
-from imaginaire_tpu.parallel.pipeline import (
-    FrameDAG,
-    PipelineOrderError,
-    RolloutPipeline,
-    hoist_invariants,
-    pipeline_settings,
-)
 from imaginaire_tpu.parallel.sharding import (
     batch_sharding,
     replicated_sharding,
@@ -60,11 +53,6 @@ __all__ = [
     "is_master",
     "master_only",
     "master_only_print",
-    "FrameDAG",
-    "PipelineOrderError",
-    "RolloutPipeline",
-    "hoist_invariants",
-    "pipeline_settings",
     "batch_sharding",
     "replicated_sharding",
     "shard_batch",

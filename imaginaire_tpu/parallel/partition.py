@@ -1,7 +1,7 @@
 """Named partition rules: logical param axes -> mesh axes (ISSUE 6).
 
-Breaks the replicated-state memory wall measured in PROFILE.md (spade-512
-zoo gen step: 6.8 GiB params+opt+EMA replicated on EVERY chip). Two
+Breaks the replicated-state memory wall (zoo-width SPADE: 6.8 GiB of
+params+opt+EMA replicated on EVERY chip). Two
 coupled mechanisms, both expressed as plain ``NamedSharding`` trees the
 jitted step programs consume through ``jax.device_put`` +
 ``with_sharding_constraint`` (GSPMD inserts the collectives, choosing

@@ -368,7 +368,7 @@ class RedistributionPlanner:
 
     def summary(self):
         """The redistribution record ``record_resize`` folds into the
-        ``elastic/resize`` meta event (and PROFILE.md's cost table)."""
+        ``elastic/resize`` meta event."""
         counts = self.route_counts()
         return {
             "redistributed_bytes": int(self.total_bytes),

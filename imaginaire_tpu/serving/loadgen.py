@@ -1,7 +1,7 @@
 """Closed- and open-loop serving load generation (ISSUE 20).
 
-The PR-19 SERVEBENCH numbers were measured one request at a time —
-p99 under ZERO concurrent load, which is not a tail latency at all.
+A p99 over requests served one at a time is a p99 under ZERO
+concurrent load, which is not a tail latency at all.
 This module drives a :class:`ServingEngine` the way traffic actually
 arrives and measures what the aggregate counters then mean:
 
@@ -17,8 +17,8 @@ arrives and measures what the aggregate counters then mean:
   overload behavior. Both are needed for an honest curve.
 - **sweep** (``run_load_sweep``): open-loop points at increasing
   offered rates, ``engine.reset_stats()`` between points so point N's
-  p99 cannot inherit point N-1's tail. This is what SERVEBENCH.json's
-  offered-load-vs-latency curve comes from.
+  p99 cannot inherit point N-1's tail. ``scripts/serving_loadgen.py``
+  prints its offered-load-vs-latency curve from this.
 - **streams** (``run_stream_burst``): interleaved StreamSession frame
   loops, exercising the per-stream lifecycle traces under load.
 
@@ -179,7 +179,7 @@ def run_load_sweep(engine, rates, duration_s, lanes, seed=0):
     """One open-loop point per offered rate, lowest first,
     ``reset_stats()`` between points (the measurement-boundary
     contract: each point's percentiles cover only its own window).
-    Returns the list of point dicts — the SERVEBENCH curve."""
+    Returns the list of point dicts — the offered-load curve."""
     points = []
     for i, rate in enumerate(rates):
         engine.reset_stats()

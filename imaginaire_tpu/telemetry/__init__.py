@@ -13,7 +13,7 @@ else reports through the module-level singleton:
 See ``core.py`` for the event model, ``sinks.py`` for where events go,
 ``watchdog.py`` for the hang dumper, and ``report.py`` /
 ``scripts/telemetry_report.py`` for rendering a run's JSONL into the
-PROFILE.md-style phase table.
+phase table.
 """
 
 from imaginaire_tpu.telemetry.core import (  # noqa: F401

@@ -46,8 +46,7 @@ bus the whole stack reports into:
 - derived counters     — imgs/sec over the fenced window, step-time EWMA
   and p50/p99 over a bounded ring buffer, and MFU from the XLA cost
   analysis registered once at jit time
-  (``BaseTrainer._register_step_flops``, the ``scripts/perf_lab.py``
-  method).
+  (``BaseTrainer._register_step_flops``).
 - hang watchdog        — if no ``step_complete`` heartbeat lands within
   ``telemetry.hang_timeout_s``, every Python thread's stack (prefetcher
   producer and checkpoint pointer thread included) is dumped to the

@@ -1,5 +1,5 @@
-"""Render a run's ``telemetry.jsonl`` into the PROFILE.md-style
-per-phase attribution table, plus the derived counters (imgs/sec, MFU,
+"""Render a run's ``telemetry.jsonl`` into a per-phase attribution
+table, plus the derived counters (imgs/sec, MFU,
 step percentiles) and any hang dumps.
 
 Library half of ``scripts/telemetry_report.py``; also run by the
@@ -1022,8 +1022,8 @@ def _experts_section(s):
 
 
 def render_report(path_or_events):
-    """Markdown-ish report (the PROFILE.md table format) for a
-    telemetry.jsonl path or a pre-loaded event list."""
+    """Markdown-ish report for a telemetry.jsonl path or a pre-loaded
+    event list."""
     events = (load_events(path_or_events)
               if isinstance(path_or_events, str) else path_or_events)
     s = summarize(events)

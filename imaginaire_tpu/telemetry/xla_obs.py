@@ -443,7 +443,7 @@ class CompiledProgram:
     fresh fingerprint pays one timed ``lower().compile()`` whose
     memory/cost analyses go to the ledger, warm fingerprints call the
     cached executable directly. The plain jitted function survives as
-    ``.lower()`` (perf_lab) and as the fallback when observability is
+    ``.lower()`` and as the fallback when observability is
     off, ``jax_debug_nans`` is on (the eager re-run needs jit's
     dispatch path), or an AOT call rejects an input the fingerprint
     collapsed (weak-type corners) — correctness never depends on the

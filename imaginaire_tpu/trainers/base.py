@@ -151,12 +151,6 @@ class BaseTrainer:
         # donated buffers (see train.py)
         self._donate = ((0,) if cfg_get(tcfg, "donate_step_buffers", True)
                         else ())
-        # Software-pipelined rollout dispatch (parallel/pipeline.py,
-        # ISSUE 14): resolved here so every trainer shares one knob
-        # group; only the video trainers' per-frame rollout consumes it.
-        from imaginaire_tpu.parallel.pipeline import pipeline_settings
-
-        self.pipeline_cfg = pipeline_settings(cfg)
         # step programs dispatch through the compile ledger
         # (telemetry/xla_obs.py): the same compile that runs the step
         # records memory_analysis/cost_analysis and arms the recompile
@@ -712,9 +706,9 @@ class BaseTrainer:
 
     def _register_step_flops(self, data):
         """Register per-iteration FLOPs with telemetry ONCE, from the
-        compile ledger's cost analysis of the two step programs (the
-        ``scripts/perf_lab.py`` numbers, but recorded by the SAME
-        compile that runs the step — no duplicate lower/compile),
+        compile ledger's cost analysis of the two step programs
+        (recorded by the SAME compile that runs the step — no duplicate
+        lower/compile),
         weighted by the dis_step/gen_step multipliers. Also emits the
         one-shot static memory-budget report (executable footprints +
         state tree sizes). Falls back to an explicit lower/compile when

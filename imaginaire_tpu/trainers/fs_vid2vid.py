@@ -20,20 +20,17 @@ from imaginaire_tpu.utils.misc import numeric_only, to_device
 class Trainer(Vid2VidTrainer):
     def _frame0(self, data):
         out = super()._frame0(data)
-        out["ref_images"] = data["ref_images"]
-        if "ref_labels" in data:
-            out["ref_labels"] = data["ref_labels"]
+        out.update(self._rollout_constants(data))
         return out
 
     def _get_data_t(self, data, t, prev_labels, prev_images):
         data_t = super()._get_data_t(data, t, prev_labels, prev_images)
-        data_t.update(self._rollout_scan_constants(data))
+        data_t.update(self._rollout_constants(data))
         return data_t
 
-    def _rollout_scan_constants(self, data):
-        """The few-shot reference window is constant across the clip —
-        declared here so the rollout-scan tail threads it into every
-        frame's data_t (see Vid2VidTrainer._scan_eligible)."""
+    def _rollout_constants(self, data):
+        """The few-shot reference window: constant across the clip, so
+        every frame's data_t carries it unchanged."""
         out = {"ref_images": data["ref_images"]}
         if "ref_labels" in data:
             out["ref_labels"] = data["ref_labels"]
@@ -159,12 +156,7 @@ class Trainer(Vid2VidTrainer):
         # the step programs closed over the old optimizer: drop the
         # cached executables and re-trace. This is the one legitimate
         # re-jit in the codebase — the ledger records it as expected
-        # (allowlisted) so the recompile tripwire stays silent. Any
-        # deferred pipeline observations must land first — they hold
-        # outputs of the about-to-be-dropped executables (gen_update
-        # drains at rollout end, so this is a no-op outside mid-rollout
-        # callers; see parallel/pipeline.py).
-        self._rollout_pipeline.drain()
+        # (allowlisted) so the recompile tripwire stays silent.
         self._jit_vid_dis.retrace("fs_vid2vid finetune re-jit")
         self._jit_vid_gen.retrace("fs_vid2vid finetune re-jit")
 

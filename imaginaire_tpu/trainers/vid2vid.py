@@ -38,7 +38,6 @@ from imaginaire_tpu.losses import (
 from imaginaire_tpu.losses.flow import masked_l1_loss
 from imaginaire_tpu.model_utils.fs_vid2vid import concat_frames, skip_stride_span
 from imaginaire_tpu.optim import init_optimizer_state
-from imaginaire_tpu.parallel.pipeline import RolloutPipeline, hoist_invariants
 from imaginaire_tpu.trainers.base import MUTABLE, BaseTrainer
 from imaginaire_tpu.utils.misc import numeric_only, to_device
 from imaginaire_tpu.utils.model_average import ema_init, ema_update
@@ -71,39 +70,6 @@ class Trainer(BaseTrainer):
         self._jit_vid_gen = xla_obs.compiled_program(
             "vid_gen_step", self._vid_gen_step_fn,
             donate_argnums=self._donate, allow_shape_growth=True)
-        # Whole-rollout mode (SURVEY §7 hard-part #3): once the history
-        # ring buffers reach their steady-state shapes, the remaining
-        # frames run as ONE lax.scan program — per-frame D+G updates with
-        # (params, opt state, ring buffers) in carry — instead of 2
-        # host-dispatched programs per frame. Opt-in via
-        # trainer.rollout_scan; see gen_update/_rollout_scan_tail.
-        self.rollout_scan = bool(cfg_get(cfg.trainer, "rollout_scan",
-                                         False))
-        if self.rollout_scan:
-            # Demoted knob (ISSUE 14 / PROFILE.md Round 5): the whole-rollout
-            # scan measured ~19% SLOWER than the per-frame path (5.93 vs
-            # 7.28 frames/s) because one fused program forfeits the D/G
-            # async-dispatch overlap. Kept opt-in for the program-count
-            # story; warn once so nobody re-discovers the regression.
-            logging.warning(
-                "trainer.rollout_scan is a measured regression on the "
-                "per-frame path (5.93 vs 7.28 frames/s, see PROFILE.md "
-                "Round 5); prefer trainer.pipeline for rollout overlap")
-            telemetry.get().meta(
-                "rollout_scan_enabled",
-                verdict="PROFILE.md Round 5: ~19% slower than per-frame",
-                per_frame_fps=7.28, rollout_scan_fps=5.93)
-        self._jit_rollout_tail = xla_obs.compiled_program(
-            "rollout_tail", self._rollout_tail_fn,
-            donate_argnums=self._donate, allow_shape_growth=True)
-        # Software-pipelined rollout dispatch (parallel/pipeline.py,
-        # ISSUE 14): one persistent scheduler per trainer, reset at each
-        # rollout. The sequential path runs the same instrument at
-        # depth=0, so the dispatch-gap/overlap meters are always live.
-        self._rollout_pipeline = RolloutPipeline(
-            depth=self.pipeline_cfg["depth"],
-            overlap_collectives=self.pipeline_cfg["overlap_collectives"])
-        self._seq_pipeline = RolloutPipeline(depth=0)
 
     # ---------------------------------------------------------------- loss
 
@@ -579,138 +545,8 @@ class Trainer(BaseTrainer):
                                    past_fake[:, -t_span::t_step])
         return stacks
 
-    def _rollout_tail_fn(self, state, buffers, tail, constants):
-        """Steady-state rollout tail as ONE program: lax.scan over frames
-        with (trainer state, history ring buffers) in carry and the
-        per-frame D then G updates in the body (SURVEY §7 hard-part #3).
-
-        Replaces 2 host dispatches + host-side ring-buffer concats per
-        frame with a single XLA while-loop — the compiler pipelines the
-        buffer rolls into the step programs, and dispatch latency
-        is paid once per clip instead of twice per frame. Only valid
-        once every buffer has its steady shape (see gen_update's
-        t_steady); the warm-up frames keep the per-frame programs, whose
-        shapes differ structurally (no prev / growing stacks).
-        """
-        prev_labels, prev_images, past_real, past_fake = buffers
-        use_past = self.num_temporal_scales > 0 and past_real is not None
-        tD = self.num_frames_D
-        max_prev = (tD ** max(self.num_temporal_scales - 1, 0)) * (tD - 1)
-
-        def body(carry, xs):
-            if use_past:
-                state, prev_labels, prev_images, past_real, past_fake = carry
-            else:
-                state, prev_labels, prev_images = carry
-            data_t = dict(constants, label=xs["label"], image=xs["image"],
-                          real_prev_image=xs["real_prev_image"],
-                          prev_labels=prev_labels, prev_images=prev_images)
-            if "flow_gt" in xs:
-                data_t["flow_gt"] = xs["flow_gt"]
-                data_t["conf_gt"] = xs["conf_gt"]
-            data_t["past_stacks"] = (
-                self._past_stacks(past_real, past_fake) if use_past else {})
-            # per-frame health summaries are dropped inside the scan
-            # (stacking them would defeat the fixed-size contract); the
-            # in-graph non-finite guard still protects every tail frame
-            state, d_losses, _ = self._vid_dis_step_fn(state, data_t)
-            state, g_losses, fake, _ = self._vid_gen_step_fn(state, data_t)
-            prev_labels = concat_frames(prev_labels, xs["label"],
-                                        self.num_frames_G - 1)
-            prev_images = concat_frames(prev_images, fake,
-                                        self.num_frames_G - 1)
-            if use_past:
-                past_real = concat_frames(past_real, xs["image"], max_prev)
-                past_fake = concat_frames(past_fake, fake, max_prev)
-                carry = (state, prev_labels, prev_images, past_real,
-                         past_fake)
-            else:
-                carry = (state, prev_labels, prev_images)
-            return carry, (d_losses, g_losses)
-
-        xs = jax.tree_util.tree_map(lambda a: jnp.moveaxis(a, 1, 0), tail)
-        carry0 = ((state, prev_labels, prev_images, past_real, past_fake)
-                  if use_past else (state, prev_labels, prev_images))
-        carry, (d_hist, g_hist) = jax.lax.scan(body, carry0, xs)
-        return carry[0], d_hist, g_hist
-
-    def _rollout_scan_constants(self, data):
-        """Per-frame-constant keys the scan-tail body must thread into
-        each data_t. A subclass that overrides ``_get_data_t`` MUST also
-        override this to declare its extra keys (fs-vid2vid does) — the
-        scan body builds data_t itself and would otherwise silently drop
-        them; _scan_eligible enforces the pairing."""
-        return {}
-
-    def _scan_eligible(self, data, seq_len):
-        """The scan tail is semantics-preserving only when the per-frame
-        host hooks are the defaults (wc-vid2vid colors point clouds per
-        frame), any ``_get_data_t`` override has declared its constant
-        keys, and the clip is a real 5-D sequence."""
-        cls = type(self)
-        data_t_accounted = (
-            cls._get_data_t is Trainer._get_data_t
-            or cls._rollout_scan_constants
-            is not Trainer._rollout_scan_constants)
-        return (self.rollout_scan and seq_len > 1
-                and data["images"].ndim == 5
-                and data["label"].ndim == 5  # static 4-D labels use the
-                # per-frame path (the tail slices labels along time)
-                and data_t_accounted
-                and cls._frame_override is Trainer._frame_override
-                and cls._after_gen_frame is Trainer._after_gen_frame
-                and self._scan_keys_consistent(data, seq_len))
-
-    def _scan_keys_consistent(self, data, seq_len):
-        """Runtime cross-check of the ``_rollout_scan_constants``
-        pairing: probe ``_get_data_t`` at a steady-state frame and
-        require every key it emits to be one the scan body rebuilds
-        (label/image/real_prev_image/prev_*/past_stacks) or a declared
-        constant. An override whose extra keys vary per frame would
-        otherwise silently train the tail on stale constants — disable
-        the scan instead. Verdict cached per batch key-set (the probe
-        slices device arrays; once per data layout is enough)."""
-        cache_key = tuple(sorted(str(k) for k in data))
-        cached = getattr(self, "_scan_key_verdict", None)
-        if cached is not None and cached[0] == cache_key:
-            return cached[1]
-        t_probe = min(max(self.num_frames_G - 1, 1), seq_len - 1)
-        probe = self._get_data_t(data, t_probe,
-                                 data["label"][:, :1],
-                                 data["images"][:, :1])
-        rebuilt = {"label", "image", "prev_labels", "prev_images",
-                   "real_prev_image", "past_stacks", "flow_gt", "conf_gt"}
-        rebuilt |= set(self._rollout_scan_constants(data))
-        extra = sorted(k for k in probe
-                       if not str(k).startswith("_") and k not in rebuilt)
-        if extra:
-            print(f"rollout_scan disabled: _get_data_t emits per-frame "
-                  f"keys {extra} the scan tail would not rebuild")
-        self._scan_key_verdict = (cache_key, not extra)
-        return not extra
-
-    def _pipeline_eligible(self, data, seq_len):
-        """The software-pipelined dispatch (parallel/pipeline.py) defers
-        the monitor's one-behind finite polls by ``depth`` frames. That is
-        bit-identical to the sequential loop — same programs, same inputs,
-        same observation order — except for three cases it must refuse:
-        per-frame host hooks (wc-vid2vid reads back each generated frame,
-        so deferral would feed its renderer stale data), the ``rollback``
-        non-finite policy (its per-observation state snapshots must be
-        taken before later frames mutate the state), and overridden
-        ``_frame_override`` (same readback coupling)."""
-        cls = type(self)
-        return (self.pipeline_cfg["enabled"]
-                and self._rollout_pipeline.depth > 0
-                and cls._frame_override is Trainer._frame_override
-                and cls._after_gen_frame is Trainer._after_gen_frame
-                and getattr(self.diag, "on_nonfinite", "halt") != "rollback")
-
     def gen_update(self, data):
-        """Interleaved per-frame D/G rollout (ref: vid2vid.py:238-288).
-
-        With trainer.rollout_scan, frames past the ring-buffer warm-up
-        run inside one lax.scan program (_rollout_tail_fn)."""
+        """Interleaved per-frame D/G rollout (ref: vid2vid.py:238-288)."""
         # the gen_step span covers the whole rollout (per-frame dis_step
         # spans nest inside it — D updates happen here, dis_update is a
         # no-op for this family)
@@ -722,43 +558,20 @@ class Trainer(BaseTrainer):
                 and "flow_gt" not in data \
                 and getattr(data.get("images"), "ndim", 0) == 5:
             # safety net for callers that skip start_of_iteration
-            # (direct gen_update in tests/benches): the amortized
-            # teacher must still supply the supervision the cached step
-            # program expects
+            # (direct gen_update in tests): the amortized teacher must
+            # still supply the supervision the cached step program
+            # expects
             data = self.flow_cache.attach(dict(data))
         data = numeric_only(data)
         seq_len = (data["images"].shape[1] if data["images"].ndim == 5
                    else 1)
         tD = self.num_frames_D
         max_prev = (tD ** max(self.num_temporal_scales - 1, 0)) * (tD - 1)
-        # first frame at which every history buffer has its final shape
-        t_steady = max(self.num_frames_G - 1,
-                       max_prev if self.num_temporal_scales > 0 else 0, 1)
-        use_scan = self._scan_eligible(data, seq_len) and seq_len > t_steady
-        use_pipeline = self._pipeline_eligible(data, seq_len)
-        head_len = t_steady if use_scan else seq_len
-        # both paths run the same dispatch-gap/overlap instrument; the
-        # sequential loop at depth=0 keeps its inline observes, so the
-        # meters measure the old behaviour unchanged
-        pipe = self._rollout_pipeline if use_pipeline else self._seq_pipeline
-        pipe.begin()
-        tm = telemetry.get()
-        if use_pipeline and pipe.overlap_collectives:
-            # ISSUE-14 satellite: loop-invariant per-frame operands
-            # (fs-vid2vid's reference window) gather ONCE per rollout
-            # instead of once per frame program — the gather overlaps
-            # frame 0's issue window and the per-frame collective bytes
-            # drop out of the graph-audit counters
-            data, hoisted = hoist_invariants(
-                data, self._rollout_scan_constants(data))
-            if hoisted:
-                tm.counter("pipeline/hoisted_bytes", hoisted,
-                           step=self.current_iteration)
         prev_labels = prev_images = None
         past_real = past_fake = None
         t0 = time.time() if self.speed_benchmark else None
         d_hist, g_hist = [], []
-        for t in range(head_len):
+        for t in range(seq_len):
             data_t = self._get_data_t(data, t, prev_labels, prev_images)
             fake = self._frame_override(data_t)
             if fake is None:
@@ -769,109 +582,46 @@ class Trainer(BaseTrainer):
                 # boundary
                 data_jit = {k: v for k, v in data_t.items()
                             if not k.startswith("_")}
-                if use_pipeline:
-                    # pipelined: issue D_t/G_t back-to-back and DEFER the
-                    # monitor's finite polls by `depth` frames — the host
-                    # runs ahead slicing/dispatching while frame t's
-                    # programs and their gradient all-reduce are in
-                    # flight. Observation ORDER is unchanged; the DAG
-                    # marks prove the donated state handle threads
-                    # legally (G_{t-1} returned before D_t consumes it).
-                    with pipe.frame(t, tm, self.current_iteration):
-                        pipe.mark("data", t)
-                        with telemetry.span("dis_step",
-                                            step=self.current_iteration):
-                            self.state, d_losses, d_health = \
-                                self._jit_vid_dis(self.state, data_jit)
-                        pipe.mark("D", t)
-                        self.state, g_losses, fake, g_health = \
-                            self._jit_vid_gen(self.state, data_jit)
-                        pipe.mark("G", t)
-                        pipe.mark("grads", t)
-                    pipe.defer(lambda dl=d_losses, dh=d_health,
-                               gl=g_losses, gh=g_health, dj=data_jit,
-                               it=self.current_iteration: (
-                        self.diag.observe(self, "D", dl, dh, dj, it),
-                        self.diag.observe(self, "G", gl, gh, dj, it)))
-                else:
-                    with pipe.frame(t, tm, self.current_iteration):
-                        pipe.mark("data", t)
-                        with telemetry.span("dis_step",
-                                            step=self.current_iteration):
-                            self.state, d_losses, d_health = \
-                                self._jit_vid_dis(self.state, data_jit)
-                        pipe.mark("D", t)
-                    # per-frame health hooks: each frame's D and G update
-                    # reports its own summary/finite flag (the monitor's
-                    # cadence runs on the per-frame step counters). The
-                    # one-behind poll inside observe is what the frame
-                    # windows exclude — it lands in the dispatch gap.
-                    self.diag.observe(self, "D", d_losses, d_health,
-                                      data_jit, self.current_iteration)
-                    with pipe.frame(t, tm, self.current_iteration):
-                        self.state, g_losses, fake, g_health = \
-                            self._jit_vid_gen(self.state, data_jit)
-                        pipe.mark("G", t)
-                        pipe.mark("grads", t)
-                    self.diag.observe(self, "G", g_losses, g_health,
-                                      data_jit, self.current_iteration)
+                with telemetry.span("dis_step",
+                                    step=self.current_iteration):
+                    self.state, d_losses, d_health = \
+                        self._jit_vid_dis(self.state, data_jit)
+                # per-frame health hooks: each frame's D and G update
+                # reports its own summary/finite flag (the monitor's
+                # cadence runs on the per-frame step counters)
+                self.diag.observe(self, "D", d_losses, d_health,
+                                  data_jit, self.current_iteration)
+                self.state, g_losses, fake, g_health = \
+                    self._jit_vid_gen(self.state, data_jit)
+                self.diag.observe(self, "G", g_losses, g_health,
+                                  data_jit, self.current_iteration)
                 d_hist.append(d_losses)
                 g_hist.append(g_losses)
                 if self.num_temporal_scales > 0:
                     past_real = concat_frames(past_real, data_t["image"],
                                               max_prev)
                     past_fake = concat_frames(past_fake, fake, max_prev)
-            else:
-                pipe.override(t)
             self._after_gen_frame(data_t, fake)
             prev_labels = concat_frames(prev_labels, data_t["label"],
                                         self.num_frames_G - 1)
             prev_images = concat_frames(prev_images, fake,
                                         self.num_frames_G - 1)
-        # drain every deferred observation before anything else consumes
-        # the state: the monitor leaves this rollout in exactly the state
-        # the sequential loop would (one pending entry, same order)
-        pipe.finish(tm, step=self.current_iteration)
-        tail_counts = 0
-        if use_scan:
-            # constants every frame of the tail shares (few-shot refs)
-            constants = self._rollout_scan_constants(data)
-            tail = {"label": data["label"][:, t_steady:],
-                    "image": data["images"][:, t_steady:],
-                    "real_prev_image": data["images"][:, t_steady - 1:-1]}
-            if data.get("flow_gt") is not None:
-                # pair index t-1 supervises frame t
-                tail["flow_gt"] = data["flow_gt"][:, t_steady - 1:]
-                tail["conf_gt"] = data["conf_gt"][:, t_steady - 1:]
-            buffers = (prev_labels, prev_images, past_real, past_fake)
-            self.state, d_tail, g_tail = self._jit_rollout_tail(
-                self.state, buffers, tail, constants)
-            tail_counts = seq_len - t_steady
-            d_hist.append({k: jnp.sum(v) for k, v in d_tail.items()})
-            g_hist.append({k: jnp.sum(v) for k, v in g_tail.items()})
         if self.speed_benchmark:
             # lint: allow(host-sync) -- speed_benchmark timing fence
             jax.block_until_ready(self.state["vars_G"]["params"])
             self._meter("time/gen_step").write(time.time() - t0)
 
-        def mean_losses(hist, tail_n):
-            # the last entry may be a summed tail worth tail_n frames
-            keys = set().union(*(h.keys() for h in hist))
+        def mean_losses(hist):
+            # a key averages over the frames that report it (the
+            # temporal scales' losses appear once their stacks fill)
             out = {}
-            for k in keys:
-                total = 0.0
-                count = 0
-                for i, h in enumerate(hist):
-                    if k not in h:
-                        continue
-                    is_tail = tail_n and i == len(hist) - 1
-                    total = total + h[k]
-                    count += tail_n if is_tail else 1
-                out[k] = total / count
+            for k in set().union(*(h.keys() for h in hist)):
+                values = [h[k] for h in hist if k in h]
+                out[k] = sum(values) / len(values)
             return out
 
-        d_losses = mean_losses(d_hist, tail_counts)
-        g_losses = mean_losses(g_hist, tail_counts)
+        d_losses = mean_losses(d_hist)
+        g_losses = mean_losses(g_hist)
         self._log_losses("dis_update", d_losses)
         self._log_losses("gen_update", g_losses)
         return g_losses
@@ -1110,10 +860,10 @@ class Trainer(BaseTrainer):
         return None
 
     def _register_step_flops(self, data):
-        """No-op: the video families step through per-frame programs
-        (+ an optional scan tail), not the base two-program step —
-        lowering those unused programs here would trigger pointless
-        compiles. MFU for this family comes from scripts/perf_lab.py."""
+        """No-op: the video families step through per-frame programs,
+        not the base two-program step — lowering those unused programs
+        here would trigger pointless compiles. No ``perf/mfu`` for this
+        family."""
         return None
 
     # ----------------------------------------------------------- curriculum

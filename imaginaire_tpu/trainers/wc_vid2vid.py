@@ -130,15 +130,6 @@ class Trainer(Vid2VidTrainer):
             base = parent
         return path  # let Config() raise its own FileNotFoundError
 
-    def _pipeline_eligible(self, data, seq_len):
-        """Never pipeline (ISSUE 14): every frame here round-trips through
-        host-side hooks — ``_frame_override`` below and the point-cloud
-        coloring in ``_after_gen_frame`` read back the generated frame
-        before the next one may be sliced, so there is nothing to overlap.
-        The base eligibility check would already refuse on the hook
-        overrides; stating it explicitly keeps the contract visible."""
-        return False
-
     def _frame_override(self, data_t):
         """Frozen single-image SPADE takeover while flow features are
         unavailable (ref: generators/wc_vid2vid.py:169-185): the same

@@ -140,8 +140,8 @@ def save_checkpoint(logdir, state, epoch, iteration, max_to_keep=None,
     it never names an uncommitted checkpoint.
 
     ``checksum`` computes per-leaf crc32 checksums of the state at
-    dispatch time (one device_get of the addressable leaves — see
-    PROFILE.md for the cost) and writes them into the checkpoint's
+    dispatch time (one device_get of the addressable leaves) and
+    writes them into the checkpoint's
     sidecar after the commit; ``partition_descriptor`` (the active
     partition plan's ``describe()``) makes that sidecar the existing
     ``.partition.json``, otherwise checksums land in

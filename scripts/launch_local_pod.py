@@ -53,7 +53,6 @@ instead of failing the drill.
 from __future__ import annotations
 
 import argparse
-import json
 import os
 import re
 import signal
@@ -119,14 +118,6 @@ def parse_args(argv=None):
                          "as a joiner into the same pod — the grow-back "
                          "hook for a rank whose in-process resize "
                          "failed")
-    ap.add_argument("--bench", action="store_true",
-                    help="clean throughput-bench mode (ISSUE 14): no "
-                         "chaos/drill scaffolding armed (inherited "
-                         "IMAGINAIRE_ELASTIC*/persistent-cache env is "
-                         "scrubbed from the children), child stdout is "
-                         "relayed UN-prefixed, and every JSON line a "
-                         "child prints is captured into one final "
-                         "leg-summary JSON on the harness stdout")
     ap.add_argument("--child-log-dir", default=None,
                     help="tee each child's full output to "
                          "<dir>/p<i>.log (elastic mode default: "
@@ -142,12 +133,6 @@ def parse_args(argv=None):
         ap.error("no command given (everything after '--')")
     if args.elastic and not args.logdir:
         ap.error("--elastic requires --logdir (join rendezvous dir)")
-    if args.bench and (args.elastic or args.expect_failure
-                       or args.kill_rank is not None
-                       or args.kill_after_s is not None or args.relaunch):
-        ap.error("--bench is a clean throughput mode: no chaos/drill "
-                 "flags (--elastic/--expect-failure/--kill-rank/"
-                 "--kill-after-s/--relaunch)")
     args.command = cmd
     args.expect_exit_map = parse_exit_map(args.expect_exit_map, ap)
     if args.child_log_dir is None and args.elastic and args.logdir:
@@ -176,18 +161,11 @@ def parse_exit_map(spec, ap=None):
     return out
 
 
-def _relay_factory(write_lock, log_dir=None, bare=False, json_sink=None):
+def _relay_factory(write_lock, log_dir=None):
     """A relay function that prefixes each child line onto stdout and —
     when ``log_dir`` is set — tees the child's FULL output to
     ``<log_dir>/<tag>.log`` (the post-mortem record a truncated
-    harness capture loses, ISSUE 13).
-
-    Bench mode (ISSUE 14): ``bare=True`` drops the ``[p<i>] `` prefix —
-    throughput legs feed downstream JSON parsers, and a prefix turns
-    every child metric line into garbage.  With ``json_sink`` set, any
-    child line that parses as a JSON object is captured as
-    ``(tag, obj)`` instead of echoed; the caller folds the rows into one
-    leg-summary JSON so N children never interleave N summaries."""
+    harness capture loses, ISSUE 13)."""
     if log_dir:
         os.makedirs(log_dir, exist_ok=True)
 
@@ -202,17 +180,8 @@ def _relay_factory(write_lock, log_dir=None, bare=False, json_sink=None):
             if logf is not None:
                 logf.write(line)
                 logf.flush()
-            if json_sink is not None and line.lstrip().startswith("{"):
-                try:
-                    obj = json.loads(line)
-                except ValueError:
-                    obj = None
-                if isinstance(obj, dict):
-                    with write_lock:
-                        json_sink.append((tag, obj))
-                    continue
             with write_lock:
-                sys.stdout.write(line if bare else f"[{tag}] {line}")
+                sys.stdout.write(f"[{tag}] {line}")
                 sys.stdout.flush()
         pipe.close()
         if logf is not None:
@@ -223,33 +192,19 @@ def _relay_factory(write_lock, log_dir=None, bare=False, json_sink=None):
 
 def launch_pod(command, num_processes=2, devices_per_process=1,
                timeout=1800.0, coordinator_port=None, extra_env=None,
-               prefix_output=True, cwd=None, log_dir=None,
-               bare_output=False, json_sink=None, scrub_env=()):
+               prefix_output=True, cwd=None, log_dir=None):
     """Spawn the pod; returns ``(exit_codes, wall_s)`` with one exit
     code per process (None replaced by -9 when the timeout killed it).
-
-    ``bare_output``/``json_sink`` select the bench relay (see
-    ``_relay_factory``); ``scrub_env`` names env keys (or ``prefix*``
-    patterns) popped from every child env — bench legs must not inherit
-    drill scaffolding or the known-bad persistent-cache deserialize path
-    (PR-7 bisect).
     """
     port = coordinator_port or free_port()
     here = cwd or os.getcwd()
     procs = []
     readers = []
     write_lock = threading.Lock()
-    relay = _relay_factory(write_lock, log_dir, bare=bare_output,
-                           json_sink=json_sink)
+    relay = _relay_factory(write_lock, log_dir)
 
     for idx in range(num_processes):
         env = dict(os.environ, **(extra_env or {}))
-        for pattern in scrub_env:
-            if pattern.endswith("*"):
-                for key in [k for k in env if k.startswith(pattern[:-1])]:
-                    env.pop(key, None)
-            else:
-                env.pop(pattern, None)
         env["IMAGINAIRE_DIST_COORDINATOR"] = f"127.0.0.1:{port}"
         env["IMAGINAIRE_DIST_NUM_PROCESSES"] = str(num_processes)
         env["IMAGINAIRE_DIST_PROCESS_ID"] = str(idx)
@@ -502,31 +457,6 @@ def main(argv=None):
               and all(first[i] == expected.get(i, 0)
                       for i in range(args.num_processes)))
         return 0 if ok else 1
-    if args.bench:
-        # clean throughput leg: children run without drill scaffolding
-        # (inherited elastic env); every JSON line they print folds into
-        # ONE leg-summary JSON here
-        sink = []
-        codes, wall, timed_out = launch_pod(
-            args.command, num_processes=args.num_processes,
-            devices_per_process=args.devices_per_process,
-            timeout=args.timeout, coordinator_port=args.coordinator_port,
-            log_dir=args.child_log_dir, bare_output=True, json_sink=sink,
-            scrub_env=("IMAGINAIRE_ELASTIC*",))
-        summary = {
-            "pod_bench": {
-                "process_count": args.num_processes,
-                "devices_per_process": args.devices_per_process,
-                "exit_codes": codes,
-                "wall_s": round(wall, 2),
-                "timed_out": timed_out,
-                "rows": [dict(obj, _rank=tag) for tag, obj in sink],
-            }
-        }
-        print(json.dumps(summary))
-        if timed_out:
-            return 124
-        return 0 if all(c == 0 for c in codes) else 1
     codes, wall, timed_out = launch_pod(
         args.command, num_processes=args.num_processes,
         devices_per_process=args.devices_per_process,

@@ -17,7 +17,7 @@ scripts/partition_budget.py.
 
 Usage (fresh process; the virtual mesh must be set before jax wakes up):
   python scripts/memory_autotune.py --families spade --hw 512 512 \
-      --bs 4 --json MEMBENCH.json
+      --bs 4 --json /tmp/membench.json
   python scripts/memory_autotune.py --families vid2vid --hw 512 1024 \
       --bs 1 --policies none,blocks --dtypes float32,bfloat16
   python scripts/memory_autotune.py \
@@ -157,7 +157,7 @@ def recommend(rows, bytes_limit=None, mem_budget_frac=0.9):
 
 
 def profile_rows(family, hw, rows, frontier_names, recommended_name):
-    """PROFILE.md table lines for one family sweep."""
+    """Markdown table lines for one family sweep."""
     lines = []
     for r in sorted(rows, key=lambda r: r["name"]):
         if r.get("temp_bytes") is None:
@@ -359,8 +359,7 @@ def _apply_candidate(cfg, cand):
     instance-norm statistics only, so the axis also pins the SPADE base
     norm to 'instance' on BOTH arms (fused AND unfused) to keep the
     comparison apples-to-apples; rows from such sweeps are therefore
-    not directly comparable to sync_batch-base rows (PROFILE.md notes
-    this next to the ISSUE-16 table)."""
+    not directly comparable to sync_batch-base rows."""
     from imaginaire_tpu.config import cfg_get
 
     cfg.gen.remat = cand["remat_policy"]
@@ -443,8 +442,8 @@ def measure_candidate(family, hw, cand, mesh):
                           xla_obs.ledger_flops(),
                           _tree_bytes(state_shapes))
     if cand["compute_dtype"] != "float32" and jax.default_backend() != "tpu":
-        # the CPU backend legalizes bf16 convs through f32 (+~24% temp,
-        # PROFILE.md ISSUE-10): record the row but bar it from
+        # the CPU backend legalizes bf16 convs through f32 (about +24%
+        # temp in an earlier CPU sweep): record the row but bar it from
         # pareto/recommendation (ISSUE 16)
         row["legalized"] = True
     return row
@@ -472,8 +471,7 @@ def main(argv=None):
     ap.add_argument("--devices", type=int, default=1,
                     help="virtual CPU mesh size (data axis)")
     ap.add_argument("--json", default=None,
-                    help="write the machine-readable report here "
-                         "(MEMBENCH.json)")
+                    help="write the machine-readable report here")
     args = ap.parse_args(argv)
     families = [f.strip() for f in args.families.split(",") if f.strip()]
     unknown = [f for f in families if f not in FAMILIES]

@@ -4,8 +4,8 @@ Lowers + compiles a family's step programs through the compile ledger
 WITHOUT executing them — state and batch enter as ``ShapeDtypeStruct``
 trees carrying the plan's ``NamedSharding``s, so shapes that do NOT fit
 a real chip (spade-512 zoo, 512x1024 vid2vid) still compile on the
-virtual CPU mesh and report ``memory_analysis``. Emits the PROFILE.md
-before/after rows: per-executable temp/argument bytes plus the per-chip
+virtual CPU mesh and report ``memory_analysis``. Emits before/after
+rows: per-executable temp/argument bytes plus the per-chip
 state-tree residency under the requested mesh.
 
 Usage (virtual mesh; run in a fresh process):

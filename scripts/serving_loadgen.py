@@ -1,11 +1,10 @@
 #!/usr/bin/env python
 """Closed/open-loop serving load harness CLI (ISSUE 20).
 
-Drives the tiny-SPADE serving engine (the same width/buckets as
-``bench.py run_serving_ab``) with Poisson offered load at a sweep of
-rates, plus an optional closed-loop capacity point and a streaming
-burst, and records the offered-load-vs-latency curve into
-SERVEBENCH.json under ``"loadgen"``:
+Drives the tiny-SPADE serving engine with Poisson offered load at a
+sweep of rates, plus an optional closed-loop capacity point and a
+streaming burst, and prints the offered-load-vs-latency curve under
+``"loadgen"``:
 
     per point: offered_rps, achieved_rps, p50_ms, p99_ms,
                queue_depth_max/mean, rejected, slo_burn_rate
@@ -20,7 +19,7 @@ Usage:
     python scripts/serving_loadgen.py                      # default sweep
     python scripts/serving_loadgen.py --rates 2,6,12 --duration 4
     python scripts/serving_loadgen.py --slo-p99-ms 150 --streams 2
-    python scripts/serving_loadgen.py --no-merge --telemetry-out /tmp/t.jsonl
+    python scripts/serving_loadgen.py --telemetry-out /tmp/t.jsonl
 """
 
 from __future__ import annotations
@@ -39,8 +38,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 def _tiny_spade_cfg(hw_buckets, batch_sizes, slo_p99_ms, availability,
                     window, sample_rate, max_queue):
-    """The run_serving_ab tiny width, plus the ISSUE-20 serving knobs
-    (trace sampling + SLO budget) the bench A/B leaves at defaults."""
+    """A tiny SPADE width (nf 4) with the ISSUE-20 serving knobs
+    (trace sampling + SLO budget) set."""
     from imaginaire_tpu.config import Config
 
     cfg = Config()
@@ -127,7 +126,7 @@ def build_engine(hw_buckets, batch_sizes, slo_p99_ms=None,
 def main():
     ap = argparse.ArgumentParser(
         description="Offered-load sweep against the tiny-SPADE serving "
-                    "engine (SERVEBENCH loadgen curve)")
+                    "engine")
     ap.add_argument("--rates", default="2,6,12",
                     help="comma-separated offered rates (requests/s) "
                          "for the open-loop sweep, lowest first")
@@ -161,8 +160,6 @@ def main():
     ap.add_argument("--telemetry-out", default=None,
                     help="dump the run's telemetry events (trace/ "
                          "records, serve/slo/* counters) to this jsonl")
-    ap.add_argument("--no-merge", action="store_true",
-                    help="skip merging the curve into SERVEBENCH.json")
     args = ap.parse_args()
 
     import jax
@@ -223,12 +220,6 @@ def main():
             for ev in events:
                 f.write(json.dumps(ev, default=str) + "\n")
         payload["loadgen"]["telemetry_jsonl"] = args.telemetry_out
-    if not args.no_merge:
-        sys.path.insert(0, os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        from bench import _merge_servebench
-
-        _merge_servebench(payload)
     print(json.dumps(payload, indent=1, default=str))
     return payload
 

@@ -1,6 +1,5 @@
 #!/usr/bin/env python
-"""Render a run's telemetry.jsonl into the PROFILE.md-style per-phase
-attribution table (counts, totals, p50/p99, share of wall) plus the
+"""Render a run's telemetry.jsonl into a per-phase attribution table (counts, totals, p50/p99, share of wall) plus the
 derived counters (imgs/sec, MFU, step percentiles), the training-health
 section (grad-norm / update-ratio trends, D real/fake accuracy, D/G
 loss-ratio EWMA with breach counts, non-finite triage events), the

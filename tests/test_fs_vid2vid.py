@@ -10,6 +10,7 @@ import pytest
 
 from imaginaire_tpu.config import Config
 from imaginaire_tpu.registry import resolve
+from imaginaire_tpu.telemetry import xla_obs
 
 CFG = os.path.join(os.path.dirname(__file__), "..", "configs", "unit_test",
                    "fs_vid2vid.yaml")
@@ -57,8 +58,11 @@ class TestFsVid2VidTraining:
         for it in range(1, 3):
             batch = trainer.start_of_iteration(fewshot_video_batch(rng), it)
             trainer.dis_update(batch)
+            mark = xla_obs.ledger().snapshot()
             g = trainer.gen_update(batch)
             trainer.end_of_iteration(batch, 0, it)
+        # the second rollout, of the same shapes, compiled nothing
+        assert xla_obs.snapshot_delta(mark)["compiles"] == 0
         for name, v in g.items():
             assert np.isfinite(float(jax.device_get(v))), name
         # ref-warp flow loss active from frame 0 (warp_ref=True)

@@ -44,8 +44,7 @@ class TestEnumeration:
 
     def test_modulation_axis_opt_in(self):
         # ISSUE 16: the fused-SPADE axis doubles the grid and suffixes
-        # candidate names; omitting it keeps the PR-9 name shape so old
-        # MEMBENCH rows stay comparable
+        # candidate names; omitting it keeps the PR-9 name shape
         plain = ma.enumerate_candidates(["none"], ["float32"], [4])
         assert [c["name"] for c in plain] == ["none/float32/bs4"]
         assert "spade_modulation" not in plain[0]

@@ -160,7 +160,7 @@ def test_mlp_multiclass(key, rng):
 
 
 class TestSpadeRemat:
-    """gen.remat knob (TPU memory/speed lever; measured in PROFILE.md)."""
+    """gen.remat knob (TPU memory/speed lever)."""
 
     def test_param_tree_identical_and_bad_value_loud(self, rng, tmp_path):
         import jax

@@ -19,6 +19,7 @@ from imaginaire_tpu.model_utils.fs_vid2vid import (
     get_hand_bbox_for_output,
 )
 from imaginaire_tpu.registry import resolve
+from imaginaire_tpu.telemetry import xla_obs
 
 HERE = os.path.dirname(__file__)
 CFG = os.path.join(HERE, "..", "configs", "unit_test", "vid2vid_pose.yaml")
@@ -136,7 +137,10 @@ class TestPoseTraining:
         for it in range(1, 3):
             b = trainer.start_of_iteration(batch, it)
             trainer.dis_update(b)
+            mark = xla_obs.ledger().snapshot()
             g = trainer.gen_update(b)
+        # the second rollout, of the same shapes, compiled nothing
+        assert xla_obs.snapshot_delta(mark)["compiles"] == 0
         for name, v in g.items():
             assert np.isfinite(float(jax.device_get(v))), name
         assert "GAN_face" in g and "GAN_hand" in g

@@ -25,7 +25,7 @@ from imaginaire_tpu.ops import spade_modulation
 from imaginaire_tpu.ops.spade_modulation import AUTO_IMPLEMENTATION
 
 # downscaled-channel stand-ins for the spade-128/256/512 pyramid levels
-# (full-channel operating points are scripts/opsbench.py's job); the last
+# (full-channel shapes are too slow for the CPU tier); the last
 # is the multi-cond accumulation case (seg + edge + prior-frame maps)
 SHAPES = [((2, 32, 32, 8), 1),    # spade-128 deep block
           ((2, 16, 16, 12), 2),   # spade-256 deep block, 2 conditions

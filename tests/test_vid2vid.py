@@ -11,6 +11,7 @@ import pytest
 
 from imaginaire_tpu.config import Config
 from imaginaire_tpu.registry import resolve
+from imaginaire_tpu.telemetry import xla_obs
 
 CFG = os.path.join(os.path.dirname(__file__), "..", "configs", "unit_test",
                    "vid2vid_street.yaml")
@@ -55,8 +56,11 @@ class TestVid2VidTraining:
         for it in range(1, 3):
             batch = trainer.start_of_iteration(video_batch(rng), it)
             trainer.dis_update(batch)  # no-op by contract
+            mark = xla_obs.ledger().snapshot()
             g = trainer.gen_update(batch)
             trainer.end_of_iteration(batch, 0, it)
+        # the second rollout, of the same shapes, compiled nothing
+        assert xla_obs.snapshot_delta(mark)["compiles"] == 0
         for name, v in g.items():
             assert np.isfinite(float(jax.device_get(v))), name
         # flow loss active (warp happened) and temporal GAN active
@@ -326,34 +330,60 @@ class TestMultiDeviceVid2Vid:
             set_mesh(old)
 
 
-@pytest.mark.slow
-class TestRolloutScan:
-    """trainer.rollout_scan: the steady-state tail of the interleaved
-    rollout runs as one lax.scan program (trainers/vid2vid.py::
-    _rollout_tail_fn, SURVEY §7 hard-part #3). Same data + same seeds
-    must give the same training result as the per-frame path."""
+def _rollout_batch(cfg):
+    """One dataset item of the config as a batch of one clip."""
+    item = resolve(cfg.data.type, "Dataset")(cfg)[0]
+    return {k: jnp.asarray(v)[None] for k, v in item.items()
+            if isinstance(v, np.ndarray) and v.ndim >= 3}
 
-    def _run(self, scan, tmp_path, t=4):
-        cfg = Config(CFG)
-        cfg.logdir = str(tmp_path / ("scan" if scan else "loop"))
-        cfg.trainer.rollout_scan = scan
-        # shrink the perceptual graph: equivalence, not capacity
-        cfg.trainer.perceptual_loss.layers = ["relu_1_1", "relu_2_1"]
-        cfg.trainer.perceptual_loss.weights = [0.5, 1.0]
+
+class TestRolloutDispatch:
+    """The per-frame sequential loop is the video trainers' one dispatch
+    form. The step programs are stand-ins that count (what they compute
+    is the slow tier's business, ``test_rollout_two_iterations``): per
+    frame a D step then a G step, each taking the state the last one
+    returned, each handed to the health monitor before the next is
+    issued, nothing left to hand over when ``gen_update`` returns."""
+
+    @pytest.mark.parametrize("name", ["vid2vid_street.yaml",
+                                      "vid2vid_pose.yaml",
+                                      "fs_vid2vid.yaml"])
+    def test_each_frame_d_then_g_in_order(self, name, tmp_path):
+        cfg = Config(os.path.join(os.path.dirname(CFG), name))
+        cfg.logdir = str(tmp_path)
+        data = _rollout_batch(cfg)
+        frames = data["images"].shape[1]
         trainer = resolve(cfg.trainer.type, "Trainer")(cfg)
-        data = video_batch(np.random.RandomState(7), t=t)
-        trainer.init_state(jax.random.PRNGKey(0), data)
-        losses = trainer.gen_update(data)
-        leaf = jax.tree_util.tree_leaves(
-            trainer.state["vars_G"]["params"])[0]
-        return ({k: float(jax.device_get(v)) for k, v in losses.items()},
-                np.asarray(jax.device_get(leaf)))
+        events = []
 
-    def test_scan_matches_per_frame_path(self, tmp_path):
-        losses_a, leaf_a = self._run(False, tmp_path)
-        losses_b, leaf_b = self._run(True, tmp_path)
-        assert set(losses_a) == set(losses_b)
-        for k in losses_a:
-            np.testing.assert_allclose(losses_b[k], losses_a[k],
-                                       rtol=2e-3, atol=2e-4, err_msg=k)
-        np.testing.assert_allclose(leaf_b, leaf_a, rtol=2e-3, atol=2e-4)
+        def dis_step(state, data_t):
+            events.append(("D step", state["n"]))
+            return {"n": state["n"] + 1}, {"GAN": jnp.float32(1)}, None
+
+        def gen_step(state, data_t):
+            events.append(("G step", state["n"]))
+            # frame t sees the t fakes before it, newest last
+            prev = data_t.get("prev_images")
+            seen = [] if prev is None else [
+                int(v) for v in np.asarray(prev[0, :, 0, 0, 0])]
+            t = state["n"] // 2
+            assert seen == list(range(t))[-(trainer.num_frames_G - 1):]
+            fake = jnp.full_like(data_t["image"], t)
+            return ({"n": state["n"] + 1}, {"total": jnp.float32(t)},
+                    fake, None)
+
+        def observe(owner, which, losses, health, batch, iteration):
+            events.append((f"{which} observed", owner.state["n"]))
+
+        trainer._jit_vid_dis, trainer._jit_vid_gen = dis_step, gen_step
+        trainer.diag.observe = observe
+        trainer.state = {"n": 0}
+        trainer.current_iteration = 1
+        losses = trainer.gen_update(trainer._start_of_iteration(data, 1))
+        assert events == [
+            event for t in range(frames) for event in (
+                ("D step", 2 * t), ("D observed", 2 * t + 1),
+                ("G step", 2 * t + 1), ("G observed", 2 * t + 2))]
+        assert trainer.state == {"n": 2 * frames}
+        # the rollout's loss is the mean over its frames
+        assert float(losses["total"]) == (frames - 1) / 2
