@@ -15,18 +15,23 @@ held experts' part of the result for the tokens routed to them and
 leaves the absent experts' terms out. ``vocab_slice`` is the slice of
 the vocabulary held here: ids, logits and loss are over the slice.
 
-The numerics are plain ``jax.numpy``/``lax``: the chunked state-space
-dual form of the Mamba-2 recurrence (``ssd_scan``), causal attention by
-query blocks (``causal_attention``), and dropless routing with static
-shapes (``route_held``: sort the assignments by expert, the held ones
-first, into a buffer of ``expert_buffer_rows`` rows, two grouped products
-by ``lax.ragged_dot``, scatter back weighted). ``expert_buffer_rows`` is
-the buffer's capacity, what the largest routing may hold; a step computes
-the filled prefix of it (``on_filled_prefix``: a row a token where its
-held assignments fit that, the whole buffer otherwise, the same
-arithmetic either way). The fp32 islands (router
-scores, the scan's step sizes, decays and carried state, RMS statistics,
-the loss) are declared in ``analysis/islands.py``.
+The numerics are plain ``jax.numpy``/``lax`` but for attention: the
+chunked state-space dual form of the Mamba-2 recurrence (``ssd_scan``),
+and dropless routing with static shapes (``route_held``: sort the
+assignments by expert, the held ones first, into a buffer of
+``expert_buffer_rows`` rows, two grouped products by ``lax.ragged_dot``,
+scatter back weighted). ``expert_buffer_rows`` is the buffer's capacity,
+what the largest routing may hold; a step computes the filled prefix of
+it (``on_filled_prefix``: a row a token where its held assignments fit
+that, the whole buffer otherwise, the same arithmetic either way).
+Causal grouped-query attention lives in ``ops/attention.py`` and picks
+its own arm from what it observes: one fused Pallas kernel that keeps the
+scores in VMEM where the backend is a TPU, the head size a multiple of
+128 and the length a multiple of the kernel's tiles; query blocks of
+``attn_query_block`` rows in plain ``jax.numpy`` everywhere else (the
+CPU, ragged lengths). The fp32 islands (router scores, the scan's step
+sizes, decays and carried state, RMS statistics, the loss) are declared
+in ``analysis/islands.py``.
 
 Precision: the parameters arrive in float32 and each layer casts its
 kernels to ``compute_dtype`` where it uses them, inside the layer's
@@ -50,6 +55,7 @@ from jax import lax
 
 from imaginaire_tpu.analysis import islands
 from imaginaire_tpu.config import cfg_get
+from imaginaire_tpu.ops.attention import attention
 from imaginaire_tpu.optim.remat import remat_block
 
 
@@ -232,35 +238,6 @@ def _a_log_init(key, shape, dtype=jnp.float32):
 # --------------------------------------------------------------- attention
 
 
-def causal_attention(q, k, v, block):
-    """Causal grouped-query attention, scale ``1/sqrt(head size)``, no
-    position embedding. ``q`` (B, L, Hq, d), ``k``, ``v`` (B, L, Hkv, d);
-    query head ``h`` reads key-value head ``h // (Hq/Hkv)``. Query blocks
-    of ``block`` rows, each against the keys up to its own end, each
-    under ``jax.checkpoint``: the (Hq, block, keys) scores of one block
-    stand at a time, in float32."""
-    bsz, length, q_heads, dim = q.shape
-    kv_heads = k.shape[2]
-    q = q.reshape(bsz, length, kv_heads, q_heads // kv_heads, dim)
-    scale = 1.0 / math.sqrt(dim)
-
-    @jax.checkpoint
-    def one(qb, kb, vb, start):
-        s = jnp.einsum("bqgrd,bkgd->bgrqk", qb, kb,
-                       preferred_element_type=jnp.float32) * scale
-        rows = start + jnp.arange(qb.shape[1])[:, None]
-        s = jnp.where(rows >= jnp.arange(kb.shape[1])[None, :], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1).astype(vb.dtype)
-        return jnp.einsum("bgrqk,bkgd->bqgrd", p, vb)
-
-    outs = []
-    for start in range(0, length, block):
-        end = min(start + block, length)
-        outs.append(one(q[:, start:end], k[:, :end], v[:, :end], start))
-    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
-    return out.reshape(bsz, length, q_heads * dim)
-
-
 class AttentionMixer(nn.Module):
     cfg: Any
 
@@ -283,7 +260,7 @@ class AttentionMixer(nn.Module):
             v = (u @ w_v.astype(dtype)).reshape(
                 *lead, g.num_key_value_heads, g.head_dim)
         with jax.named_scope("lm/attn/scores"):
-            y = causal_attention(q, k, v, g.attn_query_block)
+            y = attention(q, k, v, g.attn_query_block)
         with jax.named_scope("lm/attn/out"):
             return y @ w_o.astype(dtype)
 

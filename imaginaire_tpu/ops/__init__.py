@@ -37,6 +37,15 @@ recast as per-displacement-row matmuls plus a strided band-gather — with
 the scan path covering general kernel sizes, spade_modulation to 'fused'
 (the custom_vjp residual-trimming path).
 
+attention
+---------
+``ops/attention.py`` (the token model's causal grouped-query attention)
+is not a reference op and takes no ``implementation``: it picks its own
+arm from the backend, the head size and the length (``arm_of``), the
+fused Pallas kernel of ``ops/pallas/causal_attention_kernel.py`` or
+query blocks in plain ``jax.numpy``. Import it as a module,
+``from imaginaire_tpu.ops import attention``.
+
 auto pins
 ---------
 Every ``AUTO_IMPLEMENTATION`` is pinned to the XLA formulation; not

@@ -1,0 +1,130 @@
+"""Causal grouped-query attention: scale ``1/sqrt(head size)``, no
+position embedding, query head ``h`` on key-value head ``h // (Hq/Hkv)``.
+
+One algorithm, two arms, and ``attention`` picks between them from what
+it can observe (``arm_of``), with no option:
+
+  ``fused``   one Pallas TPU kernel of three passes
+              (``ops/pallas/causal_attention_kernel.py``): the scores of a
+              tile stand in VMEM, the row maximum and row sum run along
+              the key tiles in float32, scores and probabilities never
+              reach HBM, and the tiles above the diagonal are neither
+              computed nor fetched. Where the backend is a TPU, the head
+              size a multiple of 128 and the length a multiple of the
+              kernel's largest tile.
+  ``blocks``  ``causal_attention``: query blocks in plain ``jax.numpy``,
+              each against the keys up to its own end, the (Hq, block,
+              keys) scores of one block in HBM at a time. Everywhere
+              else: the CPU, where the tests run, and ragged lengths.
+
+Both take bfloat16 (the compute dtype's) operands, accumulate products in
+float32, mask and take the softmax statistics in float32 and cast the
+probabilities to the values' dtype for their product. The kernel's tiles
+are constants here, chosen on a v5e chip (PERF.md, PR 30); they are not
+configuration.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from imaginaire_tpu.ops.pallas import causal_attention_kernel as kernel
+
+
+class Tiles(NamedTuple):
+    """(query rows, key rows) of a tile, for each pass of the kernel."""
+    fwd: tuple
+    dkv: tuple
+    dq: tuple
+
+    @property
+    def largest(self):
+        return max(*self.fwd, *self.dkv, *self.dq)
+
+
+TILES = Tiles(fwd=(1024, 1024), dkv=(1024, 1024), dq=(1024, 1024))
+
+
+def arm_of(head_dim, length):
+    """``"fused"`` or ``"blocks"``: which arm ``attention`` takes for a
+    head size and a length on this process's backend."""
+    on_tpu = jax.default_backend() == "tpu"
+    fits = head_dim % kernel.LANES == 0 and length % TILES.largest == 0
+    return "fused" if on_tpu and fits else "blocks"
+
+
+def attention(q, k, v, block):
+    """``q`` (B, L, Hq, d), ``k``, ``v`` (B, L, Hkv, d) to (B, L, Hq*d).
+    ``block`` is the plain arm's query block; the fused arm's tiles are
+    ``TILES``."""
+    if arm_of(q.shape[-1], q.shape[1]) == "fused":
+        return fused_causal_attention(q, k, v)
+    return causal_attention(q, k, v, block)
+
+
+def causal_attention(q, k, v, block):
+    """Causal grouped-query attention, scale ``1/sqrt(head size)``, no
+    position embedding. ``q`` (B, L, Hq, d), ``k``, ``v`` (B, L, Hkv, d);
+    query head ``h`` reads key-value head ``h // (Hq/Hkv)``. Query blocks
+    of ``block`` rows, each against the keys up to its own end, each
+    under ``jax.checkpoint``: the (Hq, block, keys) scores of one block
+    stand at a time, in float32."""
+    bsz, length, q_heads, dim = q.shape
+    kv_heads = k.shape[2]
+    q = q.reshape(bsz, length, kv_heads, q_heads // kv_heads, dim)
+    scale = 1.0 / math.sqrt(dim)
+
+    @jax.checkpoint
+    def one(qb, kb, vb, start):
+        s = jnp.einsum("bqgrd,bkgd->bgrqk", qb, kb,
+                       preferred_element_type=jnp.float32) * scale
+        rows = start + jnp.arange(qb.shape[1])[:, None]
+        s = jnp.where(rows >= jnp.arange(kb.shape[1])[None, :], s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1).astype(vb.dtype)
+        return jnp.einsum("bgrqk,bkgd->bqgrd", p, vb)
+
+    outs = []
+    for start in range(0, length, block):
+        end = min(start + block, length)
+        outs.append(one(q[:, start:end], k[:, :end], v[:, :end], start))
+    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+    return out.reshape(bsz, length, q_heads * dim)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def fused_causal_attention(q, k, v, tiles=TILES, interpret=False):
+    """``causal_attention`` by the fused kernel. ``interpret`` runs the
+    kernel in Pallas's interpreter (the CPU tests)."""
+    return _fused_fwd(q, k, v, tiles, interpret)[0]
+
+
+def _flat(x):
+    return x.reshape(*x.shape[:2], -1)
+
+
+def _fused_fwd(q, k, v, tiles, interpret):
+    out, lse = kernel.forward(_flat(q), _flat(k), _flat(v), q.shape[2],
+                              k.shape[2], *tiles.fwd, interpret=interpret)
+    return out, (q, k, v, out, lse)
+
+
+def _fused_bwd(tiles, interpret, saved, do):
+    q, k, v, out, lse = saved
+    heads = q.shape[2], k.shape[2]
+    # the rows' sum(do * out), (B, Hq, L) as the log-sum-exp
+    di = (do.astype(jnp.float32) * out.astype(jnp.float32)).reshape(
+        q.shape).sum(-1).transpose(0, 2, 1)
+    operands = _flat(q), _flat(k), _flat(v), do, lse, di
+    dk, dv = kernel.backward_dkv(*operands, *heads, *tiles.dkv,
+                                 interpret=interpret)
+    dq = kernel.backward_dq(*operands, *heads, *tiles.dq,
+                            interpret=interpret)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
+fused_causal_attention.defvjp(_fused_fwd, _fused_bwd)

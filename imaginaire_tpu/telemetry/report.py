@@ -1064,6 +1064,16 @@ def render_report(path_or_events):
                      f"({flops_meta.get('source')}, "
                      + (f"peak {peak:.4g} FLOP/s via " if peak else "")
                      + f"{flops_meta.get('peak_source')})")
+    attn = s["meta"].get("attn_impl")
+    if attn:
+        tiles = attn.get("tiles") or {}
+        lines.append(
+            f"- attn_impl at length {attn.get('length')}: "
+            + ", ".join(f"layer {i} {arm}" for i, arm in sorted(
+                (attn.get("layers") or {}).items(), key=lambda kv: int(kv[0])))
+            + "; fused tiles (queries x keys) "
+            + ", ".join(f"{k} {'x'.join(map(str, v))}"
+                        for k, v in tiles.items()))
     lines.extend(_experts_section(s))
     lines.extend(_health_section(s))
     lines.extend(_xla_section(s))

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from imaginaire_tpu import telemetry
 from imaginaire_tpu.config import as_attrdict, cfg_get
+from imaginaire_tpu.ops import attention
 from imaginaire_tpu.trainers.base import BaseTrainer
 
 COUNTERS = ("held_assignments", "load_max_over_mean", "buffer_occupancy",
@@ -81,10 +82,24 @@ class Trainer(BaseTrainer):
     def gen_update(self, data):
         if self._flush_t0 is None:
             self._flush_t0 = time.perf_counter()
+            self._note_attn_impl(int(data["tokens"].shape[1]))
         losses = super().gen_update(data)
         self._last_losses = losses
         self._tokens_since_flush += int(data["tokens"].size)
         return losses
+
+    def _note_attn_impl(self, length):
+        """One ``attn_impl`` meta as the step is first built: the arm
+        each attention layer's scores take at this length on this
+        backend (``ops/attention.py`` decides; nothing here does), and
+        the fused arm's tiles."""
+        tm = telemetry.get()
+        if not tm.enabled:
+            return
+        g = self.cfg.gen
+        tm.meta("attn_impl", length=length, tiles=attention.TILES._asdict(),
+                layers={str(i): attention.arm_of(g.head_dim, length)
+                        for i, kind in enumerate(g.pattern) if kind == "*"})
 
     def _flush_counters(self, tm, step):
         """At telemetry's flush, behind its fence: tokens a second over

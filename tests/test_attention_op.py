@@ -1,0 +1,140 @@
+"""``ops/attention.py``: the fused causal grouped-query attention kernel
+(in Pallas's interpreter, on the CPU) against the plain arm and against a
+float32 evaluation of the same rounded inputs; causality; the rule that
+picks the arm."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from imaginaire_tpu.ops import attention
+
+# 4 query heads over 2 key-value heads, head size 128, 512 positions; tiles
+# that differ between the passes and between queries and keys, so every
+# pass sees tiles below, on and (skipped) above the diagonal
+SHAPE = dict(bsz=2, length=512, q_heads=4, kv_heads=2, dim=128)
+TILES = attention.Tiles(fwd=(256, 128), dkv=(128, 256), dq=(256, 256))
+NAMES = ("out", "dq", "dk", "dv")
+
+
+def _inputs(seed=0, dtype=jnp.bfloat16, **over):
+    s = dict(SHAPE, **over)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    shapes = [(s["bsz"], s["length"], s["q_heads"], s["dim"]),
+              (s["bsz"], s["length"], s["kv_heads"], s["dim"]),
+              (s["bsz"], s["length"], s["kv_heads"], s["dim"]),
+              (s["bsz"], s["length"], s["q_heads"] * s["dim"])]
+    return [jax.random.normal(k, shape, jnp.float32).astype(dtype)
+            for k, shape in zip(keys, shapes)]
+
+
+def _fused(q, k, v, tiles=TILES):
+    return attention.fused_causal_attention(q, k, v, tiles, True)
+
+
+def _with_gradients(fn, q, k, v, ct):
+    out, vjp = jax.vjp(fn, q, k, v)
+    return (out, *vjp(ct.astype(out.dtype)))
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+@functools.lru_cache(maxsize=None)
+def _three_ways():
+    """(fused, plain, float32) outputs and gradients on one set of
+    bfloat16 inputs; the float32 side evaluates the same rounded inputs."""
+    q, k, v, ct = _inputs()
+    fused = _with_gradients(_fused, q, k, v, ct)
+    plain = _with_gradients(
+        lambda q, k, v: attention.causal_attention(q, k, v, 128), q, k, v, ct)
+    exact = _with_gradients(
+        lambda q, k, v: attention.causal_attention(q, k, v, 512),
+        *(x.astype(jnp.float32) for x in (q, k, v)), ct)
+    return fused, plain, exact
+
+
+@pytest.mark.parametrize("which", range(4), ids=NAMES)
+def test_fused_arm_matches_plain_arm(which):
+    fused, plain, _ = _three_ways()
+    assert fused[which].shape == plain[which].shape
+    assert fused[which].dtype == plain[which].dtype == jnp.bfloat16
+    assert np.isfinite(np.asarray(fused[which], np.float32)).all()
+    # two bfloat16 evaluations: each is some 2 to 3e-3 from float32
+    assert _rel(fused[which], plain[which]) < 6e-3
+
+
+@pytest.mark.parametrize("which", range(4), ids=NAMES)
+def test_fused_arm_is_as_close_to_float32_as_the_plain_arm(which):
+    fused, plain, exact = _three_ways()
+    fused_err = _rel(fused[which], exact[which])
+    plain_err = _rel(plain[which], exact[which])
+    assert plain_err < 5e-3
+    assert fused_err <= 1.25 * plain_err, (fused_err, plain_err)
+
+
+def test_fused_arm_in_float32_is_the_plain_arm():
+    """In float32 nothing is rounded on the way: the two arms differ by
+    the order of their sums alone."""
+    q, k, v, ct = _inputs(seed=1, dtype=jnp.float32, bsz=1)
+    fused = _with_gradients(_fused, q, k, v, ct)
+    plain = _with_gradients(
+        lambda q, k, v: attention.causal_attention(q, k, v, 512), q, k, v, ct)
+    for name, f, p in zip(NAMES, fused, plain):
+        assert _rel(f, p) < 2e-5, name
+
+
+@pytest.mark.parametrize("t", [0, 127, 128, 300, 511])
+def test_nothing_after_a_position_reaches_it(t):
+    q, k, v, _ = _inputs(seed=2, bsz=1)
+    noise = _inputs(seed=3, bsz=1)
+    after = (jnp.arange(SHAPE["length"]) > t)[None, :, None, None]
+    k2 = jnp.where(after, noise[1], k)
+    v2 = jnp.where(after, noise[2], v)
+    a = np.asarray(_fused(q, k, v), np.float32)
+    b = np.asarray(_fused(q, k2, v2), np.float32)
+    np.testing.assert_array_equal(a[:, :t + 1], b[:, :t + 1])
+    if t + 1 < SHAPE["length"]:
+        assert np.abs(a[:, t + 1:] - b[:, t + 1:]).max() > 0
+
+
+def test_one_tile_and_many_tiles_agree():
+    q, k, v, _ = _inputs(seed=4, bsz=1)
+    whole = attention.Tiles(fwd=(512, 512), dkv=(512, 512), dq=(512, 512))
+    small = attention.Tiles(fwd=(128, 128), dkv=(128, 128), dq=(128, 128))
+    assert _rel(_fused(q, k, v, small), _fused(q, k, v, whole)) < 4e-3
+
+
+@pytest.mark.parametrize("backend, dim, length, arm", [
+    ("tpu", 128, 8192, "fused"),
+    ("tpu", 256, 2048, "fused"),
+    ("cpu", 128, 8192, "blocks"),      # where the tests run
+    ("tpu", 128, 8192 + 50, "blocks"),  # a ragged length
+    ("tpu", 128, 512, "blocks"),       # shorter than a tile
+    ("tpu", 64, 8192, "blocks"),       # a head the lanes do not divide
+    ("tpu", 192, 8192, "blocks"),
+])
+def test_the_rule(monkeypatch, backend, dim, length, arm):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert attention.arm_of(dim, length) == arm
+
+
+@pytest.mark.parametrize("length", [64, 50])
+def test_attention_takes_the_plain_arm_here(length):
+    """On the CPU ``attention`` is ``causal_attention``, to the bit, at a
+    length the block divides and at a ragged one."""
+    q, k, v, _ = _inputs(seed=5, bsz=1, length=length, dim=16)
+    assert attention.arm_of(16, length) == "blocks"
+    np.testing.assert_array_equal(
+        np.asarray(attention.attention(q, k, v, 32), np.float32),
+        np.asarray(attention.causal_attention(q, k, v, 32), np.float32))
+
+
+def test_tiles_are_lane_multiples_and_divide_the_cells_length():
+    assert all(n % 128 == 0 for pair in attention.TILES for n in pair)
+    assert 8192 % attention.TILES.largest == 0

@@ -79,12 +79,19 @@ def test_train_py_trains_checkpoints_and_resumes(entry_point_sandbox,
         e.get("label") for e in events if e["kind"] == "meta"}
     metas = {e["name"] for e in events if e["kind"] == "meta"}
     assert "step_flops" in metas and "xla_compile/gen_step" in metas
+    # the one attention layer of 'MEM*E', on the CPU: the plain arm
+    attn = [e for e in events
+            if e["kind"] == "meta" and e["name"] == "attn_impl"]
+    assert len(attn) == 1
+    assert attn[0]["layers"] == {"3": "blocks"} and attn[0]["length"] == 64
+    assert set(attn[0]["tiles"]) == {"fwd", "dkv", "dq"}
 
     from imaginaire_tpu.telemetry.report import render_report
 
     report = render_report(os.path.join(logdir, "telemetry.jsonl"))
     assert "## experts" in report and "perf/tokens_per_sec" in report
     assert "gen_step: 0 violation(s)" in report
+    assert "attn_impl at length 64: layer 3 blocks; fused tiles" in report
 
     # the resume leg: restores iteration 2 and trains on to 3
     capsys.readouterr()

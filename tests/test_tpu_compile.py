@@ -89,6 +89,38 @@ def test_spade_modulation_kernel_compiles(one_chip, shape, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_fused_attention_compiles_at_the_token_cells_shape(one_chip):
+    """The three passes of ``ops/attention.py``'s fused arm at
+    nemotron3_nano_30b_a3b's attention layer (32 query heads over 2, head
+    size 128, 8,192 positions) and the tiles the program uses. Each
+    kernel's instruction stands on one line of the optimized HLO with its
+    ``op_name`` under the caller's scope: that is how a trace's events
+    are counted under ``lm/attn/scores``."""
+    from imaginaire_tpu.ops import attention
+
+    def loss(q, k, v):
+        with jax.named_scope("lm/attn/scores"):
+            out = attention.fused_causal_attention(q, k, v)
+        return jnp.sum(out.astype(jnp.float32))
+
+    q = _sds((1, 8192, 32, 128), jnp.bfloat16, one_chip)
+    kv = _sds((1, 8192, 2, 128), jnp.bfloat16, one_chip)
+    compiled = _compile(
+        jax.value_and_grad(jax.checkpoint(loss), argnums=(0, 1, 2)), q, kv, kv)
+    calls = [line for line in compiled.as_text().splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert sorted(line.split("=")[0].strip().lstrip("%").split(".")[0]
+                  for line in calls) == [
+        # forward, its recompute under the checkpoint, and the backward
+        "causal_gqa_dkv", "causal_gqa_dq", "causal_gqa_fwd", "causal_gqa_fwd"]
+    assert all('op_name="' in line and "lm/attn/scores" in line
+               for line in calls)
+    # no score leaves the chip: the whole layer's temporaries are a few
+    # times its operands (67 MB of queries), nowhere near the 4 GB of
+    # float32 scores
+    assert compiled.memory_analysis().temp_size_in_bytes < 6e8
+
+
 # -------------------------------------------- what ``auto`` resolves to
 
 
