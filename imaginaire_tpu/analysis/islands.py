@@ -117,3 +117,9 @@ register("ssm_scan",
          "their cumulative sums and the state carried across chunks stay "
          "fp32; bf16 decays compound over thousands of steps",
          where="imaginaire_tpu/models/generators/hybrid_lm.py")
+register("rotary_angles",
+         "a rotary position embedding's angles (position times frequency, "
+         "thousands of radians at long contexts), their cosines and sines "
+         "and the turn itself run in fp32; a bf16 angle is off by whole "
+         "radians past a few hundred positions",
+         where="imaginaire_tpu/models/generators/hybrid_lm.py")

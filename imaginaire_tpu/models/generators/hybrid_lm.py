@@ -1,12 +1,28 @@
-"""Hybrid token model: Mamba-2 mixers, sparse grouped-query attention and
-mixture-of-experts layers, laid out by a pattern string (Nemotron-H,
-``hybrid_override_pattern``: ``M`` a Mamba-2 mixer, ``*`` attention,
-``E`` a mixture of experts).
+"""Hybrid token model: Mamba-2 mixers, causal attention, dense and
+mixture-of-experts feed-forwards, laid out by a pattern string (Nemotron-H,
+``hybrid_override_pattern``: ``M`` a Mamba-2 mixer, ``*`` attention, ``-``
+a dense feed-forward, ``E`` a mixture of experts). A transformer block is
+two letters: ``*-`` or ``*E``.
 
 Every layer is one mixer behind a pre-norm residual,
 ``h = h + Mixer(RMSNorm(h))``; logits are ``RMSNorm(h) W_head``. The
 module returns the mean next-token cross-entropy itself, over token
 chunks, so the (tokens, vocabulary) logits never stand whole.
+
+What a layer is follows from the sizes the config gives, which are the
+published ones under their published names; a size a model does not have
+is absent (``_NEEDS`` says what each letter reads). ``*`` is latent
+attention where there is a ``kv_lora_rank`` (DeepSeek-V2's: queries and
+keys-values through normed low-rank latents, one rotary key shared by all
+heads beside each head's un-rotated part) and grouped-query attention
+without a position embedding otherwise. ``hidden_act`` ``silu`` makes
+every feed-forward gated, ``W_down (silu(W_gate x) * W_up x)``; ``relu2``
+(the default: Nemotron-H's ``mlp_hidden_act``) is ``W_down relu(W_up
+x)^2``. ``nextn_pattern`` adds one multi-token-prediction module
+(DeepSeek-V3's): position ``i``'s final hidden state and token ``i+1``'s
+embedding, each normed, merged by one product, through the module's own
+layers to the logits for token ``i+2`` under the main model's embedding
+and head; its loss is returned beside the main one.
 
 A share of an expert-parallel deployment: ``experts_held`` names the
 routed experts this chip holds (``{first, count, of}``). The router keeps
@@ -19,19 +35,19 @@ The numerics are plain ``jax.numpy``/``lax`` but for attention: the
 chunked state-space dual form of the Mamba-2 recurrence (``ssd_scan``),
 and dropless routing with static shapes (``route_held``: sort the
 assignments by expert, the held ones first, into a buffer of
-``expert_buffer_rows`` rows, two grouped products by ``lax.ragged_dot``,
-scatter back weighted). ``expert_buffer_rows`` is the buffer's capacity,
+``expert_buffer_rows`` rows, two grouped products by ``lax.ragged_dot``
+(three where the expert is gated), scatter back weighted). ``expert_buffer_rows`` is the buffer's capacity,
 what the largest routing may hold; a step computes the filled prefix of
 it (``on_filled_prefix``: a row a token where its held assignments fit
 that, the whole buffer otherwise, the same arithmetic either way).
-Causal grouped-query attention lives in ``ops/attention.py`` and picks
+The causal scores live in ``ops/attention.py``, which picks
 its own arm from what it observes: one fused Pallas kernel that keeps the
 scores in VMEM where the backend is a TPU, the head size a multiple of
 128 and the length a multiple of the kernel's tiles; query blocks of
 ``attn_query_block`` rows in plain ``jax.numpy`` everywhere else (the
 CPU, ragged lengths). The fp32 islands (router scores, the scan's step
-sizes, decays and carried state, RMS statistics, the loss) are declared
-in ``analysis/islands.py``.
+sizes, decays and carried state, the rotary angles, RMS statistics, the
+loss) are declared in ``analysis/islands.py``.
 
 Precision: the parameters arrive in float32 and each layer casts its
 kernels to ``compute_dtype`` where it uses them, inside the layer's
@@ -80,6 +96,36 @@ def rms_norm(x, scale, eps, groups=1):
 
 def relu2(x):
     return jnp.square(jax.nn.relu(x))
+
+
+# ``hidden_act`` -> whether a feed-forward has a gate beside its up-product
+GATED = {"relu2": False, "silu": True}
+
+
+def hidden_activation(ups):
+    """A feed-forward's hidden activations from its in-products: of one,
+    ``relu(up)^2``; of two, the gated ``silu(gate) * up``."""
+    if len(ups) == 1:
+        return relu2(ups[0])
+    gate, up = ups
+    return jax.nn.silu(gate) * up
+
+
+def feed_forward(x, kernels):
+    """``W_down act(...)`` of ``kernels`` (gate, up, down) or (up, down),
+    each cast to ``x``'s dtype where it is used."""
+    hidden = hidden_activation([x @ w.astype(x.dtype) for w in kernels[:-1]])
+    return hidden @ kernels[-1].astype(x.dtype)
+
+
+def _feed_forward_params(module, g, prefix, in_shape, down_shape):
+    """A feed-forward's kernels as parameters of ``module``:
+    ``<prefix>gate`` (where ``hidden_act`` is gated), ``<prefix>up``,
+    ``<prefix>down``."""
+    names = ("gate", "up") if GATED[g.hidden_act] else ("up",)
+    return (*(module.param(prefix + name, _kernel_init, in_shape)
+              for name in names),
+            module.param(prefix + "down", _kernel_init, down_shape))
 
 
 # ----------------------------------------------------------------- Mamba-2
@@ -265,6 +311,90 @@ class AttentionMixer(nn.Module):
             return y @ w_o.astype(dtype)
 
 
+def rotary(x, theta):
+    """Rotary position embedding over the whole last axis of ``x`` (B, L,
+    ..., d), the position along axis 1, pairs (i, i + d/2): the pair turns
+    by the angle ``t theta^(-2i/d)``. Angles, cosines and the turn in
+    float32; the result in ``x``'s dtype."""
+    dtype = x.dtype
+    length, dim = x.shape[1], x.shape[-1]
+    x32 = x.astype(jnp.float32)
+    with islands.scope("rotary_angles"):
+        inverse = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+        angles = jnp.arange(length, dtype=jnp.float32)[:, None] * inverse
+        angles = angles.reshape(1, length, *(1,) * (x.ndim - 3), dim // 2)
+        cos, sin = jnp.cos(angles), jnp.sin(angles)
+        a, b = jnp.split(x32, 2, axis=-1)
+        y = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return y.astype(dtype)
+
+
+class LatentAttentionMixer(nn.Module):
+    """Multi-head latent attention in its decompressed (training) form:
+    ``c_q = RMSNorm(x W_qa)``, ``q = c_q W_qb`` a head ``[q_nope |
+    q_rope]``; ``[c_kv | k_rope] = x W_kva``, ``[k_nope | v]`` a head
+    ``= RMSNorm(c_kv) W_kvb``; the rotary turn on each head's ``q_rope``
+    and on the one ``k_rope`` every head shares; causal softmax of ``[q_nope
+    | q_rope] . [k_nope | k_rope] / sqrt(head size)`` over ``v``."""
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, u):
+        g = self.cfg
+        heads, rank = g.num_attention_heads, g.kv_lora_rank
+        nope, rope = g.qk_nope_head_dim, g.qk_rope_head_dim
+        dtype = u.dtype
+        ones = nn.initializers.ones
+        w_qa = self.param("q_a_proj", _kernel_init,
+                          (g.hidden_size, g.q_lora_rank))
+        q_scale = self.param("q_a_scale", ones, (g.q_lora_rank,))
+        w_qb = self.param("q_b_proj", _kernel_init,
+                          (g.q_lora_rank, heads * (nope + rope)))
+        w_kva = self.param("kv_a_proj", _kernel_init,
+                           (g.hidden_size, rank + rope))
+        kv_scale = self.param("kv_a_scale", ones, (rank,))
+        w_kvb = self.param("kv_b_proj", _kernel_init,
+                           (rank, heads * (nope + g.v_head_dim)))
+        w_o = self.param("o_proj", _kernel_init,
+                         (heads * g.v_head_dim, g.hidden_size))
+        lead = u.shape[:2]
+        with jax.named_scope("lm/attn/q_latent"):
+            c_q = rms_norm(u @ w_qa.astype(dtype), q_scale, g.norm_eps)
+            q = (c_q @ w_qb.astype(dtype)).reshape(*lead, heads, nope + rope)
+        with jax.named_scope("lm/attn/kv_latent"):
+            c_kv, k_rope = jnp.split(u @ w_kva.astype(dtype), [rank], -1)
+            c_kv = rms_norm(c_kv, kv_scale, g.norm_eps)
+            k_nope, v = jnp.split(
+                (c_kv @ w_kvb.astype(dtype)).reshape(*lead, heads, -1),
+                [nope], -1)
+        with jax.named_scope("lm/attn/rope"):
+            q_nope, q_rope = jnp.split(q, [nope], -1)
+            q = jnp.concatenate([q_nope, rotary(q_rope, g.rope_theta)], -1)
+            k_rope = rotary(k_rope[:, :, None], g.rope_theta)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope, (*lead, heads, rope))], -1)
+        with jax.named_scope("lm/attn/scores"):
+            y = attention(q, k, v, g.attn_query_block)
+        with jax.named_scope("lm/attn/out"):
+            return y @ w_o.astype(dtype)
+
+
+# ------------------------------------------------------------ feed-forwards
+
+
+class DenseMixer(nn.Module):
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, u):
+        g = self.cfg
+        kernels = _feed_forward_params(
+            self, g, "", (g.hidden_size, g.intermediate_size),
+            (g.intermediate_size, g.hidden_size))
+        with jax.named_scope("lm/mlp/dense"):
+            return feed_forward(u, kernels)
+
+
 # ------------------------------------------------------ mixture of experts
 
 
@@ -317,14 +447,13 @@ def route_held(experts, weights, first, count, rows):
     return token, weight, valid, group_sizes, stats
 
 
-def held_experts_part(x, w_up, w_down, weight, token, valid, group_sizes,
-                      rows):
+def held_experts_part(x, kernels, weight, token, valid, group_sizes, rows):
     """The held experts' part of the layer's result, computed on the first
     ``rows`` rows of ``route_held``'s buffer: all of it where the step
     holds no more than ``rows`` assignments, since the rows past the held
     ones are masked to zero wherever they are read. ``x`` (T, hidden) and
-    the kernels (count, hidden, width), (count, width, hidden) in the
-    compute dtype."""
+    ``kernels`` (gate where the expert is gated, up: (count, hidden,
+    width); down: (count, width, hidden)) in the compute dtype."""
     token, weight = token[:rows], weight[:rows]
     # a row past the groups' end is not the grouped products' to write,
     # forward or backward: whatever stands there is masked on the way in
@@ -334,9 +463,10 @@ def held_experts_part(x, w_up, w_down, weight, token, valid, group_sizes,
     with jax.named_scope("lm/moe/dispatch"):
         filled = jnp.where(mask, x[token], 0)
     with jax.named_scope("lm/moe/experts"):
-        up = lax.ragged_dot(filled, w_up, group_sizes)
-        act = relu2(jnp.where(mask, up, 0))
-        out = lax.ragged_dot(act, w_down, group_sizes)
+        act = hidden_activation([
+            jnp.where(mask, lax.ragged_dot(filled, w, group_sizes), 0)
+            for w in kernels[:-1]])
+        out = lax.ragged_dot(act, kernels[-1], group_sizes)
         out = jnp.where(mask, out, 0)
     with jax.named_scope("lm/moe/combine"):
         out = out.astype(jnp.float32) * weight[:, None]
@@ -351,7 +481,7 @@ def _tier(tiers, n_held):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def on_filled_prefix(tiers, n_held, x, w_up, w_down, weight, *placed):
+def on_filled_prefix(tiers, n_held, x, kernels, weight, *placed):
     """``held_experts_part`` on the shortest of the static, ascending
     ``tiers`` of rows that holds the step's ``n_held`` assignments, by
     ``lax.switch``. Its gradient is each tier's own, recomputed inside
@@ -362,7 +492,7 @@ def on_filled_prefix(tiers, n_held, x, w_up, w_down, weight, *placed):
     return lax.switch(
         _tier(tiers, n_held),
         [functools.partial(held_experts_part, rows=rows) for rows in tiers],
-        x, w_up, w_down, weight, *placed)
+        x, kernels, weight, *placed)
 
 
 def _on_filled_prefix_fwd(tiers, n_held, *operands):
@@ -405,13 +535,12 @@ class MoEMixer(nn.Module):
         # training recipe's, not the model's)
         score_bias = self.variable("buffers", "score_bias", jnp.zeros,
                                    (g.n_routed_experts,), jnp.float32).value
-        w_up = self.param("experts_up", _kernel_init,
-                          (g.held_count, hidden, width))
-        w_down = self.param("experts_down", _kernel_init,
-                            (g.held_count, width, hidden))
-        s_up = self.param("shared_up", _kernel_init, (hidden, shared_width))
-        s_down = self.param("shared_down", _kernel_init,
-                            (shared_width, hidden))
+        kernels = _feed_forward_params(
+            self, g, "experts_", (g.held_count, hidden, width),
+            (g.held_count, width, hidden))
+        shared = _feed_forward_params(
+            self, g, "shared_", (hidden, shared_width),
+            (shared_width, hidden))
         lead = u.shape[:2]
         x = u.reshape(-1, hidden)
         with jax.named_scope("lm/moe/router"):
@@ -431,17 +560,52 @@ class MoEMixer(nn.Module):
         # the switch stands under no scope: its branches' operations
         # carry their own
         with jax.named_scope("lm/moe/experts"):
-            w_up, w_down = w_up.astype(dtype), w_down.astype(dtype)
-        routed = on_filled_prefix(tiers, n_held, x, w_up, w_down, weight,
+            kernels = tuple(w.astype(dtype) for w in kernels)
+        routed = on_filled_prefix(tiers, n_held, x, kernels, weight,
                                   token, valid, group_sizes)
         with jax.named_scope("lm/moe/shared"):
-            shared = relu2(x @ s_up.astype(dtype)) @ s_down.astype(dtype)
+            shared = feed_forward(x, shared)
         return (routed + shared).reshape(*lead, hidden), stats
 
 
 # ------------------------------------------------------------------- model
 
-_MIXERS = {"M": Mamba2Mixer, "*": AttentionMixer, "E": MoEMixer}
+_MIXERS = {"M": Mamba2Mixer, "*": AttentionMixer, "-": DenseMixer,
+           "E": MoEMixer}
+# the sizes each letter of a pattern reads; ``*`` reads those of the form
+# of attention the config has the sizes of
+_LATENT = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+           "qk_rope_head_dim", "v_head_dim", "rope_theta")
+_NEEDS = {
+    "M": ("mamba_num_heads", "mamba_head_dim", "n_groups", "ssm_state_size",
+          "conv_kernel", "chunk_size", "time_step_min", "time_step_max",
+          "time_step_floor"),
+    "*": ("num_attention_heads", "attn_query_block"),
+    "-": ("intermediate_size",),
+    "E": ("n_routed_experts", "num_experts_per_tok", "routed_scaling_factor",
+          "moe_intermediate_size", "moe_shared_expert_intermediate_size",
+          "expert_buffer_rows"),
+}
+
+
+def mixer_of(g, kind):
+    """The module of a pattern's letter at the sizes ``g``."""
+    if kind == "*" and g.kv_lora_rank is not None:
+        return LatentAttentionMixer
+    return _MIXERS[kind]
+
+
+def attention_head_dim(g):
+    """The head size the causal scores run at."""
+    if g.kv_lora_rank is not None:
+        return g.qk_nope_head_dim + g.qk_rope_head_dim
+    return g.head_dim
+
+
+def layer_kinds(g):
+    """The letters of every layer by its index: the pattern's, then the
+    multi-token-prediction module's."""
+    return g.pattern + (g.nextn_pattern or "")
 
 
 class Block(nn.Module):
@@ -455,7 +619,7 @@ class Block(nn.Module):
         scale = self.param("scale", nn.initializers.ones,
                            (self.cfg.hidden_size,))
         u = rms_norm(h, scale, self.cfg.norm_eps)
-        out = _MIXERS[self.kind](self.cfg, name="mixer")(u)
+        out = mixer_of(self.cfg, self.kind)(self.cfg, name="mixer")(u)
         out, stats = out if self.kind == "E" else (out, {})
         return h + out, stats
 
@@ -492,41 +656,52 @@ def chunked_cross_entropy(h, w_head, targets, weights, chunk):
 class Settings:
     """The model's sizes as the modules read them (hashable: flax turns
     a dict field into a FrozenDict). The names are the published
-    config's, but for the held share and the three bounds the program
-    sets itself."""
+    config's, but for the held share, the module's layers and loss weight
+    and the three bounds the program sets itself. A size the model has no
+    layer for stays None (``_NEEDS``)."""
     pattern: str
     hidden_size: int
     vocab_slice: int
     norm_eps: float
-    mamba_num_heads: int
-    mamba_head_dim: int
-    n_groups: int
-    ssm_state_size: int
-    conv_kernel: int
-    chunk_size: int
-    time_step_min: float
-    time_step_max: float
-    time_step_floor: float
-    num_attention_heads: int
-    num_key_value_heads: int
-    head_dim: int
-    n_routed_experts: int
-    num_experts_per_tok: int
-    routed_scaling_factor: float
-    moe_intermediate_size: int
-    moe_shared_expert_intermediate_size: int
-    held_first: int
-    held_count: int
-    expert_buffer_rows: int
-    attn_query_block: int
     loss_chunk_tokens: int
     remat: str
     compute_dtype: str
+    hidden_act: str = "relu2"
+    mamba_num_heads: int | None = None
+    mamba_head_dim: int | None = None
+    n_groups: int | None = None
+    ssm_state_size: int | None = None
+    conv_kernel: int | None = None
+    chunk_size: int | None = None
+    time_step_min: float | None = None
+    time_step_max: float | None = None
+    time_step_floor: float | None = None
+    num_attention_heads: int | None = None
+    attn_query_block: int | None = None
+    num_key_value_heads: int | None = None
+    head_dim: int | None = None
+    q_lora_rank: int | None = None
+    kv_lora_rank: int | None = None
+    qk_nope_head_dim: int | None = None
+    qk_rope_head_dim: int | None = None
+    v_head_dim: int | None = None
+    rope_theta: float | None = None
+    intermediate_size: int | None = None
+    n_routed_experts: int | None = None
+    num_experts_per_tok: int | None = None
+    routed_scaling_factor: float | None = None
+    moe_intermediate_size: int | None = None
+    moe_shared_expert_intermediate_size: int | None = None
+    held_first: int | None = None
+    held_count: int | None = None
+    expert_buffer_rows: int | None = None
+    nextn_pattern: str | None = None
+    nextn_loss_weight: float | None = None
 
 
-def model_settings(gen_cfg):
-    """``Settings`` from the config's ``gen`` section, with the held
-    share checked against the router's width."""
+def _held_share(gen_cfg):
+    """(first, count) of ``gen.experts_held``, checked against the
+    router's width; all of the experts where the key is absent."""
     held = dict(cfg_get(gen_cfg, "experts_held", None) or {})
     of = int(held.get("of", gen_cfg["n_routed_experts"]))
     first, count = int(held.get("first", 0)), int(held.get("count", of))
@@ -535,25 +710,58 @@ def model_settings(gen_cfg):
         raise ValueError(
             f"gen.experts_held {held} does not lie inside the router's "
             f"{gen_cfg['n_routed_experts']} experts")
-    unknown = set(gen_cfg["pattern"]) - set(_MIXERS)
-    if unknown:
-        raise ValueError(f"gen.pattern {gen_cfg['pattern']!r} has layers "
-                         f"{sorted(unknown)}; known: {sorted(_MIXERS)}")
+    return first, count
+
+
+def model_settings(gen_cfg):
+    """``Settings`` from the config's ``gen`` section: every layer of the
+    pattern has its sizes, and the held share lies inside the router's
+    width."""
     given = {f.name: gen_cfg[f.name] for f in dataclasses.fields(Settings)
              if f.name in gen_cfg}
-    given.update(held_first=first, held_count=count,
-                 vocab_slice=int(cfg_get(gen_cfg, "vocab_slice", None)
+    given.update(vocab_slice=int(cfg_get(gen_cfg, "vocab_slice", None)
                                  or gen_cfg["vocab_size"]),
                  remat=str(cfg_get(gen_cfg, "remat", "none")),
                  compute_dtype=str(cfg_get(gen_cfg, "compute_dtype",
                                            "float32")))
-    return Settings(**given)
+    kinds = gen_cfg["pattern"] + (given.get("nextn_pattern") or "")
+    unknown = set(kinds) - set(_MIXERS)
+    if unknown:
+        raise ValueError(f"gen.pattern {kinds!r} has layers "
+                         f"{sorted(unknown)}; known: {sorted(_MIXERS)}")
+    if "E" in kinds and "n_routed_experts" in gen_cfg:
+        given["held_first"], given["held_count"] = _held_share(gen_cfg)
+    g = Settings(**given)
+    if g.hidden_act not in GATED:
+        raise ValueError(f"gen.hidden_act {g.hidden_act!r} is not one of "
+                         f"{sorted(GATED)}")
+    needs = {kind: _NEEDS[kind] for kind in set(kinds)}
+    if "*" in needs:
+        needs["*"] += (_LATENT if g.kv_lora_rank is not None
+                       else ("num_key_value_heads", "head_dim"))
+    missing = {kind: [n for n in names if getattr(g, n) is None]
+               for kind, names in needs.items()}
+    if any(missing.values()):
+        raise ValueError("gen.pattern's layers lack their sizes: " + "; ".join(
+            f"{kind!r} needs gen.{', gen.'.join(names)}"
+            for kind, names in sorted(missing.items()) if names))
+    if g.kv_lora_rank is not None and "*" in kinds \
+            and g.qk_nope_head_dim + g.qk_rope_head_dim != g.v_head_dim:
+        raise ValueError(
+            "the causal scores run at one head size: gen.qk_nope_head_dim + "
+            f"gen.qk_rope_head_dim is {attention_head_dim(g)}, "
+            f"gen.v_head_dim {g.v_head_dim}")
+    if g.nextn_pattern and g.nextn_loss_weight is None:
+        raise ValueError("gen.nextn_pattern needs gen.nextn_loss_weight")
+    return g
 
 
 class Generator(nn.Module):
     """``data["tokens"]`` (B, L) int32 -> {"loss": the mean next-token
-    cross-entropy over the L-1 targets of each sequence, "moe/<layer>/
-    <stat>": each expert layer's routing counts}."""
+    cross-entropy over the L-1 targets of each sequence, "mtp_loss": where
+    the model has the module, the mean cross-entropy of its logits against
+    the token after the next over the L-2 targets, "moe/<layer>/<stat>":
+    each expert layer's routing counts}."""
     gen_cfg: Any = None
     data_cfg: Any = None
 
@@ -568,22 +776,53 @@ class Generator(nn.Module):
         w_head = self.param("head", _kernel_init,
                             (g.hidden_size, g.vocab_slice))
         dtype = jnp.dtype(g.compute_dtype)
-        with jax.named_scope("lm/embed"):
-            h = embedding[tokens].astype(dtype)
         out = {}
-        for index, kind in enumerate(g.pattern):
-            block = remat_block(Block, g.remat, where="gen.remat", cfg=g,
-                                kind=kind, name=f"layer_{index}")
-            h, stats = block(h, training=training)
-            for key, value in stats.items():
-                out[f"moe/{index}/{key}"] = value
-        with jax.named_scope("lm/head_loss"):
-            h = rms_norm(h, final_scale, g.norm_eps)
-            # position t's logits against token t+1; the last has none
-            targets = jnp.roll(tokens, -1, axis=1).reshape(-1)
-            weights = jnp.ones(tokens.shape, jnp.float32).at[:, -1].set(0.0)
+
+        def layers(h, kinds, first):
+            for index, kind in enumerate(kinds, first):
+                block = remat_block(Block, g.remat, where="gen.remat", cfg=g,
+                                    kind=kind, name=f"layer_{index}")
+                h, stats = block(h, training=training)
+                for key, value in stats.items():
+                    out[f"moe/{index}/{key}"] = value
+            return h
+
+        def head_loss(h, scale, ahead):
+            """(mean cross-entropy of position t's logits against token
+            t + ``ahead``, the normed ``h``)."""
+            h = rms_norm(h, scale, g.norm_eps)
+            targets = jnp.roll(tokens, -ahead, axis=1).reshape(-1)
+            weights = jnp.ones(tokens.shape, jnp.float32)
+            for last in range(1, ahead + 1):   # these have no such token
+                weights = weights.at[:, -last].set(0.0)
             total = chunked_cross_entropy(
                 h.reshape(-1, g.hidden_size), w_head.astype(dtype), targets,
                 weights.reshape(-1), g.loss_chunk_tokens)
-            out["loss"] = total / weights.sum()
+            return total / weights.sum(), h
+
+        with jax.named_scope("lm/embed"):
+            h = embedding[tokens].astype(dtype)
+        h = layers(h, g.pattern, 0)
+        with jax.named_scope("lm/head_loss"):
+            out["loss"], h = head_loss(h, final_scale, 1)
+        if not g.nextn_pattern:
+            return out
+        ones = nn.initializers.ones
+        e_scale = self.param("mtp_embed_scale", ones, (g.hidden_size,))
+        h_scale = self.param("mtp_hidden_scale", ones, (g.hidden_size,))
+        w_merge = self.param("mtp_merge", _kernel_init,
+                             (2 * g.hidden_size, g.hidden_size))
+        mtp_scale = self.param("mtp_final_scale", ones, (g.hidden_size,))
+        with jax.named_scope("lm/mtp/merge"):
+            # position i: its final hidden state beside token i+1. The
+            # last position has no such token and is given the roll's;
+            # nothing reads it: attention is causal and its two targets
+            # weigh nothing
+            ahead = embedding[jnp.roll(tokens, -1, axis=1)].astype(dtype)
+            merged = jnp.concatenate(
+                [rms_norm(ahead, e_scale, g.norm_eps),
+                 rms_norm(h, h_scale, g.norm_eps)], -1) @ w_merge.astype(dtype)
+        h = layers(merged, g.nextn_pattern, len(g.pattern))
+        with jax.named_scope("lm/head_loss"):
+            out["mtp_loss"], _ = head_loss(h, mtp_scale, 2)
         return out

@@ -20,8 +20,9 @@ it can observe (``arm_of``), with no option:
 Both take bfloat16 (the compute dtype's) operands, accumulate products in
 float32, mask and take the softmax statistics in float32 and cast the
 probabilities to the values' dtype for their product. The kernel's tiles
-are constants here, chosen on a v5e chip (PERF.md, PR 30); they are not
-configuration.
+are constants here, chosen on a v5e chip at head size 128 with 32 query
+heads on 2 (PERF.md, PR 30) and found the best again at head size 256 with
+20 on 20 (PR 31); they are not configuration.
 """
 
 from __future__ import annotations

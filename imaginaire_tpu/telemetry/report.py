@@ -1046,8 +1046,9 @@ def render_report(path_or_events):
                  "durations are dispatch times on async backends — wall "
                  "and imgs/sec are fenced at flush intervals.")
 
+    # perf/*, and a token model's two losses (lm/main, lm/mtp)
     perf = {k: v for k, v in s["counters"].items()
-            if k.startswith("perf/")}
+            if k.startswith(("perf/", "lm/"))}
     if perf:
         lines.append("")
         lines.append("derived counters (latest):")
@@ -1068,7 +1069,9 @@ def render_report(path_or_events):
     if attn:
         tiles = attn.get("tiles") or {}
         lines.append(
-            f"- attn_impl at length {attn.get('length')}: "
+            f"- attn_impl at length {attn.get('length')}"
+            + (f", head size {attn['head_dim']}" if "head_dim" in attn
+               else "") + ": "
             + ", ".join(f"layer {i} {arm}" for i, arm in sorted(
                 (attn.get("layers") or {}).items(), key=lambda kv: int(kv[0])))
             + "; fused tiles (queries x keys) "

@@ -1,11 +1,14 @@
 """Token-model trainer: one step program, no discriminator.
 
 ``gen_forward`` is the mean next-token cross-entropy the model returns
-over its vocabulary slice; the step is ``BaseTrainer._gen_step_fn`` as it
-is. The expert layers' routing counts ride the step's losses, so the
+over its vocabulary slice (``lm``) and, where the model has a
+multi-token-prediction module, that module's (``mtp``, weighed by
+``gen.nextn_loss_weight``); the step is ``BaseTrainer._gen_step_fn`` as
+it is. The expert layers' routing counts ride the step's losses, so the
 loop reads them without a device sync of its own: telemetry's flush hook
-turns the newest step's into the ``moe/<layer>/*`` counters and the
-window's tokens into ``perf/tokens_per_sec``.
+turns the newest step's into the ``moe/<layer>/*`` counters, its losses
+into ``lm/main`` and ``lm/mtp``, and the window's tokens into
+``perf/tokens_per_sec``.
 """
 
 from __future__ import annotations
@@ -17,11 +20,14 @@ import jax.numpy as jnp
 
 from imaginaire_tpu import telemetry
 from imaginaire_tpu.config import as_attrdict, cfg_get
+from imaginaire_tpu.models.generators import hybrid_lm
 from imaginaire_tpu.ops import attention
 from imaginaire_tpu.trainers.base import BaseTrainer
 
 COUNTERS = ("held_assignments", "load_max_over_mean", "buffer_occupancy",
             "compact")
+# the step's unweighted losses -> the counters the flush hook gives them
+LOSS_COUNTERS = {"lm": "lm/main", "mtp": "lm/mtp"}
 
 
 class Trainer(BaseTrainer):
@@ -44,6 +50,8 @@ class Trainer(BaseTrainer):
 
     def _init_loss(self, cfg):
         self.weights["lm"] = 1.0
+        if cfg_get(cfg.gen, "nextn_pattern", None):
+            self.weights["mtp"] = float(cfg.gen.nextn_loss_weight)
 
     def _to_compute_dtype(self, tree):
         """Nothing is cast here: the model casts each layer's kernels
@@ -76,6 +84,8 @@ class Trainer(BaseTrainer):
         # an assignment the buffer had no row for fails the step's finite
         # flag (the update does not land) rather than vanishing
         losses = {"lm": out["loss"] + jnp.where(overflow > 0, jnp.nan, 0.0)}
+        if "mtp_loss" in out:
+            losses["mtp"] = out["mtp_loss"]
         losses.update({k: v for k, v in out.items() if k.startswith("moe/")})
         return losses, {}
 
@@ -89,17 +99,20 @@ class Trainer(BaseTrainer):
         return losses
 
     def _note_attn_impl(self, length):
-        """One ``attn_impl`` meta as the step is first built: the arm
-        each attention layer's scores take at this length on this
-        backend (``ops/attention.py`` decides; nothing here does), and
-        the fused arm's tiles."""
+        """One ``attn_impl`` meta as the step is first built: the head
+        size the scores run at, the arm each attention layer's scores
+        take at it and this length on this backend (``ops/attention.py``
+        decides; nothing here does), and the fused arm's tiles."""
         tm = telemetry.get()
         if not tm.enabled:
             return
-        g = self.cfg.gen
-        tm.meta("attn_impl", length=length, tiles=attention.TILES._asdict(),
-                layers={str(i): attention.arm_of(g.head_dim, length)
-                        for i, kind in enumerate(g.pattern) if kind == "*"})
+        g = hybrid_lm.model_settings(self.cfg.gen)
+        head_dim = hybrid_lm.attention_head_dim(g)
+        tm.meta("attn_impl", length=length, head_dim=head_dim,
+                tiles=attention.TILES._asdict(),
+                layers={str(i): attention.arm_of(head_dim, length)
+                        for i, kind in enumerate(hybrid_lm.layer_kinds(g))
+                        if kind == "*"})
 
     def _flush_counters(self, tm, step):
         """At telemetry's flush, behind its fence: tokens a second over
@@ -114,6 +127,9 @@ class Trainer(BaseTrainer):
             return
         wanted = {k: v for k, v in self._last_losses.items()
                   if k.rsplit("/", 1)[-1] in COUNTERS}
+        wanted.update({name: self._last_losses[k]
+                       for k, name in LOSS_COUNTERS.items()
+                       if k in self._last_losses})
         # lint: allow(host-sync) -- flush cadence, behind the flush's own fence
         for name, value in jax.device_get(wanted).items():
             tm.counter(name, float(value), step=step)

@@ -57,6 +57,12 @@ _SUPERSEDED = {
     "test_the_ten_entries_are_appended_and_whole":
         "asserts that PR 24's entries are the last of an append-only list; "
         "superseded by test_pr24_span_entries_are_still_whole (PR 27)",
+    "tests/benchmark_rehearsal/test_bench_lm_rehearsal.py::"
+    "test_every_new_reader_is_declared_for_the_cell_alone":
+        "asserts that each *.lm metric lists Nemotron's cell and no other; "
+        "a second token cell reports ten of the twelve. Superseded by "
+        "test_bench_glm_rehearsal.py::test_pr27_reader_entries_are_still_"
+        "whole (PR 31)",
 }
 
 

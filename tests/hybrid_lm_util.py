@@ -9,14 +9,20 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 TINY_YAML = os.path.join(ROOT, "configs", "unit_test", "hybrid_lm.yaml")
+LATENT_YAML = os.path.join(ROOT, "configs", "unit_test",
+                           "glm4_moe_lite.yaml")
+# tiny preset -> (its YAML, its plain reference under benchmark/reference,
+# the index of its first expert layer)
+PRESETS = {"nemotron_h": (TINY_YAML, "nemotron_h_train", 1),
+           "glm4_moe_lite": (LATENT_YAML, "glm4_moe_lite_train", 3)}
 
 
-def tiny_cfg(**gen):
-    """The tiny preset's config in float32 compute (the tests compare
+def tiny_cfg(preset="nemotron_h", **gen):
+    """A tiny preset's config in float32 compute (the tests compare
     with the float32 reference), with `gen` written over its gen section."""
     from imaginaire_tpu.config import Config
 
-    cfg = Config(TINY_YAML)
+    cfg = Config(PRESETS[preset][0])
     cfg.gen.update(gen)
     return cfg
 
@@ -41,11 +47,14 @@ def unflatten(flat):
     return tree
 
 
-def seeded(cfg, seed):
+def seeded(cfg, seed, preset="nemotron_h"):
     """(reference module, sizes, trainable, buffers) for `cfg`."""
-    from benchmark.lib import lm_weights
-    from benchmark.reference import nemotron_h_train as reference
+    import importlib
 
+    from benchmark.lib import lm_weights
+
+    reference = importlib.import_module(
+        "benchmark.reference." + PRESETS[preset][1])
     sizes = sizes_of(cfg)
     train, buffers = reference.split(
         lm_weights.make(reference.spec(sizes), seed))

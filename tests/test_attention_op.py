@@ -78,6 +78,28 @@ def test_fused_arm_is_as_close_to_float32_as_the_plain_arm(which):
     assert fused_err <= 1.25 * plain_err, (fused_err, plain_err)
 
 
+@pytest.mark.parametrize("which", range(4), ids=NAMES)
+def test_fused_arm_at_head_size_256_matches_plain_arm(which):
+    """The latent-attention cell's head: 256 wide, as many key-value heads
+    as query heads; 256 positions in tiles of 128."""
+    fused, plain = _at_256()
+    assert fused[which].shape == plain[which].shape
+    assert np.isfinite(np.asarray(fused[which], np.float32)).all()
+    assert _rel(fused[which], plain[which]) < 6e-3
+
+
+@functools.lru_cache(maxsize=None)
+def _at_256():
+    q, k, v, ct = _inputs(seed=6, bsz=1, length=256, q_heads=2, kv_heads=2,
+                          dim=256)
+    tiles = attention.Tiles(fwd=(128, 128), dkv=(128, 128), dq=(128, 128))
+    return (_with_gradients(lambda q, k, v: _fused(q, k, v, tiles),
+                            q, k, v, ct),
+            _with_gradients(
+                lambda q, k, v: attention.causal_attention(q, k, v, 128),
+                q, k, v, ct))
+
+
 def test_fused_arm_in_float32_is_the_plain_arm():
     """In float32 nothing is rounded on the way: the two arms differ by
     the order of their sums alone."""

@@ -10,7 +10,7 @@ import sys
 
 import pytest
 
-from hybrid_lm_util import ROOT, TINY_YAML
+from hybrid_lm_util import LATENT_YAML, ROOT, TINY_YAML
 
 from imaginaire_tpu.parallel import mesh as mesh_mod
 from imaginaire_tpu.telemetry import core as tcore
@@ -35,13 +35,13 @@ def entry_point_sandbox():
     xla_obs._reset_for_tests()
 
 
-def _train(monkeypatch, logdir, max_iter):
+def _train(monkeypatch, logdir, max_iter, config=TINY_YAML):
     if ROOT not in sys.path:
         sys.path.insert(0, ROOT)
     import train
 
     monkeypatch.setattr(sys, "argv", [
-        "train.py", "--config", TINY_YAML, "--logdir", logdir,
+        "train.py", "--config", config, "--logdir", logdir,
         "--max_iter", str(max_iter), "--seed", "0"])
     return train.main()
 
@@ -84,6 +84,7 @@ def test_train_py_trains_checkpoints_and_resumes(entry_point_sandbox,
             if e["kind"] == "meta" and e["name"] == "attn_impl"]
     assert len(attn) == 1
     assert attn[0]["layers"] == {"3": "blocks"} and attn[0]["length"] == 64
+    assert attn[0]["head_dim"] == 16
     assert set(attn[0]["tiles"]) == {"fwd", "dkv", "dq"}
 
     from imaginaire_tpu.telemetry.report import render_report
@@ -91,7 +92,8 @@ def test_train_py_trains_checkpoints_and_resumes(entry_point_sandbox,
     report = render_report(os.path.join(logdir, "telemetry.jsonl"))
     assert "## experts" in report and "perf/tokens_per_sec" in report
     assert "gen_step: 0 violation(s)" in report
-    assert "attn_impl at length 64: layer 3 blocks; fused tiles" in report
+    assert ("attn_impl at length 64, head size 16: layer 3 blocks; fused "
+            "tiles") in report
 
     # the resume leg: restores iteration 2 and trains on to 3
     capsys.readouterr()
@@ -104,3 +106,36 @@ def test_train_py_trains_checkpoints_and_resumes(entry_point_sandbox,
     steps = [e["step"] for e in _events(logdir)
              if e["kind"] == "span" and e["name"] == "gen_step"]
     assert steps == [0, 1, 2]
+
+
+def test_train_py_trains_the_latent_attention_preset(entry_point_sandbox,
+                                                     monkeypatch, tmp_path):
+    """ISSUE 31: `configs/unit_test/glm4_moe_lite.yaml` through
+    `train.main()`: both losses reported, the module's expert layer under
+    its own index, every attention layer (the module's too) in the
+    `attn_impl` meta, no recompile, a clean graph audit (the rotary turn
+    is a float32 island)."""
+    logdir = str(tmp_path / "log")
+    trainer = _train(monkeypatch, logdir, 2, config=LATENT_YAML)
+    assert trainer.current_iteration == 2
+    assert trainer.weights == {"lm": 1.0, "mtp": 0.3}
+    events = _events(logdir)
+    counters = {e["name"]: e["value"] for e in events
+                if e["kind"] == "counter"}
+    assert counters["lm/main"] > 0 and counters["lm/mtp"] > 0
+    assert counters["perf/tokens_per_sec"] > 0
+    for layer in (3, 5, 7):
+        assert counters[f"moe/{layer}/held_assignments"] > 0
+    assert counters["xla/recompiles"] == 0
+    assert counters["xla/graph_violations"] == 0
+    attn = [e for e in events
+            if e["kind"] == "meta" and e["name"] == "attn_impl"]
+    assert len(attn) == 1 and attn[0]["head_dim"] == 32
+    assert attn[0]["layers"] == {str(i): "blocks" for i in (0, 2, 4, 6)}
+
+    from imaginaire_tpu.telemetry.report import render_report
+
+    report = render_report(os.path.join(logdir, "telemetry.jsonl"))
+    assert "| 7 |" in report.split("## experts")[1]
+    assert "- lm/main: " in report and "- lm/mtp: " in report
+    assert "head size 32: layer 0 blocks, layer 2 blocks" in report

@@ -1,6 +1,7 @@
 """The hybrid token model through the trainer, against the plain
 reference (ISSUE 27 (b), (d), (f)): the whole model's loss and gradients,
-two `gen_update` steps against the reference's Adam steps, an overfull
+two `gen_update` steps against the reference's Adam steps (both for the
+latent-attention preset too, whose loss is two: ISSUE 31), an overfull
 expert buffer failing the step's health flag, and a token batch passing
 the feed's index-map rule untouched."""
 
@@ -38,19 +39,24 @@ def _worst(ours, theirs):
                      / (jnp.linalg.norm(theirs[k]) + 1e-12)) for k in theirs)
 
 
-def test_model_loss_and_gradients_follow_the_reference():
+PRESETS = ["nemotron_h", "glm4_moe_lite"]
+
+
+@pytest.mark.parametrize("preset", PRESETS)
+def test_model_loss_and_gradients_follow_the_reference(preset):
     from benchmark.lib import program
     from imaginaire_tpu.models.generators import hybrid_lm
 
-    cfg = tiny_cfg()
-    reference, sizes, train, buffers = seeded(cfg, 7)
+    cfg = tiny_cfg(preset)
+    reference, sizes, train, buffers = seeded(cfg, 7, preset)
     tokens = jnp.asarray(_tokens(cfg))
     net = hybrid_lm.Generator(cfg.gen, cfg.data)
+    weight = cfg.gen.get("nextn_loss_weight", 0.0)
 
     def ours(train):
         out = net.apply({"params": unflatten(train),
                          "buffers": unflatten(buffers)}, {"tokens": tokens})
-        return out["loss"], out
+        return out["loss"] + weight * out.get("mtp_loss", 0.0), out
 
     def theirs(train):
         return reference.loss(train, buffers, sizes, tokens)
@@ -62,15 +68,63 @@ def test_model_loss_and_gradients_follow_the_reference():
     assert abs(float(l_ours) - float(l_theirs)) < 1e-5 * float(l_theirs)
     assert _worst(g_ours, g_theirs) < 1e-4
     for layer, counts in aux.items():
-        assert float(out[f"moe/{layer}/held_assignments"]) == float(
-            counts["held_assignments"])
+        # the module's layers also count the last position's assignments,
+        # which the program computes and nothing reads
+        spare = (tokens.shape[0] * cfg.gen.num_experts_per_tok
+                 if layer >= len(cfg.gen.pattern) else 0)
+        assert 0 <= float(out[f"moe/{layer}/held_assignments"]) - float(
+            counts["held_assignments"]) <= spare
     # the seam's names are the program's own paths
     assert set(program.flatten(unflatten(train))) == set(train)
 
 
-def test_two_trainer_steps_follow_the_reference_adam():
-    cfg = tiny_cfg()
-    reference, sizes, train, buffers = seeded(cfg, 11)
+def test_the_module_predicts_the_token_after_the_next():
+    """ISSUE 31: the two losses each follow the reference's (whose
+    module's targets are `tokens[:, 2:]`); the embedding and the head are
+    one array each, and the module's loss reaches both."""
+    from benchmark.reference import glm4_moe_lite_train as reference
+    from imaginaire_tpu.models.generators import hybrid_lm
+
+    preset = "glm4_moe_lite"
+    cfg = tiny_cfg(preset)
+    _, sizes, train, buffers = seeded(cfg, 3, preset)
+    tokens = jnp.asarray(_tokens(cfg))
+    net = hybrid_lm.Generator(cfg.gen, cfg.data)
+
+    def ours(train, name):
+        return net.apply({"params": unflatten(train),
+                          "buffers": unflatten(buffers)},
+                         {"tokens": tokens})[name]
+
+    def theirs(train, which):
+        return reference.losses(train, buffers, sizes, tokens)[which]
+
+    for name, which in (("loss", 0), ("mtp_loss", 1)):
+        l_ours, g_ours = jax.jit(jax.value_and_grad(
+            ours), static_argnums=1)(train, name)
+        l_theirs, g_theirs = jax.jit(jax.value_and_grad(
+            theirs), static_argnums=1)(train, which)
+        assert abs(float(l_ours) - float(l_theirs)) < 1e-5 * float(l_theirs)
+        assert _worst(g_ours, g_theirs) < 1e-4
+        for shared in ("embedding", "head"):
+            assert float(jnp.abs(g_ours[shared]).max()) > 0
+    # the module's own layers see only its loss
+    assert float(jnp.abs(jax.grad(ours)(train, "loss")[
+        "layer_6/mixer/o_proj"]).max()) == 0
+    # the last token is nobody's input in the module (position L - 2's
+    # next token, whose own target is past the end), only position L - 3's
+    # target: moving it moves the module's loss and leaves every logit
+    moved = tokens.at[:, -1].set((tokens[:, -1] + 1) % cfg.gen.vocab_slice)
+    apply = jax.jit(ours, static_argnums=1)
+    assert float(apply(train, "mtp_loss")) != float(net.apply(
+        {"params": unflatten(train), "buffers": unflatten(buffers)},
+        {"tokens": moved})["mtp_loss"])
+
+
+@pytest.mark.parametrize("preset", PRESETS)
+def test_two_trainer_steps_follow_the_reference_adam(preset):
+    cfg = tiny_cfg(preset)
+    reference, sizes, train, buffers = seeded(cfg, 11, preset)
     trainer, data = _trainer(cfg, train, buffers)
     assert trainer.net_D is None and trainer.tx_D is None
     assert "opt_D" not in trainer.state and trainer.dis_update(data) is None

@@ -89,10 +89,14 @@ def test_spade_modulation_kernel_compiles(one_chip, shape, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_fused_attention_compiles_at_the_token_cells_shape(one_chip):
+@pytest.mark.parametrize("q_heads,kv_heads,dim", [(32, 2, 128),
+                                                  (20, 20, 256)])
+def test_fused_attention_compiles_at_the_token_cells_shape(
+        one_chip, q_heads, kv_heads, dim):
     """The three passes of ``ops/attention.py``'s fused arm at
     nemotron3_nano_30b_a3b's attention layer (32 query heads over 2, head
-    size 128, 8,192 positions) and the tiles the program uses. Each
+    size 128, 8,192 positions) and at glm4_7_flash's (20 heads on 20, head
+    size 256), with the tiles the program uses. Each
     kernel's instruction stands on one line of the optimized HLO with its
     ``op_name`` under the caller's scope: that is how a trace's events
     are counted under ``lm/attn/scores``."""
@@ -103,8 +107,8 @@ def test_fused_attention_compiles_at_the_token_cells_shape(one_chip):
             out = attention.fused_causal_attention(q, k, v)
         return jnp.sum(out.astype(jnp.float32))
 
-    q = _sds((1, 8192, 32, 128), jnp.bfloat16, one_chip)
-    kv = _sds((1, 8192, 2, 128), jnp.bfloat16, one_chip)
+    q = _sds((1, 8192, q_heads, dim), jnp.bfloat16, one_chip)
+    kv = _sds((1, 8192, kv_heads, dim), jnp.bfloat16, one_chip)
     compiled = _compile(
         jax.value_and_grad(jax.checkpoint(loss), argnums=(0, 1, 2)), q, kv, kv)
     calls = [line for line in compiled.as_text().splitlines()
