@@ -17,6 +17,12 @@ it can observe (``arm_of``), with no option:
               keys) scores of one block in HBM at a time. Everywhere
               else: the CPU, where the tests run, and ragged lengths.
 
+The fused arm's forward pass names its output and the rows'
+log-sum-exp ``KERNEL_RESIDUAL``: a block recomputed under
+``optim/remat.py``'s ``blocks`` keeps the two, so its backward pass
+rebuilds ``q``, ``k``, ``v`` and runs the two backward kernels, not the
+forward kernel a second time (PERF.md, PR 33).
+
 Both take bfloat16 (the compute dtype's) operands, accumulate products in
 float32, mask and take the softmax statistics in float32 and cast the
 probabilities to the values' dtype for their product. The kernel's tiles
@@ -33,6 +39,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from imaginaire_tpu.ops.pallas import causal_attention_kernel as kernel
 
@@ -49,6 +56,10 @@ class Tiles(NamedTuple):
 
 
 TILES = Tiles(fwd=(1024, 1024), dkv=(1024, 1024), dq=(1024, 1024))
+
+# the ``checkpoint_name`` of what a kernel's forward pass hands its
+# backward passes and only that kernel can rebuild
+KERNEL_RESIDUAL = "kernel_residual"
 
 
 def arm_of(head_dim, length):
@@ -104,6 +115,13 @@ def fused_causal_attention(q, k, v, tiles=TILES, interpret=False):
     return _fused_fwd(q, k, v, tiles, interpret)[0]
 
 
+def residual_bytes(bsz, length, q_heads, head_dim, dtype):
+    """Bytes of what the fused arm's forward pass names
+    ``KERNEL_RESIDUAL``: its output in ``dtype`` and the rows' float32
+    log-sum-exp."""
+    return bsz * length * q_heads * (head_dim * jnp.dtype(dtype).itemsize + 4)
+
+
 def _flat(x):
     return x.reshape(*x.shape[:2], -1)
 
@@ -111,6 +129,10 @@ def _flat(x):
 def _fused_fwd(q, k, v, tiles, interpret):
     out, lse = kernel.forward(_flat(q), _flat(k), _flat(v), q.shape[2],
                               k.shape[2], *tiles.fwd, interpret=interpret)
+    # named before ``out`` is returned too: the product with ``W_o`` after
+    # it reads ``out`` for its own gradient, from the kept array
+    out = checkpoint_name(out, KERNEL_RESIDUAL)
+    lse = checkpoint_name(lse, KERNEL_RESIDUAL)
     return out, (q, k, v, out, lse)
 
 

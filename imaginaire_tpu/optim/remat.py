@@ -8,18 +8,24 @@ one error message, one registry:
 
   ``none``           no remat: every block activation stays live for the
                      backward pass (the fp32 seed behavior).
-  ``blocks``         ``jax.checkpoint`` around each block with the
-                     default policy (save nothing inside the block;
-                     recompute the block forward during backward). The
-                     historical spade knob value.
+  ``blocks``         ``jax.checkpoint`` around each block: recompute
+                     the block forward during backward; keep what a
+                     kernel's forward pass handed its backward passes
+                     (the arrays a block names
+                     ``ops.attention.KERNEL_RESIDUAL``: the fused
+                     attention kernel's output and log-sum-exp, which
+                     only a second run of that kernel would give back).
+                     A block that names nothing keeps nothing: every
+                     GAN family's, and a token model's off the fused
+                     arm. The historical spade knob value.
   ``dots_saveable``  checkpoint each block but let XLA keep matmul/conv
                      outputs (``jax.checkpoint_policies.dots_saveable``)
                      — recompute only the cheap elementwise tail, the
                      middle ground on MXU-heavy blocks.
   ``save_nothing``   explicit ``nothing_saveable`` — the offload-style
-                     maximally-frugal policy (same residency as
-                     ``blocks`` today; named separately so configs can
-                     pin the aggressive end of the ladder explicitly).
+                     maximally-frugal policy: keeps nothing at all, a
+                     kernel's residuals neither (``blocks``' residency
+                     wherever a block names none).
 
 ``training`` must be a STATIC positional argument under remat: a traced
 kwarg bool breaks the blocks' Python control flow (norm mode switches,
@@ -38,20 +44,29 @@ from typing import Any, NamedTuple
 import jax
 from flax import linen as nn
 
+from imaginaire_tpu.ops.attention import KERNEL_RESIDUAL
+
 
 class RematPolicy(NamedTuple):
     """A resolved registry entry. ``enabled`` False means no checkpoint
     wrapping at all; ``policy`` is the jax.checkpoint saveable-filter
-    (None = the checkpoint default: save nothing)."""
+    (None = the checkpoint default: save nothing);
+    ``keeps_kernel_residuals`` says whether a block under it holds the
+    arrays it names ``KERNEL_RESIDUAL`` from its forward to its backward
+    pass (what ``policy`` does to them; the ``attn_impl`` meta reads it)."""
 
     name: str
     enabled: bool
     policy: Any
+    keeps_kernel_residuals: bool = False
 
 
 POLICIES = {
-    "none": RematPolicy("none", False, None),
-    "blocks": RematPolicy("blocks", True, None),
+    "none": RematPolicy("none", False, None, keeps_kernel_residuals=True),
+    "blocks": RematPolicy(
+        "blocks", True,
+        jax.checkpoint_policies.save_only_these_names(KERNEL_RESIDUAL),
+        keeps_kernel_residuals=True),
     "dots_saveable": RematPolicy(
         "dots_saveable", True, jax.checkpoint_policies.dots_saveable),
     "save_nothing": RematPolicy(

@@ -1076,7 +1076,10 @@ def render_report(path_or_events):
                 (attn.get("layers") or {}).items(), key=lambda kv: int(kv[0])))
             + "; fused tiles (queries x keys) "
             + ", ".join(f"{k} {'x'.join(map(str, v))}"
-                        for k, v in tiles.items()))
+                        for k, v in tiles.items())
+            + (f"; the blocks keep {sum(attn['kept_bytes'].values())} bytes "
+               "of the kernel's forward passes for its backward passes"
+               if "kept_bytes" in attn else ""))
     lines.extend(_experts_section(s))
     lines.extend(_health_section(s))
     lines.extend(_xla_section(s))

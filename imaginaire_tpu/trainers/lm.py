@@ -22,12 +22,36 @@ from imaginaire_tpu import telemetry
 from imaginaire_tpu.config import as_attrdict, cfg_get
 from imaginaire_tpu.models.generators import hybrid_lm
 from imaginaire_tpu.ops import attention
+from imaginaire_tpu.optim.remat import resolve_policy
 from imaginaire_tpu.trainers.base import BaseTrainer
 
 COUNTERS = ("held_assignments", "load_max_over_mean", "buffer_occupancy",
             "compact")
 # the step's unweighted losses -> the counters the flush hook gives them
 LOSS_COUNTERS = {"lm": "lm/main", "mtp": "lm/mtp"}
+
+
+def attn_impl(gen_cfg, tokens_shape):
+    """The ``attn_impl`` meta of a (batch, length) step: the head size the
+    scores run at, the arm each attention layer's scores take at it and
+    this length on this backend (``ops/attention.py`` decides; nothing
+    here does), the fused arm's tiles, and the bytes each layer's block
+    keeps of the kernel's forward pass for its backward passes (the
+    output and the log-sum-exp: ``gen.remat``'s policy decides; 0 where
+    the backward pass runs the forward kernel again, and on the plain
+    arm, which has no kernel)."""
+    bsz, length = (int(n) for n in tokens_shape)
+    g = hybrid_lm.model_settings(gen_cfg)
+    head_dim = hybrid_lm.attention_head_dim(g)
+    arms = {str(i): attention.arm_of(head_dim, length)
+            for i, kind in enumerate(hybrid_lm.layer_kinds(g)) if kind == "*"}
+    keeps = resolve_policy(g.remat).keeps_kernel_residuals
+    a_layer = attention.residual_bytes(
+        bsz, length, g.num_attention_heads, head_dim, g.compute_dtype)
+    return dict(length=length, head_dim=head_dim,
+                tiles=attention.TILES._asdict(), layers=arms,
+                kept_bytes={i: a_layer if keeps and arm == "fused" else 0
+                            for i, arm in arms.items()})
 
 
 class Trainer(BaseTrainer):
@@ -92,27 +116,17 @@ class Trainer(BaseTrainer):
     def gen_update(self, data):
         if self._flush_t0 is None:
             self._flush_t0 = time.perf_counter()
-            self._note_attn_impl(int(data["tokens"].shape[1]))
+            self._note_attn_impl(data["tokens"].shape)
         losses = super().gen_update(data)
         self._last_losses = losses
         self._tokens_since_flush += int(data["tokens"].size)
         return losses
 
-    def _note_attn_impl(self, length):
-        """One ``attn_impl`` meta as the step is first built: the head
-        size the scores run at, the arm each attention layer's scores
-        take at it and this length on this backend (``ops/attention.py``
-        decides; nothing here does), and the fused arm's tiles."""
+    def _note_attn_impl(self, tokens_shape):
+        """One ``attn_impl`` meta as the step is first built."""
         tm = telemetry.get()
-        if not tm.enabled:
-            return
-        g = hybrid_lm.model_settings(self.cfg.gen)
-        head_dim = hybrid_lm.attention_head_dim(g)
-        tm.meta("attn_impl", length=length, head_dim=head_dim,
-                tiles=attention.TILES._asdict(),
-                layers={str(i): attention.arm_of(head_dim, length)
-                        for i, kind in enumerate(hybrid_lm.layer_kinds(g))
-                        if kind == "*"})
+        if tm.enabled:
+            tm.meta("attn_impl", **attn_impl(self.cfg.gen, tokens_shape))
 
     def _flush_counters(self, tm, step):
         """At telemetry's flush, behind its fence: tokens a second over

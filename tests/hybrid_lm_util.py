@@ -67,3 +67,17 @@ def layer_params(flat, index):
     prefix = f"layer_{index}/mixer/"
     return {k[len(prefix):]: v for k, v in flat.items()
             if k.startswith(prefix)}
+
+
+def pallas_calls(jaxpr):
+    """The names of every ``pallas_call`` in a jaxpr, inner jaxprs
+    included."""
+    import jax
+
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            names.extend(pallas_calls(inner))
+    return names

@@ -10,6 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from hybrid_lm_util import pallas_calls
+
 from imaginaire_tpu.ops import attention
 
 # 4 query heads over 2 key-value heads, head size 128, 512 positions; tiles
@@ -160,3 +162,58 @@ def test_attention_takes_the_plain_arm_here(length):
 def test_tiles_are_lane_multiples_and_divide_the_cells_length():
     assert all(n % 128 == 0 for pair in attention.TILES for n in pair)
     assert 8192 % attention.TILES.largest == 0
+
+
+# -------------------------------------------- under a block's recompute
+
+
+def _block_gradients(dim, policy):
+    """(the kernel calls in the gradient's jaxpr, the gradients) of a
+    block ``x -> qkv -> fused attention -> W_o`` recomputed under a remat
+    policy; head size 128 with 4 query heads on 2, 256 with 2 on 2."""
+    from imaginaire_tpu.optim.remat import POLICIES
+
+    q_heads, kv_heads = (4, 2) if dim == 128 else (2, 2)
+    hidden, length = 64, 256
+    tiles = attention.Tiles(fwd=(128, 128), dkv=(128, 128), dq=(128, 128))
+    keys = jax.random.split(jax.random.PRNGKey(dim), 5)
+    x = jax.random.normal(keys[0], (1, length, hidden)).astype(jnp.bfloat16)
+    widths = [q_heads * dim, kv_heads * dim, kv_heads * dim]
+    kernels = [(jax.random.normal(k, (hidden, w)) / 8).astype(jnp.bfloat16)
+               for k, w in zip(keys[1:4], widths)]
+    kernels.append((jax.random.normal(keys[4], (q_heads * dim, hidden))
+                    / 16).astype(jnp.bfloat16))
+
+    def block(x, kernels):
+        w_q, w_k, w_v, w_o = kernels
+        q, k, v = ((x @ w).reshape(1, length, -1, dim)
+                   for w in (w_q, w_k, w_v))
+        return _fused(q, k, v, tiles) @ w_o
+
+    def loss(x, kernels):
+        out = jax.checkpoint(block, policy=POLICIES[policy].policy)(x, kernels)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    grad = jax.grad(loss, argnums=(0, 1))
+    return (pallas_calls(jax.make_jaxpr(grad)(x, kernels).jaxpr),
+            jax.tree_util.tree_leaves(grad(x, kernels)))
+
+
+@pytest.mark.parametrize("dim", [128, 256])
+def test_a_recomputed_block_runs_the_forward_kernel_once(dim):
+    """ISSUE 33: under ``blocks`` the block keeps the output and the
+    log-sum-exp the kernel's forward pass handed its backward passes, so
+    its recompute holds no second forward call; under ``save_nothing`` it
+    holds one; and the kept arrays are the ones the second call would have
+    written, so no gradient moves by a bit."""
+    kept_calls, kept = _block_gradients(dim, "blocks")
+    again_calls, again = _block_gradients(dim, "save_nothing")
+    assert sorted(kept_calls) == [
+        "causal_gqa_dkv", "causal_gqa_dq", "causal_gqa_fwd"]
+    assert sorted(again_calls) == [
+        "causal_gqa_dkv", "causal_gqa_dq", "causal_gqa_fwd", "causal_gqa_fwd"]
+    assert len(kept) == len(again) == 5
+    for a, b in zip(kept, again):
+        assert np.abs(np.asarray(a, np.float32)).max() > 0
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))

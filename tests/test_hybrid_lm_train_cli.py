@@ -86,6 +86,8 @@ def test_train_py_trains_checkpoints_and_resumes(entry_point_sandbox,
     assert attn[0]["layers"] == {"3": "blocks"} and attn[0]["length"] == 64
     assert attn[0]["head_dim"] == 16
     assert set(attn[0]["tiles"]) == {"fwd", "dkv", "dq"}
+    # the plain arm has no kernel whose residuals a block could keep
+    assert attn[0]["kept_bytes"] == {"3": 0}
 
     from imaginaire_tpu.telemetry.report import render_report
 
@@ -94,6 +96,7 @@ def test_train_py_trains_checkpoints_and_resumes(entry_point_sandbox,
     assert "gen_step: 0 violation(s)" in report
     assert ("attn_impl at length 64, head size 16: layer 3 blocks; fused "
             "tiles") in report
+    assert "; the blocks keep 0 bytes of the kernel's forward" in report
 
     # the resume leg: restores iteration 2 and trains on to 3
     capsys.readouterr()
@@ -132,6 +135,7 @@ def test_train_py_trains_the_latent_attention_preset(entry_point_sandbox,
             if e["kind"] == "meta" and e["name"] == "attn_impl"]
     assert len(attn) == 1 and attn[0]["head_dim"] == 32
     assert attn[0]["layers"] == {str(i): "blocks" for i in (0, 2, 4, 6)}
+    assert attn[0]["kept_bytes"] == {str(i): 0 for i in (0, 2, 4, 6)}
 
     from imaginaire_tpu.telemetry.report import render_report
 

@@ -156,6 +156,47 @@ def test_two_trainer_steps_follow_the_reference_adam(preset):
                                       np.asarray(buffers[name]))
 
 
+@pytest.mark.parametrize("yaml, layers, a_layer", [
+    # 8,192 x 20 heads x 256 in bfloat16 and 20 x 8,192 float32 rows
+    ("glm4_moe_lite/flash_ep8_share.yaml", (0, 2, 4, 6, 8, 10),
+     83_886_080 + 655_360),
+    # 8,192 x 32 heads x 128, 32 x 8,192 rows
+    ("nemotron_h/nano_30b_a3b_ep16_share.yaml", (5,),
+     67_108_864 + 1_048_576)], ids=["glm4_7_flash", "nemotron3_nano"])
+def test_attn_impl_counts_what_the_fused_blocks_keep(monkeypatch, yaml,
+                                                     layers, a_layer):
+    """ISSUE 33: the `attn_impl` meta of the published configurations'
+    step (one sequence of 8,192) on a TPU: every attention layer fused,
+    its block keeping the kernel's output and log-sum-exp under the
+    YAML's `remat: blocks` and nothing under a policy that recomputes
+    the kernel; on this CPU no layer is fused and none keeps a byte."""
+    import os
+
+    from hybrid_lm_util import ROOT
+
+    from imaginaire_tpu.config import Config
+    from imaginaire_tpu.telemetry.report import render_report
+    from imaginaire_tpu.trainers import lm
+
+    gen = Config(os.path.join(ROOT, "configs", "projects", yaml)).gen
+    assert gen.remat == "blocks"
+    gen["compute_dtype"] = "bfloat16"     # as the trainer sets it
+    names = [str(i) for i in layers]
+    here = lm.attn_impl(gen, (1, 8192))
+    assert here["layers"] == dict.fromkeys(names, "blocks")
+    assert here["kept_bytes"] == dict.fromkeys(names, 0)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    meta = lm.attn_impl(gen, (1, 8192))
+    assert meta["layers"] == dict.fromkeys(names, "fused")
+    assert meta["kept_bytes"] == dict.fromkeys(names, a_layer)
+    assert (f"; the blocks keep {len(layers) * a_layer} bytes of the "
+            "kernel's forward passes") in render_report(
+                [{"kind": "meta", "name": "attn_impl", **meta}])
+    gen["remat"] = "save_nothing"
+    assert lm.attn_impl(gen, (1, 8192))["kept_bytes"] == dict.fromkeys(
+        names, 0)
+
+
 def test_an_overfull_expert_buffer_fails_the_health_flag():
     """ISSUE 27 (d): 16 rows for some 140 held assignments: the step's
     finite flag fails, the update does not land, the monitor counts it."""

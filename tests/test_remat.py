@@ -198,3 +198,105 @@ class TestFamilies:
         out = m.apply(variables, x, training=False)
         assert out.shape == x.shape
         assert np.isfinite(np.asarray(out)).all()
+
+
+# --------------------------- what a recomputed block keeps (ISSUE 33)
+
+
+def _residuals(module, variables, *inputs):
+    """The arrays the module's forward pass leaves for its backward pass,
+    inputs and parameters included."""
+    _, vjp = jax.vjp(lambda v, *xs: module.apply(v, *xs, training=True),
+                     variables, *inputs)
+    return jax.tree_util.tree_leaves(vjp)
+
+
+def _spade_block(policy):
+    import flax.linen as nn
+
+    class M(nn.Module):
+        @nn.compact
+        def __call__(self, x, seg, training=False):
+            return remat_block(
+                Res2dBlock, policy, where="gen.remat", out_channels=6,
+                weight_norm_type="spectral",
+                activation_norm_type="spatially_adaptive",
+                activation_norm_params={"num_filters": 8,
+                                        "activation_norm_type": "instance"},
+                order="NACNAC", name="res")(x, seg, training=training)
+
+    return M(), (jnp.ones((1, 8, 8, 4)), jnp.ones((1, 8, 8, 3)))
+
+
+def _lm_block(kind, policy, **gen):
+    import flax.linen as nn
+    from hybrid_lm_util import tiny_cfg
+
+    from imaginaire_tpu.models.generators import hybrid_lm
+
+    g = hybrid_lm.model_settings(tiny_cfg(**gen).gen)
+
+    class M(nn.Module):
+        @nn.compact
+        def __call__(self, h, training=False):
+            return remat_block(hybrid_lm.Block, policy, where="gen.remat",
+                               cfg=g, kind=kind,
+                               name="layer_0")(h, training=training)[0]
+
+    return M(), (jnp.ones((1, 256, g.hidden_size)) / 8,)
+
+
+@pytest.mark.parametrize("build", [
+    _spade_block, lambda policy: _lm_block("M", policy)],
+    ids=["spade", "mamba2"])
+def test_a_block_that_names_nothing_keeps_nothing_under_blocks(build):
+    """``blocks`` keeps what a block names ``KERNEL_RESIDUAL``; a block
+    with no such value (every GAN family's, a Mamba-2 block) leaves its
+    backward pass what it leaves under ``save_nothing``, and fewer arrays
+    than with no recompute at all."""
+    kept = {}
+    for policy in ("none", "blocks", "save_nothing"):
+        module, inputs = build(policy)
+        variables = module.init(jax.random.PRNGKey(0), *inputs)
+        kept[policy] = sorted(
+            (r.shape, str(r.dtype))
+            for r in _residuals(module, variables, *inputs))
+    assert kept["blocks"] == kept["save_nothing"]
+    assert len(kept["blocks"]) < len(kept["none"])
+
+
+@pytest.mark.parametrize("policy", sorted(POLICIES))
+def test_an_attention_block_through_the_flax_lift(monkeypatch, policy):
+    """A ``hybrid_lm.Block`` of kind ``*`` built through ``remat_block``
+    (``nn.remat``, not bare ``jax.checkpoint``), the fused arm forced in
+    Pallas's interpreter: the gradient runs the forward kernel once where
+    the policy keeps the kernel's residuals (``blocks``; ``none``, which
+    recomputes nothing) and twice where it does not, which is what the
+    registry's ``keeps_kernel_residuals`` says of each."""
+    from hybrid_lm_util import pallas_calls
+
+    from imaginaire_tpu.models.generators import hybrid_lm
+    from imaginaire_tpu.ops import attention
+
+    tiles = attention.Tiles(fwd=(128, 128), dkv=(128, 128), dq=(128, 128))
+    monkeypatch.setattr(
+        hybrid_lm, "attention",
+        lambda q, k, v, block: attention.fused_causal_attention(
+            q, k, v, tiles, True))
+    module, (h,) = _lm_block("*", policy, head_dim=128, num_attention_heads=2,
+                             num_key_value_heads=1, compute_dtype="bfloat16")
+    h = h.astype(jnp.bfloat16)
+    variables = module.init(jax.random.PRNGKey(0), h)
+
+    def loss(variables, h):
+        out = module.apply(variables, h, training=True)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    calls = pallas_calls(
+        jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(variables, h).jaxpr)
+    forward = calls.count("causal_gqa_fwd")
+    assert sorted(calls) == sorted(
+        ["causal_gqa_fwd"] * forward + ["causal_gqa_dkv", "causal_gqa_dq"])
+    assert forward == {"none": 1, "blocks": 1, "dots_saveable": 2,
+                       "save_nothing": 2}[policy]
+    assert POLICIES[policy].keeps_kernel_residuals == (forward == 1)

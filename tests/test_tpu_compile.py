@@ -89,18 +89,24 @@ def test_spade_modulation_kernel_compiles(one_chip, shape, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("policy,forward_calls", [("save_nothing", 2),
+                                                  ("blocks", 1)])
 @pytest.mark.parametrize("q_heads,kv_heads,dim", [(32, 2, 128),
                                                   (20, 20, 256)])
 def test_fused_attention_compiles_at_the_token_cells_shape(
-        one_chip, q_heads, kv_heads, dim):
+        one_chip, q_heads, kv_heads, dim, policy, forward_calls):
     """The three passes of ``ops/attention.py``'s fused arm at
     nemotron3_nano_30b_a3b's attention layer (32 query heads over 2, head
     size 128, 8,192 positions) and at glm4_7_flash's (20 heads on 20, head
     size 256), with the tiles the program uses. Each
     kernel's instruction stands on one line of the optimized HLO with its
     ``op_name`` under the caller's scope: that is how a trace's events
-    are counted under ``lm/attn/scores``."""
+    are counted under ``lm/attn/scores``. Under a checkpoint of
+    ``optim/remat.py``'s ``blocks`` the forward kernel's output and
+    log-sum-exp are kept, so the recompute holds no second forward call
+    (ISSUE 33); under ``save_nothing`` it holds one."""
     from imaginaire_tpu.ops import attention
+    from imaginaire_tpu.optim.remat import POLICIES
 
     def loss(q, k, v):
         with jax.named_scope("lm/attn/scores"):
@@ -110,13 +116,15 @@ def test_fused_attention_compiles_at_the_token_cells_shape(
     q = _sds((1, 8192, q_heads, dim), jnp.bfloat16, one_chip)
     kv = _sds((1, 8192, kv_heads, dim), jnp.bfloat16, one_chip)
     compiled = _compile(
-        jax.value_and_grad(jax.checkpoint(loss), argnums=(0, 1, 2)), q, kv, kv)
+        jax.value_and_grad(
+            jax.checkpoint(loss, policy=POLICIES[policy].policy),
+            argnums=(0, 1, 2)), q, kv, kv)
     calls = [line for line in compiled.as_text().splitlines()
              if "custom-call(" in line and "tpu_custom_call" in line]
     assert sorted(line.split("=")[0].strip().lstrip("%").split(".")[0]
                   for line in calls) == [
-        # forward, its recompute under the checkpoint, and the backward
-        "causal_gqa_dkv", "causal_gqa_dq", "causal_gqa_fwd", "causal_gqa_fwd"]
+        # the backward, the forward and its recompute under the checkpoint
+        "causal_gqa_dkv", "causal_gqa_dq", *["causal_gqa_fwd"] * forward_calls]
     assert all('op_name="' in line and "lm/attn/scores" in line
                for line in calls)
     # no score leaves the chip: the whole layer's temporaries are a few
