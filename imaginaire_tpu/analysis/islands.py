@@ -123,3 +123,11 @@ register("rotary_angles",
          "and the turn itself run in fp32; a bf16 angle is off by whole "
          "radians past a few hundred positions",
          where="imaginaire_tpu/models/generators/hybrid_lm.py")
+register("delta_rule",
+         "a delta-rule linear attention's log-decays (softplus, exp(A_log)) "
+         "and their cumulative sums, beta, the decays exp(c_i - c_j), the "
+         "unit-lower-triangular inverse of each chunk's WY form and the "
+         "state carried across chunks stay fp32; a bf16 decay compounds "
+         "over thousands of steps and a bf16 solve loses the rank-one "
+         "corrections it sums",
+         where="imaginaire_tpu/models/generators/hybrid_lm.py")

@@ -1,8 +1,9 @@
-"""Hybrid token model: Mamba-2 mixers, causal attention, dense and
-mixture-of-experts feed-forwards, laid out by a pattern string (Nemotron-H,
-``hybrid_override_pattern``: ``M`` a Mamba-2 mixer, ``*`` attention, ``-``
-a dense feed-forward, ``E`` a mixture of experts). A transformer block is
-two letters: ``*-`` or ``*E``.
+"""Hybrid token model: Mamba-2 mixers, causal attention, delta-rule linear
+attention, dense and mixture-of-experts feed-forwards, laid out by a
+pattern string (Nemotron-H, ``hybrid_override_pattern``: ``M`` a Mamba-2
+mixer, ``*`` attention, ``-`` a dense feed-forward, ``E`` a mixture of
+experts; ``K`` is this file's own letter for a Kimi Delta Attention
+mixer). A transformer block is two letters: ``*-``, ``*E`` or ``KE``.
 
 Every layer is one mixer behind a pre-norm residual,
 ``h = h + Mixer(RMSNorm(h))``; logits are ``RMSNorm(h) W_head``. The
@@ -15,7 +16,10 @@ is absent (``_NEEDS`` says what each letter reads). ``*`` is latent
 attention where there is a ``kv_lora_rank`` (DeepSeek-V2's: queries and
 keys-values through normed low-rank latents, one rotary key shared by all
 heads beside each head's un-rotated part) and grouped-query attention
-without a position embedding otherwise. ``hidden_act`` ``silu`` makes
+without a position embedding otherwise, with a sigmoid gate on its output
+where ``use_gqa_gate`` says so. ``K`` is the gated delta rule with a
+decay a key channel (``KDAMixer``; its sizes are the published
+``linear_attn_config`` group's). ``hidden_act`` ``silu`` makes
 every feed-forward gated, ``W_down (silu(W_gate x) * W_up x)``; ``relu2``
 (the default: Nemotron-H's ``mlp_hidden_act``) is ``W_down relu(W_up
 x)^2``. ``nextn_pattern`` adds one multi-token-prediction module
@@ -24,7 +28,9 @@ embedding, each normed, merged by one product, through the module's own
 layers to the logits for token ``i+2`` under the main model's embedding
 and head; its loss is returned beside the main one.
 
-A share of an expert-parallel deployment: ``experts_held`` names the
+A share of a deployment: a head count under the published head size is
+a head-parallel rank's heads (their rows of ``W_o`` give the rank's part
+of the mixer's result), and ``experts_held`` names the
 routed experts this chip holds (``{first, count, of}``). The router keeps
 its ``of`` outputs and its experts per token; the layer computes the
 held experts' part of the result for the tokens routed to them and
@@ -33,6 +39,7 @@ the vocabulary held here: ids, logits and loss are over the slice.
 
 The numerics are plain ``jax.numpy``/``lax`` but for attention: the
 chunked state-space dual form of the Mamba-2 recurrence (``ssd_scan``),
+the chunked WY form of the delta rule (``kda_scan``),
 and dropless routing with static shapes (``route_held``: sort the
 assignments by expert, the held ones first, into a buffer of
 ``expert_buffer_rows`` rows, two grouped products by ``lax.ragged_dot``
@@ -46,8 +53,9 @@ scores in VMEM where the backend is a TPU, the head size a multiple of
 128 and the length a multiple of the kernel's tiles; query blocks of
 ``attn_query_block`` rows in plain ``jax.numpy`` everywhere else (the
 CPU, ragged lengths). The fp32 islands (router scores, the scan's step
-sizes, decays and carried state, the rotary angles, RMS statistics, the
-loss) are declared in ``analysis/islands.py``.
+sizes, decays and carried state, the delta rule's decays, solve and
+state, the rotary angles, RMS statistics, the loss) are declared in
+``analysis/islands.py``.
 
 Precision: the parameters arrive in float32 and each layer casts its
 kernels to ``compute_dtype`` where it uses them, inside the layer's
@@ -234,7 +242,9 @@ class Mamba2Mixer(nn.Module):
         w_conv = self.param("conv_kernel", _kernel_init,
                             (g.conv_kernel, conv_dim))
         b_conv = self.param("conv_bias", nn.initializers.zeros, (conv_dim,))
-        dt_bias = self.param("dt_bias", _dt_bias_init(g), (heads,))
+        dt_bias = self.param(
+            "dt_bias", _dt_bias_init(g.time_step_min, g.time_step_max,
+                                     g.time_step_floor), (heads,))
         a_log = self.param("A_log", _a_log_init, (heads,))
         d_skip = self.param("D", nn.initializers.ones, (heads,))
         w_norm = self.param("gate_scale", nn.initializers.ones, (inner,))
@@ -268,17 +278,219 @@ class Mamba2Mixer(nn.Module):
             return y @ w_out.astype(dtype)
 
 
-def _dt_bias_init(g):
+def _dt_bias_init(step_min, step_max, floor):
     def init(key, shape, dtype=jnp.float32):
-        lo, hi = math.log(g.time_step_min), math.log(g.time_step_max)
+        lo, hi = math.log(step_min), math.log(step_max)
         dt = jnp.exp(jax.random.uniform(key, shape, dtype, lo, hi))
-        dt = jnp.maximum(dt, g.time_step_floor)
+        dt = jnp.maximum(dt, floor)
         return dt + jnp.log(-jnp.expm1(-dt))       # softplus^-1
     return init
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):
     return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+# ------------------------------------------- delta-rule linear attention
+
+_HIGHEST = lax.Precision.HIGHEST
+# chunks of the delta rule whose (chunk, chunk, head size) decays stand
+# at once (17 MB a chunk of 64 at 8 heads of 128)
+KDA_CHUNKS_AT_ONCE = 8
+# softplus(dt_bias) is drawn as Mamba-2's step size is: log-uniform over
+# these two, floored at the third
+KDA_TIME_STEP = (1e-3, 1e-1, 1e-4)
+
+
+def unit_lower_inverse(a):
+    """``(I + a)^-1`` of a strictly lower-triangular ``a`` (..., n, n), in
+    ``a``'s float32: forward substitution row by row up to 16 rows, and
+    above that by halves, ``[[T, 0], [-B a_21 T, B]]`` of the halves'
+    inverses ``T`` and ``B``."""
+    n = a.shape[-1]
+    if n <= 16:
+        inv = jnp.broadcast_to(jnp.eye(n, dtype=a.dtype), a.shape)
+        for i in range(1, n):
+            inv = inv.at[..., i, :].add(-jnp.einsum(
+                "...j,...jk->...k", a[..., i, :i], inv[..., :i, :],
+                precision=_HIGHEST))
+        return inv
+    half = n // 2
+    top = unit_lower_inverse(a[..., :half, :half])
+    bottom = unit_lower_inverse(a[..., half:, half:])
+    corner = -jnp.matmul(jnp.matmul(bottom, a[..., half:, :half],
+                                    precision=_HIGHEST),
+                         top, precision=_HIGHEST)
+    return jnp.concatenate([
+        jnp.pad(top, [(0, 0)] * (a.ndim - 1) + [(0, n - half)]),
+        jnp.concatenate([corner, bottom], axis=-1)], axis=-2)
+
+
+def _kda_within_chunks(q, k, v, a, beta):
+    """What each chunk of the delta rule needs before the state carried
+    into it is known; every operand (N, H, C, ...) float32, ``N`` chunks
+    of ``C`` steps. With ``c`` the log-decay summed from the chunk's start
+    and ``T = (I + strict_lower(beta_i sum_d k_id k_jd e^(c_id - c_jd)))^-1``
+    (the WY form of the chunk's product of ``I - beta k k^T`` factors):
+    ``W = T (beta k e^c)``, ``U0 = T (beta v)``, the causal ``P_ij = sum_d
+    q_id k_jd e^(c_id - c_jd)``, ``q e^c``, ``k e^(c_end - c)`` and
+    ``e^(c_end)``. A decay is always ``exp`` of a difference ``c_i - c_j``
+    with ``i >= j``, at most 1; ``e^(-c_j)`` alone overflows on a fast
+    channel."""
+    chunk = q.shape[2]
+    c = jnp.cumsum(a, axis=2)
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(causal[..., None],
+                              c[:, :, :, None] - c[:, :, None], -jnp.inf))
+    k_beta = k * beta[..., None]
+
+    def decayed_products(rows):     # sum_d rows_id k_jd e^(c_id - c_jd)
+        return jnp.sum(rows[:, :, :, None] * k[:, :, None] * decay, axis=-1)
+
+    a_kk = jnp.where(jnp.eye(chunk, dtype=bool), 0.0,
+                     decayed_products(k_beta))
+    p_qk = decayed_products(q)
+    solve = unit_lower_inverse(a_kk)
+    from_start = jnp.exp(c)
+    w = jnp.matmul(solve, k_beta * from_start, precision=_HIGHEST)
+    u0 = jnp.matmul(solve, v * beta[..., None], precision=_HIGHEST)
+    to_end = jnp.exp(c[:, :, -1:] - c)
+    return (w, u0, p_qk, q * from_start, k * to_end,
+            jnp.exp(c[:, :, -1]))
+
+
+def kda_scan(q, k, v, a, beta, chunk):
+    """The gated delta rule with a decay a key channel (Kimi Delta
+    Attention), per head with state ``S`` (d, d), ``S_0 = 0``:
+
+        S_t = (I - beta_t k_t k_t^T) Diag(exp(a_t)) S_{t-1} + beta_t k_t v_t^T
+        o_t = S_t^T q_t / sqrt(d)
+
+    evaluated in chunks of ``chunk`` steps: within a chunk by the WY form
+    (``_kda_within_chunks``: one unit-lower-triangular inverse a chunk),
+    ``KDA_CHUNKS_AT_ONCE`` chunks at a time under ``jax.checkpoint`` so
+    that their (chunk, chunk, d) decays never stand for the whole
+    sequence; across chunks by the carried state, ``U = U0 - W S``, ``o =
+    (q e^c) S + P U``, ``S <- Diag(e^(c_end)) S + (k e^(c_end - c))^T U``.
+    ``q``, ``k``, ``v`` (B, L, H, d) in the compute dtype; the log-decays
+    ``a`` (B, L, H, d), at most 0, and ``beta`` (B, L, H) float32. All of
+    it runs in float32. Returns ``o`` (B, L, H, d) in ``v``'s dtype. A
+    length that the chunk does not divide is padded with steps that
+    leave the state as it is."""
+    islands.guard("delta_rule", a=a, beta=beta)
+    bsz, length, heads, dim = q.shape
+    dtype = v.dtype
+    pad = (-length) % chunk
+    n = (length + pad) // chunk
+
+    def chunked(x):     # (B, L, H, ...) -> (B n, H, C, ...)
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        return x.reshape(bsz * n, chunk, *x.shape[2:]).swapaxes(1, 2)
+
+    # the entry casts stand outside the island, as the exit cast does:
+    # their gradients are casts down
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    with islands.scope("delta_rule"):
+        operands = [chunked(x) for x in (q / math.sqrt(dim), k, v, a, beta)]
+        at_once = math.gcd(bsz * n, KDA_CHUNKS_AT_ONCE)
+        within = lax.map(
+            jax.checkpoint(lambda xs: _kda_within_chunks(*xs)),
+            [x.reshape(-1, at_once, *x.shape[1:]) for x in operands])
+        # (n, B, H, ...): one step of the carry a chunk
+        w, u0, p_qk, q_in, k_out, through = (
+            x.reshape(bsz, n, *x.shape[2:]).swapaxes(0, 1) for x in within)
+
+        def carry(state, inputs):
+            w, u0, p_qk, q_in, k_out, through = inputs
+            u = u0 - jnp.matmul(w, state, precision=_HIGHEST)
+            out = (jnp.matmul(q_in, state, precision=_HIGHEST)
+                   + jnp.matmul(p_qk, u, precision=_HIGHEST))
+            state = through[..., None] * state + jnp.matmul(
+                k_out.swapaxes(-1, -2), u, precision=_HIGHEST)
+            return state, out
+
+        _, out = lax.scan(carry, jnp.zeros((bsz, heads, dim, dim),
+                                           jnp.float32),
+                          (w, u0, p_qk, q_in, k_out, through))
+    # (n, B, H, C, d) -> (B, L, H, d)
+    out = out.transpose(1, 0, 3, 2, 4).reshape(bsz, n * chunk, heads, dim)
+    return out[:, :length].astype(dtype)
+
+
+def l2_norm(x):
+    """``x`` over the norm of its last axis, in float32; the result in
+    ``x``'s dtype."""
+    x32 = x.astype(jnp.float32)
+    with islands.scope("norm_stats"):
+        y = x32 * lax.rsqrt(jnp.sum(jnp.square(x32), -1, keepdims=True)
+                            + 1e-6)
+    return y.astype(x.dtype)
+
+
+class KDAMixer(nn.Module):
+    """Kimi Delta Attention (Kimi Linear, arXiv:2510.26692), a head of
+    size ``d``: ``q``, ``k`` the l2-normed and ``v`` the plain ``silu`` of
+    a depthwise causal convolution of ``u W``; the log-decay of each key
+    channel ``a = -exp(A_log) softplus((u W_f1) W_f2 + dt_bias)``;
+    ``beta = 2 sigmoid(u W_b)`` (the 2 is ``kda_allow_neg_eigval``: the
+    factor ``I - beta k k^T`` may turn a direction over); ``o`` by
+    ``kda_scan``; ``y = (RMSNorm_head(o) * sigmoid((u W_g1) W_g2)) W_o``.
+    The decay's and the output gate's projections are low-rank through
+    ``d`` (``kda_use_full_proj: false``)."""
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, u):
+        g = self.cfg
+        heads, dim = g.kda_num_heads, g.kda_head_dim
+        hidden, inner = g.hidden_size, g.kda_num_heads * g.kda_head_dim
+        dtype = u.dtype
+
+        def kernel(name, shape):
+            return self.param(name, _kernel_init, shape)
+
+        w_qkv = [kernel(n + "_proj", (hidden, inner)) for n in "qkv"]
+        w_conv = [kernel(n + "_conv", (g.kda_conv_kernel, inner))
+                  for n in "qkv"]
+        w_f = kernel("f_a_proj", (hidden, dim)), kernel("f_b_proj",
+                                                        (dim, inner))
+        dt_bias = self.param("dt_bias", _dt_bias_init(*KDA_TIME_STEP),
+                             (inner,))
+        a_log = self.param("A_log", _a_log_init, (heads,))
+        w_b = kernel("b_proj", (hidden, heads))
+        w_g = kernel("g_a_proj", (hidden, dim)), kernel("g_b_proj",
+                                                        (dim, inner))
+        w_norm = self.param("gate_scale", nn.initializers.ones, (dim,))
+        w_o = kernel("o_proj", (inner, hidden))
+        lead = u.shape[:2]
+
+        with jax.named_scope("lm/attn/kda_proj"):
+            q, k, v = (u @ w.astype(dtype) for w in w_qkv)
+            f, gate = ((u @ a.astype(dtype)) @ b.astype(dtype)
+                       for a, b in (w_f, w_g))
+            beta = u @ w_b.astype(dtype)
+        with jax.named_scope("lm/attn/kda_conv"):
+            q, k, v = (
+                jax.nn.silu(causal_conv1d(
+                    x, w.astype(dtype), jnp.zeros((), dtype))).reshape(
+                        *lead, heads, dim)
+                for x, w in zip((q, k, v), w_conv))
+            q, k = l2_norm(q), l2_norm(k)
+        with jax.named_scope("lm/attn/kda_scan"):
+            f32 = f.astype(jnp.float32)
+            beta32 = beta.astype(jnp.float32)
+            bias32 = dt_bias.astype(jnp.float32)
+            a32 = a_log.astype(jnp.float32)
+            with islands.scope("delta_rule"):
+                a = (-jnp.exp(a32)[:, None] * jax.nn.softplus(
+                    f32 + bias32).reshape(*lead, heads, dim))
+                beta32 = 2.0 * jax.nn.sigmoid(beta32)
+            o = kda_scan(q, k, v, a, beta32, g.kda_chunk_size)
+        with jax.named_scope("lm/attn/kda_gate_norm"):
+            y = (rms_norm(o, w_norm, g.norm_eps)
+                 * jax.nn.sigmoid(gate).reshape(o.shape))
+        with jax.named_scope("lm/attn/out"):
+            return y.reshape(*lead, inner) @ w_o.astype(dtype)
 
 
 # --------------------------------------------------------------- attention
@@ -297,6 +509,9 @@ class AttentionMixer(nn.Module):
         w_k = self.param("k_proj", _kernel_init, (g.hidden_size, kv_dim))
         w_v = self.param("v_proj", _kernel_init, (g.hidden_size, kv_dim))
         w_o = self.param("o_proj", _kernel_init, (q_dim, g.hidden_size))
+        w_gate = (self.param("gate_proj", _kernel_init,
+                             (g.hidden_size, q_dim))
+                  if g.use_gqa_gate else None)
         lead = u.shape[:2]
         with jax.named_scope("lm/attn/qkv"):
             q = (u @ w_q.astype(dtype)).reshape(
@@ -307,6 +522,10 @@ class AttentionMixer(nn.Module):
                 *lead, g.num_key_value_heads, g.head_dim)
         with jax.named_scope("lm/attn/scores"):
             y = attention(q, k, v, g.attn_query_block)
+        if w_gate is not None:
+            # one value a head channel (``use_gqa_gate``)
+            with jax.named_scope("lm/attn/gate"):
+                y = y * jax.nn.sigmoid(u @ w_gate.astype(dtype))
         with jax.named_scope("lm/attn/out"):
             return y @ w_o.astype(dtype)
 
@@ -570,8 +789,12 @@ class MoEMixer(nn.Module):
 
 # ------------------------------------------------------------------- model
 
-_MIXERS = {"M": Mamba2Mixer, "*": AttentionMixer, "-": DenseMixer,
-           "E": MoEMixer}
+_MIXERS = {"M": Mamba2Mixer, "*": AttentionMixer, "K": KDAMixer,
+           "-": DenseMixer, "E": MoEMixer}
+# ``Settings`` field -> the key of the published ``linear_attn_config``
+# group it is read from
+_LINEAR_ATTN = {"kda_num_heads": "num_heads", "kda_head_dim": "head_dim",
+                "kda_conv_kernel": "short_conv_kernel_size"}
 # the sizes each letter of a pattern reads; ``*`` reads those of the form
 # of attention the config has the sizes of
 _LATENT = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
@@ -581,6 +804,8 @@ _NEEDS = {
           "conv_kernel", "chunk_size", "time_step_min", "time_step_max",
           "time_step_floor"),
     "*": ("num_attention_heads", "attn_query_block"),
+    "K": ("kda_num_heads", "kda_head_dim", "kda_conv_kernel",
+          "kda_chunk_size"),
     "-": ("intermediate_size",),
     "E": ("n_routed_experts", "num_experts_per_tok", "routed_scaling_factor",
           "moe_intermediate_size", "moe_shared_expert_intermediate_size",
@@ -656,9 +881,10 @@ def chunked_cross_entropy(h, w_head, targets, weights, chunk):
 class Settings:
     """The model's sizes as the modules read them (hashable: flax turns
     a dict field into a FrozenDict). The names are the published
-    config's, but for the held share, the module's layers and loss weight
-    and the three bounds the program sets itself. A size the model has no
-    layer for stays None (``_NEEDS``)."""
+    config's, but for the held share, the module's layers and loss weight,
+    the linear-attention group's three sizes (``_LINEAR_ATTN``) and the
+    four bounds the program sets itself. A size the model has no layer
+    for stays None (``_NEEDS``)."""
     pattern: str
     hidden_size: int
     vocab_slice: int
@@ -680,6 +906,11 @@ class Settings:
     attn_query_block: int | None = None
     num_key_value_heads: int | None = None
     head_dim: int | None = None
+    use_gqa_gate: bool = False
+    kda_num_heads: int | None = None
+    kda_head_dim: int | None = None
+    kda_conv_kernel: int | None = None
+    kda_chunk_size: int | None = None
     q_lora_rank: int | None = None
     kv_lora_rank: int | None = None
     qk_nope_head_dim: int | None = None
@@ -719,6 +950,9 @@ def model_settings(gen_cfg):
     width."""
     given = {f.name: gen_cfg[f.name] for f in dataclasses.fields(Settings)
              if f.name in gen_cfg}
+    linear = cfg_get(gen_cfg, "linear_attn_config", None) or {}
+    given.update({field: linear[key] for field, key in _LINEAR_ATTN.items()
+                  if key in linear})
     given.update(vocab_slice=int(cfg_get(gen_cfg, "vocab_slice", None)
                                  or gen_cfg["vocab_size"]),
                  remat=str(cfg_get(gen_cfg, "remat", "none")),
@@ -742,8 +976,12 @@ def model_settings(gen_cfg):
     missing = {kind: [n for n in names if getattr(g, n) is None]
                for kind, names in needs.items()}
     if any(missing.values()):
+        def where(name):
+            return ("gen.linear_attn_config." + _LINEAR_ATTN[name]
+                    if name in _LINEAR_ATTN else "gen." + name)
+
         raise ValueError("gen.pattern's layers lack their sizes: " + "; ".join(
-            f"{kind!r} needs gen.{', gen.'.join(names)}"
+            f"{kind!r} needs {', '.join(map(where, names))}"
             for kind, names in sorted(missing.items()) if names))
     if g.kv_lora_rank is not None and "*" in kinds \
             and g.qk_nope_head_dim + g.qk_rope_head_dim != g.v_head_dim:

@@ -1080,6 +1080,13 @@ def render_report(path_or_events):
             + (f"; the blocks keep {sum(attn['kept_bytes'].values())} bytes "
                "of the kernel's forward passes for its backward passes"
                if "kept_bytes" in attn else ""))
+    kda = s["meta"].get("kda_impl")
+    if kda:
+        lines.append(
+            f"- kda_impl: layers {', '.join(map(str, kda.get('layers')))}; "
+            f"{kda.get('heads')} heads of {kda.get('head_dim')} held; "
+            f"chunks of {kda.get('chunk')} steps, "
+            f"{kda.get('chunks_at_once')} at once")
     lines.extend(_experts_section(s))
     lines.extend(_health_section(s))
     lines.extend(_xla_section(s))

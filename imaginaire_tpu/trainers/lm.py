@@ -54,6 +54,22 @@ def attn_impl(gen_cfg, tokens_shape):
                             for i, arm in arms.items()})
 
 
+def kda_impl(gen_cfg):
+    """The ``kda_impl`` meta: the delta-rule layers of the pattern, the
+    heads held here (how many the whole layer has is the deployment's to
+    say, not the program's) at their size, the chunk of the WY form and
+    how many chunks' decays stand at once; None for a model without such
+    a layer."""
+    g = hybrid_lm.model_settings(gen_cfg)
+    layers = [i for i, kind in enumerate(hybrid_lm.layer_kinds(g))
+              if kind == "K"]
+    if not layers:
+        return None
+    return dict(layers=layers, heads=g.kda_num_heads,
+                head_dim=g.kda_head_dim, chunk=g.kda_chunk_size,
+                chunks_at_once=hybrid_lm.KDA_CHUNKS_AT_ONCE)
+
+
 class Trainer(BaseTrainer):
     def __init__(self, cfg, *args, **kwargs):
         cfg = as_attrdict(cfg)
@@ -123,10 +139,15 @@ class Trainer(BaseTrainer):
         return losses
 
     def _note_attn_impl(self, tokens_shape):
-        """One ``attn_impl`` meta as the step is first built."""
+        """One ``attn_impl`` meta as the step is first built, and one
+        ``kda_impl`` where the model has delta-rule layers."""
         tm = telemetry.get()
-        if tm.enabled:
-            tm.meta("attn_impl", **attn_impl(self.cfg.gen, tokens_shape))
+        if not tm.enabled:
+            return
+        tm.meta("attn_impl", **attn_impl(self.cfg.gen, tokens_shape))
+        kda = kda_impl(self.cfg.gen)
+        if kda:
+            tm.meta("kda_impl", **kda)
 
     def _flush_counters(self, tm, step):
         """At telemetry's flush, behind its fence: tokens a second over

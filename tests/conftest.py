@@ -63,6 +63,13 @@ _SUPERSEDED = {
         "a second token cell reports ten of the twelve. Superseded by "
         "test_bench_glm_rehearsal.py::test_pr27_reader_entries_are_still_"
         "whole (PR 31)",
+    "tests/benchmark_rehearsal/test_bench_glm_rehearsal.py::"
+    "test_pr27_reader_entries_are_still_whole":
+        "asserts that the twelve *.lm entries list no cell but Nemotron's "
+        "and GLM's; a third token cell reports ten of them. Superseded by "
+        "test_bench_solar_rehearsal.py::test_pr27_and_pr31_reader_entries_"
+        "are_still_whole (PR 34), which finds the token cells by their "
+        "driver",
 }
 
 

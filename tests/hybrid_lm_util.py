@@ -11,10 +11,12 @@ if ROOT not in sys.path:
 TINY_YAML = os.path.join(ROOT, "configs", "unit_test", "hybrid_lm.yaml")
 LATENT_YAML = os.path.join(ROOT, "configs", "unit_test",
                            "glm4_moe_lite.yaml")
+DELTA_YAML = os.path.join(ROOT, "configs", "unit_test", "solar_open2.yaml")
 # tiny preset -> (its YAML, its plain reference under benchmark/reference,
 # the index of its first expert layer)
 PRESETS = {"nemotron_h": (TINY_YAML, "nemotron_h_train", 1),
-           "glm4_moe_lite": (LATENT_YAML, "glm4_moe_lite_train", 3)}
+           "glm4_moe_lite": (LATENT_YAML, "glm4_moe_lite_train", 3),
+           "solar_open2": (DELTA_YAML, "solar_open2_train", 1)}
 
 
 def tiny_cfg(preset="nemotron_h", **gen):
@@ -30,7 +32,9 @@ def tiny_cfg(preset="nemotron_h", **gen):
 def sizes_of(cfg):
     """The reference's `sizes` of a program config."""
     sizes = dict(cfg.gen)
-    sizes["experts_held"] = dict(sizes["experts_held"])
+    for group in ("experts_held", "linear_attn_config"):
+        if group in sizes:
+            sizes[group] = dict(sizes[group])
     return sizes
 
 

@@ -7,7 +7,10 @@ shares adding up to the whole layer; skew and an overfull buffer; the
 expert layer on the filled prefix of its buffer against the whole buffer
 (ISSUE 28). The same for the latent-attention preset (ISSUE 31): the
 latent mixer with its rotary turn and shared rotary key, the dense
-feed-forward, gated held experts in both tiers, its eight shares."""
+feed-forward, gated held experts in both tiers, its eight shares. And for
+the delta-rule preset (ISSUE 34): the chunked WY form against the
+step-by-step recurrence, the Kimi Delta Attention mixer, the gated
+attention, and the head shares adding up to the whole mixer."""
 
 import functools
 from unittest import mock
@@ -19,6 +22,7 @@ import pytest
 
 from hybrid_lm_util import PRESETS, layer_params, seeded, tiny_cfg
 
+from imaginaire_tpu.analysis import islands
 from imaginaire_tpu.models.generators import hybrid_lm
 
 
@@ -74,11 +78,89 @@ def test_ssd_scan_is_the_step_by_step_recurrence(length):
         _close(mine, plain, tol=1e-4)
 
 
+@pytest.mark.parametrize("length,chunk", [(64, 16), (50, 16), (70, 32)])
+def test_kda_scan_is_the_step_by_step_recurrence(length, chunk):
+    """ISSUE 34: output and every gradient of the chunked delta rule, at
+    two chunk sizes (one above the 16 rows the inverse substitutes
+    directly), lengths the chunk does not divide, beta up to 2 (most of
+    it above 1), and in every head channels that forget in a step (rate
+    e^2.5) beside channels that hold for the whole sequence (e^-6)."""
+    from benchmark.reference import solar_open2_train as reference
+
+    heads, dim = 3, 16
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    q, k, v = (jax.random.normal(key, (2, length, heads, dim))
+               for key in keys[:3])
+    q, k = reference.l2_norm(q), reference.l2_norm(k)
+    rate = jnp.exp(jax.random.uniform(keys[3], (heads, dim), minval=-6.0,
+                                      maxval=2.5))
+    a = -rate * jax.nn.softplus(
+        jax.random.normal(keys[4], (2, length, heads, dim)))
+    beta = 2 * jax.nn.sigmoid(
+        jax.random.normal(keys[5], (2, length, heads)) + 1.0)
+    assert float(a.min()) < -20 and float(beta.max()) > 1.9
+    assert float((beta > 1).mean()) > 0.5
+
+    def ours(q, k, v, a, beta):
+        return hybrid_lm.kda_scan(q, k, v, a, beta, chunk)
+
+    def theirs(q, k, v, a, beta):
+        return jax.vmap(reference.delta_rule)(q, k, v, a, beta)
+
+    (y_ours, g_ours), (y_theirs, g_theirs) = (
+        _value_and_grads(f, (q, k, v, a, beta)) for f in (ours, theirs))
+    _close(y_ours, y_theirs)
+    for mine, plain in zip(g_ours, g_theirs):
+        _close(mine, plain, tol=1e-4)
+
+
+def test_the_delta_rule_is_a_float32_island_under_bfloat16_compute():
+    """What the step's graph audit holds the mixer to on the chip, where
+    the compute dtype is bfloat16: no cast down inside `delta_rule` (or
+    any other island), forward or backward; the casts in and out stand
+    outside it."""
+    from imaginaire_tpu.analysis import jaxpr_audit
+
+    cfg = tiny_cfg("solar_open2", compute_dtype="bfloat16")
+    _, _, train, _ = seeded(cfg, 5, "solar_open2")
+    g = hybrid_lm.model_settings(cfg.gen)
+    mixer = hybrid_lm.KDAMixer(g)
+    u = _inputs(cfg, 50).astype(jnp.bfloat16)
+
+    def loss(params, u):
+        return jnp.sum(mixer.apply({"params": params}, u).astype(jnp.float32))
+
+    traced = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(
+        layer_params(train, 2), u)
+    violations, stats = jaxpr_audit.audit_jaxpr("kda", traced.jaxpr)
+    assert [v for v in violations if v.rule == "island_cast"] == []
+    assert stats["island_casts"] == 0
+    # and the island is there to be audited
+    inside = {eqn.primitive.name
+              for _, eqn in jaxpr_audit.iter_eqns(traced.jaxpr)
+              if islands.island_of(eqn.source_info.name_stack)
+              == "delta_rule"}
+    assert {"exp", "scan", "logistic"} <= inside
+
+
+def test_unit_lower_inverse_inverts():
+    """Both arms: 16 rows by forward substitution, 40 by halves (20 by
+    halves of 10, which substitute)."""
+    for n in (16, 40):
+        a = jnp.tril(jax.random.normal(jax.random.PRNGKey(n), (3, n, n)), -1)
+        inverse = hybrid_lm.unit_lower_inverse(0.3 * a)
+        _close(inverse @ (jnp.eye(n) + 0.3 * a),
+               jnp.broadcast_to(jnp.eye(n), a.shape), tol=1e-5)
+        assert float(jnp.abs(jnp.triu(inverse, 1)).max()) == 0
+
+
 @pytest.mark.parametrize("preset,kind,index,length", [
     ("nemotron_h", "M", 0, 50), ("nemotron_h", "*", 3, 50),
     ("nemotron_h", "E", 1, 64), ("glm4_moe_lite", "*", 0, 50),
     ("glm4_moe_lite", "*", 2, 64), ("glm4_moe_lite", "-", 1, 64),
-    ("glm4_moe_lite", "E", 3, 64)])
+    ("glm4_moe_lite", "E", 3, 64), ("solar_open2", "*", 0, 50),
+    ("solar_open2", "K", 2, 50), ("solar_open2", "K", 4, 64),
+    ("solar_open2", "E", 1, 64)])
 def test_mixer_follows_the_reference(preset, kind, index, length):
     """Values and gradients (to the input and to every parameter) of one
     mixer; attention at two query blocks, the second one ragged at 50."""
@@ -110,10 +192,12 @@ def test_mixer_follows_the_reference(preset, kind, index, length):
 
 
 @pytest.mark.parametrize("preset,count", [("nemotron_h", 4),
-                                          ("glm4_moe_lite", 1)])
+                                          ("glm4_moe_lite", 1),
+                                          ("solar_open2", 2)])
 def test_the_shares_add_up_to_the_whole_layer(preset, count):
-    """ISSUE 27 (c), ISSUE 31: the routed parts that the shares give (two
-    of four experts; eight of one gated expert), with the shared expert
+    """ISSUE 27 (c), ISSUE 31, ISSUE 34: the routed parts that the shares
+    give (two of four experts; eight of one gated expert; four of two
+    gated experts), with the shared expert
     counted once, are what the uncut reference gives for the layer with
     all eight experts."""
     layer = PRESETS[preset][2]
@@ -147,6 +231,52 @@ def test_the_shares_add_up_to_the_whole_layer(preset, count):
     _close(total, whole)
     # every assignment landed on exactly one share
     assert held_rows == u.shape[0] * u.shape[1] * sizes["num_experts_per_tok"]
+
+
+# the axis along which a head-parallel rank holds a slice of a mixer's
+# parameter (query heads, or key-value heads for `k_proj` and `v_proj`)
+_HEAD_AXIS = {
+    "K": {**{f"{n}_{part}": 1 for n in "qkv" for part in ("proj", "conv")},
+          "f_b_proj": 1, "g_b_proj": 1, "b_proj": 1, "dt_bias": 0,
+          "A_log": 0, "o_proj": 0},
+    "*": {"q_proj": 1, "gate_proj": 1, "k_proj": 1, "v_proj": 1,
+          "o_proj": 0},
+}
+
+
+@pytest.mark.parametrize("kind,index", [("K", 2), ("*", 0)])
+def test_the_head_shares_add_up_to_the_whole_mixer(kind, index):
+    """ISSUE 34: the whole layer has 8 heads (8 query heads on 2
+    key-value heads in the attention layer); two head-parallel ranks hold
+    4 each (and 1 key-value head), every parameter that is not a head's
+    (the two low-rank down projections, the head norm's scale) whole on
+    both. Their partial results, each its heads' rows of `W_o`, sum to
+    what the reference gives for the uncut mixer."""
+    preset = "solar_open2"
+    whole_cfg = tiny_cfg(
+        preset, num_attention_heads=8, num_key_value_heads=2,
+        linear_attn_config={"short_conv_kernel_size": 4, "head_dim": 16,
+                            "num_heads": 8})
+    reference, sizes, train, _ = seeded(whole_cfg, 7, preset)
+    prefix = f"layer_{index}/mixer/"
+    u = _inputs(whole_cfg, 50)
+    whole = jax.jit(lambda p, u: reference._MIXERS[kind](
+        p, prefix, sizes, u, "float32"))(train, u)
+    settings = hybrid_lm.model_settings(tiny_cfg(preset).gen)
+    assert (settings.kda_num_heads, settings.num_attention_heads,
+            settings.num_key_value_heads) == (4, 4, 1)
+    module = hybrid_lm.mixer_of(settings, kind)(settings)
+    total = 0.0
+    for rank in range(2):
+        params = {}
+        for name, value in layer_params(train, index).items():
+            axis = _HEAD_AXIS[kind].get(name)
+            if axis is not None:
+                held = value.shape[axis] // 2
+                value = jnp.take(value, jnp.arange(held) + rank * held, axis)
+            params[name] = value
+        total = total + jax.jit(module.apply)({"params": params}, u)
+    _close(total, whole)
 
 
 def _route_all_to(expert, tokens, top_k=2):
@@ -306,6 +436,11 @@ def test_bad_held_share_and_pattern_fail_loudly():
     ("glm4_moe_lite", dict(v_head_dim=16), "one head size"),
     ("glm4_moe_lite", dict(hidden_act="gelu"), "hidden_act"),
     ("nemotron_h", dict(nextn_pattern="*E"), "nextn_loss_weight"),
+    ("nemotron_h", dict(pattern="MKE"),
+     "'K' needs gen.linear_attn_config.num_heads, "
+     "gen.linear_attn_config.head_dim, "
+     "gen.linear_attn_config.short_conv_kernel_size, gen.kda_chunk_size"),
+    ("solar_open2", dict(kda_chunk_size=None), "'K' needs gen.kda_chunk_size"),
 ])
 def test_a_layer_without_its_sizes_fails_loudly(preset, change, message):
     with pytest.raises(ValueError, match=message):
@@ -327,6 +462,15 @@ def test_absent_sizes_are_absent_not_zero():
     assert hybrid_lm.attention_head_dim(hybrid) == 16
     assert hybrid_lm.layer_kinds(latent) == "*-*E*E*E"
     assert hybrid_lm.layer_kinds(hybrid) == "MEM*E"
+    # ISSUE 34: the delta-rule sizes are the published group's, the
+    # output gate a flag only that model sets
+    delta = hybrid_lm.model_settings(tiny_cfg("solar_open2").gen)
+    assert (delta.kda_num_heads, delta.kda_head_dim,
+            delta.kda_conv_kernel) == (4, 16, 4)
+    assert delta.use_gqa_gate and not hybrid.use_gqa_gate
+    assert hybrid.kda_num_heads is None and latent.kda_chunk_size is None
+    assert hybrid_lm.mixer_of(delta, "*") is hybrid_lm.AttentionMixer
+    assert delta.nextn_pattern is None
 
 
 # ----------------------------------------------------- the rotary turn

@@ -10,7 +10,7 @@ import sys
 
 import pytest
 
-from hybrid_lm_util import LATENT_YAML, ROOT, TINY_YAML
+from hybrid_lm_util import DELTA_YAML, LATENT_YAML, ROOT, TINY_YAML
 
 from imaginaire_tpu.parallel import mesh as mesh_mod
 from imaginaire_tpu.telemetry import core as tcore
@@ -79,6 +79,7 @@ def test_train_py_trains_checkpoints_and_resumes(entry_point_sandbox,
         e.get("label") for e in events if e["kind"] == "meta"}
     metas = {e["name"] for e in events if e["kind"] == "meta"}
     assert "step_flops" in metas and "xla_compile/gen_step" in metas
+    assert "kda_impl" not in metas      # no delta-rule layer, no meta
     # the one attention layer of 'MEM*E', on the CPU: the plain arm
     attn = [e for e in events
             if e["kind"] == "meta" and e["name"] == "attn_impl"]
@@ -143,3 +144,41 @@ def test_train_py_trains_the_latent_attention_preset(entry_point_sandbox,
     assert "| 7 |" in report.split("## experts")[1]
     assert "- lm/main: " in report and "- lm/mtp: " in report
     assert "head size 32: layer 0 blocks, layer 2 blocks" in report
+
+
+def test_train_py_trains_the_delta_rule_preset(entry_point_sandbox,
+                                               monkeypatch, tmp_path):
+    """ISSUE 34: `configs/unit_test/solar_open2.yaml` through
+    `train.main()`: one loss, the three expert layers' counters, the one
+    softmax layer in the `attn_impl` meta and the two delta-rule layers
+    in a `kda_impl` meta, no recompile, a clean graph audit (the delta
+    rule is a float32 island: nothing inside it is cast down)."""
+    logdir = str(tmp_path / "log")
+    trainer = _train(monkeypatch, logdir, 2, config=DELTA_YAML)
+    assert trainer.current_iteration == 2
+    assert trainer.weights == {"lm": 1.0}
+    events = _events(logdir)
+    counters = {e["name"]: e["value"] for e in events
+                if e["kind"] == "counter"}
+    assert counters["lm/main"] > 0 and "lm/mtp" not in counters
+    assert counters["perf/tokens_per_sec"] > 0
+    for layer in (1, 3, 5):
+        assert counters[f"moe/{layer}/held_assignments"] > 0
+    assert counters["xla/recompiles"] == 0
+    assert counters["xla/graph_violations"] == 0
+    metas = {e["name"]: e for e in events if e["kind"] == "meta"}
+    assert metas["attn_impl"]["layers"] == {"0": "blocks"}
+    assert metas["attn_impl"]["head_dim"] == 16
+    kda = metas["kda_impl"]
+    assert (kda["layers"], kda["heads"], kda["head_dim"], kda["chunk"]) == (
+        [2, 4], 4, 16, 16)
+    assert sum(1 for e in events if e["kind"] == "meta"
+               and e["name"] == "kda_impl") == 1
+
+    from imaginaire_tpu.telemetry.report import render_report
+
+    report = render_report(os.path.join(logdir, "telemetry.jsonl"))
+    assert "head size 16: layer 0 blocks" in report
+    assert ("- kda_impl: layers 2, 4; 4 heads of 16 held; chunks of 16 "
+            "steps, 8 at once") in report
+    assert "| 5 |" in report.split("## experts")[1]

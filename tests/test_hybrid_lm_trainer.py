@@ -1,7 +1,8 @@
 """The hybrid token model through the trainer, against the plain
 reference (ISSUE 27 (b), (d), (f)): the whole model's loss and gradients,
 two `gen_update` steps against the reference's Adam steps (both for the
-latent-attention preset too, whose loss is two: ISSUE 31), an overfull
+latent-attention preset too, whose loss is two: ISSUE 31; and for the
+delta-rule preset: ISSUE 34), an overfull
 expert buffer failing the step's health flag, and a token batch passing
 the feed's index-map rule untouched."""
 
@@ -39,7 +40,7 @@ def _worst(ours, theirs):
                      / (jnp.linalg.norm(theirs[k]) + 1e-12)) for k in theirs)
 
 
-PRESETS = ["nemotron_h", "glm4_moe_lite"]
+PRESETS = ["nemotron_h", "glm4_moe_lite", "solar_open2"]
 
 
 @pytest.mark.parametrize("preset", PRESETS)
