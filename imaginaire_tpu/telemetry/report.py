@@ -1085,8 +1085,8 @@ def render_report(path_or_events):
         lines.append(
             f"- kda_impl: layers {', '.join(map(str, kda.get('layers')))}; "
             f"{kda.get('heads')} heads of {kda.get('head_dim')} held; "
-            f"chunks of {kda.get('chunk')} steps, "
-            f"{kda.get('chunks_at_once')} at once")
+            f"chunks of {kda.get('chunk')} steps in sub-blocks of "
+            f"{kda.get('sub_block')}, {kda.get('chunks_at_once')} at once")
     lines.extend(_experts_section(s))
     lines.extend(_health_section(s))
     lines.extend(_xla_section(s))

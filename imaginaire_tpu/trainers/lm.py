@@ -57,9 +57,11 @@ def attn_impl(gen_cfg, tokens_shape):
 def kda_impl(gen_cfg):
     """The ``kda_impl`` meta: the delta-rule layers of the pattern, the
     heads held here (how many the whole layer has is the deployment's to
-    say, not the program's) at their size, the chunk of the WY form and
-    how many chunks' decays stand at once; None for a model without such
-    a layer."""
+    say, not the program's) at their size, the chunk of the WY form, the
+    rows of the sub-blocks its decayed products are built by (the whole
+    chunk where ``KDA_SUB_BLOCK`` does not divide it) and how many
+    chunks' decays stand at once; None for a model without such a
+    layer."""
     g = hybrid_lm.model_settings(gen_cfg)
     layers = [i for i, kind in enumerate(hybrid_lm.layer_kinds(g))
               if kind == "K"]
@@ -67,6 +69,7 @@ def kda_impl(gen_cfg):
         return None
     return dict(layers=layers, heads=g.kda_num_heads,
                 head_dim=g.kda_head_dim, chunk=g.kda_chunk_size,
+                sub_block=hybrid_lm.kda_sub_block(g.kda_chunk_size),
                 chunks_at_once=hybrid_lm.KDA_CHUNKS_AT_ONCE)
 
 

@@ -78,28 +78,29 @@ def test_ssd_scan_is_the_step_by_step_recurrence(length):
         _close(mine, plain, tol=1e-4)
 
 
-@pytest.mark.parametrize("length,chunk", [(64, 16), (50, 16), (70, 32)])
-def test_kda_scan_is_the_step_by_step_recurrence(length, chunk):
-    """ISSUE 34: output and every gradient of the chunked delta rule, at
-    two chunk sizes (one above the 16 rows the inverse substitutes
-    directly), lengths the chunk does not divide, beta up to 2 (most of
-    it above 1), and in every head channels that forget in a step (rate
-    e^2.5) beside channels that hold for the whole sequence (e^-6)."""
+def _delta_rule_operands(length, heads=3, dim=16):
+    """q, k (l2-normed), v (2, length, heads, dim), a rate a head channel
+    from e^-6 (holds for the whole sequence) to e^2.5 (forgets in a
+    step), a softplus draw a step for it to scale, and beta up to 2."""
     from benchmark.reference import solar_open2_train as reference
 
-    heads, dim = 3, 16
     keys = jax.random.split(jax.random.PRNGKey(0), 6)
     q, k, v = (jax.random.normal(key, (2, length, heads, dim))
                for key in keys[:3])
-    q, k = reference.l2_norm(q), reference.l2_norm(k)
     rate = jnp.exp(jax.random.uniform(keys[3], (heads, dim), minval=-6.0,
                                       maxval=2.5))
-    a = -rate * jax.nn.softplus(
+    steps = jax.nn.softplus(
         jax.random.normal(keys[4], (2, length, heads, dim)))
     beta = 2 * jax.nn.sigmoid(
         jax.random.normal(keys[5], (2, length, heads)) + 1.0)
-    assert float(a.min()) < -20 and float(beta.max()) > 1.9
-    assert float((beta > 1).mean()) > 0.5
+    return reference.l2_norm(q), reference.l2_norm(k), v, rate, steps, beta
+
+
+def _kda_scan_against_the_recurrence(operands, chunk):
+    """(output, five gradients) of the chunked delta rule and of the
+    reference's step-by-step recurrence, compared at the tolerances the
+    other mixers' have."""
+    from benchmark.reference import solar_open2_train as reference
 
     def ours(q, k, v, a, beta):
         return hybrid_lm.kda_scan(q, k, v, a, beta, chunk)
@@ -108,10 +109,55 @@ def test_kda_scan_is_the_step_by_step_recurrence(length, chunk):
         return jax.vmap(reference.delta_rule)(q, k, v, a, beta)
 
     (y_ours, g_ours), (y_theirs, g_theirs) = (
-        _value_and_grads(f, (q, k, v, a, beta)) for f in (ours, theirs))
+        _value_and_grads(f, operands) for f in (ours, theirs))
     _close(y_ours, y_theirs)
     for mine, plain in zip(g_ours, g_theirs):
         _close(mine, plain, tol=1e-4)
+    return y_ours, g_ours
+
+
+@pytest.mark.parametrize("length,chunk", [
+    (64, 16), (50, 16), (70, 32), (128, 64), (100, 48), (60, 24)])
+def test_kda_scan_is_the_step_by_step_recurrence(length, chunk):
+    """ISSUE 34: output and every gradient of the chunked delta rule, at
+    lengths the chunk does not divide, beta up to 2 (most of it above 1),
+    and in every head channels that forget in a step (rate e^2.5) beside
+    channels that hold for the whole sequence (e^-6). The chunks: one
+    sub-block of 16 rows (the inverse substitutes directly), two, four
+    (ISSUE 35: the token cell's chunk; the sub-blocks' inverses are one
+    batch), three (48: the inverse's halves are 24 rows), and 24 rows,
+    which 16 does not divide: one sub-block."""
+    q, k, v, rate, steps, beta = _delta_rule_operands(length)
+    a = -rate * steps
+    assert float(a.min()) < -20 and float(beta.max()) > 1.9
+    assert float((beta > 1).mean()) > 0.5
+    _kda_scan_against_the_recurrence((q, k, v, a, beta), chunk)
+
+
+def test_kda_scan_holds_where_a_channel_forgets_everything_in_a_step():
+    """ISSUE 35: log-decays down to -80 a step in some channels (e^-80
+    is under float32's smallest normal number: a sub-block's factors
+    ``e^(c_i - r_I)`` and ``e^(r_I - c_j)`` underflow to 0 there) and 0
+    in others (nothing is forgotten). No factored exponent rises above
+    0, so output and gradients are finite, and the reference's."""
+    q, k, v, _, steps, beta = _delta_rule_operands(128)
+    depth = jnp.where(jnp.arange(16) % 3 == 0, 80.0, 0.0)
+    depth = depth.at[1].set(5.0)
+    a = -depth * jnp.minimum(steps, 1.0)
+    assert float(a.min()) == -80.0 and float(a.max()) == 0.0
+    assert float(jnp.cumsum(a, 1).min()) < -5000
+    y, grads = _kda_scan_against_the_recurrence((q, k, v, a, beta), 64)
+    for x in (y, *grads):
+        assert bool(jnp.isfinite(x).all())
+
+
+@pytest.mark.parametrize("chunk,rows", [
+    (64, 16), (48, 16), (16, 16), (24, 24), (8, 8)])
+def test_a_chunk_is_cut_into_sub_blocks_where_16_divides_it(chunk, rows):
+    """What the `kda_impl` meta says of a run: the token cell's chunk of
+    64 is four sub-blocks of 16; a chunk of at most 16 rows, or one that
+    16 does not divide, is one."""
+    assert hybrid_lm.kda_sub_block(chunk) == rows
 
 
 def test_the_delta_rule_is_a_float32_island_under_bfloat16_compute():
@@ -144,9 +190,10 @@ def test_the_delta_rule_is_a_float32_island_under_bfloat16_compute():
 
 
 def test_unit_lower_inverse_inverts():
-    """Both arms: 16 rows by forward substitution, 40 by halves (20 by
-    halves of 10, which substitute)."""
-    for n in (16, 40):
+    """The arms: 16 rows by forward substitution, 40 by halves (20 by
+    halves of 10, which substitute), 64 by halves whose four sub-blocks
+    of 16 rows substitute as one batch."""
+    for n in (16, 40, 64):
         a = jnp.tril(jax.random.normal(jax.random.PRNGKey(n), (3, n, n)), -1)
         inverse = hybrid_lm.unit_lower_inverse(0.3 * a)
         _close(inverse @ (jnp.eye(n) + 0.3 * a),

@@ -170,8 +170,8 @@ def test_train_py_trains_the_delta_rule_preset(entry_point_sandbox,
     assert metas["attn_impl"]["layers"] == {"0": "blocks"}
     assert metas["attn_impl"]["head_dim"] == 16
     kda = metas["kda_impl"]
-    assert (kda["layers"], kda["heads"], kda["head_dim"], kda["chunk"]) == (
-        [2, 4], 4, 16, 16)
+    assert (kda["layers"], kda["heads"], kda["head_dim"], kda["chunk"],
+            kda["sub_block"]) == ([2, 4], 4, 16, 16, 16)
     assert sum(1 for e in events if e["kind"] == "meta"
                and e["name"] == "kda_impl") == 1
 
@@ -180,5 +180,5 @@ def test_train_py_trains_the_delta_rule_preset(entry_point_sandbox,
     report = render_report(os.path.join(logdir, "telemetry.jsonl"))
     assert "head size 16: layer 0 blocks" in report
     assert ("- kda_impl: layers 2, 4; 4 heads of 16 held; chunks of 16 "
-            "steps, 8 at once") in report
+            "steps in sub-blocks of 16, 8 at once") in report
     assert "| 5 |" in report.split("## experts")[1]
