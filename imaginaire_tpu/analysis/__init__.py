@@ -24,11 +24,12 @@ from . import ast_rules, collectives, donation, hlo_audit  # noqa: F401
 
 
 def audit_program(program, traced=None, lowered=None, compiled=None, *,
-                  const_bytes_limit=None, include_hlo=True):
+                  const_bytes_limit=None, include_hlo=True, hlo_text=None):
     """Audit one compiled program; returns the ledger ``audit`` dict:
     ``{violations, violation_count, stats, collectives, donation,
     const_bytes}``. Every sub-audit is best-effort — a failure is
-    recorded under ``errors`` instead of raised."""
+    recorded under ``errors`` instead of raised. ``hlo_text`` is the
+    executable's text where the caller already took it."""
     from .jaxpr_audit import DEFAULT_CONST_BYTES_LIMIT
 
     if const_bytes_limit is None:
@@ -50,8 +51,9 @@ def audit_program(program, traced=None, lowered=None, compiled=None, *,
     audit["stats"] = stats
     audit["const_bytes"] = stats.get("const_bytes", 0)
 
-    hlo_text = None
-    if include_hlo and compiled is not None:
+    if not include_hlo:
+        hlo_text = None
+    elif hlo_text is None and compiled is not None:
         try:
             hlo_text = compiled.as_text()
         except Exception as e:  # noqa: BLE001

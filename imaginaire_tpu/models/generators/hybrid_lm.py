@@ -920,10 +920,13 @@ class Block(nn.Module):
     def __call__(self, h, training=False):
         scale = self.param("scale", nn.initializers.ones,
                            (self.cfg.hidden_size,))
-        u = rms_norm(h, scale, self.cfg.norm_eps)
+        with jax.named_scope("lm/block/norm"):
+            u = rms_norm(h, scale, self.cfg.norm_eps)
         out = mixer_of(self.cfg, self.kind)(self.cfg, name="mixer")(u)
         out, stats = out if self.kind == "E" else (out, {})
-        return h + out, stats
+        with jax.named_scope("lm/block/residual"):
+            h = h + out
+        return h, stats
 
 
 def chunked_cross_entropy(h, w_head, targets, weights, chunk):
@@ -1105,7 +1108,8 @@ class Generator(nn.Module):
         def head_loss(h, scale, ahead):
             """(mean cross-entropy of position t's logits against token
             t + ``ahead``, the normed ``h``)."""
-            h = rms_norm(h, scale, g.norm_eps)
+            with jax.named_scope("lm/final_norm"):
+                h = rms_norm(h, scale, g.norm_eps)
             targets = jnp.roll(tokens, -ahead, axis=1).reshape(-1)
             weights = jnp.ones(tokens.shape, jnp.float32)
             for last in range(1, ahead + 1):   # these have no such token

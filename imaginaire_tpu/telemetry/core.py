@@ -394,7 +394,8 @@ class Telemetry:
             elif self._tracing_until is not None \
                     and step >= self._tracing_until:
                 jax.profiler.stop_trace()
-                self.meta("trace_stopped", step=step)
+                self.meta("trace_stopped", step=step,
+                          scopes=self._write_trace_scopes())
                 logger.info("telemetry: jax.profiler trace stopped at "
                             "step %d", step)
                 self._tracing_until = None
@@ -402,6 +403,16 @@ class Telemetry:
             logger.warning("telemetry trace capture failed: %s", e)
             self._tracing_until = None
             self.trace_at_step = None
+
+    def _write_trace_scopes(self):
+        """``<logdir>/trace/scopes.json`` beside the trace just stopped:
+        the compile ledger's {label: {instruction: op_name}}, by which a
+        device event is laid under the program's named scopes wherever
+        the trace is read. Returns the labels written."""
+        from imaginaire_tpu.telemetry import xla_obs
+
+        return xla_obs.write_op_names(
+            (self.logdir or ".") + "/trace/scopes.json")
 
     # ------------------------------------------------------- aggregates
 
@@ -675,6 +686,7 @@ class Telemetry:
                 import jax
 
                 jax.profiler.stop_trace()
+                self._write_trace_scopes()
             except Exception:  # noqa: BLE001
                 pass
             self._tracing_until = None

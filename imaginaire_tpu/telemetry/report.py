@@ -583,6 +583,11 @@ def _xla_section(s):
                              f" (temp {_fmt_bytes(mem.get('temp_bytes', 0))})")
             if compile_meta.get("flops"):
                 parts.append(f"{compile_meta['flops']:.3g} flops")
+            if compile_meta.get("scoped_instructions") is not None:
+                # 0 for a program whose source has named scopes: the
+                # executable came from a cache another build filled
+                parts.append(f"{compile_meta['scoped_instructions']} "
+                             "instructions under named scopes")
             detail = " — " + ", ".join(parts)
         lines.append(f"- {label}: {count} compile(s){detail}")
     n_re = x.get("recompiles", 0)

@@ -239,6 +239,7 @@ class CompileLedger:
         self.compile_counts = {}   # label -> compile count
         self.label_flops = {}      # label -> latest cost_analysis flops
         self.label_memory = {}     # label -> latest memory_analysis dict
+        self.label_op_names = {}   # label -> latest {instruction: op_name}
         self._active = []          # open (label, t_start) compile stack
         self._written = 0          # records already in the jsonl file
 
@@ -267,9 +268,12 @@ class CompileLedger:
 
     def record(self, entry):
         """Append one compile entry; emit counters/meta + jsonl line."""
+        op_names = entry.pop("op_names", None)
         with self._lock:
             self.records.append(entry)
             label = entry["label"]
+            if op_names is not None:
+                self.label_op_names[label] = op_names
             self.compile_counts[label] = \
                 self.compile_counts.get(label, 0) + 1
             if entry.get("flops") is not None:
@@ -624,9 +628,19 @@ class CompiledProgram:
         }
         if counted and diff is not None:
             entry["diff"] = diff
+        # the optimized module's text, once: the graph audit reads it,
+        # and with telemetry on the ledger keeps its instructions' names
+        keep_names = _telemetry().enabled
+        hlo_text = None
+        if keep_names or (_SETTINGS.graph_audit and _SETTINGS.audit_hlo):
+            hlo_text = _hlo_text(self.label, compiled)
         if _SETTINGS.graph_audit:
             entry["audit"] = _run_audit(self.label, traced, lowered,
-                                        compiled)
+                                        compiled, hlo_text)
+        if keep_names and hlo_text is not None:
+            entry["op_names"] = instruction_op_names(hlo_text)
+            entry["scoped_instructions"] = scoped_instructions(
+                entry["op_names"])
         _LEDGER.record(entry)
         if counted:
             text = _diff_text(diff)
@@ -661,7 +675,16 @@ def compiled_program(label, fn, donate_argnums=(),
                            out_shardings=out_shardings)
 
 
-def _run_audit(label, traced, lowered, compiled):
+def _hlo_text(label, compiled):
+    """The executable's optimized HLO module as text, or None."""
+    try:
+        return compiled.as_text()
+    except Exception as e:  # noqa: BLE001 — never fatal to a compile
+        logger.warning("xla_obs: no HLO text of %s (%s)", label, e)
+        return None
+
+
+def _run_audit(label, traced, lowered, compiled, hlo_text=None):
     """Graph audit (imaginaire_tpu/analysis) for one fresh compile —
     strictly best-effort: a broken audit is a ledger note, never a
     failed program."""
@@ -671,7 +694,7 @@ def _run_audit(label, traced, lowered, compiled):
         audit = analysis.audit_program(
             label, traced=traced, lowered=lowered, compiled=compiled,
             const_bytes_limit=_SETTINGS.audit_const_bytes,
-            include_hlo=_SETTINGS.audit_hlo)
+            include_hlo=_SETTINGS.audit_hlo, hlo_text=hlo_text)
     except Exception as e:  # noqa: BLE001
         return {"error": f"{type(e).__name__}: {e}"}
     if audit.get("violation_count"):
@@ -681,6 +704,80 @@ def _run_audit(label, traced, lowered, compiled):
             "; ".join(f"{v['rule']} at {v['path']}"
                       for v in audit["violations"][:4]))
     return audit
+
+
+# ------------------------------------------------------ instruction names
+
+# The profiler names a device event by its HLO instruction
+# (``%fusion.35 = ...``) and nothing else; the instruction's ``op_name``
+# holds the name stack it was traced under, ``jax.named_scope``s and
+# passes (``jvp``, ``transpose``, ``checkpoint``) alike. Keeping
+# {instruction: op_name} a program lets a trace be read by scope after
+# the trainer and its executables are gone.
+
+# `  %name = shape op(...)`: the spaces tell an instruction's head from an
+# attribute (`op_name="..."`) at the start of a continuation line
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+# what the programs' own ``jax.named_scope``s begin with: the token
+# model's layers, the GAN trainers' passes, the step's tail
+PROGRAM_SCOPES = ("lm/", "gan/", "step/")
+
+
+def instruction_op_names(hlo_text):
+    """{instruction name: op_name} of an optimized HLO module's text, for
+    the instructions that carry one. An instruction is read to its
+    closing brace: a ``frontend_attributes`` that holds a JSON string (a
+    ``pallas_call`` given ``metadata=``) is printed over several lines
+    and its ``metadata={op_name=...}`` stands on the last."""
+    out = {}
+
+    def close(name, lines):
+        found = _OP_NAME.search(" ".join(lines))
+        if found:
+            out[name] = found.group(1)
+
+    name, lines, depth = None, [], 0
+    for line in hlo_text.splitlines():
+        head = _INSTRUCTION.match(line)
+        if head:
+            if name is not None:
+                # a stray brace in a string: the next instruction's head
+                # closes this one all the same
+                close(name, lines)
+            name, lines, depth = head.group(1), [], 0
+        elif name is None:
+            continue
+        lines.append(line)
+        depth += line.count("{") - line.count("}")
+        if depth <= 0:
+            close(name, lines)
+            name = None
+    if name is not None:
+        close(name, lines)
+    return out
+
+
+def scoped_instructions(op_names):
+    """How many instructions' name stack holds a scope of the program's
+    own. 0 for a program with such scopes in its source says the
+    executable was served with another build's names (a persistent
+    cache keys a program without them)."""
+    return sum(1 for op_name in op_names.values()
+               if any(scope in op_name for scope in PROGRAM_SCOPES))
+
+
+def write_op_names(path):
+    """The labelled programs' {label: {instruction: op_name}} as JSON at
+    ``path`` (beside a trace, so that it reads by scope wherever it is
+    opened). Returns the labels written."""
+    with _LEDGER._lock:
+        # a label's map is replaced whole, never written to
+        maps = dict(_LEDGER.label_op_names)
+    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+    with open(path, "w") as f:
+        json.dump(maps, f)
+    return sorted(maps)
 
 
 def _diff_text(diff):

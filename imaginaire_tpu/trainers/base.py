@@ -401,10 +401,13 @@ class BaseTrainer:
         rng = jax.random.fold_in(state["rng_G"], step0)
 
         def loss_fn(params_G):
-            vars_G = dict(state["vars_G"], params=self._to_compute_dtype(params_G))
+            with jax.named_scope("step/cast"):
+                vars_G = dict(state["vars_G"],
+                              params=self._to_compute_dtype(params_G))
+                vars_D = self._cast_net_vars(state.get("vars_D"))
+                batch = self._to_compute_dtype(data)
             losses, new_mut = self.gen_forward(
-                vars_G, self._cast_net_vars(state.get("vars_D")),
-                state["loss_params"], self._to_compute_dtype(data), rng)
+                vars_G, vars_D, state["loss_params"], batch, rng)
             losses = {k: v.astype(jnp.float32) for k, v in losses.items()}
             total = self._total(losses)
             return total, (dict(losses, total=total), new_mut)
@@ -412,29 +415,35 @@ class BaseTrainer:
         (_, (losses, new_mut)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state["vars_G"]["params"])
         if self.clip_grad_norm_G:
-            grads, _ = optax.clip_by_global_norm(self.clip_grad_norm_G).update(grads, optax.EmptyState())
-        updates, new_opt = self.tx_G.update(
-            grads, state["opt_G"], state["vars_G"]["params"])
-        new_params = optax.apply_updates(state["vars_G"]["params"], updates)
-        new_params, new_opt, new_mut, ok, grad_norm = self._audit_guard(
-            losses, grads, state, "vars_G", "opt_G",
-            new_params, new_opt, new_mut)
+            with jax.named_scope("step/clip"):
+                grads, _ = optax.clip_by_global_norm(self.clip_grad_norm_G).update(grads, optax.EmptyState())
+        with jax.named_scope("step/optim"):
+            updates, new_opt = self.tx_G.update(
+                grads, state["opt_G"], state["vars_G"]["params"])
+            new_params = optax.apply_updates(state["vars_G"]["params"],
+                                             updates)
+        with jax.named_scope("step/guard"):
+            new_params, new_opt, new_mut, ok, grad_norm = self._audit_guard(
+                losses, grads, state, "vars_G", "opt_G",
+                new_params, new_opt, new_mut)
         new_vars_G = dict(state["vars_G"], params=new_params, **new_mut)
         state = dict(state, vars_G=new_vars_G, opt_G=new_opt,
                      step=step0 + 1)
         if self.model_average:
             n = state["num_ema_updates"] + 1
-            state["ema_G"] = ema_update(
-                state["ema_G"], new_params, n,
-                beta=self.model_average_beta,
-                start_iteration=self.model_average_start,
-                spectral=new_vars_G.get("spectral"),
-                remove_sn=self.model_average_remove_sn)
+            with jax.named_scope("step/ema"):
+                state["ema_G"] = ema_update(
+                    state["ema_G"], new_params, n,
+                    beta=self.model_average_beta,
+                    start_iteration=self.model_average_start,
+                    spectral=new_vars_G.get("spectral"),
+                    remove_sn=self.model_average_remove_sn)
             state["num_ema_updates"] = n
-        health = self._audit_health(
-            ok, grad_norm, step0, grads, new_params, updates,
-            spectral=new_vars_G.get("spectral"),
-            ema=state.get("ema_G") if self.model_average else None)
+        with jax.named_scope("step/health"):
+            health = self._audit_health(
+                ok, grad_norm, step0, grads, new_params, updates,
+                spectral=new_vars_G.get("spectral"),
+                ema=state.get("ema_G") if self.model_average else None)
         return self._constrain_state(state), losses, health
 
     def _dis_step_fn(self, state, data):
@@ -442,10 +451,13 @@ class BaseTrainer:
         rng = jax.random.fold_in(state["rng_D"], step0)
 
         def loss_fn(params_D):
-            vars_D = dict(state["vars_D"], params=self._to_compute_dtype(params_D))
+            with jax.named_scope("step/cast"):
+                vars_D = dict(state["vars_D"],
+                              params=self._to_compute_dtype(params_D))
+                vars_G = self._cast_net_vars(state["vars_G"])
+                batch = self._to_compute_dtype(data)
             losses, new_mut = self.dis_forward(
-                self._cast_net_vars(state["vars_G"]), vars_D,
-                state["loss_params"], self._to_compute_dtype(data), rng)
+                vars_G, vars_D, state["loss_params"], batch, rng)
             losses = {k: v.astype(jnp.float32) for k, v in losses.items()}
             total = self._total(losses)
             return total, (dict(losses, total=total), new_mut)
@@ -453,19 +465,24 @@ class BaseTrainer:
         (_, (losses, new_mut)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state["vars_D"]["params"])
         if self.clip_grad_norm_D:
-            grads, _ = optax.clip_by_global_norm(self.clip_grad_norm_D).update(grads, optax.EmptyState())
-        updates, new_opt = self.tx_D.update(
-            grads, state["opt_D"], state["vars_D"]["params"])
-        new_params = optax.apply_updates(state["vars_D"]["params"], updates)
-        new_params, new_opt, new_mut, ok, grad_norm = self._audit_guard(
-            losses, grads, state, "vars_D", "opt_D",
-            new_params, new_opt, new_mut)
+            with jax.named_scope("step/clip"):
+                grads, _ = optax.clip_by_global_norm(self.clip_grad_norm_D).update(grads, optax.EmptyState())
+        with jax.named_scope("step/optim"):
+            updates, new_opt = self.tx_D.update(
+                grads, state["opt_D"], state["vars_D"]["params"])
+            new_params = optax.apply_updates(state["vars_D"]["params"],
+                                             updates)
+        with jax.named_scope("step/guard"):
+            new_params, new_opt, new_mut, ok, grad_norm = self._audit_guard(
+                losses, grads, state, "vars_D", "opt_D",
+                new_params, new_opt, new_mut)
         new_vars_D = dict(state["vars_D"], params=new_params, **new_mut)
         state = dict(state, vars_D=new_vars_D,
                      opt_D=new_opt, step_D=step0 + 1)
-        health = self._audit_health(
-            ok, grad_norm, step0, grads, new_params, updates,
-            spectral=new_vars_D.get("spectral"))
+        with jax.named_scope("step/health"):
+            health = self._audit_health(
+                ok, grad_norm, step0, grads, new_params, updates,
+                spectral=new_vars_D.get("spectral"))
         return self._constrain_state(state), losses, health
 
     # ------------------------------------------------------------ lifecycle

@@ -77,49 +77,57 @@ class Trainer(BaseTrainer):
 
     def gen_forward(self, vars_G, vars_D, loss_params, data, rng, training=True):
         """(ref: trainers/spade.py:128-163)."""
-        net_G_output, new_mut = self._apply_G(vars_G, data, rng, training)
-        net_D_output = self._apply_D(vars_D, data, net_G_output, training)
+        with jax.named_scope("gan/G"):
+            net_G_output, new_mut = self._apply_G(vars_G, data, rng, training)
+        with jax.named_scope("gan/D"):
+            net_D_output = self._apply_D(vars_D, data, net_G_output, training)
 
         losses = {}
-        output_fake = self._get_outputs(net_D_output, real=False)
-        losses["GAN"] = gan_loss(output_fake, True, self.gan_mode, dis_update=False)
-        losses["FeatureMatching"] = feature_matching_loss(
-            net_D_output["fake_features"], net_D_output["real_features"])
-        if net_G_output.get("mu") is not None:
-            losses["GaussianKL"] = gaussian_kl_loss(
-                net_G_output["mu"], net_G_output["logvar"])
-        else:
-            losses["GaussianKL"] = jnp.zeros(())
+        with jax.named_scope("gan/loss/adversarial"):
+            output_fake = self._get_outputs(net_D_output, real=False)
+            losses["GAN"] = gan_loss(output_fake, True, self.gan_mode,
+                                     dis_update=False)
+            losses["FeatureMatching"] = feature_matching_loss(
+                net_D_output["fake_features"], net_D_output["real_features"])
+            if net_G_output.get("mu") is not None:
+                losses["GaussianKL"] = gaussian_kl_loss(
+                    net_G_output["mu"], net_G_output["logvar"])
+            else:
+                losses["GaussianKL"] = jnp.zeros(())
         if self.perceptual is not None:
-            losses["Perceptual"] = self.perceptual(
-                loss_params["perceptual"], net_G_output["fake_images"],
-                data["images"])
+            with jax.named_scope("gan/loss/perceptual"):
+                losses["Perceptual"] = self.perceptual(
+                    loss_params["perceptual"], net_G_output["fake_images"],
+                    data["images"])
         return losses, new_mut
 
     def dis_forward(self, vars_G, vars_D, loss_params, data, rng, training=True):
         """(ref: trainers/spade.py:165-187)."""
-        net_G_output, _ = self._apply_G(vars_G, data, rng, training)
-        net_G_output = jax.lax.stop_gradient(
-            {"fake_images": net_G_output["fake_images"]})
+        with jax.named_scope("gan/G"):
+            net_G_output, _ = self._apply_G(vars_G, data, rng, training)
+            net_G_output = jax.lax.stop_gradient(
+                {"fake_images": net_G_output["fake_images"]})
         # D runs with mutable spectral/batch_stats so the power-iteration
         # vector u advances every dis step (torch spectral_norm updates
         # weight_u on every training forward, ref: layers/weight_norm.py).
-        net_D_output, new_mut_D = self._apply_D(
-            vars_D, data, net_G_output, training, mutable=True)
+        with jax.named_scope("gan/D"):
+            net_D_output, new_mut_D = self._apply_D(
+                vars_D, data, net_G_output, training, mutable=True)
 
-        fake_loss = gan_loss(self._get_outputs(net_D_output, real=False),
-                             False, self.gan_mode, dis_update=True)
-        true_loss = gan_loss(self._get_outputs(net_D_output, real=True),
-                             True, self.gan_mode, dis_update=True)
-        losses = {"GAN/fake": fake_loss, "GAN/true": true_loss,
-                  "GAN": fake_loss + true_loss}
-        # GAN-balance diagnostics: D real/fake accuracy rides the loss
-        # dict (unweighted keys never enter the total — _total only sums
-        # registered weights) so it reaches the meters and the health
-        # monitor without an extra forward
-        losses["D_real_acc"], losses["D_fake_acc"] = dis_accuracy(
-            net_D_output["real_outputs"], net_D_output["fake_outputs"],
-            self.gan_mode)
+        with jax.named_scope("gan/loss/adversarial"):
+            fake_loss = gan_loss(self._get_outputs(net_D_output, real=False),
+                                 False, self.gan_mode, dis_update=True)
+            true_loss = gan_loss(self._get_outputs(net_D_output, real=True),
+                                 True, self.gan_mode, dis_update=True)
+            losses = {"GAN/fake": fake_loss, "GAN/true": true_loss,
+                      "GAN": fake_loss + true_loss}
+            # GAN-balance diagnostics: D real/fake accuracy rides the loss
+            # dict (unweighted keys never enter the total — _total only
+            # sums registered weights) so it reaches the meters and the
+            # health monitor without an extra forward
+            losses["D_real_acc"], losses["D_fake_acc"] = dis_accuracy(
+                net_D_output["real_outputs"], net_D_output["fake_outputs"],
+                self.gan_mode)
         return losses, new_mut_D
 
     # ---------------------------------------------------------- data hooks
