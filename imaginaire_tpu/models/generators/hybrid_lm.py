@@ -42,8 +42,10 @@ chunked state-space dual form of the Mamba-2 recurrence (``ssd_scan``),
 the chunked WY form of the delta rule (``kda_scan``),
 and dropless routing with static shapes (``route_held``: sort the
 assignments by expert, the held ones first, into a buffer of
-``expert_buffer_rows`` rows, two grouped products by ``lax.ragged_dot``
-(three where the expert is gated), scatter back weighted). ``expert_buffer_rows`` is the buffer's capacity,
+``expert_buffer_rows`` rows, two grouped products by
+``ops/grouped_matmul.py`` (three where the expert is gated; this repo's
+kernel on a TPU at widths that fill a lane tile, ``lax.ragged_dot``
+elsewhere), scatter back weighted). ``expert_buffer_rows`` is the buffer's capacity,
 what the largest routing may hold; a step computes the filled prefix of
 it (``on_filled_prefix``: a row a token where its held assignments fit
 that, the whole buffer otherwise, the same arithmetic either way).
@@ -80,6 +82,7 @@ from jax import lax
 from imaginaire_tpu.analysis import islands
 from imaginaire_tpu.config import cfg_get
 from imaginaire_tpu.ops.attention import attention
+from imaginaire_tpu.ops.grouped_matmul import grouped_matmul
 from imaginaire_tpu.optim.remat import remat_block
 
 
@@ -760,14 +763,21 @@ def held_experts_part(x, kernels, weight, token, valid, group_sizes, rows):
         filled = jnp.where(mask, x[token], 0)
     with jax.named_scope("lm/moe/experts"):
         act = hidden_activation([
-            jnp.where(mask, lax.ragged_dot(filled, w, group_sizes), 0)
+            jnp.where(mask, grouped_matmul(filled, w, group_sizes), 0)
             for w in kernels[:-1]])
-        out = lax.ragged_dot(act, kernels[-1], group_sizes)
+        out = grouped_matmul(act, kernels[-1], group_sizes)
         out = jnp.where(mask, out, 0)
     with jax.named_scope("lm/moe/combine"):
         out = out.astype(jnp.float32) * weight[:, None]
         routed = jnp.zeros(x.shape, jnp.float32).at[token].add(out)
         return routed.astype(x.dtype)
+
+
+def expert_tiers(tokens, capacity):
+    """The ascending rows a step may compute an expert layer on: a row a
+    token where the step's held assignments fit that, the whole buffer
+    of ``capacity`` rows otherwise."""
+    return tuple(sorted({min(tokens, capacity), capacity}))
 
 
 def _tier(tiers, n_held):
@@ -847,7 +857,7 @@ class MoEMixer(nn.Module):
         # the held assignments sort first, so they fill the buffer's
         # prefix: a step that holds no more than a row a token computes
         # on that prefix, any other on the whole buffer
-        tiers = tuple(sorted({min(x.shape[0], capacity), capacity}))
+        tiers = expert_tiers(x.shape[0], capacity)
         with jax.named_scope("lm/moe/dispatch"):
             token, weight, valid, group_sizes, stats = route_held(
                 experts, weights, g.held_first, g.held_count, capacity)

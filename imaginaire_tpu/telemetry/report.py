@@ -1092,6 +1092,20 @@ def render_report(path_or_events):
             f"{kda.get('heads')} heads of {kda.get('head_dim')} held; "
             f"chunks of {kda.get('chunk')} steps in sub-blocks of "
             f"{kda.get('sub_block')}, {kda.get('chunks_at_once')} at once")
+    moe = s["meta"].get("moe_impl")
+    if moe:
+        lines.append(
+            "- moe_impl: "
+            + ", ".join(f"layer {i} {arm}" for i, arm in sorted(
+                (moe.get("layers") or {}).items(), key=lambda kv: int(kv[0])))
+            + f"; {moe.get('held')} held experts of {moe.get('hidden')} x "
+            f"{moe.get('width')} on tiers of "
+            f"{', '.join(map(str, moe.get('tiers')))} rows; kernel tiles "
+            "(rows x width) "
+            + "; ".join(
+                f"{way} " + ", ".join(f"{k} {'x'.join(map(str, v))}"
+                                      for k, v in tiles.items())
+                for way, tiles in (moe.get("tiles") or {}).items()))
     lines.extend(_experts_section(s))
     lines.extend(_health_section(s))
     lines.extend(_xla_section(s))

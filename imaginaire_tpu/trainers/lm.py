@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from imaginaire_tpu import telemetry
 from imaginaire_tpu.config import as_attrdict, cfg_get
 from imaginaire_tpu.models.generators import hybrid_lm
-from imaginaire_tpu.ops import attention
+from imaginaire_tpu.ops import attention, grouped_matmul
 from imaginaire_tpu.optim.remat import resolve_policy
 from imaginaire_tpu.trainers.base import BaseTrainer
 
@@ -71,6 +71,35 @@ def kda_impl(gen_cfg):
                 head_dim=g.kda_head_dim, chunk=g.kda_chunk_size,
                 sub_block=hybrid_lm.kda_sub_block(g.kda_chunk_size),
                 chunks_at_once=hybrid_lm.KDA_CHUNKS_AT_ONCE)
+
+
+def moe_impl(gen_cfg, tokens_shape):
+    """The ``moe_impl`` meta of a (batch, length) step: the expert layers
+    of the pattern with the arm their grouped products take on this
+    backend (``ops/grouped_matmul.py`` decides; one arm where every tier
+    takes the same, else each tier's in the tiers' order), the held
+    experts' hidden size and width, the tiers of rows a step computes a
+    layer on, and the kernel's (rows, width) output tiles, forward (the
+    weights' gradient's too) and in the gradient to the rows, for the
+    product up into the width and the one down out of it; None for a
+    model without such a layer."""
+    g = hybrid_lm.model_settings(gen_cfg)
+    layers = [i for i, kind in enumerate(hybrid_lm.layer_kinds(g))
+              if kind == "E"]
+    if not layers:
+        return None
+    bsz, length = (int(n) for n in tokens_shape)
+    hidden, width = g.hidden_size, g.moe_intermediate_size
+    tiers = hybrid_lm.expert_tiers(bsz * length, g.expert_buffer_rows)
+    arms = list(dict.fromkeys(
+        grouped_matmul.arm_of(rows, *shape) for rows in tiers
+        for shape in ((hidden, width), (width, hidden))))
+    return dict(layers={str(i): "/".join(arms) for i in layers},
+                hidden=hidden, width=width, held=g.held_count,
+                tiers=list(tiers),
+                tiles={"up": grouped_matmul.tiles_of(hidden, width)._asdict(),
+                       "down": grouped_matmul.tiles_of(width,
+                                                       hidden)._asdict()})
 
 
 class Trainer(BaseTrainer):
@@ -142,15 +171,18 @@ class Trainer(BaseTrainer):
         return losses
 
     def _note_attn_impl(self, tokens_shape):
-        """One ``attn_impl`` meta as the step is first built, and one
-        ``kda_impl`` where the model has delta-rule layers."""
+        """One ``attn_impl`` meta as the step is first built, one
+        ``kda_impl`` where the model has delta-rule layers and one
+        ``moe_impl`` where it has expert layers."""
         tm = telemetry.get()
         if not tm.enabled:
             return
         tm.meta("attn_impl", **attn_impl(self.cfg.gen, tokens_shape))
-        kda = kda_impl(self.cfg.gen)
-        if kda:
-            tm.meta("kda_impl", **kda)
+        for name, meta in (("kda_impl", kda_impl(self.cfg.gen)),
+                           ("moe_impl", moe_impl(self.cfg.gen,
+                                                 tokens_shape))):
+            if meta:
+                tm.meta(name, **meta)
 
     def _flush_counters(self, tm, step):
         """At telemetry's flush, behind its fence: tokens a second over

@@ -468,6 +468,86 @@ def test_the_filled_prefix_is_the_whole_buffer(held, compact, preset):
     assert abs(reached - sent) <= 1e-4 * sent and sent > 0
 
 
+def _kernel_arm(lhs, rhs, group_sizes):
+    """``grouped_matmul``'s kernel arm through Pallas's interpreter, at
+    tiles the unit-test sizes fill: 64 rows, a lane tile or the whole
+    axis."""
+    from imaginaire_tpu.ops import grouped_matmul as gm
+
+    contracted, width = (min(n, 128) for n in rhs.shape[1:])
+    tiles = gm.Tiles(fwd=(64, width), dlhs=(64, contracted))
+    return gm.kernel_grouped_matmul(lhs, rhs, group_sizes, tiles, True)
+
+
+@pytest.mark.parametrize("held", [100, 200], ids=["prefix", "whole_buffer"])
+def test_the_kernel_arm_is_the_plain_arm_in_the_expert_layer(held):
+    """`MoEMixer`'s value and gradients under bfloat16 with the grouped
+    products by the kernel (ISSUE 38; the unit-test sizes widened to a
+    width of a lane tile and a half, 192) against the `lax.ragged_dot`
+    arm, in both tiers. The interpreter leaves the rows no visit wrote as
+    NaN: the masks on the way in, between the products and on the way out
+    keep them out of the result and of every gradient."""
+    cfg = tiny_cfg(moe_intermediate_size=192)
+    g = hybrid_lm.model_settings(cfg.gen)
+    module = hybrid_lm.MoEMixer(g)
+    _, _, train, buffers = seeded(cfg, 5)
+    cast = functools.partial(jax.tree_util.tree_map,
+                             lambda x: x.astype(jnp.bfloat16))
+    router, u = _steered(held, "nemotron_h")
+    params = dict(layer_params(train, 1), router=router)
+    buffers = layer_params(buffers, 1)
+
+    def run(params, u):
+        out, stats = module.apply({"params": params, "buffers": buffers},
+                                  cast(u))
+        weights = jax.random.normal(jax.random.PRNGKey(9), out.shape)
+        return jnp.sum(out.astype(jnp.float32) * weights), (out, stats)
+
+    def both(arm):
+        with mock.patch.object(hybrid_lm, "grouped_matmul", arm):
+            return jax.jit(jax.value_and_grad(run, argnums=(0, 1),
+                                              has_aux=True))(params, u)
+
+    (_, (out, stats)), grads = both(_kernel_arm)
+    (_, (out_p, stats_p)), grads_p = both(hybrid_lm.grouped_matmul)
+    assert float(stats["held_assignments"]) == held
+    assert float(stats["compact"]) == float(held <= 128)
+    assert out.dtype == jnp.bfloat16
+    for ours, theirs in [(out, out_p), (grads[1], grads_p[1]), *(
+            (grads[0][name], grads_p[0][name]) for name in params)]:
+        ours, theirs = (np.asarray(x, np.float32) for x in (ours, theirs))
+        assert np.isfinite(ours).all()
+        # two float32-accumulated bfloat16 evaluations
+        assert np.linalg.norm(ours - theirs) <= 6e-3 * np.linalg.norm(theirs)
+    assert float(jnp.abs(grads[0]["experts_up"]).max()) > 0
+
+
+def test_the_plain_arm_leaves_the_step_program_as_it_was():
+    """On the CPU the model's loss and gradients lower to the text they
+    lower to with `lax.ragged_dot` written where `grouped_matmul` stands
+    (ISSUE 38): the CPU arm adds no operation, so the step programs of
+    the tests, and the parent's compile-cache entries, are what they
+    were. At full size the three token steps' StableHLO has the parent's
+    sha256 (PERF.md, PR 38)."""
+    cfg = tiny_cfg(compute_dtype="bfloat16")
+    model = hybrid_lm.Generator(cfg.gen)
+    data = {"tokens": jnp.zeros((2, 64), jnp.int32)}
+    variables = jax.eval_shape(model.init, jax.random.PRNGKey(0), data)
+
+    def loss(params, rest, data):
+        return model.apply({**rest, "params": params}, data)["loss"]
+
+    def text():
+        rest = {k: v for k, v in variables.items() if k != "params"}
+        return jax.jit(jax.value_and_grad(loss)).lower(
+            variables["params"], rest, data).as_text()
+
+    ours = text()
+    with mock.patch.object(hybrid_lm, "grouped_matmul", jax.lax.ragged_dot):
+        plain = text()
+    assert ours == plain
+
+
 def test_bad_held_share_and_pattern_fail_loudly():
     with pytest.raises(ValueError, match="experts_held"):
         hybrid_lm.model_settings(

@@ -89,6 +89,15 @@ def test_train_py_trains_checkpoints_and_resumes(entry_point_sandbox,
     assert set(attn[0]["tiles"]) == {"fwd", "dkv", "dq"}
     # the plain arm has no kernel whose residuals a block could keep
     assert attn[0]["kept_bytes"] == {"3": 0}
+    # the two expert layers' grouped products, on the CPU: the plain arm
+    # (ISSUE 38), on the tiers a step of 128 tokens picks from
+    moe = [e for e in events
+           if e["kind"] == "meta" and e["name"] == "moe_impl"]
+    assert len(moe) == 1
+    assert moe[0]["layers"] == {"1": "ragged_dot", "4": "ragged_dot"}
+    assert (moe[0]["hidden"], moe[0]["width"], moe[0]["held"]) == (64, 48, 4)
+    assert moe[0]["tiers"] == [128, 256]
+    assert set(moe[0]["tiles"]) == {"up", "down"}
 
     from imaginaire_tpu.telemetry.report import render_report
 
@@ -98,6 +107,9 @@ def test_train_py_trains_checkpoints_and_resumes(entry_point_sandbox,
     assert ("attn_impl at length 64, head size 16: layer 3 blocks; fused "
             "tiles") in report
     assert "; the blocks keep 0 bytes of the kernel's forward" in report
+    assert ("- moe_impl: layer 1 ragged_dot, layer 4 ragged_dot; 4 held "
+            "experts of 64 x 48 on tiers of 128, 256 rows; kernel tiles "
+            "(rows x width) up fwd 128x128, dlhs 128x128; down fwd") in report
 
     # the resume leg: restores iteration 2 and trains on to 3
     capsys.readouterr()

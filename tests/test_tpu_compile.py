@@ -134,6 +134,48 @@ def test_fused_attention_compiles_at_the_token_cells_shape(
     assert compiled.memory_analysis().temp_size_in_bytes < 6e8
 
 
+@pytest.mark.parametrize("hidden,width", [
+    (2688, 1856),
+    pytest.param(2048, 1536, marks=pytest.mark.slow),
+    pytest.param(4096, 1280, marks=pytest.mark.slow)])
+def test_grouped_products_compile_at_the_token_cells_widths(one_chip, hidden,
+                                                            width):
+    """The three kernels of ``ops/grouped_matmul.py`` for a held expert's
+    up and down products at nemotron3_nano_30b_a3b's widths (1856 is 14
+    lane tiles and a half: a ragged width tile going up, a whole ragged
+    contraction coming down), glm4_7_flash's and solar_open2_250b's, on
+    the 8,192-row tier with the tiles the program picks. Each kernel's
+    instruction stands on one line of the optimized HLO with its
+    ``op_name`` under the caller's scope, which is how a trace's events
+    are counted under ``lm/moe/experts`` (ISSUE 38)."""
+    from imaginaire_tpu.ops import grouped_matmul
+
+    def loss(x, w_up, w_down, sizes):
+        with jax.named_scope("lm/moe/experts"):
+            act = grouped_matmul.kernel_grouped_matmul(x, w_up, sizes)
+            out = grouped_matmul.kernel_grouped_matmul(
+                jnp.square(act), w_down, sizes)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2)),
+        _sds((8192, hidden), jnp.bfloat16, one_chip),
+        _sds((8, hidden, width), jnp.bfloat16, one_chip),
+        _sds((8, width, hidden), jnp.bfloat16, one_chip),
+        _sds((8,), jnp.int32, one_chip))
+    calls = [line for line in compiled.as_text().splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert sorted(line.split("=")[0].strip().lstrip("%").split(".")[0]
+                  for line in calls) == [
+        "grouped_rows_dlhs", "grouped_rows_dlhs", "grouped_rows_fwd",
+        "grouped_rows_fwd", "grouped_weights_drhs", "grouped_weights_drhs"]
+    assert all('op_name="' in line and "lm/moe/experts" in line
+               for line in calls)
+    # no padded or transposed copy of a weight stack stands beside it
+    stack = 8 * hidden * width * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * stack
+
+
 def test_kda_layer_compiles_at_the_token_cells_shape(one_chip):
     """A Kimi Delta Attention mixer of solar_open2_250b (8 heads of 128,
     chunks of 64, 8,192 positions, bfloat16 compute), value and gradients
