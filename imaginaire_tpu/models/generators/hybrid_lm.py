@@ -1,9 +1,11 @@
 """Hybrid token model: Mamba-2 mixers, causal attention, delta-rule linear
-attention, dense and mixture-of-experts feed-forwards, laid out by a
-pattern string (Nemotron-H, ``hybrid_override_pattern``: ``M`` a Mamba-2
-mixer, ``*`` attention, ``-`` a dense feed-forward, ``E`` a mixture of
-experts; ``K`` is this file's own letter for a Kimi Delta Attention
-mixer). A transformer block is two letters: ``*-``, ``*E`` or ``KE``.
+attention, gated short convolutions, dense and mixture-of-experts
+feed-forwards, laid out by a pattern string (Nemotron-H,
+``hybrid_override_pattern``: ``M`` a Mamba-2 mixer, ``*`` attention, ``-``
+a dense feed-forward, ``E`` a mixture of experts; ``K`` and ``C`` are this
+file's own letters for a Kimi Delta Attention mixer and for LFM2's gated
+short convolution). A transformer block is two letters: ``*-``, ``*E``,
+``KE``, ``C-`` or ``CE``.
 
 Every layer is one mixer behind a pre-norm residual,
 ``h = h + Mixer(RMSNorm(h))``; logits are ``RMSNorm(h) W_head``. The
@@ -16,10 +18,17 @@ is absent (``_NEEDS`` says what each letter reads). ``*`` is latent
 attention where there is a ``kv_lora_rank`` (DeepSeek-V2's: queries and
 keys-values through normed low-rank latents, one rotary key shared by all
 heads beside each head's un-rotated part) and grouped-query attention
-without a position embedding otherwise, with a sigmoid gate on its output
-where ``use_gqa_gate`` says so. ``K`` is the gated delta rule with a
+otherwise: without a position embedding where the config gives no
+``rope_theta``, with the rotary turn over the whole head where it gives
+one; with queries and keys RMS-normed over the head's channels before the
+turn where ``use_qk_norm`` says so, and with a sigmoid gate on its output
+where ``use_gqa_gate`` does. ``K`` is the gated delta rule with a
 decay a key channel (``KDAMixer``; its sizes are the published
-``linear_attn_config`` group's). ``hidden_act`` ``silu`` makes
+``linear_attn_config`` group's). ``C`` is a depthwise causal convolution
+of ``conv_L_cache`` taps between two elementwise gates
+(``ShortConvMixer``). An ``E`` layer has a shared expert beside the routed
+ones where the config gives ``moe_shared_expert_intermediate_size``, and
+none where it does not. ``hidden_act`` ``silu`` makes
 every feed-forward gated, ``W_down (silu(W_gate x) * W_up x)``; ``relu2``
 (the default: Nemotron-H's ``mlp_hidden_act``) is ``W_down relu(W_up
 x)^2``. ``nextn_pattern`` adds one multi-token-prediction module
@@ -47,12 +56,15 @@ assignments by expert, the held ones first, into a buffer of
 kernel on a TPU at widths that fill a lane tile, ``lax.ragged_dot``
 elsewhere), scatter back weighted). ``expert_buffer_rows`` is the buffer's capacity,
 what the largest routing may hold; a step computes the filled prefix of
-it (``on_filled_prefix``: a row a token where its held assignments fit
-that, the whole buffer otherwise, the same arithmetic either way).
+it (``on_filled_prefix``: a short tier where its held assignments fit
+that, a row a token or, where the held experts' even share is more than
+half of that, twice the even share (``expert_tiers``); the whole buffer
+otherwise, the same arithmetic either way).
 The causal scores live in ``ops/attention.py``, which picks
 its own arm from what it observes: one fused Pallas kernel that keeps the
 scores in VMEM where the backend is a TPU, the head size a multiple of
-128 and the length a multiple of the kernel's tiles; query blocks of
+128 (or 64: zero-padded to 128 inside the arm) and the length a
+multiple of the kernel's tiles; query blocks of
 ``attn_query_block`` rows in plain ``jax.numpy`` everywhere else (the
 CPU, ragged lengths). The fp32 islands (router scores, the scan's step
 sizes, decays and carried state, the delta rule's decays, solve and
@@ -573,10 +585,46 @@ class KDAMixer(nn.Module):
             return y.reshape(*lead, inner) @ w_o.astype(dtype)
 
 
+# ------------------------------------------------- gated short convolution
+
+
+class ShortConvMixer(nn.Module):
+    """LFM2's gated short convolution: ``[B, C, x] = u W_in`` (three
+    equal parts, in that order), ``z_t = sum_k w_k (B x)_{t-(K-1)+k}``
+    (depthwise, causal, ``conv_L_cache`` taps a channel, no bias, no
+    activation), ``y = (C z) W_out``; the products between are
+    elementwise."""
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, u):
+        g = self.cfg
+        hidden = g.hidden_size
+        dtype = u.dtype
+        w_in = self.param("in_proj", _kernel_init, (hidden, 3 * hidden))
+        w_conv = self.param("conv_kernel", _kernel_init,
+                            (g.conv_L_cache, hidden))
+        w_out = self.param("out_proj", _kernel_init, (hidden, hidden))
+        with jax.named_scope("lm/attn/sconv_proj"):
+            b, c, x = jnp.split(u @ w_in.astype(dtype), 3, -1)
+        with jax.named_scope("lm/attn/sconv_conv"):
+            y = c * causal_conv1d(b * x, w_conv.astype(dtype),
+                                  jnp.zeros((), dtype))
+        with jax.named_scope("lm/attn/out"):
+            return y @ w_out.astype(dtype)
+
+
 # --------------------------------------------------------------- attention
 
 
 class AttentionMixer(nn.Module):
+    """Causal grouped-query attention: ``q``, ``k``, ``v`` by three
+    products; where ``use_qk_norm``, ``q`` and ``k`` RMS-normed over the
+    head's channels (one learned scale for ``q`` and one for ``k``, which
+    the heads share); where there is a ``rope_theta``, both turned by
+    ``rotary`` over the whole head; the causal scores at ``1/sqrt(head
+    size)``; where ``use_gqa_gate``, a sigmoid gate on the result; then
+    ``W_o``."""
     cfg: Any
 
     @nn.compact
@@ -592,6 +640,9 @@ class AttentionMixer(nn.Module):
         w_gate = (self.param("gate_proj", _kernel_init,
                              (g.hidden_size, q_dim))
                   if g.use_gqa_gate else None)
+        head_scales = ([self.param(name, nn.initializers.ones, (g.head_dim,))
+                        for name in ("q_norm_scale", "k_norm_scale")]
+                       if g.use_qk_norm else None)
         lead = u.shape[:2]
         with jax.named_scope("lm/attn/qkv"):
             q = (u @ w_q.astype(dtype)).reshape(
@@ -600,6 +651,13 @@ class AttentionMixer(nn.Module):
                 *lead, g.num_key_value_heads, g.head_dim)
             v = (u @ w_v.astype(dtype)).reshape(
                 *lead, g.num_key_value_heads, g.head_dim)
+        if head_scales is not None:
+            with jax.named_scope("lm/attn/qk_norm"):
+                q, k = (rms_norm(x, scale, g.norm_eps)
+                        for x, scale in zip((q, k), head_scales))
+        if g.rope_theta is not None:
+            with jax.named_scope("lm/attn/rope"):
+                q, k = rotary(q, g.rope_theta), rotary(k, g.rope_theta)
         with jax.named_scope("lm/attn/scores"):
             y = attention(q, k, v, g.attn_query_block)
         if w_gate is not None:
@@ -773,11 +831,22 @@ def held_experts_part(x, kernels, weight, token, valid, group_sizes, rows):
         return routed.astype(x.dtype)
 
 
-def expert_tiers(tokens, capacity):
-    """The ascending rows a step may compute an expert layer on: a row a
-    token where the step's held assignments fit that, the whole buffer
-    of ``capacity`` rows otherwise."""
-    return tuple(sorted({min(tokens, capacity), capacity}))
+def expert_tiers(tokens, g):
+    """The ascending rows a step may compute an expert layer on: a short
+    tier where the step's held assignments fit it, the whole buffer of
+    ``expert_buffer_rows`` otherwise. The short tier is a row a token;
+    where the held experts' even share of the ``tokens`` x top-k
+    assignments is more than half of that, the fewest whole rows a token
+    that hold twice the even share: a tier at the even share itself sends
+    every second step to the whole buffer. Where twice the even share is
+    the whole buffer (a share that holds half the experts, as the four
+    unit-test configurations do) the short tier stays a row a token."""
+    capacity = g.expert_buffer_rows
+    even = tokens * g.num_experts_per_tok * g.held_count // g.n_routed_experts
+    short = max(1, -(-2 * even // tokens)) * tokens
+    if short >= capacity:
+        short = tokens
+    return tuple(sorted({min(short, capacity), capacity}))
 
 
 def _tier(tiers, n_held):
@@ -844,9 +913,10 @@ class MoEMixer(nn.Module):
         kernels = _feed_forward_params(
             self, g, "experts_", (g.held_count, hidden, width),
             (g.held_count, width, hidden))
-        shared = _feed_forward_params(
+        # a model without a shared expert gives no width for one
+        shared = (_feed_forward_params(
             self, g, "shared_", (hidden, shared_width),
-            (shared_width, hidden))
+            (shared_width, hidden)) if shared_width is not None else None)
         lead = u.shape[:2]
         x = u.reshape(-1, hidden)
         with jax.named_scope("lm/moe/router"):
@@ -855,9 +925,9 @@ class MoEMixer(nn.Module):
                 score_bias, g.num_experts_per_tok, g.routed_scaling_factor)
         capacity = g.expert_buffer_rows
         # the held assignments sort first, so they fill the buffer's
-        # prefix: a step that holds no more than a row a token computes
-        # on that prefix, any other on the whole buffer
-        tiers = expert_tiers(x.shape[0], capacity)
+        # prefix: a step that holds no more than the short tier's rows
+        # computes on that prefix, any other on the whole buffer
+        tiers = expert_tiers(x.shape[0], g)
         with jax.named_scope("lm/moe/dispatch"):
             token, weight, valid, group_sizes, stats = route_held(
                 experts, weights, g.held_first, g.held_count, capacity)
@@ -869,6 +939,8 @@ class MoEMixer(nn.Module):
             kernels = tuple(w.astype(dtype) for w in kernels)
         routed = on_filled_prefix(tiers, n_held, x, kernels, weight,
                                   token, valid, group_sizes)
+        if shared is None:
+            return routed.reshape(*lead, hidden), stats
         with jax.named_scope("lm/moe/shared"):
             shared = feed_forward(x, shared)
         return (routed + shared).reshape(*lead, hidden), stats
@@ -877,13 +949,16 @@ class MoEMixer(nn.Module):
 # ------------------------------------------------------------------- model
 
 _MIXERS = {"M": Mamba2Mixer, "*": AttentionMixer, "K": KDAMixer,
-           "-": DenseMixer, "E": MoEMixer}
+           "C": ShortConvMixer, "-": DenseMixer, "E": MoEMixer}
 # ``Settings`` field -> the key of the published ``linear_attn_config``
 # group it is read from
 _LINEAR_ATTN = {"kda_num_heads": "num_heads", "kda_head_dim": "head_dim",
                 "kda_conv_kernel": "short_conv_kernel_size"}
 # the sizes each letter of a pattern reads; ``*`` reads those of the form
-# of attention the config has the sizes of
+# of attention the config has the sizes of, and grouped-query attention
+# turns its heads where there is a ``rope_theta`` (none is demanded); an
+# ``E`` layer has a shared expert where the config gives
+# ``moe_shared_expert_intermediate_size``
 _LATENT = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
            "qk_rope_head_dim", "v_head_dim", "rope_theta")
 _NEEDS = {
@@ -893,10 +968,10 @@ _NEEDS = {
     "*": ("num_attention_heads", "attn_query_block"),
     "K": ("kda_num_heads", "kda_head_dim", "kda_conv_kernel",
           "kda_chunk_size"),
+    "C": ("conv_L_cache",),
     "-": ("intermediate_size",),
     "E": ("n_routed_experts", "num_experts_per_tok", "routed_scaling_factor",
-          "moe_intermediate_size", "moe_shared_expert_intermediate_size",
-          "expert_buffer_rows"),
+          "moe_intermediate_size", "expert_buffer_rows"),
 }
 
 
@@ -972,9 +1047,15 @@ class Settings:
     """The model's sizes as the modules read them (hashable: flax turns
     a dict field into a FrozenDict). The names are the published
     config's, but for the held share, the module's layers and loss weight,
-    the linear-attention group's three sizes (``_LINEAR_ATTN``) and the
-    four bounds the program sets itself. A size the model has no layer
-    for stays None (``_NEEDS``)."""
+    the linear-attention group's three sizes (``_LINEAR_ATTN``), the
+    program's own ``use_qk_norm`` (beside the published ``use_gqa_gate``:
+    whether a grouped-query layer norms each head's queries and keys) and
+    the four bounds the program sets itself. A size the model has no layer
+    for stays None (``_NEEDS``): no ``rope_theta`` is grouped-query
+    attention without a position embedding, no
+    ``moe_shared_expert_intermediate_size`` an expert layer without a
+    shared expert. ``conv_L_cache`` is the taps of the sixth letter's
+    (``C``) convolution; ``conv_bias`` has to be false."""
     pattern: str
     hidden_size: int
     vocab_slice: int
@@ -997,6 +1078,9 @@ class Settings:
     num_key_value_heads: int | None = None
     head_dim: int | None = None
     use_gqa_gate: bool = False
+    use_qk_norm: bool = False
+    conv_L_cache: int | None = None
+    conv_bias: bool = False
     kda_num_heads: int | None = None
     kda_head_dim: int | None = None
     kda_conv_kernel: int | None = None
@@ -1081,6 +1165,10 @@ def model_settings(gen_cfg):
             f"gen.v_head_dim {g.v_head_dim}")
     if g.nextn_pattern and g.nextn_loss_weight is None:
         raise ValueError("gen.nextn_pattern needs gen.nextn_loss_weight")
+    if g.conv_bias and "C" in kinds:
+        raise ValueError(
+            "gen.conv_bias true: the gated short convolution ('C') has no "
+            "bias here; no model this program has run has one")
     return g
 
 

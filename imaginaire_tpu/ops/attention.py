@@ -10,12 +10,16 @@ it can observe (``arm_of``), with no option:
               the key tiles in float32, scores and probabilities never
               reach HBM, and the tiles above the diagonal are neither
               computed nor fetched. Where the backend is a TPU, the head
-              size a multiple of 128 and the length a multiple of the
-              kernel's largest tile.
+              size a multiple of 128 or half of 128 (64: zero columns
+              fill the lane tile, the kernel then runs at 128 under the
+              scale of 64, which is exact, and the matrix unit's 128
+              lanes were the head's to fill either way) and the length
+              a multiple of the kernel's largest tile.
   ``blocks``  ``causal_attention``: query blocks in plain ``jax.numpy``,
               each against the keys up to its own end, the (Hq, block,
               keys) scores of one block in HBM at a time. Everywhere
-              else: the CPU, where the tests run, and ragged lengths.
+              else: the CPU, where the tests run, ragged lengths and
+              every other head size (none has been run padded).
 
 The fused arm's forward pass names its output and the rows'
 log-sum-exp ``KERNEL_RESIDUAL``: a block recomputed under
@@ -62,11 +66,18 @@ TILES = Tiles(fwd=(1024, 1024), dkv=(1024, 1024), dq=(1024, 1024))
 KERNEL_RESIDUAL = "kernel_residual"
 
 
+def kernel_head_dim(head_dim):
+    """The head size the kernel runs a head of ``head_dim`` at: the next
+    multiple of its lanes."""
+    return -(-head_dim // kernel.LANES) * kernel.LANES
+
+
 def arm_of(head_dim, length):
     """``"fused"`` or ``"blocks"``: which arm ``attention`` takes for a
     head size and a length on this process's backend."""
     on_tpu = jax.default_backend() == "tpu"
-    fits = head_dim % kernel.LANES == 0 and length % TILES.largest == 0
+    fits = (kernel_head_dim(head_dim) in (head_dim, 2 * head_dim)
+            and length % TILES.largest == 0)
     return "fused" if on_tpu and fits else "blocks"
 
 
@@ -123,12 +134,30 @@ def residual_bytes(bsz, length, q_heads, head_dim, dtype):
 
 
 def _flat(x):
+    """(B, L, H, d) to the kernel's (B, L, H * d'), each head zero-padded
+    to the size the kernel runs it at: a zero column of ``q`` or ``k``
+    adds nothing to a score, one of ``v`` or of the output's gradient is
+    a zero column of the result, which ``_heads`` drops."""
+    pad = kernel_head_dim(x.shape[-1]) - x.shape[-1]
+    if pad:
+        x = jnp.pad(x, ((0, 0),) * 3 + ((0, pad),))
     return x.reshape(*x.shape[:2], -1)
+
+
+def _heads(flat, like):
+    """The kernel's (B, L, H * d') back to ``like``'s (B, L, H, d)."""
+    return flat.reshape(*like.shape[:3], -1)[..., :like.shape[-1]]
+
+
+def _scale(q):
+    return 1.0 / math.sqrt(q.shape[-1])
 
 
 def _fused_fwd(q, k, v, tiles, interpret):
     out, lse = kernel.forward(_flat(q), _flat(k), _flat(v), q.shape[2],
-                              k.shape[2], *tiles.fwd, interpret=interpret)
+                              k.shape[2], *tiles.fwd, interpret=interpret,
+                              scale=_scale(q))
+    out = _heads(out, q).reshape(*q.shape[:2], -1)
     # named before ``out`` is returned too: the product with ``W_o`` after
     # it reads ``out`` for its own gradient, from the kept array
     out = checkpoint_name(out, KERNEL_RESIDUAL)
@@ -142,12 +171,13 @@ def _fused_bwd(tiles, interpret, saved, do):
     # the rows' sum(do * out), (B, Hq, L) as the log-sum-exp
     di = (do.astype(jnp.float32) * out.astype(jnp.float32)).reshape(
         q.shape).sum(-1).transpose(0, 2, 1)
-    operands = _flat(q), _flat(k), _flat(v), do, lse, di
+    operands = (_flat(q), _flat(k), _flat(v), _flat(do.reshape(q.shape)),
+                lse, di)
     dk, dv = kernel.backward_dkv(*operands, *heads, *tiles.dkv,
-                                 interpret=interpret)
+                                 interpret=interpret, scale=_scale(q))
     dq = kernel.backward_dq(*operands, *heads, *tiles.dq,
-                            interpret=interpret)
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+                            interpret=interpret, scale=_scale(q))
+    return _heads(dq, q), _heads(dk, k), _heads(dv, v)
 
 
 fused_causal_attention.defvjp(_fused_fwd, _fused_bwd)

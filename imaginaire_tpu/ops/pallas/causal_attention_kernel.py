@@ -23,7 +23,9 @@ Layouts are the model's own: ``q`` (B, L, Hq*d), ``k``, ``v`` (B, L,
 Hkv*d), head ``h`` the ``d`` columns from ``h*d``; query head ``h`` reads
 key-value head ``h // (Hq/Hkv)``. No transpose on the way in or out.
 ``d`` and the tiles are multiples of 128, ``L`` a multiple of the tiles;
-``ops/attention.py`` holds the rule and the tile sizes.
+``ops/attention.py`` holds the rule and the tile sizes. ``scale`` is
+``1/sqrt(d)`` unless the caller gives it: a head zero-padded to a lane
+tile keeps the scale of the head it was.
 """
 
 from __future__ import annotations
@@ -75,10 +77,12 @@ def _scores(a_ref, b_ref, scale, row0, col0, masked, keys_in_rows):
     return s
 
 
-def _sizes(q, q_heads, kv_heads):
-    """(head size, query heads a key-value head, scale) of flat ``q``."""
+def _sizes(q, q_heads, kv_heads, scale=None):
+    """(head size, query heads a key-value head, scale) of flat ``q``;
+    the scale ``1/sqrt(head size)`` where none is given."""
     head_dim = q.shape[-1] // q_heads
-    return head_dim, q_heads // kv_heads, 1.0 / float(np.sqrt(head_dim))
+    return (head_dim, q_heads // kv_heads,
+            float(scale or 1.0 / np.sqrt(head_dim)))
 
 
 def _query_sweep(q, q_heads, kv_heads, bq, bkv):
@@ -145,10 +149,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         lse_ref[...] = m_ref[...] + jnp.log(l)
 
 
-def forward(q, k, v, q_heads, kv_heads, bq, bkv, interpret=False):
+def forward(q, k, v, q_heads, kv_heads, bq, bkv, interpret=False,
+            scale=None):
     """(out (B, L, Hq*d) in ``q``'s dtype, lse (B, Hq, L) float32)."""
     bsz, length, _ = q.shape
-    head_dim, _, scale = _sizes(q, q_heads, kv_heads)
+    head_dim, _, scale = _sizes(q, q_heads, kv_heads, scale)
     grid, q_spec, kv_spec, _ = _query_sweep(q, q_heads, kv_heads, bq, bkv)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, bq=bq, bkv=bkv),
@@ -202,10 +207,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, acc_ref,
 
 
 def backward_dq(q, k, v, do, lse, di, q_heads, kv_heads, bq, bkv,
-                interpret=False):
+                interpret=False, scale=None):
     """The queries' gradient, (B, L, Hq*d). ``lse``, ``di`` (B, Hq, L)
     float32: the rows' log-sum-exp and ``sum(do * out)``."""
-    head_dim, _, scale = _sizes(q, q_heads, kv_heads)
+    head_dim, _, scale = _sizes(q, q_heads, kv_heads, scale)
     grid, q_spec, kv_spec, row_spec = _query_sweep(q, q_heads, kv_heads,
                                                    bq, bkv)
     return pl.pallas_call(
@@ -257,10 +262,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
 
 
 def backward_dkv(q, k, v, do, lse, di, q_heads, kv_heads, bq, bkv,
-                 interpret=False):
+                 interpret=False, scale=None):
     """The keys' and the values' gradients, (B, L, Hkv*d) each."""
     bsz, length, _ = q.shape
-    head_dim, group, scale = _sizes(q, q_heads, kv_heads)
+    head_dim, group, scale = _sizes(q, q_heads, kv_heads, scale)
 
     def first_q(i, j):
         # a tile above the diagonal names the first one that is not

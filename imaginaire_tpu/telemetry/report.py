@@ -1073,13 +1073,19 @@ def render_report(path_or_events):
     attn = s["meta"].get("attn_impl")
     if attn:
         tiles = attn.get("tiles") or {}
+        layers = attn.get("layers") or {}
+        padded = ("fused" in layers.values() and attn.get("kernel_head_dim")
+                  not in (None, attn.get("head_dim")))
         lines.append(
             f"- attn_impl at length {attn.get('length')}"
             + (f", head size {attn['head_dim']}" if "head_dim" in attn
                else "") + ": "
             + ", ".join(f"layer {i} {arm}" for i, arm in sorted(
-                (attn.get("layers") or {}).items(), key=lambda kv: int(kv[0])))
-            + "; fused tiles (queries x keys) "
+                layers.items(), key=lambda kv: int(kv[0])))
+            + "; fused "
+            + (f"at head size {attn['kernel_head_dim']} (zero-padded), "
+               if padded else "")
+            + "tiles (queries x keys) "
             + ", ".join(f"{k} {'x'.join(map(str, v))}"
                         for k, v in tiles.items())
             + (f"; the blocks keep {sum(attn['kept_bytes'].values())} bytes "

@@ -33,7 +33,9 @@ LOSS_COUNTERS = {"lm": "lm/main", "mtp": "lm/mtp"}
 
 def attn_impl(gen_cfg, tokens_shape):
     """The ``attn_impl`` meta of a (batch, length) step: the head size the
-    scores run at, the arm each attention layer's scores take at it and
+    scores run at, the size the fused arm's kernel runs such a head at (a
+    head under a lane tile is zero-padded to one inside that arm), the
+    arm each attention layer's scores take at it and
     this length on this backend (``ops/attention.py`` decides; nothing
     here does), the fused arm's tiles, and the bytes each layer's block
     keeps of the kernel's forward pass for its backward passes (the
@@ -49,6 +51,7 @@ def attn_impl(gen_cfg, tokens_shape):
     a_layer = attention.residual_bytes(
         bsz, length, g.num_attention_heads, head_dim, g.compute_dtype)
     return dict(length=length, head_dim=head_dim,
+                kernel_head_dim=attention.kernel_head_dim(head_dim),
                 tiles=attention.TILES._asdict(), layers=arms,
                 kept_bytes={i: a_layer if keeps and arm == "fused" else 0
                             for i, arm in arms.items()})
@@ -90,7 +93,7 @@ def moe_impl(gen_cfg, tokens_shape):
         return None
     bsz, length = (int(n) for n in tokens_shape)
     hidden, width = g.hidden_size, g.moe_intermediate_size
-    tiers = hybrid_lm.expert_tiers(bsz * length, g.expert_buffer_rows)
+    tiers = hybrid_lm.expert_tiers(bsz * length, g)
     arms = list(dict.fromkeys(
         grouped_matmul.arm_of(rows, *shape) for rows in tiers
         for shape in ((hidden, width), (width, hidden))))

@@ -5,10 +5,12 @@ pallas.ops.tpu.megablox``) and this repo's kernel (``ops/pallas/
 grouped_matmul_kernel.py``) take for the forward product, the gradient to
 the rows and the gradient to the weights, at the token cells' widths.
 
-  python scripts/sweep_grouped_products.py [--out chiprun_out/sweep_grouped_products.json]
+  python scripts/sweep_grouped_products.py [--cells lfm2,...] [--out chiprun_out/sweep_grouped_products.json]
 
 8,192 rows, 8 groups of uneven sizes, 3,200 and then 8,192 rows filled
-(the group sizes are data: one compile serves both), bfloat16 operands.
+(the group sizes are data: one compile serves both), bfloat16 operands;
+16,384 rows with 8,192 and then 16,384 filled at the shape of the cell
+that steps on two sequences (``ROWS_OF``).
 Four families of variants, each in both directions of a layer (hidden x
 width, the up product; width x hidden, the down product):
 
@@ -64,7 +66,9 @@ FILLS = (3200, 8192)
 SHARES = (0.21, 0.06, 0.17, 0.11, 0.02, 0.19, 0.09, 0.15)
 # hidden x width of a held expert in each token cell
 CELLS = {"nemotron": (2688, 1856), "glm": (2048, 1536),
-         "solar": (4096, 1280)}
+         "solar": (4096, 1280), "lfm2": (2048, 1792)}
+# the rows of a cell's row-a-token tier where it is not ``ROWS``
+ROWS_OF = {"lfm2": 16384}
 PADDED = ((2688, 1920), (2688, 2048), (3072, 2048))
 # the Pallas calls' events: this repo's by name, the shipped by their jit's
 PALLAS_NAMES = ("grouped_", "gmm")
@@ -96,10 +100,24 @@ def megablox_grid(contracted, width):
     return [t for t in dict.fromkeys(grid) if t[1] <= contracted]
 
 
-def variants():
+def rows_of(k, n):
+    """The rows a product of this shape is timed at."""
+    return next((ROWS_OF[cell] for cell, shape in CELLS.items()
+                 if cell in ROWS_OF and shape in ((k, n), (n, k))), ROWS)
+
+
+def fills_of(k, n):
+    """The two counts of filled rows a product of this shape is timed
+    at."""
+    rows = rows_of(k, n)
+    return FILLS if rows == ROWS else (rows // 2, rows)
+
+
+def variants(cells=None):
     """[(label, family, pass, contracted, width, function of (lhs, rhs,
     dout, sizes))]: ``lhs`` (rows, k), ``rhs`` (groups, k, n), ``dout``
-    (rows, n)."""
+    (rows, n); of the ``cells`` named, or of all with the padded
+    shapes."""
     out = []
 
     def ragged(family, k, n):
@@ -149,22 +167,25 @@ def variants():
                             dout, rhs, sizes, bf16, t, transpose_rhs=True)))
 
     for cell, (hidden, width) in CELLS.items():
+        if cells and cell not in cells:
+            continue
         for k, n in ((hidden, width), (width, hidden)):
             ragged("ragged_dot", k, n)
             kernels(k, n, cell == "nemotron")
             shipped(k, n)
-    for hidden, width in PADDED:
+    for hidden, width in () if cells else PADDED:
         for k, n in ((hidden, width), (width, hidden)):
             ragged("padded", k, n)
     return out
 
 
 def operands(k, n):
+    rows = rows_of(k, n)
     keys = jax.random.split(jax.random.PRNGKey(k * 7919 + n), 3)
-    lhs = jax.random.normal(keys[0], (ROWS, k), jnp.bfloat16)
+    lhs = jax.random.normal(keys[0], (rows, k), jnp.bfloat16)
     rhs = (jax.random.normal(keys[1], (GROUPS, k, n)) * k ** -0.5
            ).astype(jnp.bfloat16)
-    dout = jax.random.normal(keys[2], (ROWS, n), jnp.bfloat16)
+    dout = jax.random.normal(keys[2], (rows, n), jnp.bfloat16)
     return lhs, rhs, dout
 
 
@@ -232,12 +253,19 @@ def main(argv=None):
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--out",
                         default="chiprun_out/sweep_grouped_products.json")
+    parser.add_argument("--cells", default="",
+                        help="comma-separated names of CELLS to sweep "
+                             "alone (default: all, and the padded shapes)")
     args = parser.parse_args(argv)
+    cells = [c for c in args.cells.split(",") if c]
+    unknown = sorted(set(cells) - set(CELLS))
+    if unknown:
+        parser.error(f"--cells {unknown}: known {sorted(CELLS)}")
     if jax.default_backend() != "tpu":
         print(f"the sweep measures a TPU; this backend is "
               f"{jax.default_backend()}", file=sys.stderr)
         return 2
-    todo = variants()
+    todo = variants(cells)
     by_shape = {}
     for v in todo:
         by_shape.setdefault((v[3], v[4]), []).append(v)
@@ -245,8 +273,9 @@ def main(argv=None):
                        "width": n, "fills": {}}
                for label, family, which, k, n, _ in todo}
     agree = {}
-    for k, n in [kn for h, w in CELLS.values() for kn in ((h, w), (w, h))]:
-        agree[f"{k}x{n}"] = agreement(k, n, FILLS[0])
+    for k, n in [kn for cell, (h, w) in CELLS.items()
+                 if not cells or cell in cells for kn in ((h, w), (w, h))]:
+        agree[f"{k}x{n}"] = agreement(k, n, fills_of(k, n)[0])
         print("agreement", f"{k}x{n}", agree[f"{k}x{n}"], flush=True)
     compiled = {}
     for (k, n), group in by_shape.items():
@@ -257,7 +286,8 @@ def main(argv=None):
             try:
                 # lint: allow(bare-jit) -- a timing probe of one product
                 jitted = jax.jit(fn)
-                jax.block_until_ready(jitted(*args_kn, group_sizes(FILLS[0])))
+                jax.block_until_ready(jitted(*args_kn, group_sizes(
+                    fills_of(k, n)[0])))
                 compiled[label] = jitted
             except Exception as e:  # a tile the compiler refuses is a row
                 lines = (str(e) or repr(e)).splitlines()
@@ -267,14 +297,14 @@ def main(argv=None):
             results[label]["first_call_s"] = round(
                 time.perf_counter() - started, 2)
         del args_kn
-    for filled in FILLS:
-        sizes = group_sizes(filled)
+    for fill in range(len(FILLS)):
         trace_dir = tempfile.mkdtemp(prefix="sweep_grouped_")
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         jax.profiler.start_trace(trace_dir, profiler_options=options)
         for (k, n), group in by_shape.items():
             args_kn = operands(k, n)
+            sizes = group_sizes(fills_of(k, n)[fill])
             for label, *_ in group:
                 for _ in range(CALLS if label in compiled else 0):
                     jax.block_until_ready(compiled[label](*args_kn, sizes))
@@ -284,6 +314,8 @@ def main(argv=None):
         if not device:
             raise SystemExit("the trace holds no module of the sweep")
         for label, (module_ms, kernel_ms, names) in device.items():
+            filled = fills_of(results[label]["contracted"],
+                              results[label]["width"])[fill]
             results[label]["fills"][str(filled)] = {
                 "module_ms": statistics.median(module_ms),
                 "kernel_ms": statistics.median(kernel_ms),
@@ -292,23 +324,26 @@ def main(argv=None):
     device0 = jax.devices()[0]
     report = {"device": {"platform": device0.platform,
                          "kind": device0.device_kind},
-              "clock": "device", "rows": ROWS, "groups": GROUPS,
-              "fills": list(FILLS), "shares": SHARES, "calls": CALLS,
+              "clock": "device", "rows": ROWS, "rows_of": ROWS_OF,
+              "groups": GROUPS, "fills": list(FILLS), "shares": SHARES,
+              "calls": CALLS,
               "agreement": agree, "results": results}
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "w") as f:
         json.dump(report, f, indent=1)
-    print(f"| variant | {' | '.join(f'{f} rows: module_ms' for f in FILLS)} "
-          "| of it kernels |")
-    print("| --- |" + " --- |" * (len(FILLS) + 1))
+    print("| variant | rows filled | "
+          f"{' | '.join('module_ms' for _ in FILLS)} | of it kernels |")
+    print("| --- |" + " --- |" * (len(FILLS) + 2))
     for label, r in results.items():
         if "error" in r:
             print(f"| {label} | refused: {r['error']} |")
             continue
-        fills = [r["fills"].get(str(f), {}) for f in FILLS]
-        cells = [f"{f.get('module_ms', float('nan')):.3f}" for f in fills]
+        counts = fills_of(r["contracted"], r["width"])
+        fills = [r["fills"].get(str(f), {}) for f in counts]
+        module = [f"{f.get('module_ms', float('nan')):.3f}" for f in fills]
         own = [f"{f.get('kernel_ms', float('nan')):.3f}" for f in fills]
-        print(f"| {label} | {' | '.join(cells)} | {' / '.join(own)} |")
+        print(f"| {label} | {' / '.join(map(str, counts))} | "
+              f"{' | '.join(module)} | {' / '.join(own)} |")
     print(json.dumps({"ok": True, "out": args.out, "clock": report["clock"],
                       "device": report["device"],
                       "variants": len(results)}))

@@ -90,6 +90,60 @@ def test_fused_arm_at_head_size_256_matches_plain_arm(which):
     assert _rel(fused[which], plain[which]) < 6e-3
 
 
+@pytest.mark.parametrize("which", range(4), ids=NAMES)
+def test_fused_arm_at_head_size_64_matches_plain_arm(which):
+    """The short-convolution cell's head (ISSUE 39): 64 wide, 4 query
+    heads on 2; the arm zero-pads each head to the kernel's 128 lanes
+    under the scale of 64, and output and gradients come back 64 wide,
+    as close to float32 as the plain arm's."""
+    fused, plain, exact = _at_64()
+    assert fused[which].shape == plain[which].shape
+    assert fused[which].dtype == plain[which].dtype == jnp.bfloat16
+    assert np.isfinite(np.asarray(fused[which], np.float32)).all()
+    assert _rel(fused[which], plain[which]) < 6e-3
+    assert _rel(fused[which], exact[which]) <= 1.25 * _rel(plain[which],
+                                                          exact[which])
+
+
+@functools.lru_cache(maxsize=None)
+def _at_64():
+    q, k, v, ct = _inputs(seed=7, bsz=1, length=256, dim=64)
+    tiles = attention.Tiles(fwd=(128, 128), dkv=(128, 128), dq=(128, 128))
+    plain = lambda q, k, v: attention.causal_attention(q, k, v, 128)  # noqa: E731
+    return (_with_gradients(lambda q, k, v: _fused(q, k, v, tiles),
+                            q, k, v, ct),
+            _with_gradients(plain, q, k, v, ct),
+            _with_gradients(plain, *(x.astype(jnp.float32)
+                                     for x in (q, k, v)), ct))
+
+
+def test_a_padded_head_runs_the_kernel_at_a_lane_tile():
+    """Head size 64 reaches the three kernels 128 wide (4 heads: 512
+    columns) and under 1/sqrt(64); a head of 128 reaches them as it is."""
+    from imaginaire_tpu.ops.pallas import causal_attention_kernel as kernel
+
+    assert [attention.kernel_head_dim(d) for d in (64, 96, 128, 192, 256)] \
+        == [128, 128, 128, 256, 256]
+    q, k, v, ct = _inputs(seed=8, bsz=1, length=128, dim=64)
+    seen = {}
+
+    def spy(name):
+        real = getattr(kernel, name)
+
+        def call(q, *rest, scale=None, **kwargs):
+            seen[name] = (q.shape[-1], scale)
+            return real(q, *rest, scale=scale, **kwargs)
+        return call
+
+    tiles = attention.Tiles(fwd=(128, 128), dkv=(128, 128), dq=(128, 128))
+    with pytest.MonkeyPatch.context() as patch:
+        for name in ("forward", "backward_dkv", "backward_dq"):
+            patch.setattr(kernel, name, spy(name))
+        _with_gradients(lambda q, k, v: _fused(q, k, v, tiles), q, k, v, ct)
+    assert seen == dict.fromkeys(("forward", "backward_dkv", "backward_dq"),
+                                 (4 * 128, 0.125))
+
+
 @functools.lru_cache(maxsize=None)
 def _at_256():
     q, k, v, ct = _inputs(seed=6, bsz=1, length=256, q_heads=2, kv_heads=2,
@@ -140,8 +194,12 @@ def test_one_tile_and_many_tiles_agree():
     ("cpu", 128, 8192, "blocks"),      # where the tests run
     ("tpu", 128, 8192 + 50, "blocks"),  # a ragged length
     ("tpu", 128, 512, "blocks"),       # shorter than a tile
-    ("tpu", 64, 8192, "blocks"),       # a head the lanes do not divide
-    ("tpu", 192, 8192, "blocks"),
+    ("tpu", 64, 8192, "fused"),        # zero-padded to a lane tile
+    ("tpu", 192, 8192, "blocks"),      # no padded form but the half tile's
+    ("tpu", 96, 8192, "blocks"),
+    ("tpu", 32, 8192, "blocks"),       # padding would quadruple the work
+    ("tpu", 16, 8192, "blocks"),
+    ("cpu", 64, 8192, "blocks"),
 ])
 def test_the_rule(monkeypatch, backend, dim, length, arm):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)

@@ -10,7 +10,8 @@ import sys
 
 import pytest
 
-from hybrid_lm_util import DELTA_YAML, LATENT_YAML, ROOT, TINY_YAML
+from hybrid_lm_util import (DELTA_YAML, LATENT_YAML, ROOT, SCONV_YAML,
+                            TINY_YAML)
 
 from imaginaire_tpu.parallel import mesh as mesh_mod
 from imaginaire_tpu.telemetry import core as tcore
@@ -193,4 +194,50 @@ def test_train_py_trains_the_delta_rule_preset(entry_point_sandbox,
     assert "head size 16: layer 0 blocks" in report
     assert ("- kda_impl: layers 2, 4; 4 heads of 16 held; chunks of 16 "
             "steps in sub-blocks of 16, 8 at once") in report
+    assert "| 5 |" in report.split("## experts")[1]
+
+
+def test_train_py_trains_the_short_convolution_preset(entry_point_sandbox,
+                                                      monkeypatch, tmp_path):
+    """ISSUE 39: `configs/unit_test/lfm2_moe.yaml` through `train.main()`
+    at its batch of 2: one loss, the two expert layers' counters (no
+    shared expert), the one rotary attention layer with normed queries and
+    keys in the `attn_impl` meta with the head size the kernel would run
+    it at, no `kda_impl`, no recompile, a clean graph audit (the head
+    norm's statistics and the turn are float32 islands), and every new
+    scope named by an instruction of the compiled step."""
+    logdir = str(tmp_path / "log")
+    trainer = _train(monkeypatch, logdir, 2, config=SCONV_YAML)
+    assert trainer.current_iteration == 2
+    assert trainer.weights == {"lm": 1.0}
+    events = _events(logdir)
+    counters = {e["name"]: e["value"] for e in events
+                if e["kind"] == "counter"}
+    assert counters["lm/main"] > 0 and "lm/mtp" not in counters
+    assert counters["perf/tokens_per_sec"] > 0
+    for layer in (3, 5):
+        assert counters[f"moe/{layer}/held_assignments"] > 0
+        assert 0 < counters[f"moe/{layer}/buffer_occupancy"] <= 1
+    assert counters["xla/recompiles"] == 0
+    assert counters["xla/graph_violations"] == 0
+    metas = {e["name"]: e for e in events if e["kind"] == "meta"}
+    assert "kda_impl" not in metas
+    attn = metas["attn_impl"]
+    assert attn["layers"] == {"2": "blocks"} and attn["head_dim"] == 16
+    assert attn["kernel_head_dim"] == 128 and attn["kept_bytes"] == {"2": 0}
+    moe = metas["moe_impl"]
+    assert moe["layers"] == {"3": "ragged_dot", "5": "ragged_dot"}
+    assert (moe["hidden"], moe["width"], moe["held"]) == (64, 48, 4)
+    names = set(xla_obs.ledger().label_op_names["gen_step"].values())
+    for scope in ("lm/attn/sconv_proj", "lm/attn/sconv_conv",
+                  "lm/attn/qk_norm", "lm/attn/rope", "lm/attn/out",
+                  "lm/mlp/dense", "lm/moe/experts"):
+        assert any(scope in name for name in names), scope
+    assert not any("lm/moe/shared" in name for name in names)
+
+    from imaginaire_tpu.telemetry.report import render_report
+
+    report = render_report(os.path.join(logdir, "telemetry.jsonl"))
+    assert ("attn_impl at length 64, head size 16: layer 2 blocks; fused "
+            "tiles") in report
     assert "| 5 |" in report.split("## experts")[1]

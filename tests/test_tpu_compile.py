@@ -93,13 +93,15 @@ def test_spade_modulation_kernel_compiles(one_chip, shape, dtype):
                                                   ("blocks", 1)])
 @pytest.mark.parametrize("q_heads,kv_heads,dim", [(32, 2, 128),
                                                   (20, 20, 256),
-                                                  (8, 1, 128)])
+                                                  (8, 1, 128),
+                                                  (32, 8, 64)])
 def test_fused_attention_compiles_at_the_token_cells_shape(
         one_chip, q_heads, kv_heads, dim, policy, forward_calls):
     """The three passes of ``ops/attention.py``'s fused arm at
     nemotron3_nano_30b_a3b's attention layer (32 query heads over 2, head
-    size 128, 8,192 positions) and at glm4_7_flash's (20 heads on 20, head
-    size 256), with the tiles the program uses. Each
+    size 128, 8,192 positions), at glm4_7_flash's (20 heads on 20, head
+    size 256) and at lfm2_8b_a1b's (32 on 8 at head size 64, which the
+    arm zero-pads to the kernel's 128), with the tiles the program uses. Each
     kernel's instruction stands on one line of the optimized HLO with its
     ``op_name`` under the caller's scope: that is how a trace's events
     are counted under ``lm/attn/scores``. Under a checkpoint of
@@ -223,6 +225,66 @@ def test_kda_layer_compiles_at_the_token_cells_shape(one_chip):
     assert scopes == {"lm/attn/kda_proj", "lm/attn/kda_conv",
                       "lm/attn/kda_scan", "lm/attn/kda_gate_norm",
                       "lm/attn/out"}
+
+
+def test_the_short_convolution_share_s_step_fits_one_chip(one_chip,
+                                                          monkeypatch):
+    """configs/projects/lfm2_moe/8b_a1b_ep4_share.yaml's whole training
+    step at its own shapes (two sequences of 8,192; from
+    ``jax.eval_shape`` shapes: no weight is materialized), lowered and
+    compiled as on the chip: the attention layer's scores at head size 64
+    on the fused arm, the held experts' products at 2048 x 1792 and
+    1792 x 2048 on this repo's grouped kernel in both tiers, and state
+    and temporaries together under one chip's 16.9e9 bytes (ISSUE 39:
+    10.6e9 as it stands)."""
+    from imaginaire_tpu.config import Config
+    from imaginaire_tpu.ops import attention, grouped_matmul
+    from imaginaire_tpu.registry import resolve
+    from imaginaire_tpu.trainers import lm
+
+    cfg = Config(os.path.join(ROOT, "configs", "projects", "lfm2_moe",
+                              "8b_a1b_ep4_share.yaml"))
+    shape = (int(cfg.data.train.batch_size), int(cfg.data.seq_len))
+    assert shape == (2, 8192)
+    trainer = resolve(cfg.trainer.type, "Trainer")(cfg)
+    # the arms decide as they would on the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    meta = lm.attn_impl(trainer.cfg.gen, shape)
+    assert meta["layers"] == {"2": "fused"}
+    assert (meta["head_dim"], meta["kernel_head_dim"]) == (64, 128)
+    moe = lm.moe_impl(trainer.cfg.gen, shape)
+    assert moe["layers"] == dict.fromkeys("3579", "kernel")
+    # the even share is a row a token (16,384 x 4 x 8 / 32), so the
+    # short tier is two
+    assert moe["tiers"] == [32768, 65536]
+    assert (moe["tiles"]["up"]["fwd"], moe["tiles"]["down"]["fwd"]) == (
+        (128, 896), (128, 1024))
+    assert attention.arm_of(64, 8192) == "fused"
+    assert grouped_matmul.arm_of(16384, 2048, 1792) == "kernel"
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+
+    data = {"tokens": jax.ShapeDtypeStruct(shape, jnp.int32)}
+    state = jax.eval_shape(trainer._init_state,
+                           jax.ShapeDtypeStruct((2,), np.uint32), data)
+    compiled = trainer._jit_gen_step.lower(on_chip(state),
+                                           on_chip(data)).compile()
+    trainer.state = None
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert 10.0e9 < total < 11.5e9 < 16.9e9, total
+    calls = [line.split("=")[0].strip().lstrip("%").split(".")[0]
+             for line in compiled.as_text().splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert {name: calls.count(name) for name in set(calls)} == {
+        "causal_gqa_fwd": 1, "causal_gqa_dkv": 1, "causal_gqa_dq": 1,
+        # four layers, two tiers, three products a pass: forward and
+        # again inside the backward branch, then the two gradients
+        "grouped_rows_fwd": 48, "grouped_rows_dlhs": 24,
+        "grouped_weights_drhs": 24}
 
 
 # -------------------------------------------- what ``auto`` resolves to
