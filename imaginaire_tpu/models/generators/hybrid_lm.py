@@ -51,7 +51,8 @@ chunked state-space dual form of the Mamba-2 recurrence (``ssd_scan``),
 the chunked WY form of the delta rule (``kda_scan``),
 and dropless routing with static shapes (``route_held``: sort the
 assignments by expert, the held ones first, into a buffer of
-``expert_buffer_rows`` rows, two grouped products by
+``expert_buffer_rows`` rows, once a step: a recomputed block keeps the
+order; two grouped products by
 ``ops/grouped_matmul.py`` (three where the expert is gated; this repo's
 kernel on a TPU at widths that fill a lane tile, ``lax.ragged_dot``
 elsewhere), scatter back weighted). ``expert_buffer_rows`` is the buffer's capacity,
@@ -59,7 +60,10 @@ what the largest routing may hold; a step computes the filled prefix of
 it (``on_filled_prefix``: a short tier where its held assignments fit
 that, a row a token or, where the held experts' even share is more than
 half of that, twice the even share (``expert_tiers``); the whole buffer
-otherwise, the same arithmetic either way).
+otherwise, the same arithmetic either way), and moves rows into a tier
+and out of it by segments, as far as the step's held rows reach
+(``gather_rows``, ``add_rows``; the backward pass is written out,
+``held_experts_part_bwd``).
 The causal scores live in ``ops/attention.py``, which picks
 its own arm from what it observes: one fused Pallas kernel that keeps the
 scores in VMEM where the backend is a TPU, the head size a multiple of
@@ -90,12 +94,13 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from imaginaire_tpu.analysis import islands
 from imaginaire_tpu.config import cfg_get
 from imaginaire_tpu.ops.attention import attention
 from imaginaire_tpu.ops.grouped_matmul import grouped_matmul
-from imaginaire_tpu.optim.remat import remat_block
+from imaginaire_tpu.optim.remat import ROUTING_PLAN, remat_block
 
 
 def _kernel_init(key, shape, dtype=jnp.float32):
@@ -778,18 +783,24 @@ def route_held(experts, weights, first, count, rows):
     int32: rows of each held expert, as the buffer holds them; stats:
     ``held_assignments``, ``overflow`` (held assignments the buffer has
     no row for), ``load_max_over_mean`` over the held experts,
-    ``buffer_occupancy``)."""
+    ``buffer_occupancy``). The order and the experts' counts carry the
+    name ``ROUTING_PLAN``: a block recomputed under a policy that keeps
+    the name sorts once a step."""
     tokens, top_k = experts.shape
     local = (experts - first).reshape(-1)
     held = (local >= 0) & (local < count)
     local = jnp.where(held, local, count)            # the others sort last
-    order = jnp.argsort(local, stable=True)[:rows]
-    sorted_local = local[order]
-    valid = sorted_local < count
-    token = (order // top_k).astype(jnp.int32)
-    weight = jnp.where(valid, weights.reshape(-1)[order], 0.0)
-    sizes = jnp.bincount(local, length=count + 1)[:count]
+    order = jnp.argsort(local, stable=True)[:rows].astype(jnp.int32)
+    # a comparison with each held expert, summed: a ``bincount`` is a
+    # scatter-add of ones, 0.57 ms for 65,536 assignments on a v5e where
+    # this is 0.002 (PERF.md, PR 40)
+    sizes = (local[:, None] == jnp.arange(count)).sum(0, dtype=jnp.int32)
+    order, sizes = checkpoint_name((order, sizes), ROUTING_PLAN)
     n_held = sizes.sum()
+    # the held assignments sort first
+    valid = jnp.arange(order.shape[0]) < n_held
+    token = order // top_k
+    weight = jnp.where(valid, weights.reshape(-1)[order], 0.0)
     ends = jnp.minimum(jnp.cumsum(sizes), rows)
     group_sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
     mean = jnp.maximum(n_held, 1) / count
@@ -804,31 +815,178 @@ def route_held(experts, weights, first, count, rows):
     return token, weight, valid, group_sizes, stats
 
 
-def held_experts_part(x, kernels, weight, token, valid, group_sizes, rows):
-    """The held experts' part of the layer's result, computed on the first
-    ``rows`` rows of ``route_held``'s buffer: all of it where the step
-    holds no more than ``rows`` assignments, since the rows past the held
-    ones are masked to zero wherever they are read. ``x`` (T, hidden) and
-    ``kernels`` (gate where the expert is gated, up: (count, hidden,
-    width); down: (count, width, hidden)) in the compute dtype."""
-    token, weight = token[:rows], weight[:rows]
-    # a row past the groups' end is not the grouped products' to write,
-    # forward or backward: whatever stands there is masked on the way in
-    # (its gradient is the first product's), between the two and on the
-    # way out
-    mask = valid[:rows, None]
-    with jax.named_scope("lm/moe/dispatch"):
-        filled = jnp.where(mask, x[token], 0)
+# The rows a tier is moved by: a sixteenth of it, where that is
+# ``SEGMENT_FLOOR`` rows or more (under it a trip costs more than its
+# rows: PERF.md, PR 40), else the tier whole. The rows' sum into
+# (tokens, hidden) goes by segments where its float32 accumulator is
+# under ``SEGMENTED_SUM_BYTES``: on a v5e a loop that carries one of 64
+# or 84 MiB adds a row as fast as the whole tier's scatter-add does, and
+# one of 128 MiB pays a quarter of a millisecond more a trip and loses
+# (PERF.md, PR 40; the limit lies between what was measured); and in the
+# whole-buffer tier, which sets the step's peak memory: one scatter-add
+# stands the tier's rows in float32 beside it (1.07 GB in the widest
+# share, which then compiles 0.8 GB over what it compiled to).
+SEGMENTS = 16
+SEGMENT_FLOOR = 512
+SEGMENTED_SUM_BYTES = 100 * 2 ** 20
+
+
+def segment_rows(rows):
+    """The rows of one segment of a tier of ``rows`` rows."""
+    segment = rows // SEGMENTS
+    whole = rows % SEGMENTS or segment < SEGMENT_FLOOR
+    return rows if whole else segment
+
+
+def _over_filled(filled, rows, body, init):
+    """``body(at, keep, carry)`` over the segments of a tier of ``rows``
+    rows that start before its first ``filled`` rows end, ascending:
+    ``at`` is the segment's first row and ``keep`` (segment,) says which
+    of its rows are filled. No trip where nothing is filled."""
+    segment = segment_rows(rows)
+    filled = jnp.minimum(filled, rows)
+
+    def trip(i, carry):
+        at = i * segment
+        return body(at, at + jnp.arange(segment) < filled, carry)
+
+    return lax.fori_loop(0, (filled + segment - 1) // segment, trip, init)
+
+
+def gather_rows(x, token, filled):
+    """``x[token]`` on the first ``filled`` rows and zeros past them,
+    (rows, hidden) in ``x``'s dtype, a segment at a time: a segment that
+    starts past the filled rows is not gathered."""
+    rows, segment = token.shape[0], segment_rows(token.shape[0])
+
+    def body(at, keep, out):
+        index = lax.dynamic_slice(token, (at,), (segment,))
+        part = x.at[index].get(mode="promise_in_bounds")
+        return lax.dynamic_update_slice(
+            out, jnp.where(keep[:, None], part, 0), (at, 0))
+
+    return _over_filled(filled, rows, body,
+                        jnp.zeros((rows, x.shape[1]), x.dtype))
+
+
+def add_rows(values, token, filled, tokens, capacity, weight=None):
+    """The first ``filled`` rows of ``values`` (rows, hidden), each times
+    its ``weight`` where one is given, added to row ``token`` of a zero
+    (tokens, hidden); what stands past the filled rows is masked. In
+    float32 and a segment at a time, not reading the segments past the
+    filled rows, where the sum's accumulator is under
+    ``SEGMENTED_SUM_BYTES`` or the tier is the whole buffer of
+    ``capacity`` rows; else the whole tier in one scatter-add, in
+    float32 where weighted and in ``values``' dtype where not (the
+    layer's sum and the transpose of its gather as they stood before
+    ISSUE 40)."""
+    rows, hidden = values.shape
+    segment = segment_rows(rows)
+
+    def weighted(part, at, keep):
+        if weight is not None:
+            part = part.astype(jnp.float32) * lax.dynamic_slice(
+                weight, (at,), keep.shape)[:, None]
+        return jnp.where(keep[:, None], part, 0)
+
+    if tokens * hidden * 4 >= SEGMENTED_SUM_BYTES and rows < capacity:
+        part = weighted(values, 0, jnp.arange(rows) < filled)
+        return jnp.zeros((tokens, hidden), part.dtype).at[token].add(part)
+
+    def body(at, keep, total):
+        index = lax.dynamic_slice(token, (at,), (segment,))
+        part = lax.dynamic_slice(values, (at, 0), (segment, hidden))
+        return total.at[index].add(
+            weighted(part, at, keep).astype(jnp.float32),
+            mode="promise_in_bounds")
+
+    return _over_filled(filled, rows, body,
+                        jnp.zeros((tokens, hidden), jnp.float32))
+
+
+def held_products(buffer, kernels, group_sizes):
+    """The held experts' feed-forward on the buffer's rows, (rows, hidden)
+    to (rows, hidden). A row past the groups' end is not the grouped
+    products' to write, forward or backward: whatever stands there is
+    masked between the products (its gradient is the first product's);
+    on the way in and on the way out the movement masks it."""
+    mask = (jnp.arange(buffer.shape[0]) < group_sizes.sum())[:, None]
     with jax.named_scope("lm/moe/experts"):
         act = hidden_activation([
-            jnp.where(mask, grouped_matmul(filled, w, group_sizes), 0)
+            jnp.where(mask, grouped_matmul(buffer, w, group_sizes), 0)
             for w in kernels[:-1]])
-        out = grouped_matmul(act, kernels[-1], group_sizes)
-        out = jnp.where(mask, out, 0)
+        return grouped_matmul(act, kernels[-1], group_sizes)
+
+
+def held_experts_part(x, kernels, weight, token, group_sizes, rows):
+    """The held experts' part of the layer's result, computed on the first
+    ``rows`` rows of ``route_held``'s buffer: all of it where the step
+    holds no more than ``rows`` assignments. Rows are moved into the
+    buffer and out of it as far as the held ones reach (``group_sizes``'
+    sum), by segments; the rows past them read as zeros. ``x`` (T, hidden)
+    and ``kernels`` (gate where the expert is gated, up: (count, hidden,
+    width); down: (count, width, hidden)) in the compute dtype."""
+    capacity = weight.shape[0]
+    token, weight = token[:rows], weight[:rows]
+    filled = group_sizes.sum()
+    with jax.named_scope("lm/moe/dispatch"):
+        buffer = gather_rows(x, token, filled)
+    out = held_products(buffer, kernels, group_sizes)
     with jax.named_scope("lm/moe/combine"):
-        out = out.astype(jnp.float32) * weight[:, None]
-        routed = jnp.zeros(x.shape, jnp.float32).at[token].add(out)
-        return routed.astype(x.dtype)
+        return add_rows(out, token, filled, x.shape[0], capacity,
+                        weight).astype(x.dtype)
+
+
+def weighted_rows_bwd(ct, out, weight, token, filled):
+    """The transpose of ``add_rows`` with a weight, for the sum's
+    cotangent ``ct`` (T, hidden): a row's cotangent is its weight times
+    its token's, (rows, hidden) in ``out``'s dtype, and its weight's is
+    the two rows' product, (rows,) float32; zeros past the first
+    ``filled`` rows, where ``out`` is not read."""
+    rows, hidden = out.shape
+    segment = segment_rows(rows)
+
+    def body(at, keep, carry):
+        d_out, d_weight = carry
+        index = lax.dynamic_slice(token, (at,), (segment,))
+        ct_rows = ct.at[index].get(mode="promise_in_bounds").astype(
+            jnp.float32)
+        out_rows = lax.dynamic_slice(out, (at, 0), (segment, hidden))
+        to_weight = (ct_rows * out_rows.astype(jnp.float32)).sum(-1)
+        to_out = ct_rows * lax.dynamic_slice(weight, (at,),
+                                             (segment,))[:, None]
+        to_out = jnp.where(keep[:, None], to_out, 0).astype(out.dtype)
+        return (lax.dynamic_update_slice(d_out, to_out, (at, 0)),
+                lax.dynamic_update_slice(
+                    d_weight, jnp.where(keep, to_weight, 0), (at,)))
+
+    return _over_filled(filled, rows, body, (
+        jnp.zeros_like(out), jnp.zeros((rows,), jnp.float32)))
+
+
+def held_experts_part_bwd(ct, x, kernels, weight, token, group_sizes, rows):
+    """The gradients of ``held_experts_part`` to ``x``, ``kernels`` and
+    ``weight`` for the result's cotangent ``ct`` (T, hidden), written out:
+    a loop that ends at the step's own count has no transpose. The
+    buffer and the products are computed again (a block keeps neither),
+    the products' gradients are the kernels' own rules, and the rows
+    move by the same segments as forward."""
+    capacity = weight.shape[0]
+    token, weight = token[:rows], weight[:rows]
+    filled = group_sizes.sum()
+    with jax.named_scope("lm/moe/dispatch"):
+        buffer = gather_rows(x, token, filled)
+    out, products_vjp = jax.vjp(
+        functools.partial(held_products, group_sizes=group_sizes),
+        buffer, kernels)
+    with jax.named_scope("lm/moe/combine"):
+        d_out, d_weight = weighted_rows_bwd(ct, out, weight, token, filled)
+    d_buffer, d_kernels = products_vjp(d_out)
+    with jax.named_scope("lm/moe/dispatch"):
+        d_x = add_rows(d_buffer, token, filled, x.shape[0],
+                       capacity).astype(x.dtype)
+    return d_x, d_kernels, jnp.pad(d_weight,
+                                   (0, capacity - d_weight.shape[0]))
 
 
 def expert_tiers(tokens, g):
@@ -855,8 +1013,16 @@ def _tier(tiers, n_held):
     return sum((n_held > rows).astype(jnp.int32) for rows in tiers[:-1])
 
 
+def moved_rows(tiers, n_held):
+    """The rows a pass over the tier that holds ``n_held`` assignments
+    moves: its segments up to the one the held rows end in."""
+    each = [jnp.minimum(jnp.ceil(n_held / segment_rows(rows))
+                        * segment_rows(rows), rows) for rows in tiers]
+    return jnp.stack(each)[_tier(tiers, n_held)]
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def on_filled_prefix(tiers, n_held, x, kernels, weight, *placed):
+def on_filled_prefix(tiers, n_held, x, kernels, weight, token, group_sizes):
     """``held_experts_part`` on the shortest of the static, ascending
     ``tiers`` of rows that holds the step's ``n_held`` assignments, by
     ``lax.switch``. Its gradient is each tier's own, recomputed inside
@@ -867,7 +1033,7 @@ def on_filled_prefix(tiers, n_held, x, kernels, weight, *placed):
     return lax.switch(
         _tier(tiers, n_held),
         [functools.partial(held_experts_part, rows=rows) for rows in tiers],
-        x, kernels, weight, *placed)
+        x, kernels, weight, token, group_sizes)
 
 
 def _on_filled_prefix_fwd(tiers, n_held, *operands):
@@ -875,20 +1041,16 @@ def _on_filled_prefix_fwd(tiers, n_held, *operands):
 
 
 def _on_filled_prefix_bwd(tiers, saved, ct):
-    n_held, (*floats, token, valid, group_sizes) = saved
-
-    def back(rows):
-        part = functools.partial(held_experts_part, token=token, valid=valid,
-                                 group_sizes=group_sizes, rows=rows)
-        return lambda ct, *floats: jax.vjp(part, *floats)[1](ct)
-
-    grads = lax.switch(_tier(tiers, n_held), [back(rows) for rows in tiers],
-                       ct, *floats)
+    n_held, operands = saved
+    grads = lax.switch(
+        _tier(tiers, n_held),
+        [functools.partial(held_experts_part_bwd, rows=rows)
+         for rows in tiers], ct, *operands)
     # the kernels' gradients leave the switch in the compute dtype: left
     # to itself the compiler moves their casts to float32 into the
     # branches, and eight leaves of twice the size stand until the
     # optimizer's pass (2 GB of temporaries at the published widths)
-    return (None, *lax.optimization_barrier(grads), None, None, None)
+    return (None, *lax.optimization_barrier(grads), None, None)
 
 
 on_filled_prefix.defvjp(_on_filled_prefix_fwd, _on_filled_prefix_bwd)
@@ -929,16 +1091,17 @@ class MoEMixer(nn.Module):
         # computes on that prefix, any other on the whole buffer
         tiers = expert_tiers(x.shape[0], g)
         with jax.named_scope("lm/moe/dispatch"):
-            token, weight, valid, group_sizes, stats = route_held(
+            token, weight, _, group_sizes, stats = route_held(
                 experts, weights, g.held_first, g.held_count, capacity)
             n_held = stats["held_assignments"]
             stats["compact"] = (n_held <= tiers[0]).astype(jnp.float32)
+            stats["moved_rows"] = moved_rows(tiers, n_held)
         # the switch stands under no scope: its branches' operations
         # carry their own
         with jax.named_scope("lm/moe/experts"):
             kernels = tuple(w.astype(dtype) for w in kernels)
         routed = on_filled_prefix(tiers, n_held, x, kernels, weight,
-                                  token, valid, group_sizes)
+                                  token, group_sizes)
         if shared is None:
             return routed.reshape(*lead, hidden), stats
         with jax.named_scope("lm/moe/shared"):

@@ -14,10 +14,14 @@ one error message, one registry:
                      (the arrays a block names
                      ``ops.attention.KERNEL_RESIDUAL``: the fused
                      attention kernel's output and log-sum-exp, which
-                     only a second run of that kernel would give back).
+                     only a second run of that kernel would give back)
+                     and where an expert layer's routing found its rows
+                     (``ROUTING_PLAN``: integers, which only a second
+                     sort of the step's assignments would give back).
                      A block that names nothing keeps nothing: every
                      GAN family's, and a token model's off the fused
-                     arm. The historical spade knob value.
+                     arm and without an expert layer. The historical
+                     spade knob value.
   ``dots_saveable``  checkpoint each block but let XLA keep matmul/conv
                      outputs (``jax.checkpoint_policies.dots_saveable``)
                      — recompute only the cheap elementwise tail, the
@@ -46,6 +50,12 @@ from flax import linen as nn
 
 from imaginaire_tpu.ops.attention import KERNEL_RESIDUAL
 
+# the ``checkpoint_name`` of what an expert layer's routing found where
+# the held experts' rows lie (``hybrid_lm.route_held``: the sorted order
+# and the experts' counts, integers of under a megabyte a layer): a block
+# that keeps it sorts a step's assignments once
+ROUTING_PLAN = "routing_plan"
+
 
 class RematPolicy(NamedTuple):
     """A resolved registry entry. ``enabled`` False means no checkpoint
@@ -65,7 +75,8 @@ POLICIES = {
     "none": RematPolicy("none", False, None, keeps_kernel_residuals=True),
     "blocks": RematPolicy(
         "blocks", True,
-        jax.checkpoint_policies.save_only_these_names(KERNEL_RESIDUAL),
+        jax.checkpoint_policies.save_only_these_names(
+            KERNEL_RESIDUAL, ROUTING_PLAN),
         keeps_kernel_residuals=True),
     "dots_saveable": RematPolicy(
         "dots_saveable", True, jax.checkpoint_policies.dots_saveable),

@@ -1000,10 +1000,13 @@ def render_serving_report(path_or_events):
 
 def _experts_section(s):
     """Routing of a token model's expert layers: the latest
-    ``moe/<layer>/*`` counters (``trainers/lm.py``'s flush hook), and of
+    ``moe/<layer>/*`` counters (``trainers/lm.py``'s flush hook), the
+    rows a pass over the layer's buffer moved over the rows it held
+    (``moe/<layer>/moved_rows``: 1 where the movement ends with the held
+    rows, the tier over them where it moves the tier whole), and of
     the flushes' newest steps the share that computed on the filled
-    prefix of the buffer (``moe/<layer>/compact``; "n/a" in a run from
-    before the counter)."""
+    prefix of the buffer (``moe/<layer>/compact``); "n/a" in a run from
+    before either counter."""
     layers = {}
     for name, (value, _) in s["counters"].items():
         parts = name.split("/")
@@ -1014,13 +1017,17 @@ def _experts_section(s):
     share = s.get("experts_compact_share") or {}
     lines = ["", "## experts",
              "| layer | held assignments | fullest over mean "
-             "| buffer occupancy | on the prefix |", "|---|---|---|---|---|"]
+             "| buffer occupancy | moved over held | on the prefix |",
+             "|---|---|---|---|---|---|"]
     for layer in sorted(layers, key=lambda k: (len(k), k)):
         row = layers[layer]
+        held = row.get("held_assignments", float("nan"))
         lines.append(
-            f"| {layer} | {row.get('held_assignments', float('nan')):.0f} "
+            f"| {layer} | {held:.0f} "
             f"| {row.get('load_max_over_mean', float('nan')):.2f} "
             f"| {row.get('buffer_occupancy', float('nan')) * 100:.1f}% "
+            + (f"| {row['moved_rows'] / held:.2f} "
+               if "moved_rows" in row and held > 0 else "| n/a ")
             + (f"| {share[layer] * 100:.0f}% |" if layer in share
                else "| n/a |"))
     return lines

@@ -26,7 +26,7 @@ from imaginaire_tpu.optim.remat import resolve_policy
 from imaginaire_tpu.trainers.base import BaseTrainer
 
 COUNTERS = ("held_assignments", "load_max_over_mean", "buffer_occupancy",
-            "compact")
+            "compact", "moved_rows")
 # the step's unweighted losses -> the counters the flush hook gives them
 LOSS_COUNTERS = {"lm": "lm/main", "mtp": "lm/mtp"}
 

@@ -15,8 +15,12 @@ short-convolution preset (ISSUE 39): the gated short convolution and its
 causality, rotary grouped-query attention with normed queries and keys,
 the expert layer without a shared expert and its four shares, and what the
 new sizes leave of the accepted models: their `*` layer and their
-parameter trees."""
+parameter trees. And for the expert layers' bookkeeping and movement
+(ISSUE 40): `route_held` against the form before, the rows gathered and
+summed by segments and the backward pass written out against `jax.vjp`
+of the whole tier, and the recomputed block without a sort."""
 
+import contextlib
 import functools
 from unittest import mock
 
@@ -337,6 +341,49 @@ def test_the_head_shares_add_up_to_the_whole_mixer(kind, index):
     _close(total, whole)
 
 
+def _route_held_before(experts, weights, first, count, rows):
+    """`route_held` as it stood before ISSUE 40, the oracle of the form
+    that took its place: a stable `argsort` of every assignment by its
+    held expert, the others last, the filled rows read off the sorted
+    experts and the experts' counts from a `bincount`."""
+    tokens, top_k = experts.shape
+    local = (experts - first).reshape(-1)
+    held = (local >= 0) & (local < count)
+    local = jnp.where(held, local, count)
+    order = jnp.argsort(local, stable=True)[:rows]
+    valid = local[order] < count
+    token = (order // top_k).astype(jnp.int32)
+    weight = jnp.where(valid, weights.reshape(-1)[order], 0.0)
+    sizes = jnp.bincount(local, length=count + 1)[:count]
+    n_held = sizes.sum()
+    ends = jnp.minimum(jnp.cumsum(sizes), rows)
+    group_sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+    stats = {
+        "held_assignments": n_held,
+        "overflow": jnp.maximum(n_held - rows, 0),
+        "load_max_over_mean": sizes.max() / (jnp.maximum(n_held, 1) / count),
+        "buffer_occupancy": n_held / rows,
+    }
+    return token, weight, valid, group_sizes, {
+        k: v.astype(jnp.float32) for k, v in stats.items()}
+
+
+def _plain_held_experts_part(x, kernels, weight, token, group_sizes, rows):
+    """`held_experts_part` as it stood before ISSUE 40, the oracle of the
+    segmented movement and of its written-out backward: the whole tier
+    gathered, masked and scatter-added, and `jax.grad` through it."""
+    token, weight = token[:rows], weight[:rows]
+    mask = (jnp.arange(token.shape[0]) < group_sizes.sum())[:, None]
+    filled = jnp.where(mask, x[token], 0)
+    act = hybrid_lm.hidden_activation([
+        jnp.where(mask, hybrid_lm.grouped_matmul(filled, w, group_sizes), 0)
+        for w in kernels[:-1]])
+    out = hybrid_lm.grouped_matmul(act, kernels[-1], group_sizes)
+    out = jnp.where(mask, out, 0).astype(jnp.float32) * weight[:, None]
+    routed = jnp.zeros(x.shape, jnp.float32).at[token].add(out)
+    return routed.astype(x.dtype)
+
+
 def _route_all_to(expert, tokens, top_k=2):
     experts = jnp.stack([jnp.full((tokens,), expert, jnp.int32),
                          jnp.full((tokens,), 7, jnp.int32)], axis=1)
@@ -411,7 +458,7 @@ def _prefix_and_whole_buffer(preset):
         return module.apply({"params": params, "buffers": buffers}, u)
 
     def whole_buffer(tiers, n_held, *operands):
-        return hybrid_lm.held_experts_part(*operands, rows=tiers[-1])
+        return _plain_held_experts_part(*operands, rows=tiers[-1])
 
     def run(params, buffers, u):
         out, stats = layer(params, buffers, u)
@@ -531,6 +578,238 @@ def test_the_kernel_arm_is_the_plain_arm_in_the_expert_layer(held):
         # two float32-accumulated bfloat16 evaluations
         assert np.linalg.norm(ours - theirs) <= 6e-3 * np.linalg.norm(theirs)
     assert float(jnp.abs(grads[0]["experts_up"]).max()) > 0
+
+
+# --- the held experts' rows found once a step and moved as far as they
+# reach (ISSUE 40)
+
+
+def _assignments(case):
+    """(experts (T, k), first, count, rows) of a `route_held` case."""
+    rng = np.random.default_rng(7)
+    tokens, top_k, first, count, of = 96, 2, 2, 4, 8
+    rows = tokens * top_k
+    absent = np.array([0, 1, 6, 7])
+    experts = absent[rng.integers(0, 4, (tokens, top_k))]
+    if case == "one":
+        experts[17, 1] = 3
+    elif case == "every_one":
+        experts = rng.integers(first, first + count, (tokens, top_k))
+    elif case == "overflow":
+        experts = rng.integers(0, of, (tokens, top_k))
+        rows = 40
+    elif case == "not_a_power_of_two":
+        tokens, top_k = 37, 3
+        experts = rng.integers(0, of, (tokens, top_k))
+        rows = tokens * top_k
+    elif case == "many_held_experts":
+        first, count = 5, 300
+        experts = rng.integers(0, 2 * count, (tokens, top_k))
+    return jnp.asarray(experts, jnp.int32), first, count, rows
+
+
+def _sorts(jaxpr):
+    """The operands of every sort in a jaxpr, inner jaxprs included."""
+    return [n for eqn in jaxpr.eqns for n in (
+        [len(eqn.invars)] if eqn.primitive.name == "sort" else [
+            n for inner in jax.core.jaxprs_in_params(eqn.params)
+            for n in _sorts(inner)])]
+
+
+@pytest.mark.parametrize("case", [
+    "none", "one", "every_one", "overflow", "not_a_power_of_two",
+    "many_held_experts"])
+def test_route_held_is_the_stable_argsort(case):
+    """The buffer's rows by held expert, tokens ascending inside an
+    expert, the others last, the same `overflow`: every output equals the
+    form's before ISSUE 40, with the experts' counts from a comparison
+    in `bincount`'s place, the filled rows from the counts, and one sort."""
+    experts, first, count, rows = _assignments(case)
+    weights = jax.random.uniform(jax.random.PRNGKey(1), experts.shape)
+    route = functools.partial(hybrid_lm.route_held, first=first, count=count,
+                              rows=rows)
+    ours = jax.jit(route)(experts, weights)
+    theirs = jax.jit(functools.partial(
+        _route_held_before, first=first, count=count, rows=rows))(
+            experts, weights)
+    for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    held = int(((np.asarray(experts) >= first)
+                & (np.asarray(experts) < first + count)).sum())
+    assert float(ours[4]["held_assignments"]) == held
+    assert {"none": held == 0, "one": held == 1,
+            "every_one": held == experts.size,
+            "overflow": held > rows}.get(case, True)
+    assert len(_sorts(jax.make_jaxpr(route)(experts, weights).jaxpr)) == 1
+
+
+# a short tier of sixteen segments of 512 rows in a buffer of twice that:
+# 4,096 tokens, top 4, experts 0 to 3 of 8 held, hidden 8, width 8
+_SEGMENTED = dict(tokens=4096, top_k=4, count=4, hidden=8, width=8)
+
+
+def _segmented_case(held, gated):
+    """Operands of `held_experts_part` whose buffer of 16,384 rows holds
+    exactly `held` assignments, and its short tier of 8,192 rows."""
+    c = _SEGMENTED
+    capacity = c["tokens"] * c["top_k"]
+    rows = capacity // 2
+    assert hybrid_lm.segment_rows(rows) == 512
+    rng = np.random.default_rng(held)
+    experts = rng.integers(4, 8, capacity)
+    experts[rng.choice(capacity, held, replace=False)] = rng.integers(
+        0, 4, held)
+    experts = jnp.asarray(experts.reshape(c["tokens"], c["top_k"]), jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(held), 6)
+    weights = jax.random.uniform(keys[0], experts.shape)
+    token, weight, _, group_sizes, stats = hybrid_lm.route_held(
+        experts, weights, 0, c["count"], capacity)
+    assert float(stats["held_assignments"]) == held
+    x = jax.random.normal(keys[1], (c["tokens"], c["hidden"]))
+    up = (c["count"], c["hidden"], c["width"])
+    kernels = tuple(
+        0.3 * jax.random.normal(key, shape) for key, shape in zip(
+            keys[2:], [up] * (2 if gated else 1) + [up[:1] + up[:0:-1]]))
+    ct = jax.random.normal(keys[5], x.shape)
+    return (x, kernels, weight, token, group_sizes), ct, rows
+
+
+@pytest.fixture(params=[100 * 2 ** 20, 0], ids=["sums_by_segments",
+                                                "sums_whole"])
+def sums(request, monkeypatch):
+    """Both forms of `add_rows` in a tier that is not the whole buffer:
+    the limit as it stands (the tests' sums are far under it) and one
+    that no sum is under."""
+    assert hybrid_lm.SEGMENTED_SUM_BYTES == 100 * 2 ** 20
+    monkeypatch.setattr(hybrid_lm, "SEGMENTED_SUM_BYTES", request.param)
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["relu2", "gated"])
+@pytest.mark.parametrize("held", [0, 1, 511, 512, 513, 8192], ids=[
+    "none", "one", "edge_minus_one", "a_segments_edge", "edge_plus_one",
+    "the_whole_tier"])
+def test_segmented_movement_is_the_whole_tiers(held, gated, sums):
+    """The rows gathered and added by segments up to the held ones, and
+    the backward pass written out, against the whole tier gathered,
+    masked and scatter-added and `jax.vjp` through that: the result and
+    the gradients to `x`, every kernel and `weight`; with the sums by
+    segments and with the sums in one scatter-add."""
+    operands, ct, rows = _segmented_case(held, gated)
+    floats, placed = operands[:3], operands[3:]
+    out = jax.jit(functools.partial(hybrid_lm.held_experts_part, rows=rows))(
+        *operands)
+    grads = jax.jit(functools.partial(hybrid_lm.held_experts_part_bwd,
+                                      rows=rows))(ct, *operands)
+    want, vjp = jax.vjp(lambda *floats: _plain_held_experts_part(
+        *floats, *placed, rows=rows), *floats)
+    _close(out, want)
+    for ours, theirs in zip(jax.tree.leaves(grads),
+                            jax.tree.leaves(vjp(ct))):
+        assert ours.shape == theirs.shape
+        _close(ours, theirs)
+    if held:
+        assert float(jnp.abs(grads[2]).max()) > 0
+    else:
+        assert not np.asarray(out).any()
+
+
+@pytest.mark.parametrize("filled", [0, 1, 700, 1024])
+def test_rows_past_the_filled_ones_are_not_read(filled, sums):
+    """A skipped segment reads as zeros though the buffer behind it holds
+    NaN, and so does the rest of the last segment that ran; a sum taken
+    over the whole tier masks what stands there."""
+    rows, tokens, hidden = 8192, 64, 4
+    keys = jax.random.split(jax.random.PRNGKey(filled), 4)
+    past = (jnp.arange(rows) >= filled)[:, None]
+    token = jax.random.randint(keys[0], (rows,), 0, tokens // 2)
+    token = jnp.where(past[:, 0], token + tokens // 2, token)
+    values = jnp.where(past, jnp.nan,
+                       jax.random.normal(keys[1], (rows, hidden)))
+    weight = jnp.where(past[:, 0], jnp.nan,
+                       jax.random.uniform(keys[2], (rows,)))
+    x = jax.random.normal(keys[3], (tokens, hidden))
+    x_nan = x.at[tokens // 2:].set(jnp.nan)
+    clean = [jnp.nan_to_num(a) for a in (values, weight)]
+
+    gathered = hybrid_lm.gather_rows(x_nan, token, filled)
+    np.testing.assert_array_equal(
+        gathered, jnp.where(past, 0, x[token]))
+    added = hybrid_lm.add_rows(values, token, filled, tokens, 2 * rows,
+                               weight)
+    assert np.isfinite(np.asarray(added)).all()
+    _close(added, jnp.zeros((tokens, hidden)).at[token].add(
+        clean[0] * clean[1][:, None]), tol=1e-6)
+    assert not np.asarray(added[tokens // 2:]).any()
+    d_out, d_weight = hybrid_lm.weighted_rows_bwd(x, values, weight, token,
+                                                  filled)
+    assert np.isfinite(np.asarray(d_out)).all()
+    assert not np.asarray(d_out[filled:]).any()
+    assert not np.asarray(d_weight[filled:]).any()
+    _close(d_weight[:filled] if filled else d_weight,
+           (x[token] * clean[0]).sum(-1)[:filled or None] * (filled > 0),
+           tol=1e-6)
+
+
+@pytest.mark.parametrize("rows,segment", [
+    (128, 128), (256, 256), (4096, 4096), (8192, 512), (32768, 2048),
+    (49152, 3072), (65536, 4096), (8200, 8200)])
+def test_a_tiers_segment_follows_from_its_rows(rows, segment):
+    """A sixteenth of the tier where that is 512 rows or more: the
+    unit-test configurations' 128 and 256 rows are one segment, the four
+    cells' tiers sixteen."""
+    assert hybrid_lm.segment_rows(rows) == segment
+
+
+@pytest.mark.parametrize("held,moved", [
+    (0, 0), (1, 512), (512, 512), (513, 1024), (8192, 8192), (8193, 12288),
+    (40000, 40960), (70000, 65536)])
+def test_moved_rows_counts_the_segments_that_ran(held, moved):
+    """`moe/<layer>/moved_rows`: the held rows rounded up to the chosen
+    tier's segments, never past the tier."""
+    assert float(hybrid_lm.moved_rows(
+        (8192, 65536), jnp.float32(held))) == moved
+
+
+def _loss_sorts_and_grads(remat, named=True):
+    """The tiny model's loss and gradients under a remat policy: how
+    many sorts the differentiated program holds, and the values."""
+    cfg = tiny_cfg(remat=remat)
+    model = hybrid_lm.Generator(cfg.gen)
+    data = {"tokens": jax.random.randint(jax.random.PRNGKey(2), (2, 64), 0,
+                                         cfg.gen.vocab_slice)}
+    variables = model.init(jax.random.PRNGKey(0), data)
+    rest = {k: v for k, v in variables.items() if k != "params"}
+
+    def loss(params):
+        return model.apply({**rest, "params": params}, data)["loss"]
+
+    plain = contextlib.nullcontext() if named else mock.patch.object(
+        hybrid_lm, "checkpoint_name", lambda tree, name: tree)
+    with plain:
+        sorts = _sorts(jax.make_jaxpr(jax.value_and_grad(loss))(
+            variables["params"]).jaxpr)
+    return len(sorts), jax.jit(jax.value_and_grad(loss))(variables["params"])
+
+
+def test_a_recomputed_block_sorts_once_a_step():
+    """ISSUE 40: under `remat: blocks` the step holds one sort a
+    held-expert layer, the forward pass's: the recomputed block reads the
+    kept order. Without the names (the form before) it holds two a layer,
+    and so it does under `save_nothing`, which keeps nothing and trains
+    to the same loss and gradients: the names are an optimisation."""
+    layers = hybrid_lm.layer_kinds(
+        hybrid_lm.model_settings(tiny_cfg().gen)).count("E")
+    assert layers == 2
+    sorts, (loss, grads) = _loss_sorts_and_grads("blocks")
+    assert sorts == layers
+    assert _loss_sorts_and_grads("blocks", named=False)[0] == 2 * layers
+    sorts, (loss_f, grads_f) = _loss_sorts_and_grads("save_nothing")
+    assert sorts == 2 * layers
+    assert np.isfinite(float(loss_f)) and float(loss) == float(loss_f)
+    for ours, theirs in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_f)):
+        _close(ours, theirs, tol=1e-6)
+    assert float(jnp.abs(grads_f["layer_1"]["mixer"]["router"]).max()) > 0
+    assert _loss_sorts_and_grads("none")[0] == layers
 
 
 def test_the_plain_arm_leaves_the_step_program_as_it_was():

@@ -74,6 +74,9 @@ def test_train_py_trains_checkpoints_and_resumes(entry_point_sandbox,
         assert counters[f"moe/{layer}/load_max_over_mean"] >= 1
         assert 0 < counters[f"moe/{layer}/buffer_occupancy"] <= 1
         assert 0 <= counters[f"moe/{layer}/compact"] <= 1
+        # a tier of the unit-test sizes is one segment (ISSUE 40)
+        assert counters[f"moe/{layer}/moved_rows"] == (
+            128 if counters[f"moe/{layer}/compact"] else 256)
     assert counters["xla/recompiles"] == 0
     assert counters["xla/graph_violations"] == 0
     assert "expand_labels" not in {
@@ -104,6 +107,7 @@ def test_train_py_trains_checkpoints_and_resumes(entry_point_sandbox,
 
     report = render_report(os.path.join(logdir, "telemetry.jsonl"))
     assert "## experts" in report and "perf/tokens_per_sec" in report
+    assert "| moved over held |" in report
     assert "gen_step: 0 violation(s)" in report
     assert ("attn_impl at length 64, head size 16: layer 3 blocks; fused "
             "tiles") in report

@@ -408,6 +408,21 @@ def test_report_renders_phase_table(tm_sandbox, tmp_path):
     assert not summary["hangs"]
 
 
+def _expert_counters(flushes, extra):
+    """`moe/<layer>/*` counter events of layers 10 and 3 over `flushes`
+    flushes; `extra(layer, flush, held)` adds to a layer's stats."""
+    events = []
+    for flush in range(flushes):
+        for layer, held in (("10", 5000 + flush), ("3", 900 + flush)):
+            stats = {"held_assignments": held, "load_max_over_mean": 2.5,
+                     "buffer_occupancy": held / 49152}
+            stats.update(extra(layer, flush, held))
+            events += [{"kind": "counter", "name": f"moe/{layer}/{k}",
+                        "value": v, "step": flush, "t": float(flush)}
+                       for k, v in stats.items()]
+    return events
+
+
 @pytest.mark.parametrize("compact,column", [
     ([1.0, 1.0, 0.0, 1.0], "| 75% |"), (None, "| n/a |")],
     ids=["with_compact", "from_before_the_counter"])
@@ -415,19 +430,13 @@ def test_report_renders_the_experts_table(compact, column):
     """The latest `moe/<layer>/*` counters a layer, layers in numeric
     order, and the share of the flushes' steps on the filled prefix (the
     whole series of `moe/<layer>/compact`, where a run has it)."""
-    events = []
-    for flush in range(4):
-        for layer, held in (("10", 5000 + flush), ("3", 900 + flush)):
-            stats = {"held_assignments": held, "load_max_over_mean": 2.5,
-                     "buffer_occupancy": held / 49152}
-            if compact is not None:
-                stats["compact"] = compact[flush] if layer == "3" else 1.0
-            events += [{"kind": "counter", "name": f"moe/{layer}/{k}",
-                        "value": v, "step": flush, "t": float(flush)}
-                       for k, v in stats.items()]
+    events = _expert_counters(4, lambda layer, flush, held: {} if compact
+                              is None else {"compact": compact[flush]
+                                            if layer == "3" else 1.0})
     lines = render_report(events).splitlines()
     start = lines.index("## experts")
-    assert lines[start + 1].endswith("| buffer occupancy | on the prefix |")
+    assert lines[start + 1].endswith(
+        "| buffer occupancy | moved over held | on the prefix |")
     rows = lines[start + 3:start + 5]
     assert rows[0].startswith("| 3 | 903 | 2.50 | 1.8% ")
     assert rows[0].endswith(column)
@@ -435,6 +444,32 @@ def test_report_renders_the_experts_table(compact, column):
     assert rows[1].endswith("| 100% |" if compact else "| n/a |")
     assert "## experts" not in render_report(
         [e for e in events if not e["name"].startswith("moe/")])
+
+
+@pytest.mark.parametrize("moved,columns", [
+    ({"10": 5120.0, "3": 8192.0}, ("| 1.02 |", "| 9.09 |")),
+    ({"10": 0.0, "3": 1024.0}, ("| 0.00 |", "| 1.14 |")),
+    (None, ("| n/a |", "| n/a |"))],
+    ids=["with_moved_rows", "a_layer_that_moved_nothing",
+         "from_before_the_counter"])
+def test_report_renders_moved_over_held_rows(moved, columns):
+    """ISSUE 40: the newest flush's `moe/<layer>/moved_rows` over its
+    `held_assignments`, a layer: near 1 where the movement ends with the
+    held rows, the tier over them where it does not."""
+    events = _expert_counters(2, lambda layer, flush, held: {} if moved
+                              is None else {"moved_rows": moved[layer]})
+    lines = render_report(events).splitlines()
+    rows = lines[lines.index("## experts") + 3:][:2]
+    assert rows[0].startswith("| 3 | 901 | 2.50 | 1.8% " + columns[1])
+    assert rows[1].startswith("| 10 | 5001 | 2.50 | 10.2% " + columns[0])
+    assert rows[0].endswith("| n/a |")
+
+
+def test_a_layer_that_holds_nothing_has_no_moved_share():
+    events = [{"kind": "counter", "name": f"moe/2/{k}", "value": 0.0,
+               "step": 0, "t": 0.0}
+              for k in ("held_assignments", "moved_rows")]
+    assert "| 2 | 0 | nan | nan% | n/a | n/a |" in render_report(events)
 
 
 def test_telemetry_report_cli(tm_sandbox, tmp_path):
