@@ -2,15 +2,20 @@
 attention, gated short convolutions, dense and mixture-of-experts
 feed-forwards, laid out by a pattern string (Nemotron-H,
 ``hybrid_override_pattern``: ``M`` a Mamba-2 mixer, ``*`` attention, ``-``
-a dense feed-forward, ``E`` a mixture of experts; ``K`` and ``C`` are this
-file's own letters for a Kimi Delta Attention mixer and for LFM2's gated
-short convolution). A transformer block is two letters: ``*-``, ``*E``,
-``KE``, ``C-`` or ``CE``.
+a dense feed-forward, ``E`` a mixture of experts; ``K``, ``C`` and ``W``
+are this file's own letters for a Kimi Delta Attention mixer, for LFM2's
+gated short convolution and for grouped-query attention under a sliding
+window). A transformer block is two letters: ``*-``, ``*E``, ``KE``,
+``C-``, ``CE``, ``W-`` or ``WE``.
 
 Every layer is one mixer behind a pre-norm residual,
-``h = h + Mixer(RMSNorm(h))``; logits are ``RMSNorm(h) W_head``. The
-module returns the mean next-token cross-entropy itself, over token
-chunks, so the (tokens, vocabulary) logits never stand whole.
+``h = h + Mixer(RMSNorm(h))``, and where ``use_post_norm`` says so the
+mixer's result is normed again before it is added, ``h = h +
+RMSNorm_post(Mixer(RMSNorm(h)))``, under a learned scale of its own (a
+block of two letters then has four norms); the embedding is multiplied by
+``embed_scale`` where the config gives one; logits are ``RMSNorm(h)
+W_head``. The module returns the mean next-token cross-entropy itself,
+over token chunks, so the (tokens, vocabulary) logits never stand whole.
 
 What a layer is follows from the sizes the config gives, which are the
 published ones under their published names; a size a model does not have
@@ -22,7 +27,11 @@ otherwise: without a position embedding where the config gives no
 ``rope_theta``, with the rotary turn over the whole head where it gives
 one; with queries and keys RMS-normed over the head's channels before the
 turn where ``use_qk_norm`` says so, and with a sigmoid gate on its output
-where ``use_gqa_gate`` does. ``K`` is the gated delta rule with a
+where ``use_gqa_gate`` does. ``W`` is the same grouped-query layer
+whose queries see the ``sliding_window`` keys up to their own, themselves
+counted; it always takes the rotary turn, and in a model that has both
+letters ``use_rope_on_full_attention`` false leaves the ``*`` layers
+without one under the same ``rope_theta``. ``K`` is the gated delta rule with a
 decay a key channel (``KDAMixer``; its sizes are the published
 ``linear_attn_config`` group's). ``C`` is a depthwise causal convolution
 of ``conv_L_cache`` taps between two elementwise gates
@@ -64,9 +73,10 @@ otherwise, the same arithmetic either way), and moves rows into a tier
 and out of it by segments, as far as the step's held rows reach
 (``gather_rows``, ``add_rows``; the backward pass is written out,
 ``held_experts_part_bwd``).
-The causal scores live in ``ops/attention.py``, which picks
-its own arm from what it observes: one fused Pallas kernel that keeps the
-scores in VMEM where the backend is a TPU, the head size a multiple of
+The causal scores, under a window or not, live in ``ops/attention.py``,
+which picks its own arm from what it observes: one fused Pallas kernel
+that keeps the scores in VMEM (and skips the tiles outside the band)
+where the backend is a TPU, the head size a multiple of
 128 (or 64: zero-padded to 128 inside the arm) and the length a
 multiple of the kernel's tiles; query blocks of
 ``attn_query_block`` rows in plain ``jax.numpy`` everywhere else (the
@@ -629,8 +639,12 @@ class AttentionMixer(nn.Module):
     the heads share); where there is a ``rope_theta``, both turned by
     ``rotary`` over the whole head; the causal scores at ``1/sqrt(head
     size)``; where ``use_gqa_gate``, a sigmoid gate on the result; then
-    ``W_o``."""
+    ``W_o``. ``windowed`` is the letter ``W``: a query sees the
+    ``sliding_window`` keys up to its own, the turn is always taken, and
+    the scores stand under ``lm/attn/window_scores``; the letter ``*``
+    takes the turn unless ``use_rope_on_full_attention`` is false."""
     cfg: Any
+    windowed: bool = False
 
     @nn.compact
     def __call__(self, u):
@@ -660,11 +674,16 @@ class AttentionMixer(nn.Module):
             with jax.named_scope("lm/attn/qk_norm"):
                 q, k = (rms_norm(x, scale, g.norm_eps)
                         for x, scale in zip((q, k), head_scales))
-        if g.rope_theta is not None:
+        if g.rope_theta is not None and (self.windowed
+                                         or g.use_rope_on_full_attention):
             with jax.named_scope("lm/attn/rope"):
                 q, k = rotary(q, g.rope_theta), rotary(k, g.rope_theta)
-        with jax.named_scope("lm/attn/scores"):
-            y = attention(q, k, v, g.attn_query_block)
+        if self.windowed:
+            with jax.named_scope("lm/attn/window_scores"):
+                y = attention(q, k, v, g.attn_query_block, g.sliding_window)
+        else:
+            with jax.named_scope("lm/attn/scores"):
+                y = attention(q, k, v, g.attn_query_block)
         if w_gate is not None:
             # one value a head channel (``use_gqa_gate``)
             with jax.named_scope("lm/attn/gate"):
@@ -1112,14 +1131,16 @@ class MoEMixer(nn.Module):
 # ------------------------------------------------------------------- model
 
 _MIXERS = {"M": Mamba2Mixer, "*": AttentionMixer, "K": KDAMixer,
-           "C": ShortConvMixer, "-": DenseMixer, "E": MoEMixer}
+           "C": ShortConvMixer, "-": DenseMixer, "E": MoEMixer,
+           "W": functools.partial(AttentionMixer, windowed=True)}
 # ``Settings`` field -> the key of the published ``linear_attn_config``
 # group it is read from
 _LINEAR_ATTN = {"kda_num_heads": "num_heads", "kda_head_dim": "head_dim",
                 "kda_conv_kernel": "short_conv_kernel_size"}
 # the sizes each letter of a pattern reads; ``*`` reads those of the form
 # of attention the config has the sizes of, and grouped-query attention
-# turns its heads where there is a ``rope_theta`` (none is demanded); an
+# turns its heads where there is a ``rope_theta`` (none is demanded); ``W``
+# is grouped-query attention alone, under a window, and always turns; an
 # ``E`` layer has a shared expert where the config gives
 # ``moe_shared_expert_intermediate_size``
 _LATENT = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
@@ -1132,6 +1153,8 @@ _NEEDS = {
     "K": ("kda_num_heads", "kda_head_dim", "kda_conv_kernel",
           "kda_chunk_size"),
     "C": ("conv_L_cache",),
+    "W": ("num_attention_heads", "attn_query_block", "num_key_value_heads",
+          "head_dim", "sliding_window", "rope_theta"),
     "-": ("intermediate_size",),
     "E": ("n_routed_experts", "num_experts_per_tok", "routed_scaling_factor",
           "moe_intermediate_size", "expert_buffer_rows"),
@@ -1159,7 +1182,8 @@ def layer_kinds(g):
 
 
 class Block(nn.Module):
-    """One layer of the pattern: ``h + Mixer(RMSNorm(h))``. Returns
+    """One layer of the pattern: ``h + Mixer(RMSNorm(h))`` or, where
+    ``use_post_norm``, ``h + RMSNorm_post(Mixer(RMSNorm(h)))``. Returns
     (h, stats): the expert layer's routing counts, {} for the others."""
     cfg: Any
     kind: str
@@ -1172,6 +1196,11 @@ class Block(nn.Module):
             u = rms_norm(h, scale, self.cfg.norm_eps)
         out = mixer_of(self.cfg, self.kind)(self.cfg, name="mixer")(u)
         out, stats = out if self.kind == "E" else (out, {})
+        if self.cfg.use_post_norm:
+            post_scale = self.param("post_scale", nn.initializers.ones,
+                                    (self.cfg.hidden_size,))
+            with jax.named_scope("lm/block/post_norm"):
+                out = rms_norm(out, post_scale, self.cfg.norm_eps)
         with jax.named_scope("lm/block/residual"):
             h = h + out
         return h, stats
@@ -1212,7 +1241,11 @@ class Settings:
     config's, but for the held share, the module's layers and loss weight,
     the linear-attention group's three sizes (``_LINEAR_ATTN``), the
     program's own ``use_qk_norm`` (beside the published ``use_gqa_gate``:
-    whether a grouped-query layer norms each head's queries and keys) and
+    whether a grouped-query layer norms each head's queries and keys),
+    ``use_rope_on_full_attention`` (false: the ``*`` layers of a model
+    whose ``W`` layers turn take no turn), ``use_post_norm`` (a second
+    norm, after each mixer) and ``embed_scale`` (the factor on the
+    embedding; absent, none), and
     the four bounds the program sets itself. A size the model has no layer
     for stays None (``_NEEDS``): no ``rope_theta`` is grouped-query
     attention without a position embedding, no
@@ -1242,6 +1275,10 @@ class Settings:
     head_dim: int | None = None
     use_gqa_gate: bool = False
     use_qk_norm: bool = False
+    use_rope_on_full_attention: bool = True
+    sliding_window: int | None = None
+    use_post_norm: bool = False
+    embed_scale: float | None = None
     conv_L_cache: int | None = None
     conv_bias: bool = False
     kda_num_heads: int | None = None
@@ -1306,6 +1343,10 @@ def model_settings(gen_cfg):
     if g.hidden_act not in GATED:
         raise ValueError(f"gen.hidden_act {g.hidden_act!r} is not one of "
                          f"{sorted(GATED)}")
+    if g.kv_lora_rank is not None and "W" in kinds:
+        raise ValueError(
+            "a sliding-window layer ('W') is grouped-query attention; "
+            "gen.kv_lora_rank makes the model's attention latent")
     needs = {kind: _NEEDS[kind] for kind in set(kinds)}
     if "*" in needs:
         needs["*"] += (_LATENT if g.kv_lora_rank is not None
@@ -1380,8 +1421,14 @@ class Generator(nn.Module):
                 weights.reshape(-1), g.loss_chunk_tokens)
             return total / weights.sum(), h
 
+        def embed(ids):
+            rows = embedding[ids]
+            if g.embed_scale is not None:
+                rows = rows * g.embed_scale
+            return rows.astype(dtype)
+
         with jax.named_scope("lm/embed"):
-            h = embedding[tokens].astype(dtype)
+            h = embed(tokens)
         h = layers(h, g.pattern, 0)
         with jax.named_scope("lm/head_loss"):
             out["loss"], h = head_loss(h, final_scale, 1)
@@ -1398,7 +1445,7 @@ class Generator(nn.Module):
             # last position has no such token and is given the roll's;
             # nothing reads it: attention is causal and its two targets
             # weigh nothing
-            ahead = embedding[jnp.roll(tokens, -1, axis=1)].astype(dtype)
+            ahead = embed(jnp.roll(tokens, -1, axis=1))
             merged = jnp.concatenate(
                 [rms_norm(ahead, e_scale, g.norm_eps),
                  rms_norm(h, h_scale, g.norm_eps)], -1) @ w_merge.astype(dtype)

@@ -1,5 +1,8 @@
 """Causal grouped-query attention: scale ``1/sqrt(head size)``, no
-position embedding, query head ``h`` on key-value head ``h // (Hq/Hkv)``.
+position embedding, query head ``h`` on key-value head ``h // (Hq/Hkv)``;
+with a ``window``, query ``i`` reads keys ``j`` with ``0 <= i - j <
+window`` (a sliding window that counts the query itself) and the scores
+are a band below the diagonal.
 
 One algorithm, two arms, and ``attention`` picks between them from what
 it can observe (``arm_of``), with no option:
@@ -8,16 +11,19 @@ it can observe (``arm_of``), with no option:
               (``ops/pallas/causal_attention_kernel.py``): the scores of a
               tile stand in VMEM, the row maximum and row sum run along
               the key tiles in float32, scores and probabilities never
-              reach HBM, and the tiles above the diagonal are neither
-              computed nor fetched. Where the backend is a TPU, the head
+              reach HBM, and the tiles above the diagonal, and under a
+              window those wholly below the band, are neither computed
+              nor fetched (``visited_tiles`` counts the rest). Where the
+              backend is a TPU, the head
               size a multiple of 128 or half of 128 (64: zero columns
               fill the lane tile, the kernel then runs at 128 under the
               scale of 64, which is exact, and the matrix unit's 128
               lanes were the head's to fill either way) and the length
               a multiple of the kernel's largest tile.
   ``blocks``  ``causal_attention``: query blocks in plain ``jax.numpy``,
-              each against the keys up to its own end, the (Hq, block,
-              keys) scores of one block in HBM at a time. Everywhere
+              each against the keys up to its own end (from its first
+              row's window on, under a window), the (Hq, block, keys)
+              scores of one block in HBM at a time. Everywhere
               else: the CPU, where the tests run, ragged lengths and
               every other head size (none has been run padded).
 
@@ -81,49 +87,83 @@ def arm_of(head_dim, length):
     return "fused" if on_tpu and fits else "blocks"
 
 
-def attention(q, k, v, block):
+def effective_window(window, length):
+    """``window`` where it hides a key from a query of a sequence of
+    ``length``, None where it does not (none given, or as long as the
+    sequence): such a call is the causal one, to the bit."""
+    return None if window is None or window >= length else int(window)
+
+
+def attention(q, k, v, block, window=None):
     """``q`` (B, L, Hq, d), ``k``, ``v`` (B, L, Hkv, d) to (B, L, Hq*d).
     ``block`` is the plain arm's query block; the fused arm's tiles are
-    ``TILES``."""
+    ``TILES``. ``window``: the keys a query sees, itself counted; None
+    for every key up to its own."""
     if arm_of(q.shape[-1], q.shape[1]) == "fused":
-        return fused_causal_attention(q, k, v)
-    return causal_attention(q, k, v, block)
+        return fused_causal_attention(q, k, v, TILES, False, window)
+    return causal_attention(q, k, v, block, window)
 
 
-def causal_attention(q, k, v, block):
+def visited_tiles(length, window=None, tiles=TILES):
+    """{pass: (tiles the fused kernel computes for one head, tiles on or
+    below the diagonal)} at ``length`` under ``window``, from the
+    kernel's own index arithmetic."""
+    window = effective_window(window, length)
+    sweeps = {"fwd": kernel.query_sweep_tiles, "dq": kernel.query_sweep_tiles,
+              "dkv": kernel.key_sweep_tiles}
+    return {name: (len(sweep(length, *getattr(tiles, name), window)),
+                   len(sweep(length, *getattr(tiles, name), None)))
+            for name, sweep in sweeps.items()}
+
+
+def causal_attention(q, k, v, block, window=None):
     """Causal grouped-query attention, scale ``1/sqrt(head size)``, no
     position embedding. ``q`` (B, L, Hq, d), ``k``, ``v`` (B, L, Hkv, d);
     query head ``h`` reads key-value head ``h // (Hq/Hkv)``. Query blocks
-    of ``block`` rows, each against the keys up to its own end, each
-    under ``jax.checkpoint``: the (Hq, block, keys) scores of one block
-    stand at a time, in float32."""
+    of ``block`` rows, each against the keys up to its own end (and, under
+    a ``window``, from the first key its first row sees), each under
+    ``jax.checkpoint``: the (Hq, block, keys) scores of one block stand
+    at a time, in float32."""
     bsz, length, q_heads, dim = q.shape
     kv_heads = k.shape[2]
     q = q.reshape(bsz, length, kv_heads, q_heads // kv_heads, dim)
     scale = 1.0 / math.sqrt(dim)
+    window = effective_window(window, length)
 
     @jax.checkpoint
-    def one(qb, kb, vb, start):
+    def one(qb, kb, vb, start, first=None):
         s = jnp.einsum("bqgrd,bkgd->bgrqk", qb, kb,
                        preferred_element_type=jnp.float32) * scale
         rows = start + jnp.arange(qb.shape[1])[:, None]
-        s = jnp.where(rows >= jnp.arange(kb.shape[1])[None, :], s, -jnp.inf)
+        cols = jnp.arange(kb.shape[1])[None, :]
+        if first is None:
+            keep = rows >= cols
+        else:       # the keys from ``first`` on, and the band's lower edge
+            cols = first + cols
+            keep = (rows >= cols) & (rows - cols < window)
+        s = jnp.where(keep, s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1).astype(vb.dtype)
         return jnp.einsum("bgrqk,bkgd->bqgrd", p, vb)
 
     outs = []
     for start in range(0, length, block):
         end = min(start + block, length)
-        outs.append(one(q[:, start:end], k[:, :end], v[:, :end], start))
+        if window is None:
+            outs.append(one(q[:, start:end], k[:, :end], v[:, :end], start))
+            continue
+        first = max(start - window + 1, 0)
+        outs.append(one(q[:, start:end], k[:, first:end], v[:, first:end],
+                        start, first))
     out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
     return out.reshape(bsz, length, q_heads * dim)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def fused_causal_attention(q, k, v, tiles=TILES, interpret=False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def fused_causal_attention(q, k, v, tiles=TILES, interpret=False,
+                           window=None):
     """``causal_attention`` by the fused kernel. ``interpret`` runs the
     kernel in Pallas's interpreter (the CPU tests)."""
-    return _fused_fwd(q, k, v, tiles, interpret)[0]
+    return _fused_fwd(q, k, v, tiles, interpret, window)[0]
 
 
 def residual_bytes(bsz, length, q_heads, head_dim, dtype):
@@ -153,10 +193,11 @@ def _scale(q):
     return 1.0 / math.sqrt(q.shape[-1])
 
 
-def _fused_fwd(q, k, v, tiles, interpret):
+def _fused_fwd(q, k, v, tiles, interpret, window=None):
+    window = effective_window(window, q.shape[1])
     out, lse = kernel.forward(_flat(q), _flat(k), _flat(v), q.shape[2],
                               k.shape[2], *tiles.fwd, interpret=interpret,
-                              scale=_scale(q))
+                              scale=_scale(q), window=window)
     out = _heads(out, q).reshape(*q.shape[:2], -1)
     # named before ``out`` is returned too: the product with ``W_o`` after
     # it reads ``out`` for its own gradient, from the kept array
@@ -165,8 +206,9 @@ def _fused_fwd(q, k, v, tiles, interpret):
     return out, (q, k, v, out, lse)
 
 
-def _fused_bwd(tiles, interpret, saved, do):
+def _fused_bwd(tiles, interpret, window, saved, do):
     q, k, v, out, lse = saved
+    window = effective_window(window, q.shape[1])
     heads = q.shape[2], k.shape[2]
     # the rows' sum(do * out), (B, Hq, L) as the log-sum-exp
     di = (do.astype(jnp.float32) * out.astype(jnp.float32)).reshape(
@@ -174,9 +216,11 @@ def _fused_bwd(tiles, interpret, saved, do):
     operands = (_flat(q), _flat(k), _flat(v), _flat(do.reshape(q.shape)),
                 lse, di)
     dk, dv = kernel.backward_dkv(*operands, *heads, *tiles.dkv,
-                                 interpret=interpret, scale=_scale(q))
+                                 interpret=interpret, scale=_scale(q),
+                                 window=window)
     dq = kernel.backward_dq(*operands, *heads, *tiles.dq,
-                            interpret=interpret, scale=_scale(q))
+                            interpret=interpret, scale=_scale(q),
+                            window=window)
     return _heads(dq, q), _heads(dk, k), _heads(dv, v)
 
 

@@ -17,7 +17,22 @@ scores nor probabilities are ever written to HBM.
 
 A tile wholly above the diagonal is not computed and not fetched (its
 index map names the tile already held); one wholly below it skips the
-mask. Every tile on or below the diagonal is computed.
+mask. Without a window every tile on or below the diagonal is computed.
+
+``window`` (static; None for none) is the number of keys a query sees,
+itself counted: query ``i`` reads keys ``j`` with ``0 <= i - j < window``.
+The scores are then a band, and a tile wholly below the band is as far
+from the work as one above the diagonal: the innermost grid axis is only
+as long as the most tiles the band crosses in one sweep, it starts at the
+first tile the sweep reads (``_first_kv``, ``_first_q``) and its index map
+clamps at the last (``_last_kv``, ``_last_q``), so no such tile is a grid
+step, computed or fetched. A tile the band's lower edge crosses takes the
+mask as one the diagonal crosses does (both edges in one comparison
+pair). A row whose keys in the sweep's first tile are all masked leaves
+that tile with the finite ``MASK_VALUE`` as its maximum; the next tile's
+``exp(MASK_VALUE - m)`` is 0 and wipes what it summed. ``query_sweep_tiles``
+and ``key_sweep_tiles`` list the tiles a pass computes, from the same
+functions.
 
 Layouts are the model's own: ``q`` (B, L, Hq*d), ``k``, ``v`` (B, L,
 Hkv*d), head ``h`` the ``d`` columns from ``h*d``; query head ``h`` reads
@@ -30,6 +45,7 @@ tile keeps the scale of the head it was.
 
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
@@ -59,20 +75,75 @@ def _first_q(j, bq, bkv):
     return (j * bkv) // bq
 
 
+def _first_kv(i, bq, bkv, window):
+    """The first key tile a row of query tile ``i`` reads under a
+    window: its first row's ``window - 1`` keys back."""
+    if isinstance(i, int):
+        return max(i * bq - window + 1, 0) // bkv
+    return jnp.maximum(i * bq - window + 1, 0) // bkv
+
+
+def _last_q(j, bq, bkv, window, q_tiles):
+    """The last query tile a row of key tile ``j`` is read by under a
+    window: its last key's ``window - 1`` queries on."""
+    last = ((j + 1) * bkv + window - 2) // bq
+    if isinstance(j, int):
+        return min(last, q_tiles - 1)
+    return jnp.minimum(last, q_tiles - 1)
+
+
 def _crosses_diagonal(i, j, bq, bkv):
     """Whether tile (i, j) holds a key after one of its queries."""
     return (j + 1) * bkv - 1 > i * bq
 
 
-def _scores(a_ref, b_ref, scale, row0, col0, masked, keys_in_rows):
-    """``a b^T * scale`` in float32, masked to the causal part where the
-    tile crosses the diagonal."""
+def _crosses_edges(i, j, bq, bkv, window):
+    """Whether tile (i, j) needs a mask: it holds a key after one of its
+    queries or, under a window, a key ``window`` or more before one."""
+    crosses = _crosses_diagonal(i, j, bq, bkv)
+    if window is not None:
+        crosses |= (i + 1) * bq - 1 - j * bkv >= window
+    return crosses
+
+
+def query_sweep_tiles(length, bq, bkv, window):
+    """[(query tile, key tile)] that the forward and the dQ pass compute
+    for one head; ``window`` None is every tile on or below the
+    diagonal."""
+    return [(i, j) for i in range(length // bq)
+            for j in range(0 if window is None
+                           else _first_kv(i, bq, bkv, window),
+                           _last_kv(i, bq, bkv) + 1)]
+
+
+def key_sweep_tiles(length, bq, bkv, window):
+    """[(query tile, key tile)] that the dK/dV pass computes for one
+    query head."""
+    q_tiles = length // bq
+    return [(i, j) for j in range(length // bkv)
+            for i in range(_first_q(j, bq, bkv),
+                           (q_tiles if window is None else
+                            _last_q(j, bq, bkv, window, q_tiles) + 1))]
+
+
+def _span(tiles, axis):
+    """The most tiles of ``tiles`` that share their index on ``axis``:
+    the length of a windowed sweep's innermost grid axis."""
+    return max(collections.Counter(tile[axis] for tile in tiles).values())
+
+
+def _scores(a_ref, b_ref, scale, row0, col0, masked, keys_in_rows,
+            window=None):
+    """``a b^T * scale`` in float32, masked to the causal part (the band,
+    under a window) where the tile crosses an edge of it."""
     s = lax.dot_general(a_ref[...], b_ref[...], _NT,
                         preferred_element_type=jnp.float32) * scale
     if masked:
         rows = row0 + lax.broadcasted_iota(jnp.int32, s.shape, 0)
         cols = col0 + lax.broadcasted_iota(jnp.int32, s.shape, 1)
         keep = cols >= rows if keys_in_rows else rows >= cols
+        if window is not None:
+            keep &= (cols - rows if keys_in_rows else rows - cols) < window
         s = jnp.where(keep, s, MASK_VALUE)
     return s
 
@@ -85,20 +156,27 @@ def _sizes(q, q_heads, kv_heads, scale=None):
             float(scale or 1.0 / np.sqrt(head_dim)))
 
 
-def _query_sweep(q, q_heads, kv_heads, bq, bkv):
+def _query_sweep(q, q_heads, kv_heads, bq, bkv, window):
     """What the forward and the dQ pass share: a grid (batch, query head,
     query tile, key tile), the key tiles innermost, and the block specs
-    of a query tile, a key tile and a (1, bq) row of statistics."""
+    of a query tile, a key tile and a (1, bq) row of statistics. Under a
+    window the innermost axis counts from the first key tile the query
+    tile reads and is as long as the band's widest sweep."""
     bsz, length, _ = q.shape
     head_dim, group, _ = _sizes(q, q_heads, kv_heads)
+    kv_steps = length // bkv
+    if window is not None:
+        kv_steps = _span(query_sweep_tiles(length, bq, bkv, window), 0)
 
     def q_map(b, h, i, j):
         return b, i, h
 
     def kv_map(b, h, i, j):
+        if window is not None:
+            j = j + _first_kv(i, bq, bkv, window)
         return b, jnp.minimum(j, _last_kv(i, bq, bkv)), h // group
 
-    return ((bsz, q_heads, length // bq, length // bkv),
+    return ((bsz, q_heads, length // bq, kv_steps),
             pl.BlockSpec((None, bq, head_dim), q_map),
             pl.BlockSpec((None, bkv, head_dim), kv_map),
             pl.BlockSpec((None, None, 1, bq), lambda b, h, i, j: (b, h, 0, i)))
@@ -115,7 +193,7 @@ def _on_tiles(needed, crosses, tile):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                *, scale, bq, bkv):
+                *, scale, bq, bkv, window):
     i, j = pl.program_id(2), pl.program_id(3)
     last = _last_kv(i, bq, bkv)
     head_dim = acc_ref.shape[-1]
@@ -126,8 +204,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    if window is not None:      # the grid's step to the key tile it is at
+        j = j + _first_kv(i, bq, bkv, window)
+
     def tile(masked):
-        s = _scores(q_ref, k_ref, scale, i * bq, j * bkv, masked, False)
+        s = _scores(q_ref, k_ref, scale, i * bq, j * bkv, masked, False,
+                    window)
         m_prev, l_prev = m_ref[...], l_ref[...]
         m_next = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - jnp.tile(m_next, (1, bkv // LANES)))
@@ -139,7 +221,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         acc_ref[...] = (acc_ref[...] * jnp.tile(alpha, (1, head_dim // LANES))
                         + pv)
 
-    _on_tiles(j <= last, _crosses_diagonal(i, j, bq, bkv), tile)
+    _on_tiles(j <= last, _crosses_edges(i, j, bq, bkv, window), tile)
 
     @pl.when(j == last)
     def _():
@@ -150,13 +232,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
 
 
 def forward(q, k, v, q_heads, kv_heads, bq, bkv, interpret=False,
-            scale=None):
+            scale=None, window=None):
     """(out (B, L, Hq*d) in ``q``'s dtype, lse (B, Hq, L) float32)."""
     bsz, length, _ = q.shape
     head_dim, _, scale = _sizes(q, q_heads, kv_heads, scale)
-    grid, q_spec, kv_spec, _ = _query_sweep(q, q_heads, kv_heads, bq, bkv)
+    grid, q_spec, kv_spec, _ = _query_sweep(q, q_heads, kv_heads, bq, bkv,
+                                            window)
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, bq=bq, bkv=bkv),
+        functools.partial(_fwd_kernel, scale=scale, bq=bq, bkv=bkv,
+                          window=window),
         grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec,
@@ -182,7 +266,7 @@ def forward(q, k, v, q_heads, kv_heads, bq, bkv, interpret=False,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, acc_ref,
-               *, scale, bq, bkv):
+               *, scale, bq, bkv, window):
     i, j = pl.program_id(2), pl.program_id(3)
     last = _last_kv(i, bq, bkv)
 
@@ -190,8 +274,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, acc_ref,
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    if window is not None:
+        j = j + _first_kv(i, bq, bkv, window)
+
     def tile(masked):
-        s = _scores(q_ref, k_ref, scale, i * bq, j * bkv, masked, False)
+        s = _scores(q_ref, k_ref, scale, i * bq, j * bkv, masked, False,
+                    window)
         p = jnp.exp(s - jnp.expand_dims(lse_ref[0], -1))
         dp = lax.dot_general(do_ref[...], v_ref[...], _NT,
                              preferred_element_type=jnp.float32)
@@ -199,7 +287,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, acc_ref,
         acc_ref[...] += jnp.dot(ds.astype(k_ref.dtype), k_ref[...],
                                 preferred_element_type=jnp.float32)
 
-    _on_tiles(j <= last, _crosses_diagonal(i, j, bq, bkv), tile)
+    _on_tiles(j <= last, _crosses_edges(i, j, bq, bkv, window), tile)
 
     @pl.when(j == last)
     def _():
@@ -207,14 +295,15 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, acc_ref,
 
 
 def backward_dq(q, k, v, do, lse, di, q_heads, kv_heads, bq, bkv,
-                interpret=False, scale=None):
+                interpret=False, scale=None, window=None):
     """The queries' gradient, (B, L, Hq*d). ``lse``, ``di`` (B, Hq, L)
     float32: the rows' log-sum-exp and ``sum(do * out)``."""
     head_dim, _, scale = _sizes(q, q_heads, kv_heads, scale)
     grid, q_spec, kv_spec, row_spec = _query_sweep(q, q_heads, kv_heads,
-                                                   bq, bkv)
+                                                   bq, bkv, window)
     return pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, bq=bq, bkv=bkv),
+        functools.partial(_dq_kernel, scale=scale, bq=bq, bkv=bkv,
+                          window=window),
         grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
@@ -230,7 +319,8 @@ def backward_dq(q, k, v, do, lse, di, q_heads, kv_heads, bq, bkv,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
-                dk_acc, dv_acc, *, scale, bq, bkv, group, q_tiles):
+                dk_acc, dv_acc, *, scale, bq, bkv, group, q_tiles, q_steps,
+                window):
     j, r, i = pl.program_id(2), pl.program_id(3), pl.program_id(4)
 
     @pl.when((r == 0) & (i == 0))
@@ -238,10 +328,17 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
+    at_end = (r == group - 1) & (i == q_steps - 1)
+    needed = i >= _first_q(j, bq, bkv)
+    if window is not None:      # the grid's step to the query tile it is at
+        i = i + _first_q(j, bq, bkv)
+        needed = i <= _last_q(j, bq, bkv, window, q_tiles)
+
     def tile(masked):
         # keys in rows, queries in lanes: the rows' statistics broadcast
         # down the sublanes and no product needs a transposed operand
-        s = _scores(k_ref, q_ref, scale, j * bkv, i * bq, masked, True)
+        s = _scores(k_ref, q_ref, scale, j * bkv, i * bq, masked, True,
+                    window)
         p = jnp.exp(s - lse_ref[...])
         do = do_ref[...]
         dv_acc[...] += jnp.dot(p.astype(do.dtype), do,
@@ -252,23 +349,33 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
         dk_acc[...] += jnp.dot(ds.astype(q_ref.dtype), q_ref[...],
                                preferred_element_type=jnp.float32)
 
-    _on_tiles(i >= _first_q(j, bq, bkv), _crosses_diagonal(i, j, bq, bkv),
-              tile)
+    _on_tiles(needed, _crosses_edges(i, j, bq, bkv, window), tile)
 
-    @pl.when((r == group - 1) & (i == q_tiles - 1))
+    @pl.when(at_end)
     def _():
         dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def backward_dkv(q, k, v, do, lse, di, q_heads, kv_heads, bq, bkv,
-                 interpret=False, scale=None):
-    """The keys' and the values' gradients, (B, L, Hkv*d) each."""
+def _key_sweep(q, q_heads, kv_heads, bq, bkv, window):
+    """The dK/dV pass's grid (batch, key-value head, key tile, query head
+    of the group, query tile), the query tiles innermost, its steps along
+    that axis, and the block specs of a query tile, a key tile and a
+    (1, bq) row of statistics. Under a window the innermost axis counts
+    from the first query tile that reads the key tile and is as long as
+    the band's tallest sweep."""
     bsz, length, _ = q.shape
-    head_dim, group, scale = _sizes(q, q_heads, kv_heads, scale)
+    head_dim, group, _ = _sizes(q, q_heads, kv_heads)
+    q_tiles = q_steps = length // bq
+    if window is not None:
+        q_steps = _span(key_sweep_tiles(length, bq, bkv, window), 1)
 
     def first_q(i, j):
-        # a tile above the diagonal names the first one that is not
+        # a tile above the diagonal names the first one that is not; one
+        # below the band the last that is in it
+        if window is not None:
+            return jnp.minimum(i + _first_q(j, bq, bkv),
+                               _last_q(j, bq, bkv, window, q_tiles))
         return jnp.maximum(i, _first_q(j, bq, bkv))
 
     def q_map(b, g, j, r, i):
@@ -280,13 +387,24 @@ def backward_dkv(q, k, v, do, lse, di, q_heads, kv_heads, bq, bkv,
     def row_map(b, g, j, r, i):
         return b, g * group + r, 0, first_q(i, j)
 
-    q_spec = pl.BlockSpec((None, bq, head_dim), q_map)
-    kv_spec = pl.BlockSpec((None, bkv, head_dim), kv_map)
-    row_spec = pl.BlockSpec((None, None, 1, bq), row_map)
+    return ((bsz, kv_heads, length // bkv, group, q_steps), q_steps,
+            pl.BlockSpec((None, bq, head_dim), q_map),
+            pl.BlockSpec((None, bkv, head_dim), kv_map),
+            pl.BlockSpec((None, None, 1, bq), row_map))
+
+
+def backward_dkv(q, k, v, do, lse, di, q_heads, kv_heads, bq, bkv,
+                 interpret=False, scale=None, window=None):
+    """The keys' and the values' gradients, (B, L, Hkv*d) each."""
+    length = q.shape[1]
+    head_dim, group, scale = _sizes(q, q_heads, kv_heads, scale)
+    grid, q_steps, q_spec, kv_spec, row_spec = _key_sweep(
+        q, q_heads, kv_heads, bq, bkv, window)
     return pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, bq=bq, bkv=bkv,
-                          group=group, q_tiles=length // bq),
-        grid=(bsz, kv_heads, length // bkv, group, length // bq),
+                          group=group, q_tiles=length // bq, q_steps=q_steps,
+                          window=window),
+        grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[kv_spec, kv_spec],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),

@@ -998,6 +998,22 @@ def render_serving_report(path_or_events):
     return "\n".join(lines)
 
 
+def _window_note(attn, layer):
+    """What the ``attn_impl`` line says after a layer's arm: its window
+    and, where the meta counts them, the tiles each pass of the kernel
+    visits over those on or below the diagonal; nothing for a layer
+    without a window."""
+    window = (attn.get("windows") or {}).get(layer)
+    if window is None:
+        return ""
+    visited = (attn.get("visited_tiles") or {}).get(layer)
+    if not visited:
+        return f" (window {window})"
+    return f" (window {window}: " + ", ".join(
+        f"{name} {done} of {below}"
+        for name, (done, below) in visited.items()) + " tiles a head)"
+
+
 def _experts_section(s):
     """Routing of a token model's expert layers: the latest
     ``moe/<layer>/*`` counters (``trainers/lm.py``'s flush hook), the
@@ -1087,7 +1103,8 @@ def render_report(path_or_events):
             f"- attn_impl at length {attn.get('length')}"
             + (f", head size {attn['head_dim']}" if "head_dim" in attn
                else "") + ": "
-            + ", ".join(f"layer {i} {arm}" for i, arm in sorted(
+            + ", ".join(f"layer {i} {arm}" + _window_note(attn, i)
+                        for i, arm in sorted(
                 layers.items(), key=lambda kv: int(kv[0])))
             + "; fused "
             + (f"at head size {attn['kernel_head_dim']} (zero-padded), "

@@ -41,20 +41,34 @@ def attn_impl(gen_cfg, tokens_shape):
     keeps of the kernel's forward pass for its backward passes (the
     output and the log-sum-exp: ``gen.remat``'s policy decides; 0 where
     the backward pass runs the forward kernel again, and on the plain
-    arm, which has no kernel)."""
+    arm, which has no kernel). A model with sliding-window layers adds
+    each such layer's ``windows`` entry (the keys a query sees) and
+    ``visited_tiles`` where the tiles divide the length: for each pass
+    of the kernel, the tiles a head's sweep computes under the window
+    over those on or below the diagonal, whichever arm the layer takes
+    on this backend."""
     bsz, length = (int(n) for n in tokens_shape)
     g = hybrid_lm.model_settings(gen_cfg)
     head_dim = hybrid_lm.attention_head_dim(g)
+    kinds = dict(enumerate(hybrid_lm.layer_kinds(g)))
     arms = {str(i): attention.arm_of(head_dim, length)
-            for i, kind in enumerate(hybrid_lm.layer_kinds(g)) if kind == "*"}
+            for i, kind in kinds.items() if kind in "*W"}
     keeps = resolve_policy(g.remat).keeps_kernel_residuals
     a_layer = attention.residual_bytes(
         bsz, length, g.num_attention_heads, head_dim, g.compute_dtype)
-    return dict(length=length, head_dim=head_dim,
+    meta = dict(length=length, head_dim=head_dim,
                 kernel_head_dim=attention.kernel_head_dim(head_dim),
                 tiles=attention.TILES._asdict(), layers=arms,
                 kept_bytes={i: a_layer if keeps and arm == "fused" else 0
                             for i, arm in arms.items()})
+    windowed = [str(i) for i, kind in kinds.items() if kind == "W"]
+    if windowed:
+        meta["windows"] = dict.fromkeys(windowed, g.sliding_window)
+    if windowed and length % attention.TILES.largest == 0:
+        visited = {name: list(counts) for name, counts in
+                   attention.visited_tiles(length, g.sliding_window).items()}
+        meta["visited_tiles"] = dict.fromkeys(windowed, visited)
+    return meta
 
 
 def kda_impl(gen_cfg):

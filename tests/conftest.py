@@ -99,8 +99,37 @@ _SUPERSEDED = {
 }
 
 
+# The tier-1 command hands whole files to six workers (`--dist
+# loadfile`), and xdist by default queues the files by their NUMBER of
+# tests, most first: the six per-config step sweeps (one to five tests,
+# 330 to 650 s each) came last, one worker ended up holding two of them,
+# and the run's last 400 s kept one core busy (junit times and the
+# scheduler replayed, PR 41: 1,557 s by count, 1,323 s in collection
+# order, 1,230 s with these first; the sum of all files over six workers
+# is 1,197 s). So the queue keeps the collection's order, and the files
+# that take minutes stand first in it, longest first. A file is missing
+# from the list at no cost but its place.
+_LONGEST_FILES = (
+    "test_config_steps_funit.py", "test_config_steps_munit.py",
+    "test_config_steps_wc_vid2vid.py", "test_config_steps_image.py",
+    "test_config_steps_fs_vid2vid.py", "test_chip_smoke.py",
+    "test_hybrid_lm_layers.py", "test_config_steps_vid2vid.py",
+    "test_bench_serve_rehearsal.py", "test_hybrid_lm_trainer.py",
+    "test_tpu_compile.py", "test_resilience.py", "test_flownet2.py",
+    "test_models.py", "test_step_scopes.py",
+)
+
+
+def pytest_configure(config):
+    config.option.loadscopereorder = False
+
+
 def pytest_collection_modifyitems(config, items):
     for item in items:
         reason = _SUPERSEDED.get(item.nodeid)
         if reason:
             item.add_marker(pytest.mark.skip(reason=reason))
+    place = {name: i for i, name in enumerate(_LONGEST_FILES)}
+    # stable: a file's tests, and the other files, keep their order
+    items.sort(key=lambda item: place.get(
+        os.path.basename(item.nodeid.split("::")[0]), len(place)))

@@ -770,14 +770,15 @@ def test_moved_rows_counts_the_segments_that_ran(held, moved):
         (8192, 65536), jnp.float32(held))) == moved
 
 
-def _loss_sorts_and_grads(remat, named=True):
+def _loss_sorts_and_grads(remat, named=True, values=True):
     """The tiny model's loss and gradients under a remat policy: how
-    many sorts the differentiated program holds, and the values."""
+    many sorts the differentiated program holds, and the values (None
+    where only the count is asked for: nothing is compiled then)."""
     cfg = tiny_cfg(remat=remat)
     model = hybrid_lm.Generator(cfg.gen)
     data = {"tokens": jax.random.randint(jax.random.PRNGKey(2), (2, 64), 0,
                                          cfg.gen.vocab_slice)}
-    variables = model.init(jax.random.PRNGKey(0), data)
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0), data)
     rest = {k: v for k, v in variables.items() if k != "params"}
 
     def loss(params):
@@ -788,7 +789,8 @@ def _loss_sorts_and_grads(remat, named=True):
     with plain:
         sorts = _sorts(jax.make_jaxpr(jax.value_and_grad(loss))(
             variables["params"]).jaxpr)
-    return len(sorts), jax.jit(jax.value_and_grad(loss))(variables["params"])
+    return len(sorts), (jax.jit(jax.value_and_grad(loss))(variables["params"])
+                        if values else None)
 
 
 def test_a_recomputed_block_sorts_once_a_step():
@@ -802,14 +804,15 @@ def test_a_recomputed_block_sorts_once_a_step():
     assert layers == 2
     sorts, (loss, grads) = _loss_sorts_and_grads("blocks")
     assert sorts == layers
-    assert _loss_sorts_and_grads("blocks", named=False)[0] == 2 * layers
+    assert _loss_sorts_and_grads("blocks", named=False,
+                                 values=False)[0] == 2 * layers
     sorts, (loss_f, grads_f) = _loss_sorts_and_grads("save_nothing")
     assert sorts == 2 * layers
     assert np.isfinite(float(loss_f)) and float(loss) == float(loss_f)
     for ours, theirs in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_f)):
         _close(ours, theirs, tol=1e-6)
     assert float(jnp.abs(grads_f["layer_1"]["mixer"]["router"]).max()) > 0
-    assert _loss_sorts_and_grads("none")[0] == layers
+    assert _loss_sorts_and_grads("none", values=False)[0] == layers
 
 
 def test_the_plain_arm_leaves_the_step_program_as_it_was():

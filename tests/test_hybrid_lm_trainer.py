@@ -6,6 +6,8 @@ delta-rule preset: ISSUE 34), an overfull
 expert buffer failing the step's health flag, and a token batch passing
 the feed's index-map rule untouched."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -92,51 +94,70 @@ def test_the_module_predicts_the_token_after_the_next():
     tokens = jnp.asarray(_tokens(cfg))
     net = hybrid_lm.Generator(cfg.gen, cfg.data)
 
-    def ours(train, name):
-        return net.apply({"params": unflatten(train),
-                          "buffers": unflatten(buffers)},
-                         {"tokens": tokens})[name]
+    def ours(train, tokens):
+        out = net.apply({"params": unflatten(train),
+                         "buffers": unflatten(buffers)}, {"tokens": tokens})
+        return out["loss"], out["mtp_loss"]
 
-    def theirs(train, which):
-        return reference.losses(train, buffers, sizes, tokens)[which]
+    def theirs(train, tokens):
+        return reference.losses(train, buffers, sizes, tokens)[:2]
 
-    for name, which in (("loss", 0), ("mtp_loss", 1)):
-        l_ours, g_ours = jax.jit(jax.value_and_grad(
-            ours), static_argnums=1)(train, name)
-        l_theirs, g_theirs = jax.jit(jax.value_and_grad(
-            theirs), static_argnums=1)(train, which)
+    def each_with_its_gradient(fn):
+        """((loss, its gradient), (the module's loss, its gradient)) in
+        one compiled program."""
+        def run(train, tokens):
+            losses, vjp = jax.vjp(lambda train: fn(train, tokens), train)
+            return [(loss, vjp(tuple(jnp.float32(i == which)
+                                     for i in range(2)))[0])
+                    for which, loss in enumerate(losses)]
+        return jax.jit(run)
+
+    ours_both = each_with_its_gradient(ours)
+    found = dict(zip(("loss", "mtp_loss"), zip(
+        ours_both(train, tokens),
+        each_with_its_gradient(theirs)(train, tokens))))
+    for name, ((l_ours, g_ours), (l_theirs, g_theirs)) in found.items():
         assert abs(float(l_ours) - float(l_theirs)) < 1e-5 * float(l_theirs)
         assert _worst(g_ours, g_theirs) < 1e-4
         for shared in ("embedding", "head"):
             assert float(jnp.abs(g_ours[shared]).max()) > 0
     # the module's own layers see only its loss
-    assert float(jnp.abs(jax.grad(ours)(train, "loss")[
+    assert float(jnp.abs(found["loss"][0][1][
         "layer_6/mixer/o_proj"]).max()) == 0
     # the last token is nobody's input in the module (position L - 2's
     # next token, whose own target is past the end), only position L - 3's
     # target: moving it moves the module's loss and leaves every logit
     moved = tokens.at[:, -1].set((tokens[:, -1] + 1) % cfg.gen.vocab_slice)
-    apply = jax.jit(ours, static_argnums=1)
-    assert float(apply(train, "mtp_loss")) != float(net.apply(
-        {"params": unflatten(train), "buffers": unflatten(buffers)},
-        {"tokens": moved})["mtp_loss"])
+    assert float(found["mtp_loss"][0][0]) != float(
+        ours_both(train, moved)[1][0])
 
 
-@pytest.mark.parametrize("preset,steps", [
-    *((preset, 2) for preset in PRESETS), ("lfm2_moe", 3)])
-def test_trainer_steps_follow_the_reference_adam(preset, steps):
-    """Two `gen_update` steps at a batch of 2 against the reference's Adam
-    steps; three for the short-convolution preset, as many as the
-    benchmark's cell checks (ISSUE 39)."""
+STEP_CASES = [*((preset, 2) for preset in PRESETS), ("lfm2_moe", 3)]
+
+
+@functools.lru_cache(maxsize=None)
+def _stepped(preset, steps):
+    """One trainer and one reference step program a preset, built once
+    for all of its cases: `steps` `gen_update` steps at a batch of 2
+    beside the reference's Adam steps, with each side's loss and
+    parameters kept after every step."""
+    from benchmark.lib import program
+
     cfg = tiny_cfg(preset)
     reference, sizes, train, buffers = seeded(cfg, 11, preset)
     trainer, data = _trainer(cfg, train, buffers)
     assert trainer.net_D is None and trainer.tx_D is None
     assert "opt_D" not in trainer.state and trainer.dis_update(data) is None
     assert data["tokens"].shape[0] == 2
-    losses = [float(trainer.gen_update(data)["total"]) for _ in range(steps)]
 
-    from benchmark.lib import program
+    def host(tree):
+        return {k: np.array(v, copy=True) for k, v in tree.items()}
+
+    ours = []
+    for _ in range(steps):
+        loss = float(trainer.gen_update(data)["total"])
+        ours.append((loss, host(program.flatten(
+            trainer.state["vars_G"]["params"]))))
 
     @jax.jit
     def step(train, mu, nu, count):
@@ -148,18 +169,28 @@ def test_trainer_steps_follow_the_reference_adam(preset, steps):
 
     mu = {k: jnp.zeros_like(v) for k, v in train.items()}
     nu = dict(mu)
-    ref_losses = []
+    theirs = []
     for count in range(steps):
         loss, train, mu, nu = step(train, mu, nu, count)
-        ref_losses.append(float(loss))
-    np.testing.assert_allclose(losses, ref_losses, rtol=1e-5)
-    ours = program.flatten(trainer.state["vars_G"]["params"])
-    assert _worst(ours, train) < 1e-5
+        theirs.append((float(loss), host(train)))
+    after = host(program.flatten(trainer.state["vars_G"]["buffers"]))
+    return ours, theirs, after, host(buffers)
+
+
+@pytest.mark.parametrize("preset,steps", STEP_CASES)
+def test_trainer_steps_follow_the_reference_adam(preset, steps):
+    """Two `gen_update` steps at a batch of 2 against the reference's Adam
+    steps; three for the short-convolution preset, as many as the
+    benchmark's cell checks (ISSUE 39)."""
+    longest = max([steps] + [n for name, n in STEP_CASES if name == preset])
+    ours, theirs, buffers_after, buffers = _stepped(preset, longest)
+    np.testing.assert_allclose([loss for loss, _ in ours[:steps]],
+                               [loss for loss, _ in theirs[:steps]],
+                               rtol=1e-5)
+    assert _worst(ours[steps - 1][1], theirs[steps - 1][1]) < 1e-5
     # the score-correction bias is a buffer: nothing moved it
-    for name, value in program.flatten(
-            trainer.state["vars_G"]["buffers"]).items():
-        np.testing.assert_array_equal(np.asarray(value),
-                                      np.asarray(buffers[name]))
+    for name, value in buffers_after.items():
+        np.testing.assert_array_equal(value, buffers[name])
 
 
 @pytest.mark.parametrize("yaml, batch, layers, a_layer, kernel_dim", [
