@@ -7,7 +7,8 @@ are a band below the diagonal.
 One algorithm, two arms, and ``attention`` picks between them from what
 it can observe (``arm_of``), with no option:
 
-  ``fused``   one Pallas TPU kernel of three passes
+  ``fused``   one Pallas TPU kernel of two passes, a forward and one
+              backward sweep that gives all three gradients
               (``ops/pallas/causal_attention_kernel.py``): the scores of a
               tile stand in VMEM, the row maximum and row sum run along
               the key tiles in float32, scores and probabilities never
@@ -18,8 +19,10 @@ it can observe (``arm_of``), with no option:
               size a multiple of 128 or half of 128 (64: zero columns
               fill the lane tile, the kernel then runs at 128 under the
               scale of 64, which is exact, and the matrix unit's 128
-              lanes were the head's to fill either way) and the length
-              a multiple of the kernel's largest tile.
+              lanes were the head's to fill either way), the length
+              a multiple of the kernel's largest tile, and a key-value
+              head's ``dk`` and ``dv`` of that length small enough to
+              stand in VMEM through the backward sweep (``arm_of``).
   ``blocks``  ``causal_attention``: query blocks in plain ``jax.numpy``,
               each against the keys up to its own end (from its first
               row's window on, under a window), the (Hq, block, keys)
@@ -30,15 +33,16 @@ it can observe (``arm_of``), with no option:
 The fused arm's forward pass names its output and the rows'
 log-sum-exp ``KERNEL_RESIDUAL``: a block recomputed under
 ``optim/remat.py``'s ``blocks`` keeps the two, so its backward pass
-rebuilds ``q``, ``k``, ``v`` and runs the two backward kernels, not the
+rebuilds ``q``, ``k``, ``v`` and runs the backward kernel, not the
 forward kernel a second time (PERF.md, PR 33).
 
 Both take bfloat16 (the compute dtype's) operands, accumulate products in
 float32, mask and take the softmax statistics in float32 and cast the
 probabilities to the values' dtype for their product. The kernel's tiles
 are constants here, chosen on a v5e chip at head size 128 with 32 query
-heads on 2 (PERF.md, PR 30) and found the best again at head size 256 with
-20 on 20 (PR 31); they are not configuration.
+heads on 2 (PERF.md, PR 30), found the best again at head size 256 with
+20 on 20 (PR 31), and the backward sweep's swept again at both head sizes
+and 16,384 positions (PR 42); they are not configuration.
 """
 
 from __future__ import annotations
@@ -55,21 +59,22 @@ from imaginaire_tpu.ops.pallas import causal_attention_kernel as kernel
 
 
 class Tiles(NamedTuple):
-    """(query rows, key rows) of a tile, for each pass of the kernel."""
+    """(query rows, key rows) of a tile, for each pass of the kernel:
+    the forward and the one backward sweep."""
     fwd: tuple
-    dkv: tuple
-    dq: tuple
+    bwd: tuple
 
     @property
     def largest(self):
-        return max(*self.fwd, *self.dkv, *self.dq)
+        return max(*self.fwd, *self.bwd)
 
 
-TILES = Tiles(fwd=(1024, 1024), dkv=(1024, 1024), dq=(1024, 1024))
+TILES = Tiles(fwd=(1024, 1024), bwd=(1024, 1024))
 
 # the ``checkpoint_name`` of what a kernel's forward pass hands its
-# backward passes and only that kernel can rebuild
+# backward pass and only that kernel can rebuild
 KERNEL_RESIDUAL = "kernel_residual"
+BACKWARD_PRODUCTS = kernel.BACKWARD_PRODUCTS
 
 
 def kernel_head_dim(head_dim):
@@ -78,12 +83,29 @@ def kernel_head_dim(head_dim):
     return -(-head_dim // kernel.LANES) * kernel.LANES
 
 
+def accumulator_bytes(length, head_dim):
+    """Bytes of a key-value head's float32 ``dk`` and ``dv`` that stand in
+    VMEM through the fused arm's backward sweep at ``length``."""
+    return kernel.accumulator_bytes(length, kernel_head_dim(head_dim))
+
+
 def arm_of(head_dim, length):
     """``"fused"`` or ``"blocks"``: which arm ``attention`` takes for a
-    head size and a length on this process's backend."""
+    head size and a length on this process's backend.
+
+    The last condition is on bytes: the backward sweep keeps a key-value
+    head's ``dk`` and ``dv`` whole in VMEM (``8 x length x head size``
+    bytes) beside its tiles, and the whole has to stay under
+    ``kernel.VMEM_BYTES``. With ``TILES`` and two-byte operands that
+    holds through 65,536 positions at head size 128 (and 64) and through
+    32,768 at 256; from 131,072 and 65,536 on a length takes ``blocks``
+    as a ragged one does. No configuration in the tree comes near."""
     on_tpu = jax.default_backend() == "tpu"
-    fits = (kernel_head_dim(head_dim) in (head_dim, 2 * head_dim)
-            and length % TILES.largest == 0)
+    at = kernel_head_dim(head_dim)
+    fits = (at in (head_dim, 2 * head_dim)
+            and length % TILES.largest == 0
+            and kernel.backward_vmem_bytes(length, at, *TILES.bwd, 2)
+            <= kernel.VMEM_BYTES)
     return "fused" if on_tpu and fits else "blocks"
 
 
@@ -105,15 +127,17 @@ def attention(q, k, v, block, window=None):
 
 
 def visited_tiles(length, window=None, tiles=TILES):
-    """{pass: (tiles the fused kernel computes for one head, tiles on or
-    below the diagonal)} at ``length`` under ``window``, from the
-    kernel's own index arithmetic."""
+    """{``fwd``, ``dq``, ``dkv``: (tiles the fused kernel computes for one
+    head toward the output, the queries' gradient, the keys' and values'
+    gradients; tiles on or below the diagonal)} at ``length`` under
+    ``window``, from the kernel's own index arithmetic. One backward
+    sweep computes all three gradients, so ``dq`` and ``dkv`` count the
+    same tiles."""
     window = effective_window(window, length)
-    sweeps = {"fwd": kernel.query_sweep_tiles, "dq": kernel.query_sweep_tiles,
-              "dkv": kernel.key_sweep_tiles}
-    return {name: (len(sweep(length, *getattr(tiles, name), window)),
-                   len(sweep(length, *getattr(tiles, name), None)))
-            for name, sweep in sweeps.items()}
+    passes = {"fwd": tiles.fwd, "dq": tiles.bwd, "dkv": tiles.bwd}
+    return {name: (len(kernel.query_sweep_tiles(length, *tile, window)),
+                   len(kernel.query_sweep_tiles(length, *tile, None)))
+            for name, tile in passes.items()}
 
 
 def causal_attention(q, k, v, block, window=None):
@@ -215,12 +239,9 @@ def _fused_bwd(tiles, interpret, window, saved, do):
         q.shape).sum(-1).transpose(0, 2, 1)
     operands = (_flat(q), _flat(k), _flat(v), _flat(do.reshape(q.shape)),
                 lse, di)
-    dk, dv = kernel.backward_dkv(*operands, *heads, *tiles.dkv,
+    dq, dk, dv = kernel.backward(*operands, *heads, *tiles.bwd,
                                  interpret=interpret, scale=_scale(q),
                                  window=window)
-    dq = kernel.backward_dq(*operands, *heads, *tiles.dq,
-                            interpret=interpret, scale=_scale(q),
-                            window=window)
     return _heads(dq, q), _heads(dk, k), _heads(dv, v)
 
 

@@ -1,37 +1,49 @@
 """Causal grouped-query attention, forward and backward, as Pallas TPU
 kernels that keep the scores on the chip.
 
-Three passes, one tiling scheme. Each works on a (queries, keys) tile of
+Two passes, one tiling scheme. Each works on a (queries, keys) tile of
 the scores in VMEM: the products take bfloat16 operands and accumulate in
 float32, the scale, the mask and the softmax statistics are float32, the
 probabilities are cast to the values' dtype for their product, and neither
 scores nor probabilities are ever written to HBM.
 
-  ``forward``       online softmax over the key tiles of a query tile (a
-                    running row maximum and row sum, lane-replicated);
-                    returns the output and the rows' log-sum-exp
-  ``backward_dq``   the queries' gradient, the same sweep
-  ``backward_dkv``  the keys' and values' gradients, on the transposed
-                    tile (keys in rows), summed over the query heads of a
-                    key-value head in VMEM
+  ``forward``   online softmax over the key tiles of a query tile (a
+                running row maximum and row sum, lane-replicated);
+                returns the output and the rows' log-sum-exp
+  ``backward``  the three gradients from one sweep of the same tiles,
+                five products a tile: the scores and ``do v^T`` once,
+                then ``dv += p^T do``, ``dk += ds^T q``, ``dq += ds k``.
+                The tile stands keys in rows (the rows' statistics then
+                broadcast down the sublanes and four of the products
+                need no turned operand). ``dq`` sums along the sweep
+                into a tile of scratch; a key-value head's ``dk`` and
+                ``dv`` sum across the sweeps of its query heads' query
+                tiles and stand whole in VMEM in float32
+                (``accumulator_bytes``: 16 MiB at 8,192 x 256 and at
+                16,384 x 128), each tile written out once, after the
+                last sum into it. Every sum runs in the order two
+                separate passes would run it (key tiles ascending into
+                ``dq``; query head, then query tile, ascending into
+                ``dk``, ``dv``).
 
 A tile wholly above the diagonal is not computed and not fetched (its
-index map names the tile already held); one wholly below it skips the
+index map names a tile already held); one wholly below it skips the
 mask. Without a window every tile on or below the diagonal is computed.
 
 ``window`` (static; None for none) is the number of keys a query sees,
 itself counted: query ``i`` reads keys ``j`` with ``0 <= i - j < window``.
 The scores are then a band, and a tile wholly below the band is as far
 from the work as one above the diagonal: the innermost grid axis is only
-as long as the most tiles the band crosses in one sweep, it starts at the
-first tile the sweep reads (``_first_kv``, ``_first_q``) and its index map
-clamps at the last (``_last_kv``, ``_last_q``), so no such tile is a grid
-step, computed or fetched. A tile the band's lower edge crosses takes the
+as long as the most tiles the band crosses in one sweep (``_kv_steps``),
+the sweep runs from the first tile it reads (``_first_kv``) to the last
+(``_last_kv``) and a step it does not need names a tile it holds, so no
+such tile is a grid step, computed or fetched. A tile the band's lower
+edge crosses takes the
 mask as one the diagonal crosses does (both edges in one comparison
 pair). A row whose keys in the sweep's first tile are all masked leaves
 that tile with the finite ``MASK_VALUE`` as its maximum; the next tile's
-``exp(MASK_VALUE - m)`` is 0 and wipes what it summed. ``query_sweep_tiles``
-and ``key_sweep_tiles`` list the tiles a pass computes, from the same
+``exp(MASK_VALUE - m)`` is 0 and wipes what it summed.
+``query_sweep_tiles`` lists the tiles a pass computes, from the same
 functions.
 
 Layouts are the model's own: ``q`` (B, L, Hq*d), ``k``, ``v`` (B, L,
@@ -60,19 +72,22 @@ LANES = 128
 # against the maximum its earlier tiles left, never inf - inf
 MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
-# a kernel gets 16 MiB of VMEM unless it asks; a 1024 x 1024 tile's float32
-# scores, probabilities and their gradients stand beside the operands
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+# a kernel gets 16 MiB of VMEM unless it asks; the forward's 1024 x 1024
+# tile of float32 scores and probabilities stands beside the operands
 _VMEM_LIMIT = 32 * 1024 * 1024
+# what the compiler lays out beside what ``backward_vmem_bytes`` counts
+_VMEM_ROOM = 8 * 1024 * 1024
+# the most a pass asks for, of a v5e core's 128 MiB
+VMEM_BYTES = 112 * 1024 * 1024
+# of a tile in the backward sweep: the scores, ``do v^T`` and the three
+# gradients (``tests/test_attention_op.py`` counts them in the body)
+BACKWARD_PRODUCTS = 5
 
 
 def _last_kv(i, bq, bkv):
     """The last key tile a row of query tile ``i`` reads."""
     return ((i + 1) * bq - 1) // bkv
-
-
-def _first_q(j, bq, bkv):
-    """The first query tile a row of key tile ``j`` is read by."""
-    return (j * bkv) // bq
 
 
 def _first_kv(i, bq, bkv, window):
@@ -83,13 +98,12 @@ def _first_kv(i, bq, bkv, window):
     return jnp.maximum(i * bq - window + 1, 0) // bkv
 
 
-def _last_q(j, bq, bkv, window, q_tiles):
-    """The last query tile a row of key tile ``j`` is read by under a
-    window: its last key's ``window - 1`` queries on."""
-    last = ((j + 1) * bkv + window - 2) // bq
-    if isinstance(j, int):
-        return min(last, q_tiles - 1)
-    return jnp.minimum(last, q_tiles - 1)
+def _whole_kv(i, bq, bkv, window, q_tiles, kv_tiles):
+    """How many key tiles, from the first, no query tile after ``i``
+    reads: every one after the last query tile, under a window those
+    before the next query tile's first, none otherwise."""
+    rest = 0 if window is None else _first_kv(i + 1, bq, bkv, window)
+    return jnp.where(i == q_tiles - 1, kv_tiles, rest)
 
 
 def _crosses_diagonal(i, j, bq, bkv):
@@ -107,8 +121,8 @@ def _crosses_edges(i, j, bq, bkv, window):
 
 
 def query_sweep_tiles(length, bq, bkv, window):
-    """[(query tile, key tile)] that the forward and the dQ pass compute
-    for one head; ``window`` None is every tile on or below the
+    """[(query tile, key tile)] that a pass computes for one query head,
+    in its sweep's order; ``window`` None is every tile on or below the
     diagonal."""
     return [(i, j) for i in range(length // bq)
             for j in range(0 if window is None
@@ -116,20 +130,13 @@ def query_sweep_tiles(length, bq, bkv, window):
                            _last_kv(i, bq, bkv) + 1)]
 
 
-def key_sweep_tiles(length, bq, bkv, window):
-    """[(query tile, key tile)] that the dK/dV pass computes for one
-    query head."""
-    q_tiles = length // bq
-    return [(i, j) for j in range(length // bkv)
-            for i in range(_first_q(j, bq, bkv),
-                           (q_tiles if window is None else
-                            _last_q(j, bq, bkv, window, q_tiles) + 1))]
-
-
-def _span(tiles, axis):
-    """The most tiles of ``tiles`` that share their index on ``axis``:
-    the length of a windowed sweep's innermost grid axis."""
-    return max(collections.Counter(tile[axis] for tile in tiles).values())
+def _kv_steps(length, bq, bkv, window):
+    """The length of a sweep's innermost grid axis: every key tile, or
+    under a window the most that one query tile reads."""
+    if window is None:
+        return length // bkv
+    tiles = query_sweep_tiles(length, bq, bkv, window)
+    return max(collections.Counter(i for i, _ in tiles).values())
 
 
 def _scores(a_ref, b_ref, scale, row0, col0, masked, keys_in_rows,
@@ -157,16 +164,13 @@ def _sizes(q, q_heads, kv_heads, scale=None):
 
 
 def _query_sweep(q, q_heads, kv_heads, bq, bkv, window):
-    """What the forward and the dQ pass share: a grid (batch, query head,
-    query tile, key tile), the key tiles innermost, and the block specs
-    of a query tile, a key tile and a (1, bq) row of statistics. Under a
-    window the innermost axis counts from the first key tile the query
-    tile reads and is as long as the band's widest sweep."""
+    """The forward pass's grid (batch, query head, query tile, key tile),
+    the key tiles innermost, and the block specs of a query tile and a
+    key tile. Under a window the innermost axis counts from the first
+    key tile the query tile reads and is as long as the band's widest
+    sweep."""
     bsz, length, _ = q.shape
     head_dim, group, _ = _sizes(q, q_heads, kv_heads)
-    kv_steps = length // bkv
-    if window is not None:
-        kv_steps = _span(query_sweep_tiles(length, bq, bkv, window), 0)
 
     def q_map(b, h, i, j):
         return b, i, h
@@ -176,10 +180,9 @@ def _query_sweep(q, q_heads, kv_heads, bq, bkv, window):
             j = j + _first_kv(i, bq, bkv, window)
         return b, jnp.minimum(j, _last_kv(i, bq, bkv)), h // group
 
-    return ((bsz, q_heads, length // bq, kv_steps),
+    return ((bsz, q_heads, length // bq, _kv_steps(length, bq, bkv, window)),
             pl.BlockSpec((None, bq, head_dim), q_map),
-            pl.BlockSpec((None, bkv, head_dim), kv_map),
-            pl.BlockSpec((None, None, 1, bq), lambda b, h, i, j: (b, h, 0, i)))
+            pl.BlockSpec((None, bkv, head_dim), kv_map))
 
 
 def _on_tiles(needed, crosses, tile):
@@ -236,8 +239,8 @@ def forward(q, k, v, q_heads, kv_heads, bq, bkv, interpret=False,
     """(out (B, L, Hq*d) in ``q``'s dtype, lse (B, Hq, L) float32)."""
     bsz, length, _ = q.shape
     head_dim, _, scale = _sizes(q, q_heads, kv_heads, scale)
-    grid, q_spec, kv_spec, _ = _query_sweep(q, q_heads, kv_heads, bq, bkv,
-                                            window)
+    grid, q_spec, kv_spec = _query_sweep(q, q_heads, kv_heads, bq, bkv,
+                                         window)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, bq=bq, bkv=bkv,
                           window=window),
@@ -265,156 +268,153 @@ def forward(q, k, v, q_heads, kv_heads, bq, bkv, interpret=False,
 # ----------------------------------------------------------------- backward
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, acc_ref,
-               *, scale, bq, bkv, window):
-    i, j = pl.program_id(2), pl.program_id(3)
-    last = _last_kv(i, bq, bkv)
-
-    @pl.when(j == 0)
-    def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    if window is not None:
-        j = j + _first_kv(i, bq, bkv, window)
-
-    def tile(masked):
-        s = _scores(q_ref, k_ref, scale, i * bq, j * bkv, masked, False,
-                    window)
-        p = jnp.exp(s - jnp.expand_dims(lse_ref[0], -1))
-        dp = lax.dot_general(do_ref[...], v_ref[...], _NT,
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - jnp.expand_dims(di_ref[0], -1))
-        acc_ref[...] += jnp.dot(ds.astype(k_ref.dtype), k_ref[...],
-                                preferred_element_type=jnp.float32)
-
-    _on_tiles(j <= last, _crosses_edges(i, j, bq, bkv, window), tile)
-
-    @pl.when(j == last)
-    def _():
-        dq_ref[...] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+def accumulator_bytes(length, head_dim):
+    """Bytes of what stands in VMEM through a key-value head's sweep: its
+    ``dk`` and ``dv`` whole, in float32."""
+    return 2 * length * head_dim * 4
 
 
-def backward_dq(q, k, v, do, lse, di, q_heads, kv_heads, bq, bkv,
-                interpret=False, scale=None, window=None):
-    """The queries' gradient, (B, L, Hq*d). ``lse``, ``di`` (B, Hq, L)
-    float32: the rows' log-sum-exp and ``sum(do * out)``."""
-    head_dim, _, scale = _sizes(q, q_heads, kv_heads, scale)
-    grid, q_spec, kv_spec, row_spec = _query_sweep(q, q_heads, kv_heads,
-                                                   bq, bkv, window)
-    return pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, bq=bq, bkv=bkv,
-                          window=window),
-        grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, head_dim), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT),
-        name="causal_gqa_dq",
-        interpret=interpret,
-    )(q, k, v, do, lse[:, :, None], di[:, :, None])
+def backward_vmem_bytes(length, head_dim, bq, bkv, itemsize):
+    """Bytes of VMEM the backward pass asks for: the accumulators, the
+    pipeline's two copies of each block, the query tile's float32 ``dq``
+    and what a tile's arithmetic stands on (the float32 scores,
+    probabilities and their two gradients, and ``p``, ``ds`` and ``ds``
+    turned in the operands' dtype)."""
+    blocks = 2 * itemsize * head_dim * (3 * bq + 4 * bkv) + 2 * 2 * 8 * bq * 4
+    tile = bq * bkv * (4 * 4 + 3 * itemsize)
+    return (accumulator_bytes(length, head_dim) + blocks
+            + bq * head_dim * 4 + tile + _VMEM_ROOM)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref,
-                dk_acc, dv_acc, *, scale, bq, bkv, group, q_tiles, q_steps,
-                window):
-    j, r, i = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+def _backward_tile(i, j, bq, bkv, window, kv_steps):
+    """(the key tile that step ``j`` of query tile ``i``'s backward sweep
+    is at, whether the step computes it). The sweep's tiles take its LAST
+    steps: the steps a shorter sweep does not need come first and name
+    its first tile, so everything a new query tile fetches is asked for
+    during the last tile of the sweep before, behind that tile's
+    arithmetic, and not behind a step that does nothing."""
+    first = 0 if window is None else _first_kv(i, bq, bkv, window)
+    idle = kv_steps - 1 - (_last_kv(i, bq, bkv) - first)
+    return first + jnp.maximum(j - idle, 0), j >= idle
 
-    @pl.when((r == 0) & (i == 0))
+
+def _backward_sweep(q, q_heads, kv_heads, bq, bkv, window):
+    """The backward pass's grid (batch, key-value head, query head of the
+    group, query tile, key tile): the key tiles innermost and ascending,
+    under a loop over the group's query heads, and the block specs of a
+    query tile, a key tile, a (1, bq) row of statistics and a key tile
+    of ``dk``, ``dv``.
+
+    A key tile's gradients stand in VMEM until the last query tile that
+    reads it has, in the group's last head (``_whole_kv``): its output
+    block is named from that step on and tile 0's before, so each block
+    is one run of grid steps that ends after its one write, and HBM is
+    written once a tile."""
+    bsz, length, _ = q.shape
+    head_dim, group, _ = _sizes(q, q_heads, kv_heads)
+    q_tiles, kv_steps = length // bq, _kv_steps(length, bq, bkv, window)
+
+    def kv_tile(i, j):
+        return _backward_tile(i, j, bq, bkv, window, kv_steps)[0]
+
+    def q_map(b, g, r, i, j):
+        return b, i, g * group + r
+
+    def kv_map(b, g, r, i, j):
+        return b, kv_tile(i, j), g
+
+    def row_map(b, g, r, i, j):
+        return b, g * group + r, 0, i
+
+    def out_map(b, g, r, i, j):
+        whole = _whole_kv(i, bq, bkv, window, q_tiles, length // bkv)
+        at = jnp.minimum(kv_tile(i, j), jnp.maximum(whole - 1, 0))
+        return b, jnp.where(r == group - 1, at, 0), g
+
+    return ((bsz, kv_heads, group, q_tiles, kv_steps),
+            pl.BlockSpec((None, bq, head_dim), q_map),
+            pl.BlockSpec((None, bkv, head_dim), kv_map),
+            pl.BlockSpec((None, None, 1, bq), row_map),
+            pl.BlockSpec((None, bkv, head_dim), out_map))
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
+                *, scale, bq, bkv, group, window):
+    r, i, step = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    q_tiles, kv_steps = pl.num_programs(3), pl.num_programs(4)
+    kv_tiles = dk_acc.shape[0]
+
+    @pl.when((r == 0) & (i == 0) & (step == 0))
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    at_end = (r == group - 1) & (i == q_steps - 1)
-    needed = i >= _first_q(j, bq, bkv)
-    if window is not None:      # the grid's step to the query tile it is at
-        i = i + _first_q(j, bq, bkv)
-        needed = i <= _last_q(j, bq, bkv, window, q_tiles)
+    @pl.when(step == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    j, needed = _backward_tile(i, step, bq, bkv, window, kv_steps)
 
     def tile(masked):
         # keys in rows, queries in lanes: the rows' statistics broadcast
-        # down the sublanes and no product needs a transposed operand
+        # down the sublanes, and of the five products only the queries'
+        # gradient contracts over its left operand's rows
         s = _scores(k_ref, q_ref, scale, j * bkv, i * bq, masked, True,
                     window)
         p = jnp.exp(s - lse_ref[...])
         do = do_ref[...]
-        dv_acc[...] += jnp.dot(p.astype(do.dtype), do,
-                               preferred_element_type=jnp.float32)
+        dv_acc[j] += jnp.dot(p.astype(do.dtype), do,
+                             preferred_element_type=jnp.float32)
         dp = lax.dot_general(v_ref[...], do, _NT,
                              preferred_element_type=jnp.float32)
-        ds = p * (dp - di_ref[...])
-        dk_acc[...] += jnp.dot(ds.astype(q_ref.dtype), q_ref[...],
-                               preferred_element_type=jnp.float32)
+        ds = (p * (dp - di_ref[...])).astype(q_ref.dtype)
+        dk_acc[j] += jnp.dot(ds, q_ref[...],
+                             preferred_element_type=jnp.float32)
+        dq_acc[...] += lax.dot_general(ds, k_ref[...], _TN,
+                                       preferred_element_type=jnp.float32)
 
     _on_tiles(needed, _crosses_edges(i, j, bq, bkv, window), tile)
 
-    @pl.when(at_end)
+    @pl.when(step == kv_steps - 1)       # the sweep's last tile, always
     def _():
-        dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
-        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+        dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+    whole = _whole_kv(i, bq, bkv, window, q_tiles, kv_tiles)
+
+    @pl.when(needed & (r == group - 1) & (j < whole))
+    def _():
+        dk_ref[...] = (dk_acc[j] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[j].astype(dv_ref.dtype)
 
 
-def _key_sweep(q, q_heads, kv_heads, bq, bkv, window):
-    """The dK/dV pass's grid (batch, key-value head, key tile, query head
-    of the group, query tile), the query tiles innermost, its steps along
-    that axis, and the block specs of a query tile, a key tile and a
-    (1, bq) row of statistics. Under a window the innermost axis counts
-    from the first query tile that reads the key tile and is as long as
-    the band's tallest sweep."""
+def backward(q, k, v, do, lse, di, q_heads, kv_heads, bq, bkv,
+             interpret=False, scale=None, window=None):
+    """The three gradients, ``dq`` (B, L, Hq*d), ``dk``, ``dv`` (B, L,
+    Hkv*d), from one sweep. ``lse``, ``di`` (B, Hq, L) float32: the rows'
+    log-sum-exp and ``sum(do * out)``."""
     bsz, length, _ = q.shape
-    head_dim, group, _ = _sizes(q, q_heads, kv_heads)
-    q_tiles = q_steps = length // bq
-    if window is not None:
-        q_steps = _span(key_sweep_tiles(length, bq, bkv, window), 1)
-
-    def first_q(i, j):
-        # a tile above the diagonal names the first one that is not; one
-        # below the band the last that is in it
-        if window is not None:
-            return jnp.minimum(i + _first_q(j, bq, bkv),
-                               _last_q(j, bq, bkv, window, q_tiles))
-        return jnp.maximum(i, _first_q(j, bq, bkv))
-
-    def q_map(b, g, j, r, i):
-        return b, first_q(i, j), g * group + r
-
-    def kv_map(b, g, j, r, i):
-        return b, j, g
-
-    def row_map(b, g, j, r, i):
-        return b, g * group + r, 0, first_q(i, j)
-
-    return ((bsz, kv_heads, length // bkv, group, q_steps), q_steps,
-            pl.BlockSpec((None, bq, head_dim), q_map),
-            pl.BlockSpec((None, bkv, head_dim), kv_map),
-            pl.BlockSpec((None, None, 1, bq), row_map))
-
-
-def backward_dkv(q, k, v, do, lse, di, q_heads, kv_heads, bq, bkv,
-                 interpret=False, scale=None, window=None):
-    """The keys' and the values' gradients, (B, L, Hkv*d) each."""
-    length = q.shape[1]
     head_dim, group, scale = _sizes(q, q_heads, kv_heads, scale)
-    grid, q_steps, q_spec, kv_spec, row_spec = _key_sweep(
+    grid, q_spec, kv_spec, row_spec, out_spec = _backward_sweep(
         q, q_heads, kv_heads, bq, bkv, window)
+    held = (length // bkv, bkv, head_dim)
     return pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, bq=bq, bkv=bkv,
-                          group=group, q_tiles=length // bq, q_steps=q_steps,
-                          window=window),
+        functools.partial(_bwd_kernel, scale=scale, bq=bq, bkv=bkv,
+                          group=group, window=window),
         grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[kv_spec, kv_spec],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+        out_specs=[q_spec, out_spec, out_spec],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bkv, head_dim), jnp.float32),
-                        pltpu.VMEM((bkv, head_dim), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, head_dim), jnp.float32),
+                        pltpu.VMEM(held, jnp.float32),
+                        pltpu.VMEM(held, jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
+            dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT),
-        name="causal_gqa_dkv",
+            vmem_limit_bytes=backward_vmem_bytes(
+                length, head_dim, bq, bkv, q.dtype.itemsize)),
+        name="causal_gqa_bwd",
         interpret=interpret,
     )(q, k, v, do, lse[:, :, None], di[:, :, None])

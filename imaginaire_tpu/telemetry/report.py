@@ -1000,8 +1000,9 @@ def render_serving_report(path_or_events):
 
 def _window_note(attn, layer):
     """What the ``attn_impl`` line says after a layer's arm: its window
-    and, where the meta counts them, the tiles each pass of the kernel
-    visits over those on or below the diagonal; nothing for a layer
+    and, where the meta counts them, the tiles the kernel computes
+    toward its output and toward each gradient over those on or below
+    the diagonal; nothing for a layer
     without a window."""
     window = (attn.get("windows") or {}).get(layer)
     if window is None:
@@ -1114,7 +1115,11 @@ def render_report(path_or_events):
                         for k, v in tiles.items())
             + (f"; the blocks keep {sum(attn['kept_bytes'].values())} bytes "
                "of the kernel's forward passes for its backward passes"
-               if "kept_bytes" in attn else ""))
+               if "kept_bytes" in attn else "")
+            + (f"; one backward sweep of {attn['backward_products']} "
+               f"products a tile, {attn.get('vmem_accumulator_bytes')} "
+               "bytes of a key-value head's dk and dv standing in VMEM"
+               if "backward_products" in attn else ""))
     kda = s["meta"].get("kda_impl")
     if kda:
         lines.append(

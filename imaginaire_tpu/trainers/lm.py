@@ -41,10 +41,15 @@ def attn_impl(gen_cfg, tokens_shape):
     keeps of the kernel's forward pass for its backward passes (the
     output and the log-sum-exp: ``gen.remat``'s policy decides; 0 where
     the backward pass runs the forward kernel again, and on the plain
-    arm, which has no kernel). A model with sliding-window layers adds
+    arm, which has no kernel). Where a layer is fused: the products a
+    tile of the kernel's one backward sweep computes
+    (``backward_products``) and the bytes of a key-value head's ``dk``
+    and ``dv`` that stand in VMEM through it
+    (``vmem_accumulator_bytes``). A model with sliding-window layers adds
     each such layer's ``windows`` entry (the keys a query sees) and
-    ``visited_tiles`` where the tiles divide the length: for each pass
-    of the kernel, the tiles a head's sweep computes under the window
+    ``visited_tiles`` where the tiles divide the length: toward the
+    output and toward each gradient, the tiles a head's sweep computes
+    under the window
     over those on or below the diagonal, whichever arm the layer takes
     on this backend."""
     bsz, length = (int(n) for n in tokens_shape)
@@ -61,6 +66,11 @@ def attn_impl(gen_cfg, tokens_shape):
                 tiles=attention.TILES._asdict(), layers=arms,
                 kept_bytes={i: a_layer if keeps and arm == "fused" else 0
                             for i, arm in arms.items()})
+    if "fused" in arms.values():
+        meta.update(
+            backward_products=attention.BACKWARD_PRODUCTS,
+            vmem_accumulator_bytes=attention.accumulator_bytes(
+                length, head_dim))
     windowed = [str(i) for i, kind in kinds.items() if kind == "W"]
     if windowed:
         meta["windows"] = dict.fromkeys(windowed, g.sliding_window)

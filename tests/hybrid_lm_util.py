@@ -77,15 +77,20 @@ def layer_params(flat, index):
             if k.startswith(prefix)}
 
 
+def pallas_bodies(jaxpr):
+    """(name, the kernel body's jaxpr) of every ``pallas_call`` in a
+    jaxpr, inner jaxprs included."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"], eqn.params["jaxpr"]
+            continue
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from pallas_bodies(inner)
+
+
 def pallas_calls(jaxpr):
     """The names of every ``pallas_call`` in a jaxpr, inner jaxprs
     included."""
-    import jax
-
-    names = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            names.append(eqn.params["name"])
-        for inner in jax.core.jaxprs_in_params(eqn.params):
-            names.extend(pallas_calls(inner))
-    return names
+    return [name for name, _ in pallas_bodies(jaxpr)]

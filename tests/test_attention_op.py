@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hybrid_lm_util import pallas_calls
+from hybrid_lm_util import pallas_bodies, pallas_calls
 
 from imaginaire_tpu.ops import attention
 
@@ -18,7 +18,8 @@ from imaginaire_tpu.ops import attention
 # that differ between the passes and between queries and keys, so every
 # pass sees tiles below, on and (skipped) above the diagonal
 SHAPE = dict(bsz=2, length=512, q_heads=4, kv_heads=2, dim=128)
-TILES = attention.Tiles(fwd=(256, 128), dkv=(128, 256), dq=(256, 256))
+TILES = attention.Tiles(fwd=(256, 128), bwd=(128, 256))
+SMALL = attention.Tiles(fwd=(128, 128), bwd=(128, 128))
 NAMES = ("out", "dq", "dk", "dv")
 
 
@@ -108,9 +109,8 @@ def test_fused_arm_at_head_size_64_matches_plain_arm(which):
 @functools.lru_cache(maxsize=None)
 def _at_64():
     q, k, v, ct = _inputs(seed=7, bsz=1, length=256, dim=64)
-    tiles = attention.Tiles(fwd=(128, 128), dkv=(128, 128), dq=(128, 128))
     plain = lambda q, k, v: attention.causal_attention(q, k, v, 128)  # noqa: E731
-    return (_with_gradients(lambda q, k, v: _fused(q, k, v, tiles),
+    return (_with_gradients(lambda q, k, v: _fused(q, k, v, SMALL),
                             q, k, v, ct),
             _with_gradients(plain, q, k, v, ct),
             _with_gradients(plain, *(x.astype(jnp.float32)
@@ -118,7 +118,7 @@ def _at_64():
 
 
 def test_a_padded_head_runs_the_kernel_at_a_lane_tile():
-    """Head size 64 reaches the three kernels 128 wide (4 heads: 512
+    """Head size 64 reaches the two kernels 128 wide (4 heads: 512
     columns) and under 1/sqrt(64); a head of 128 reaches them as it is."""
     from imaginaire_tpu.ops.pallas import causal_attention_kernel as kernel
 
@@ -135,25 +135,91 @@ def test_a_padded_head_runs_the_kernel_at_a_lane_tile():
             return real(q, *rest, scale=scale, **kwargs)
         return call
 
-    tiles = attention.Tiles(fwd=(128, 128), dkv=(128, 128), dq=(128, 128))
     with pytest.MonkeyPatch.context() as patch:
-        for name in ("forward", "backward_dkv", "backward_dq"):
+        for name in ("forward", "backward"):
             patch.setattr(kernel, name, spy(name))
-        _with_gradients(lambda q, k, v: _fused(q, k, v, tiles), q, k, v, ct)
-    assert seen == dict.fromkeys(("forward", "backward_dkv", "backward_dq"),
-                                 (4 * 128, 0.125))
+        _with_gradients(lambda q, k, v: _fused(q, k, v, SMALL), q, k, v, ct)
+    assert seen == dict.fromkeys(("forward", "backward"), (4 * 128, 0.125))
 
 
 @functools.lru_cache(maxsize=None)
 def _at_256():
     q, k, v, ct = _inputs(seed=6, bsz=1, length=256, q_heads=2, kv_heads=2,
                           dim=256)
-    tiles = attention.Tiles(fwd=(128, 128), dkv=(128, 128), dq=(128, 128))
-    return (_with_gradients(lambda q, k, v: _fused(q, k, v, tiles),
+    return (_with_gradients(lambda q, k, v: _fused(q, k, v, SMALL),
                             q, k, v, ct),
             _with_gradients(
                 lambda q, k, v: attention.causal_attention(q, k, v, 128),
                 q, k, v, ct))
+
+
+# each cell's layout at a small length: (query heads, key-value heads, head
+# size, sequences, window), 512 positions in tiles of 128, so that a sweep
+# comes back to the key-value head's standing ``dk``, ``dv`` four times a
+# query head
+LAYOUTS = {
+    "glm4_7_flash": (2, 2, 256, 1, None),         # group 1 at head 256
+    "nemotron3_nano": (32, 2, 128, 1, None),      # 16 query heads a head
+    "solar_open2": (8, 1, 128, 1, None),
+    "lfm2_8b_a1b": (32, 8, 64, 2, None),          # padded to 128, 2 sequences
+    # the band's lower edge crosses tiles inside: 200 is no multiple of 128
+    "trinity_mini": (32, 4, 128, 1, 200),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _at_layout(name):
+    q_heads, kv_heads, dim, bsz, window = LAYOUTS[name]
+    q, k, v, ct = _inputs(seed=len(name), bsz=bsz, q_heads=q_heads,
+                          kv_heads=kv_heads, dim=dim)
+    return (_with_gradients(
+        lambda q, k, v: attention.fused_causal_attention(
+            q, k, v, SMALL, True, window), q, k, v, ct),
+        _with_gradients(lambda q, k, v: attention.causal_attention(
+            q, k, v, 128, window), q, k, v, ct))
+
+
+@pytest.mark.parametrize("which", range(4), ids=NAMES)
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_fused_arm_at_each_cells_layout_matches_plain_arm(layout, which):
+    """ISSUE 42: one backward sweep serves every cell's heads; the
+    key-value head's gradients stand in VMEM across its query heads and
+    their query tiles, and come out whole."""
+    fused, plain = _at_layout(layout)
+    assert fused[which].shape == plain[which].shape
+    assert fused[which].dtype == plain[which].dtype == jnp.bfloat16
+    assert np.isfinite(np.asarray(fused[which], np.float32)).all()
+    assert _rel(fused[which], plain[which]) < 6e-3
+
+
+def _products_a_branch(jaxpr):
+    """The ``dot_general``s of each innermost branch of a kernel's body
+    that holds any (``pl.when`` is a ``cond``)."""
+    counts, here = [], 0
+    for eqn in jaxpr.eqns:
+        here += eqn.primitive.name == "dot_general"
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            counts.extend(_products_a_branch(inner))
+    return counts + [here] * bool(here)
+
+
+@pytest.mark.parametrize("window", [None, 200])
+def test_the_backward_is_one_kernel_of_five_products_a_tile(window):
+    """ISSUE 42: the gradient of the fused arm holds the forward kernel
+    and ONE backward kernel; the backward's body computes five products
+    in the tile with the mask and five in the tile without (the scores,
+    ``do v^T`` and the three gradients), the forward's two."""
+    q, k, v, ct = _inputs(seed=9, bsz=1)
+
+    def loss(q, k, v):
+        out = attention.fused_causal_attention(q, k, v, SMALL, True, window)
+        return jnp.sum(out.astype(jnp.float32) * ct.astype(jnp.float32))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr
+    products = {name: _products_a_branch(body)
+                for name, body in pallas_bodies(jaxpr)}
+    assert attention.BACKWARD_PRODUCTS == 5
+    assert products == {"causal_gqa_fwd": [2, 2], "causal_gqa_bwd": [5, 5]}
 
 
 def test_fused_arm_in_float32_is_the_plain_arm():
@@ -183,9 +249,8 @@ def test_nothing_after_a_position_reaches_it(t):
 
 def test_one_tile_and_many_tiles_agree():
     q, k, v, _ = _inputs(seed=4, bsz=1)
-    whole = attention.Tiles(fwd=(512, 512), dkv=(512, 512), dq=(512, 512))
-    small = attention.Tiles(fwd=(128, 128), dkv=(128, 128), dq=(128, 128))
-    assert _rel(_fused(q, k, v, small), _fused(q, k, v, whole)) < 4e-3
+    whole = attention.Tiles(fwd=(512, 512), bwd=(512, 512))
+    assert _rel(_fused(q, k, v, SMALL), _fused(q, k, v, whole)) < 4e-3
 
 
 @pytest.mark.parametrize("backend, dim, length, arm", [
@@ -200,6 +265,13 @@ def test_one_tile_and_many_tiles_agree():
     ("tpu", 32, 8192, "blocks"),       # padding would quadruple the work
     ("tpu", 16, 8192, "blocks"),
     ("cpu", 64, 8192, "blocks"),
+    # ISSUE 42: a key-value head's dk and dv stand in VMEM through the
+    # backward sweep: 64 MiB of them fit beside the tiles, 128 do not
+    ("tpu", 128, 65536, "fused"),
+    ("tpu", 64, 65536, "fused"),
+    ("tpu", 256, 32768, "fused"),
+    ("tpu", 128, 131072, "blocks"),
+    ("tpu", 256, 65536, "blocks"),
 ])
 def test_the_rule(monkeypatch, backend, dim, length, arm):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
@@ -233,7 +305,6 @@ def _block_gradients(dim, policy):
 
     q_heads, kv_heads = (4, 2) if dim == 128 else (2, 2)
     hidden, length = 64, 256
-    tiles = attention.Tiles(fwd=(128, 128), dkv=(128, 128), dq=(128, 128))
     keys = jax.random.split(jax.random.PRNGKey(dim), 5)
     x = jax.random.normal(keys[0], (1, length, hidden)).astype(jnp.bfloat16)
     widths = [q_heads * dim, kv_heads * dim, kv_heads * dim]
@@ -246,7 +317,7 @@ def _block_gradients(dim, policy):
         w_q, w_k, w_v, w_o = kernels
         q, k, v = ((x @ w).reshape(1, length, -1, dim)
                    for w in (w_q, w_k, w_v))
-        return _fused(q, k, v, tiles) @ w_o
+        return _fused(q, k, v, SMALL) @ w_o
 
     def loss(x, kernels):
         out = jax.checkpoint(block, policy=POLICIES[policy].policy)(x, kernels)
@@ -266,10 +337,9 @@ def test_a_recomputed_block_runs_the_forward_kernel_once(dim):
     written, so no gradient moves by a bit."""
     kept_calls, kept = _block_gradients(dim, "blocks")
     again_calls, again = _block_gradients(dim, "save_nothing")
-    assert sorted(kept_calls) == [
-        "causal_gqa_dkv", "causal_gqa_dq", "causal_gqa_fwd"]
+    assert sorted(kept_calls) == ["causal_gqa_bwd", "causal_gqa_fwd"]
     assert sorted(again_calls) == [
-        "causal_gqa_dkv", "causal_gqa_dq", "causal_gqa_fwd", "causal_gqa_fwd"]
+        "causal_gqa_bwd", "causal_gqa_fwd", "causal_gqa_fwd"]
     assert len(kept) == len(again) == 5
     for a, b in zip(kept, again):
         assert np.abs(np.asarray(a, np.float32)).max() > 0

@@ -2,7 +2,8 @@
 fused kernel in Pallas's interpreter) against a dense masked softmax in
 float32, forward and the three gradients; a window that hides nothing is
 the causal call to the bit; the tiles the kernel's passes visit, walked
-through their own grids and index maps."""
+through their own grids and index maps; and where the one backward
+sweep (ISSUE 42) writes a key tile's gradients out."""
 
 import math
 
@@ -18,7 +19,7 @@ LENGTH, DIM = 512, 128
 # query and key tiles that differ, and differ between the passes; four
 # tiles of 128 a side, so that a short window leaves tiles wholly below
 # its band
-TILES = attention.Tiles(fwd=(256, 128), dkv=(128, 256), dq=(128, 128))
+TILES = attention.Tiles(fwd=(256, 128), bwd=(128, 256))
 NAMES = ("out", "dq", "dk", "dv")
 
 
@@ -60,9 +61,11 @@ ARMS = {
 
 
 # under a tile, a tile, between two tiles' sizes and off any multiple,
-# over the larger tile, and a window of one key: the query's own
+# over the larger tile, and a window of one key: the query's own; the
+# last is the window cell's heads, 8 query heads a key-value head
 @pytest.mark.parametrize("window,q_heads,kv_heads", [
-    (1, 2, 2), (40, 2, 2), (128, 8, 1), (200, 2, 2), (300, 2, 1)])
+    (1, 2, 2), (40, 2, 2), (128, 8, 1), (200, 2, 2), (300, 2, 1),
+    (200, 32, 4)])
 @pytest.mark.parametrize("arm", sorted(ARMS))
 def test_the_window_follows_the_dense_masked_softmax(arm, window, q_heads,
                                                      kv_heads):
@@ -113,25 +116,32 @@ def _in_band(i, j, bq, bkv, window):
 
 
 def _walk_query_sweep(length, bq, bkv, window):
-    """{(query tile, key tile)} that the forward and dQ passes' grid
-    fetches for one head, through ``_query_sweep``'s own index map."""
+    """{(query tile, key tile)} that the forward pass's grid fetches for
+    one head, through ``_query_sweep``'s own index map."""
     q = jax.ShapeDtypeStruct((1, length, 128), jnp.bfloat16)
-    grid, _, kv_spec, _ = kernel._query_sweep(q, 1, 1, bq, bkv, window)
+    grid, _, kv_spec = kernel._query_sweep(q, 1, 1, bq, bkv, window)
     return grid, {(i, int(kv_spec.index_map(0, 0, i, j)[1]))
                   for i in range(grid[2]) for j in range(grid[3])}
 
 
-def _walk_key_sweep(length, bq, bkv, window):
-    q = jax.ShapeDtypeStruct((1, length, 128), jnp.bfloat16)
-    grid, steps, q_spec, _, row_spec = kernel._key_sweep(q, 1, 1, bq, bkv,
-                                                         window)
-    fetched = set()
-    for j in range(grid[2]):
-        for i in range(steps):
-            tile = int(q_spec.index_map(0, 0, j, 0, i)[1])
-            assert int(row_spec.index_map(0, 0, j, 0, i)[3]) == tile
-            fetched.add((tile, j))
-    return grid, fetched
+def _walk_backward_sweep(length, bq, bkv, window, group=1):
+    """The same of the backward pass's grid, and [(query head of the
+    group, query tile, key tile fetched, key tile of ``dk``, ``dv``
+    named)] step by step for one key-value head."""
+    q = jax.ShapeDtypeStruct((1, length, 128 * group), jnp.bfloat16)
+    grid, q_spec, kv_spec, row_spec, out_spec = kernel._backward_sweep(
+        q, group, 1, bq, bkv, window)
+    assert grid[:3] == (1, 1, group)
+    steps = []
+    for r in range(group):
+        for i in range(grid[3]):
+            for j in range(grid[4]):
+                at = (0, 0, r, i, j)
+                assert (q_spec.index_map(*at)[1:] == (i, r)
+                        and row_spec.index_map(*at) == (0, r, 0, i))
+                steps.append((r, i, int(kv_spec.index_map(*at)[1]),
+                              int(out_spec.index_map(*at)[1])))
+    return grid, {(i, j) for _, i, j, _ in steps}, steps
 
 
 @pytest.mark.parametrize("length,window,tile,visited,below", [
@@ -145,17 +155,17 @@ def test_each_pass_visits_the_bands_tiles_and_no_other(length, window, tile,
     pass computes 45 tiles a head of the 136 on or below the diagonal, and
     its grid fetches those and no tile wholly outside the band: the grid's
     innermost axis is 3 steps long, not 16."""
-    tiles = attention.Tiles(*((tile, tile),) * 3)
+    tiles = attention.Tiles(*((tile, tile),) * 2)
     assert attention.visited_tiles(length, window, tiles) == dict.fromkeys(
         ("fwd", "dq", "dkv"), (visited, below))
     band = {(i, j) for i in range(length // tile)
             for j in range(length // tile)
             if _in_band(i, j, tile, tile, window)}
     assert len(band) == visited
-    for walk, listed in ((_walk_query_sweep, kernel.query_sweep_tiles),
-                         (_walk_key_sweep, kernel.key_sweep_tiles)):
-        grid, fetched = walk(length, tile, tile, window)
-        assert fetched == band == set(listed(length, tile, tile, window))
+    for walk in (_walk_query_sweep, _walk_backward_sweep):
+        grid, fetched = walk(length, tile, tile, window)[:2]
+        assert fetched == band == set(kernel.query_sweep_tiles(
+            length, tile, tile, window))
         # the innermost axis: as long as the band's widest sweep
         assert grid[-1] == max(sum(1 for tile_ in band if tile_[0] == i)
                                for i in range(length // tile))
@@ -172,4 +182,60 @@ def test_uneven_tiles_visit_their_own_band():
                 for j in range(length // bkv)
                 if _in_band(i, j, bq, bkv, window)}
         assert _walk_query_sweep(length, bq, bkv, window)[1] == band
-        assert _walk_key_sweep(length, bq, bkv, window)[1] == band
+        assert _walk_backward_sweep(length, bq, bkv, window)[1] == band
+
+
+@pytest.mark.parametrize("length,window,bq,bkv", [
+    (16384, 2048, 1024, 1024), (8192, None, 1024, 1024),
+    (2048, 300, 256, 128), (2048, None, 128, 256)])
+def test_a_backward_sweep_ends_on_its_last_tile(length, window, bq, bkv):
+    """The steps a short sweep does not need come FIRST and name its
+    first tile: a new query tile's blocks are all asked for during the
+    sweep before's last tile, and every sweep's last step is the tile on
+    the diagonal (where the kernel writes ``dq``)."""
+    grid, _, steps = _walk_backward_sweep(length, bq, bkv, window)
+    tiles = kernel.query_sweep_tiles(length, bq, bkv, window)
+    for i in range(grid[3]):
+        fetched = [j for _, i_, j, _ in steps if i_ == i]
+        needed = [j for i_, j in tiles if i_ == i]
+        idle = grid[4] - len(needed)
+        assert fetched == [needed[0]] * idle + needed
+        assert fetched[-1] == kernel._last_kv(i, bq, bkv)
+
+
+@pytest.mark.parametrize("length,window,bq,bkv,group", [
+    (16384, 2048, 1024, 1024, 8),      # the window cell's
+    (16384, None, 1024, 1024, 8),
+    (8192, None, 1024, 1024, 1),       # one query head a key-value head
+    (2048, 300, 256, 128, 2), (2048, 300, 128, 256, 2),
+    (2048, None, 256, 128, 2), (2048, None, 128, 256, 2),
+    (512, 1, 128, 128, 4)])
+def test_a_key_tiles_gradients_leave_once_and_whole(length, window, bq, bkv,
+                                                    group):
+    """ISSUE 42: ``dk``, ``dv`` of a key-value head stand in VMEM while
+    its query heads' sweeps add to them. Walked through the backward
+    pass's own index maps and ``_whole_kv``: the output block named
+    changes only to a tile whose last contribution is that very step's
+    (the kernel writes it then, by the same predicate), never comes back
+    to a tile it left, and every tile is named at its last contribution;
+    so each tile reaches HBM once, after every sum into it."""
+    q_tiles, kv_tiles = length // bq, length // bkv
+    grid, _, steps = _walk_backward_sweep(length, bq, bkv, window, group)
+    last_read = {}          # key tile -> its last (head, query tile)
+    for r, i, j, _ in steps:
+        last_read[j] = (r, i)
+    assert sorted(last_read) == list(range(kv_tiles))
+    written, named_before = set(), 0
+    for r, i, j, named in steps:
+        writes = (r == group - 1 and j < kernel._whole_kv(
+            i, bq, bkv, window, q_tiles, kv_tiles))
+        assert writes == ((r, i) == last_read[j])
+        if named != named_before:
+            # a block is left only once written, and none is come back to
+            assert named_before in written and named not in written
+            assert writes and named == j
+        if writes:
+            assert named == j
+            written.add(j)
+        named_before = named
+    assert written == set(range(kv_tiles))

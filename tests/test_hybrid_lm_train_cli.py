@@ -90,7 +90,7 @@ def test_train_py_trains_checkpoints_and_resumes(entry_point_sandbox,
     assert len(attn) == 1
     assert attn[0]["layers"] == {"3": "blocks"} and attn[0]["length"] == 64
     assert attn[0]["head_dim"] == 16
-    assert set(attn[0]["tiles"]) == {"fwd", "dkv", "dq"}
+    assert set(attn[0]["tiles"]) == {"fwd", "bwd"}
     # the plain arm has no kernel whose residuals a block could keep
     assert attn[0]["kept_bytes"] == {"3": 0}
     # the two expert layers' grouped products, on the CPU: the plain arm

@@ -230,13 +230,23 @@ def test_attn_impl_counts_what_the_fused_blocks_keep(monkeypatch, yaml,
     assert here["layers"] == dict.fromkeys(names, "blocks")
     assert here["kept_bytes"] == dict.fromkeys(names, 0)
     assert here["kernel_head_dim"] == kernel_dim
+    assert "backward_products" not in here      # no kernel, no sweep
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     meta = lm.attn_impl(gen, (batch, 8192))
     assert meta["layers"] == dict.fromkeys(names, "fused")
     assert meta["kept_bytes"] == dict.fromkeys(names, a_layer)
+    # ISSUE 42: one backward sweep of five products a tile, a key-value
+    # head's float32 dk and dv standing in VMEM through it
+    assert meta["backward_products"] == 5
+    assert meta["vmem_accumulator_bytes"] == 2 * 8192 * kernel_dim * 4
+    assert set(meta["tiles"]) == {"fwd", "bwd"}
     report = render_report([{"kind": "meta", "name": "attn_impl", **meta}])
     assert (f"; the blocks keep {len(layers) * a_layer} bytes of the "
             "kernel's forward passes") in report
+    assert report.rstrip().endswith(
+        f"; one backward sweep of 5 products a tile, "
+        f"{2 * 8192 * kernel_dim * 4} bytes of a key-value head's dk and dv "
+        "standing in VMEM")
     # the line names the kernel's head size where it is not the model's
     assert ("fused at head size 128 (zero-padded), tiles" in report) == (
         kernel_dim != meta["head_dim"])

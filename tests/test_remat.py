@@ -278,7 +278,7 @@ def test_an_attention_block_through_the_flax_lift(monkeypatch, policy):
     from imaginaire_tpu.models.generators import hybrid_lm
     from imaginaire_tpu.ops import attention
 
-    tiles = attention.Tiles(fwd=(128, 128), dkv=(128, 128), dq=(128, 128))
+    tiles = attention.Tiles(fwd=(128, 128), bwd=(128, 128))
     monkeypatch.setattr(
         hybrid_lm, "attention",
         lambda q, k, v, block: attention.fused_causal_attention(
@@ -296,7 +296,7 @@ def test_an_attention_block_through_the_flax_lift(monkeypatch, policy):
         jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(variables, h).jaxpr)
     forward = calls.count("causal_gqa_fwd")
     assert sorted(calls) == sorted(
-        ["causal_gqa_fwd"] * forward + ["causal_gqa_dkv", "causal_gqa_dq"])
+        ["causal_gqa_fwd"] * forward + ["causal_gqa_bwd"])
     assert forward == {"none": 1, "blocks": 1, "dots_saveable": 2,
                        "save_nothing": 2}[policy]
     assert POLICIES[policy].keeps_kernel_residuals == (forward == 1)
