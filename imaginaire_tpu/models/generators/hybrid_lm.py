@@ -55,9 +55,11 @@ held experts' part of the result for the tokens routed to them and
 leaves the absent experts' terms out. ``vocab_slice`` is the slice of
 the vocabulary held here: ids, logits and loss are over the slice.
 
-The numerics are plain ``jax.numpy``/``lax`` but for attention: the
+The numerics are plain ``jax.numpy``/``lax`` but for attention and, on a
+TPU, the delta rule: the
 chunked state-space dual form of the Mamba-2 recurrence (``ssd_scan``),
-the chunked WY form of the delta rule (``kda_scan``),
+the chunked WY form of the delta rule (``ops/delta_rule.py``: one Pallas
+kernel a sweep where the shapes fill its tiles, ``kda_scan`` elsewhere),
 and dropless routing with static shapes (``route_held``: sort the
 assignments by expert, the held ones first, into a buffer of
 ``expert_buffer_rows`` rows, once a step: a recomputed block keeps the
@@ -108,6 +110,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from imaginaire_tpu.analysis import islands
 from imaginaire_tpu.config import cfg_get
+from imaginaire_tpu.ops import delta_rule
 from imaginaire_tpu.ops.attention import attention
 from imaginaire_tpu.ops.grouped_matmul import grouped_matmul
 from imaginaire_tpu.optim.remat import ROUTING_PLAN, remat_block
@@ -323,205 +326,9 @@ def _a_log_init(key, shape, dtype=jnp.float32):
 
 # ------------------------------------------- delta-rule linear attention
 
-_HIGHEST = lax.Precision.HIGHEST
-# rows of a sub-block of a chunk of the delta rule: only a sub-block
-# against itself builds (rows, rows, head size) decays; a chunk that
-# this does not divide is one sub-block
-KDA_SUB_BLOCK = 16
-# chunks of the delta rule whose sub-blocks' decays stand at once (4.2
-# MB a chunk of 64 at 8 heads of 128); the whole step's temporaries rise
-# with it (PERF.md, PR 35: 17 MB more at 16, 34 MB at 32)
-KDA_CHUNKS_AT_ONCE = 8
 # softplus(dt_bias) is drawn as Mamba-2's step size is: log-uniform over
 # these two, floored at the third
 KDA_TIME_STEP = (1e-3, 1e-1, 1e-4)
-
-
-def kda_sub_block(chunk):
-    """Rows of the sub-blocks a chunk of ``chunk`` steps is cut into."""
-    return KDA_SUB_BLOCK if chunk % KDA_SUB_BLOCK == 0 else chunk
-
-
-def _substituted(a):
-    """``(I + a)^-1`` of a strictly lower-triangular ``a`` (..., n, n) by
-    forward substitution, row by row."""
-    n = a.shape[-1]
-    inv = jnp.broadcast_to(jnp.eye(n, dtype=a.dtype), a.shape)
-    for i in range(1, n):
-        inv = inv.at[..., i, :].add(-jnp.einsum(
-            "...j,...jk->...k", a[..., i, :i], inv[..., :i, :],
-            precision=_HIGHEST))
-    return inv
-
-
-def unit_lower_inverse(a):
-    """``(I + a)^-1`` of a strictly lower-triangular ``a`` (..., n, n), in
-    ``a``'s float32: forward substitution row by row up to
-    ``KDA_SUB_BLOCK`` rows, and above that by halves, ``[[T, 0], [-B a_21
-    T, B]]`` of the halves' inverses ``T`` and ``B``. Where the halves
-    come down to sub-blocks of ``KDA_SUB_BLOCK`` rows (64 rows: four),
-    those substitute as one batch, so the steps that run one after
-    another are one sub-block's."""
-    n, sub = a.shape[-1], KDA_SUB_BLOCK
-    count = n // sub
-    batched = None
-    if n % sub == 0 and count > 1 and count & (count - 1) == 0:
-        batched = _substituted(jnp.stack(
-            [a[..., i:i + sub, i:i + sub] for i in range(0, n, sub)],
-            axis=-3))
-
-    def inverse(lo, hi):
-        if hi - lo <= sub:
-            return (_substituted(a[..., lo:hi, lo:hi]) if batched is None
-                    else batched[..., lo // sub, :, :])
-        mid = lo + (hi - lo) // 2
-        top, bottom = inverse(lo, mid), inverse(mid, hi)
-        corner = -jnp.matmul(jnp.matmul(bottom, a[..., mid:hi, lo:mid],
-                                        precision=_HIGHEST),
-                             top, precision=_HIGHEST)
-        return jnp.concatenate([
-            jnp.pad(top, [(0, 0)] * (a.ndim - 1) + [(0, hi - mid)]),
-            jnp.concatenate([corner, bottom], axis=-1)], axis=-2)
-
-    return inverse(0, n)
-
-
-def _kda_within_chunks(q, k, v, a, beta):
-    """What each chunk of the delta rule needs before the state carried
-    into it is known; every operand (N, H, C, ...) float32, ``N`` chunks
-    of ``C`` steps. With ``c`` the log-decay summed from the chunk's start
-    and ``T = (I + strict_lower(beta_i sum_d k_id k_jd e^(c_id - c_jd)))^-1``
-    (the WY form of the chunk's product of ``I - beta k k^T`` factors):
-    ``W = T (beta k e^c)``, ``U0 = T (beta v)``, the causal ``P_ij = sum_d
-    q_id k_jd e^(c_id - c_jd)``, ``q e^c``, ``k e^(c_end - c)`` and
-    ``e^(c_end)``. A decay is always ``exp`` of a difference ``c_i - c_j``
-    with ``i >= j``, at most 1; ``e^(-c_j)`` alone overflows on a fast
-    channel.
-
-    The two decayed products ``sum_d x_id k_jd e^(c_id - c_jd)`` (``x``
-    the rows of ``beta k`` and of ``q``) are built by sub-blocks of ``s =
-    KDA_SUB_BLOCK`` rows (a chunk that ``s`` does not divide is one
-    sub-block). Every ``a <= 0``, so ``c`` never rises along the rows;
-    ``r_I = c[s I]`` is the sum at sub-block ``I``'s first row. For a row
-    ``i`` of sub-block ``I`` and a column ``j``:
-
-    - ``j`` in the same sub-block: ``sum_d x_id k_jd exp(where(i >= j,
-      c_id - c_jd, -inf))`` on (s, s, d), the sub-blocks of all chunks
-      one batch axis: the only three-index tensor there is.
-    - ``j`` in an earlier sub-block (``j < s I``): ``sum_d (x_id e^(c_id -
-      r_Id)) (k_jd e^(r_Id - c_jd))``. Both exponents are at most 0 (``i
-      >= s I > j``), so neither factor overflows, and one that underflows
-      does so where the true product is smaller still. That is a plain
-      product on the matrix unit: for each ``I >= 1`` the stacked rows
-      ``[beta k; q]`` of the sub-block, (2 s, d), against the ``s I``
-      earlier keys scaled for this ``I``.
-    - ``j`` in a later sub-block: zero."""
-    chunk, dim = q.shape[2:]
-    sub = kda_sub_block(chunk)
-    count = chunk // sub
-    c = jnp.cumsum(a, axis=2)
-    k_beta = k * beta[..., None]
-
-    def blocks(x):      # (N, H, C, d) -> (N, H, C / s, s, d)
-        return x.reshape(*x.shape[:2], count, sub, dim)
-
-    c_sub, k_sub = blocks(c), blocks(k)
-    causal = jnp.tril(jnp.ones((sub, sub), bool))
-    decay = jnp.exp(jnp.where(
-        causal[..., None], c_sub[..., :, None, :] - c_sub[..., None, :, :],
-        -jnp.inf))
-
-    def within_sub_block(rows):     # (N, H, C / s, s, s)
-        return jnp.sum(blocks(rows)[..., :, None, :]
-                       * k_sub[..., None, :, :] * decay, axis=-1)
-
-    since_first = jnp.exp(c_sub - c_sub[..., :1, :])
-    stacked = jnp.concatenate([blocks(k_beta) * since_first,
-                               blocks(q) * since_first], axis=-2)
-
-    def earlier(i):     # (N, H, 2 s, s i): sub-block i's rows, earlier keys
-        keys = k[:, :, :sub * i] * jnp.exp(
-            c_sub[:, :, i, :1] - c[:, :, :sub * i])
-        return jnp.matmul(stacked[:, :, i], keys.swapaxes(-1, -2),
-                          precision=_HIGHEST)
-
-    before = [earlier(i) for i in range(count)]
-
-    def lower(diagonal, rows):  # (N, H, C, C) of its sub-blocks
-        return jnp.concatenate([
-            jnp.pad(jnp.concatenate([before[i][:, :, rows],
-                                     diagonal[:, :, i]], axis=-1),
-                    [(0, 0)] * 3 + [(0, chunk - sub * (i + 1))])
-            for i in range(count)], axis=-2)
-
-    a_kk = lower(jnp.where(jnp.eye(sub, dtype=bool), 0.0,
-                           within_sub_block(k_beta)), slice(0, sub))
-    p_qk = lower(within_sub_block(q), slice(sub, None))
-    solve = unit_lower_inverse(a_kk)
-    from_start = jnp.exp(c)
-    w = jnp.matmul(solve, k_beta * from_start, precision=_HIGHEST)
-    u0 = jnp.matmul(solve, v * beta[..., None], precision=_HIGHEST)
-    to_end = jnp.exp(c[:, :, -1:] - c)
-    return (w, u0, p_qk, q * from_start, k * to_end,
-            jnp.exp(c[:, :, -1]))
-
-
-def kda_scan(q, k, v, a, beta, chunk):
-    """The gated delta rule with a decay a key channel (Kimi Delta
-    Attention), per head with state ``S`` (d, d), ``S_0 = 0``:
-
-        S_t = (I - beta_t k_t k_t^T) Diag(exp(a_t)) S_{t-1} + beta_t k_t v_t^T
-        o_t = S_t^T q_t / sqrt(d)
-
-    evaluated in chunks of ``chunk`` steps: within a chunk by the WY form
-    (``_kda_within_chunks``: one unit-lower-triangular inverse a chunk),
-    ``KDA_CHUNKS_AT_ONCE`` chunks at a time under ``jax.checkpoint`` so
-    that their sub-blocks' (rows, rows, d) decays never stand for the
-    whole sequence; across chunks by the carried state, ``U = U0 - W S``, ``o =
-    (q e^c) S + P U``, ``S <- Diag(e^(c_end)) S + (k e^(c_end - c))^T U``.
-    ``q``, ``k``, ``v`` (B, L, H, d) in the compute dtype; the log-decays
-    ``a`` (B, L, H, d), at most 0, and ``beta`` (B, L, H) float32. All of
-    it runs in float32. Returns ``o`` (B, L, H, d) in ``v``'s dtype. A
-    length that the chunk does not divide is padded with steps that
-    leave the state as it is."""
-    islands.guard("delta_rule", a=a, beta=beta)
-    bsz, length, heads, dim = q.shape
-    dtype = v.dtype
-    pad = (-length) % chunk
-    n = (length + pad) // chunk
-
-    def chunked(x):     # (B, L, H, ...) -> (B n, H, C, ...)
-        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-        return x.reshape(bsz * n, chunk, *x.shape[2:]).swapaxes(1, 2)
-
-    # the entry casts stand outside the island, as the exit cast does:
-    # their gradients are casts down
-    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
-    with islands.scope("delta_rule"):
-        operands = [chunked(x) for x in (q / math.sqrt(dim), k, v, a, beta)]
-        at_once = math.gcd(bsz * n, KDA_CHUNKS_AT_ONCE)
-        within = lax.map(
-            jax.checkpoint(lambda xs: _kda_within_chunks(*xs)),
-            [x.reshape(-1, at_once, *x.shape[1:]) for x in operands])
-        # (n, B, H, ...): one step of the carry a chunk
-        w, u0, p_qk, q_in, k_out, through = (
-            x.reshape(bsz, n, *x.shape[2:]).swapaxes(0, 1) for x in within)
-
-        def carry(state, inputs):
-            w, u0, p_qk, q_in, k_out, through = inputs
-            u = u0 - jnp.matmul(w, state, precision=_HIGHEST)
-            out = (jnp.matmul(q_in, state, precision=_HIGHEST)
-                   + jnp.matmul(p_qk, u, precision=_HIGHEST))
-            state = through[..., None] * state + jnp.matmul(
-                k_out.swapaxes(-1, -2), u, precision=_HIGHEST)
-            return state, out
-
-        _, out = lax.scan(carry, jnp.zeros((bsz, heads, dim, dim),
-                                           jnp.float32),
-                          (w, u0, p_qk, q_in, k_out, through))
-    # (n, B, H, C, d) -> (B, L, H, d)
-    out = out.transpose(1, 0, 3, 2, 4).reshape(bsz, n * chunk, heads, dim)
-    return out[:, :length].astype(dtype)
 
 
 def l2_norm(x):
@@ -592,7 +399,8 @@ class KDAMixer(nn.Module):
                 a = (-jnp.exp(a32)[:, None] * jax.nn.softplus(
                     f32 + bias32).reshape(*lead, heads, dim))
                 beta32 = 2.0 * jax.nn.sigmoid(beta32)
-            o = kda_scan(q, k, v, a, beta32, g.kda_chunk_size)
+            o = delta_rule.delta_rule(q, k, v, a, beta32,
+                                      g.kda_chunk_size)
         with jax.named_scope("lm/attn/kda_gate_norm"):
             y = (rms_norm(o, w_norm, g.norm_eps)
                  * jax.nn.sigmoid(gate).reshape(o.shape))

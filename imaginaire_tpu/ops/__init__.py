@@ -46,6 +46,16 @@ fused Pallas kernel of ``ops/pallas/causal_attention_kernel.py`` or
 query blocks in plain ``jax.numpy``. Import it as a module,
 ``from imaginaire_tpu.ops import attention``.
 
+delta_rule
+----------
+``ops/delta_rule.py`` (the token model's gated delta rule, Kimi Delta
+Attention's chunked WY form) is not a reference op either and takes no
+``implementation``: it picks its own arm from the backend, the head size,
+the chunk and the length (``arm_of``), the two Pallas sweeps of
+``ops/pallas/delta_rule_kernel.py`` or ``kda_scan`` in plain
+``jax.numpy``. Import it as a module,
+``from imaginaire_tpu.ops import delta_rule``.
+
 auto pins
 ---------
 Every ``AUTO_IMPLEMENTATION`` is pinned to the XLA formulation; not

@@ -1126,7 +1126,16 @@ def render_report(path_or_events):
             f"- kda_impl: layers {', '.join(map(str, kda.get('layers')))}; "
             f"{kda.get('heads')} heads of {kda.get('head_dim')} held; "
             f"chunks of {kda.get('chunk')} steps in sub-blocks of "
-            f"{kda.get('sub_block')}, {kda.get('chunks_at_once')} at once")
+            f"{kda.get('sub_block')}, {kda.get('chunks_at_once')} at once"
+            + ("; " + ", ".join(
+                f"layer {i} {arm}" for i, arm in sorted(
+                    kda["arm"].items(), key=lambda kv: int(kv[0])))
+               + "; fused tiles (chunks a grid step) "
+               + ", ".join(f"{k} {v}" for k, v in
+                           (kda.get("tiles") or {}).items())
+               + f"; the blocks keep {sum(kda['kept_bytes'].values())} "
+               "bytes of the kernel's forward sweeps for its backward "
+               "sweeps" if "arm" in kda else ""))
     moe = s["meta"].get("moe_impl")
     if moe:
         lines.append(

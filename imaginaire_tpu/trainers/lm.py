@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from imaginaire_tpu import telemetry
 from imaginaire_tpu.config import as_attrdict, cfg_get
 from imaginaire_tpu.models.generators import hybrid_lm
-from imaginaire_tpu.ops import attention, grouped_matmul
+from imaginaire_tpu.ops import attention, delta_rule, grouped_matmul
 from imaginaire_tpu.optim.remat import resolve_policy
 from imaginaire_tpu.trainers.base import BaseTrainer
 
@@ -81,23 +81,38 @@ def attn_impl(gen_cfg, tokens_shape):
     return meta
 
 
-def kda_impl(gen_cfg):
-    """The ``kda_impl`` meta: the delta-rule layers of the pattern, the
-    heads held here (how many the whole layer has is the deployment's to
-    say, not the program's) at their size, the chunk of the WY form, the
-    rows of the sub-blocks its decayed products are built by (the whole
-    chunk where ``KDA_SUB_BLOCK`` does not divide it) and how many
-    chunks' decays stand at once; None for a model without such a
-    layer."""
+def kda_impl(gen_cfg, tokens_shape):
+    """The ``kda_impl`` meta of a (batch, length) step: the delta-rule
+    layers of the pattern, the heads held here (how many the whole layer
+    has is the deployment's to say, not the program's) at their size, the
+    chunk of the WY form, the rows of the sub-blocks its decayed products
+    are built by (the whole chunk where ``KDA_SUB_BLOCK`` does not divide
+    it), how many chunks' decays stand at once on the ``chunks`` arm, the
+    arm each layer takes at this length on this backend
+    (``ops/delta_rule.py`` decides; nothing here does), the fused arm's
+    tiles (chunks a grid step of each sweep) and the bytes each layer's
+    block keeps of the kernel's forward sweep for its backward sweep (the
+    output and the chunks' entry states: ``gen.remat``'s policy decides;
+    0 on the ``chunks`` arm, which has no kernel); None for a model
+    without such a layer."""
     g = hybrid_lm.model_settings(gen_cfg)
     layers = [i for i, kind in enumerate(hybrid_lm.layer_kinds(g))
               if kind == "K"]
     if not layers:
         return None
+    bsz, length = (int(n) for n in tokens_shape)
+    arm = delta_rule.arm_of(g.kda_head_dim, g.kda_chunk_size, length)
+    kept = (delta_rule.residual_bytes(
+        bsz, length, g.kda_num_heads, g.kda_head_dim, g.kda_chunk_size)
+            if arm == "fused"
+            and resolve_policy(g.remat).keeps_kernel_residuals else 0)
     return dict(layers=layers, heads=g.kda_num_heads,
                 head_dim=g.kda_head_dim, chunk=g.kda_chunk_size,
-                sub_block=hybrid_lm.kda_sub_block(g.kda_chunk_size),
-                chunks_at_once=hybrid_lm.KDA_CHUNKS_AT_ONCE)
+                sub_block=delta_rule.kda_sub_block(g.kda_chunk_size),
+                chunks_at_once=delta_rule.KDA_CHUNKS_AT_ONCE,
+                arm={str(i): arm for i in layers},
+                tiles=delta_rule.TILES._asdict(),
+                kept_bytes={str(i): kept for i in layers})
 
 
 def moe_impl(gen_cfg, tokens_shape):
@@ -205,9 +220,8 @@ class Trainer(BaseTrainer):
         if not tm.enabled:
             return
         tm.meta("attn_impl", **attn_impl(self.cfg.gen, tokens_shape))
-        for name, meta in (("kda_impl", kda_impl(self.cfg.gen)),
-                           ("moe_impl", moe_impl(self.cfg.gen,
-                                                 tokens_shape))):
+        for name, impl in (("kda_impl", kda_impl), ("moe_impl", moe_impl)):
+            meta = impl(self.cfg.gen, tokens_shape)
             if meta:
                 tm.meta(name, **meta)
 

@@ -33,6 +33,7 @@ from hybrid_lm_util import PRESETS, layer_params, seeded, tiny_cfg
 
 from imaginaire_tpu.analysis import islands
 from imaginaire_tpu.models.generators import hybrid_lm
+from imaginaire_tpu.ops import delta_rule
 
 
 def _close(ours, theirs, tol=2e-5):
@@ -112,7 +113,7 @@ def _kda_scan_against_the_recurrence(operands, chunk):
     from benchmark.reference import solar_open2_train as reference
 
     def ours(q, k, v, a, beta):
-        return hybrid_lm.kda_scan(q, k, v, a, beta, chunk)
+        return delta_rule.kda_scan(q, k, v, a, beta, chunk)
 
     def theirs(q, k, v, a, beta):
         return jax.vmap(reference.delta_rule)(q, k, v, a, beta)
@@ -166,7 +167,7 @@ def test_a_chunk_is_cut_into_sub_blocks_where_16_divides_it(chunk, rows):
     """What the `kda_impl` meta says of a run: the token cell's chunk of
     64 is four sub-blocks of 16; a chunk of at most 16 rows, or one that
     16 does not divide, is one."""
-    assert hybrid_lm.kda_sub_block(chunk) == rows
+    assert delta_rule.kda_sub_block(chunk) == rows
 
 
 def test_the_delta_rule_is_a_float32_island_under_bfloat16_compute():
@@ -204,7 +205,7 @@ def test_unit_lower_inverse_inverts():
     of 16 rows substitute as one batch."""
     for n in (16, 40, 64):
         a = jnp.tril(jax.random.normal(jax.random.PRNGKey(n), (3, n, n)), -1)
-        inverse = hybrid_lm.unit_lower_inverse(0.3 * a)
+        inverse = delta_rule.unit_lower_inverse(0.3 * a)
         _close(inverse @ (jnp.eye(n) + 0.3 * a),
                jnp.broadcast_to(jnp.eye(n), a.shape), tol=1e-5)
         assert float(jnp.abs(jnp.triu(inverse, 1)).max()) == 0

@@ -180,22 +180,25 @@ def test_grouped_products_compile_at_the_token_cells_widths(one_chip, hidden,
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * stack
 
 
-def test_kda_layer_compiles_at_the_token_cells_shape(one_chip):
+def test_kda_layer_compiles_at_the_token_cells_shape(one_chip, monkeypatch):
     """A Kimi Delta Attention mixer of solar_open2_250b (8 heads of 128,
     chunks of 64, 8,192 positions, bfloat16 compute), value and gradients
-    under the block's checkpoint: the chip's compiler takes the chunked
-    delta rule (the row-by-row inverse, the map over blocks of chunks,
-    the scan over chunks) and its gradient. The decays are built by
-    sub-blocks of 16 rows (ISSUE 35): a float32 (..., 16, 16, 128)
-    stands in the compiled text and no (..., 64, 64, 128), and the
-    layer's temporaries stay under the 0.97 GB they were with the whole
-    chunk's decays (0.950 GB as it stands: the carry's kept steps, not
-    the decays, set the peak). Every instruction of the mixer names one
-    of its scopes."""
+    under the block's checkpoint, on the arm the chip takes (ISSUE 43):
+    the chip's compiler takes the delta rule's two kernels, one forward
+    sweep (the block keeps its output and the chunks' entry states, so
+    the recompute holds no second one) and one backward sweep, each a
+    custom call on one line under ``lm/attn/kda_scan``; no ``while`` is
+    left under that scope; nothing (..., 16, 16, 128) or (..., 64, 64,
+    128) of the decays stands in HBM; the layer's temporaries are 0.464
+    GB (464,229,376 bytes as this test compiles it), the kept float32
+    output and entry states in them, where the ``chunks`` arm's are
+    0.950 (the carry's kept steps set that peak). Every instruction of
+    the mixer names one of its scopes."""
     import re
 
     from imaginaire_tpu.config import Config
     from imaginaire_tpu.models.generators import hybrid_lm
+    from imaginaire_tpu.ops import delta_rule
     from imaginaire_tpu.optim.remat import POLICIES
 
     gen = Config(os.path.join(ROOT, "configs", "projects", "solar_open2",
@@ -209,6 +212,9 @@ def test_kda_layer_compiles_at_the_token_cells_shape(one_chip):
                                          jnp.bfloat16)))
     params = jax.tree_util.tree_map(
         lambda leaf: _sds(leaf.shape, leaf.dtype, one_chip), params)
+    # the arm decides as it would on the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert delta_rule.arm_of(128, 64, 8192) == "fused"
 
     def loss(params, u):
         return jnp.sum(mixer.apply(params, u).astype(jnp.float32))
@@ -218,15 +224,84 @@ def test_kda_layer_compiles_at_the_token_cells_shape(one_chip):
             jax.checkpoint(loss, policy=POLICIES["blocks"].policy),
             argnums=(0, 1)),
         params, _sds((1, 8192, g.hidden_size), jnp.bfloat16, one_chip))
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.97e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
     text = compiled.as_text()
-    assert re.search(r"f32\[(\d+,)*16,16,128\]", text)
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert _kernel_calls(text) == ["delta_rule_bwd", "delta_rule_fwd"]
+    assert all('op_name="' in line and "lm/attn/kda_scan" in line
+               for line in calls)
+    assert not [line for line in text.splitlines()
+                if " while(" in line and "lm/attn/kda_scan" in line]
+    assert not re.search(r"f32\[(\d+,)*16,16,128\]", text)
     assert not re.search(r"f32\[(\d+,)*64,64,128\]", text)
     scopes = {scope for name in re.findall(r'op_name="([^"]*)"', text)
               for scope in re.findall(r"lm/attn/\w+", name)[-1:]}
     assert scopes == {"lm/attn/kda_proj", "lm/attn/kda_conv",
                       "lm/attn/kda_scan", "lm/attn/kda_gate_norm",
                       "lm/attn/out"}
+
+
+def _delta_rule_operands(one_chip, length, heads, dim):
+    rows = _sds((1, length, heads, dim), jnp.bfloat16, one_chip)
+    return (rows, rows, rows,
+            _sds((1, length, heads, dim), jnp.float32, one_chip),
+            _sds((1, length, heads), jnp.float32, one_chip))
+
+
+def _kernel_calls(text):
+    return sorted(
+        line.split("=")[0].strip().lstrip("%").split(".")[0]
+        for line in text.splitlines()
+        if "custom-call(" in line and "tpu_custom_call" in line)
+
+
+@pytest.mark.parametrize("dim,chunk", [(256, 64), (128, 128), (128, 32),
+                                       (128, 16)])
+def test_delta_rule_kernels_compile_at_the_other_shapes_the_rule_sends_them(
+        one_chip, monkeypatch, dim, chunk):
+    """What ``arm_of`` calls ``fused`` beside the token cell's 128 and
+    64: head size 256 (a (8, 256, 256) state in scratch and blocks twice
+    as wide under the same VMEM limit) and chunks of one, two and eight
+    sub-blocks, value and gradients at 8 heads and 8,192 positions under
+    the committed ``TILES``. The chip's compiler takes both sweeps."""
+    from imaginaire_tpu.ops import delta_rule
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert delta_rule.arm_of(dim, chunk, 8192) == "fused"
+
+    def loss(*xs):
+        return jnp.sum(delta_rule.delta_rule(*xs, chunk).astype(jnp.float32))
+
+    compiled = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
+                        *_delta_rule_operands(one_chip, 8192, 8, dim))
+    assert _kernel_calls(compiled.as_text()) == ["delta_rule_bwd",
+                                                 "delta_rule_fwd"]
+
+
+def test_the_chunks_arm_compiles_for_the_chip_at_a_ragged_length(one_chip):
+    """A length the chunk does not divide takes ``kda_scan`` on a TPU too
+    (``arm_of``), so the chip's compiler still has to take the XLA form
+    and its gradient: the map over blocks of chunks and the carry as
+    ``while`` loops, the sub-blocks' float32 (..., 16, 16, 128) decays,
+    no (..., 64, 64, 128), and no kernel. ``default_backend`` is this
+    process's own here: the CPU takes the same arm."""
+    import re
+
+    from imaginaire_tpu.ops import delta_rule
+
+    length = 1000
+    assert delta_rule.arm_of(128, 64, length) == "chunks"
+
+    def loss(*xs):
+        return jnp.sum(delta_rule.delta_rule(*xs, 64).astype(jnp.float32))
+
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
+                    *_delta_rule_operands(one_chip, length, 2, 128)).as_text()
+    assert _kernel_calls(text) == []
+    assert " while(" in text
+    assert re.search(r"f32\[(\d+,)*16,16,128\]", text)
+    assert not re.search(r"f32\[(\d+,)*64,64,128\]", text)
 
 
 def test_the_short_convolution_share_s_step_fits_one_chip(one_chip,
