@@ -36,8 +36,8 @@ class IslandViolation(TypeError):
 
 
 def register(name, description, where=""):
-    """Declare an fp32 island. ``where`` is the home module, for docs
-    and reports."""
+    """Declare an fp32 island. ``where`` is the home module (or the
+    two that share it, each with its part), for docs and reports."""
     _REGISTRY[str(name)] = {"description": str(description),
                             "where": str(where)}
     return str(name)
@@ -116,7 +116,9 @@ register("ssm_scan",
          "a state-space scan's step sizes (softplus), decays exp(dt A), "
          "their cumulative sums and the state carried across chunks stay "
          "fp32; bf16 decays compound over thousands of steps",
-         where="imaginaire_tpu/models/generators/hybrid_lm.py")
+         where="imaginaire_tpu/ops/state_space.py (the scan), "
+               "imaginaire_tpu/models/generators/hybrid_lm.py (the mixer's "
+               "step sizes and decays)")
 register("rotary_angles",
          "a rotary position embedding's angles (position times frequency, "
          "thousands of radians at long contexts), their cosines and sines "
@@ -130,4 +132,6 @@ register("delta_rule",
          "state carried across chunks stay fp32; a bf16 decay compounds "
          "over thousands of steps and a bf16 solve loses the rank-one "
          "corrections it sums",
-         where="imaginaire_tpu/models/generators/hybrid_lm.py")
+         where="imaginaire_tpu/ops/delta_rule.py (the rule), "
+               "imaginaire_tpu/models/generators/hybrid_lm.py (the mixer's "
+               "log-decays and beta)")

@@ -55,37 +55,20 @@ held experts' part of the result for the tokens routed to them and
 leaves the absent experts' terms out. ``vocab_slice`` is the slice of
 the vocabulary held here: ids, logits and loss are over the slice.
 
-The numerics are plain ``jax.numpy``/``lax`` but for attention and, on a
-TPU, the delta rule: the
-chunked state-space dual form of the Mamba-2 recurrence (``ssd_scan``),
-the chunked WY form of the delta rule (``ops/delta_rule.py``: one Pallas
-kernel a sweep where the shapes fill its tiles, ``kda_scan`` elsewhere),
-and dropless routing with static shapes (``route_held``: sort the
-assignments by expert, the held ones first, into a buffer of
-``expert_buffer_rows`` rows, once a step: a recomputed block keeps the
-order; two grouped products by
-``ops/grouped_matmul.py`` (three where the expert is gated; this repo's
-kernel on a TPU at widths that fill a lane tile, ``lax.ragged_dot``
-elsewhere), scatter back weighted). ``expert_buffer_rows`` is the buffer's capacity,
-what the largest routing may hold; a step computes the filled prefix of
-it (``on_filled_prefix``: a short tier where its held assignments fit
-that, a row a token or, where the held experts' even share is more than
-half of that, twice the even share (``expert_tiers``); the whole buffer
-otherwise, the same arithmetic either way), and moves rows into a tier
-and out of it by segments, as far as the step's held rows reach
-(``gather_rows``, ``add_rows``; the backward pass is written out,
-``held_experts_part_bwd``).
-The causal scores, under a window or not, live in ``ops/attention.py``,
-which picks its own arm from what it observes: one fused Pallas kernel
-that keeps the scores in VMEM (and skips the tiles outside the band)
-where the backend is a TPU, the head size a multiple of
-128 (or 64: zero-padded to 128 inside the arm) and the length a
-multiple of the kernel's tiles; query blocks of
-``attn_query_block`` rows in plain ``jax.numpy`` everywhere else (the
-CPU, ragged lengths). The fp32 islands (router scores, the scan's step
-sizes, decays and carried state, the delta rule's decays, solve and
-state, the rotary angles, RMS statistics, the loss) are declared in
-``analysis/islands.py``.
+How a layer is computed lives in ``ops/``, one module a block of numerics
+that has arms, constants or a written-out gradient, each taking shapes
+and sizes (never ``Settings``) and, where it has more than one arm,
+picking its own from what it observes:
+``ops/attention.py`` (the causal scores, under a window or not),
+``ops/state_space.py`` (the Mamba-2 recurrence's chunked dual form,
+``ssd_scan``), ``ops/delta_rule.py`` (the chunked WY form of the delta
+rule), ``ops/held_experts.py`` (dropless routing with static shapes over
+the held share: the sort into a buffer of ``expert_buffer_rows`` rows,
+the tiers of its filled prefix, the rows' movement and the backward pass
+written out) and ``ops/grouped_matmul.py`` (the experts' products). The
+fp32 islands (router scores, the scan's step sizes, decays and carried
+state, the delta rule's decays, solve and state, the rotary angles, RMS
+statistics, the loss) are declared in ``analysis/islands.py``.
 
 Precision: the parameters arrive in float32 and each layer casts its
 kernels to ``compute_dtype`` where it uses them, inside the layer's
@@ -106,14 +89,12 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
-from jax.ad_checkpoint import checkpoint_name
 
 from imaginaire_tpu.analysis import islands
 from imaginaire_tpu.config import cfg_get
-from imaginaire_tpu.ops import delta_rule
+from imaginaire_tpu.ops import delta_rule, held_experts, state_space
 from imaginaire_tpu.ops.attention import attention
-from imaginaire_tpu.ops.grouped_matmul import grouped_matmul
-from imaginaire_tpu.optim.remat import ROUTING_PLAN, remat_block
+from imaginaire_tpu.optim.remat import remat_block
 
 
 def _kernel_init(key, shape, dtype=jnp.float32):
@@ -135,27 +116,15 @@ def rms_norm(x, scale, eps, groups=1):
     return y.astype(dtype)
 
 
-def relu2(x):
-    return jnp.square(jax.nn.relu(x))
-
-
 # ``hidden_act`` -> whether a feed-forward has a gate beside its up-product
 GATED = {"relu2": False, "silu": True}
-
-
-def hidden_activation(ups):
-    """A feed-forward's hidden activations from its in-products: of one,
-    ``relu(up)^2``; of two, the gated ``silu(gate) * up``."""
-    if len(ups) == 1:
-        return relu2(ups[0])
-    gate, up = ups
-    return jax.nn.silu(gate) * up
 
 
 def feed_forward(x, kernels):
     """``W_down act(...)`` of ``kernels`` (gate, up, down) or (up, down),
     each cast to ``x``'s dtype where it is used."""
-    hidden = hidden_activation([x @ w.astype(x.dtype) for w in kernels[:-1]])
+    hidden = held_experts.hidden_activation(
+        [x @ w.astype(x.dtype) for w in kernels[:-1]])
     return hidden @ kernels[-1].astype(x.dtype)
 
 
@@ -182,81 +151,6 @@ def causal_conv1d(x, kernel, bias):
     for i in range(k):
         out = out + padded[:, i:i + length] * kernel[i]
     return out
-
-
-def ssd_scan(x, dt, a, b, c, chunk):
-    """The Mamba-2 recurrence, per head with state ``S`` (P, N):
-
-        S_t = exp(dt_t a) S_{t-1} + dt_t x_t b_t^T,    y_t = S_t c_t
-
-    evaluated in chunks of ``chunk`` steps: within a chunk by the masked
-    ``c b^T`` product, across chunks by the carried state. ``x``
-    (B, L, H, P) and ``b``, ``c`` (B, L, G, N) in the compute dtype (head
-    ``h`` reads group ``h // (H/G)``); ``dt`` (B, L, H) and ``a`` (H,)
-    float32. Step sizes, decays and the carried state stay float32.
-    Returns ``y`` (B, L, H, P) in ``x``'s dtype. A length that the chunk
-    does not divide is padded with steps of size zero."""
-    islands.guard("ssm_scan", dt=dt, a=a)
-    bsz, length, heads, _ = x.shape
-    groups = b.shape[2]
-    per = heads // groups
-    pad = (-length) % chunk
-    if pad:
-        x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
-                       for v in (x, dt, b, c))
-    n = (length + pad) // chunk
-    dtype = x.dtype
-
-    def chunked(v):
-        return v.reshape(bsz, n, chunk, *v.shape[2:])
-
-    x, dt, b, c = chunked(x), chunked(dt), chunked(b), chunked(c)
-    x32 = x.astype(jnp.float32)
-    with islands.scope("ssm_scan"):
-        cum = jnp.cumsum(dt * a, axis=2).swapaxes(2, 3)   # (B, n, H, Q)
-        # decay from step s to step l of one chunk, l >= s
-        tril = jnp.tril(jnp.ones((chunk, chunk), bool))
-        within = jnp.exp(jnp.where(
-            tril, cum[..., :, None] - cum[..., None, :], -jnp.inf))
-        to_end = jnp.exp(cum[..., -1:] - cum).swapaxes(2, 3)  # (B, n, Q, H)
-        from_start = jnp.exp(cum).swapaxes(2, 3)              # (B, n, Q, H)
-        chunk_decay = jnp.exp(cum[..., -1])                   # (B, n, H)
-        xdt32 = x32 * dt[..., None]
-        decayed32 = xdt32 * to_end[..., None]
-
-    def grouped(v):                        # (B, n, Q, H, P) -> (.., G, per, P)
-        return v.reshape(*v.shape[:3], groups, per, v.shape[-1])
-
-    xdt = grouped(xdt32.astype(dtype))
-    decayed = grouped(decayed32.astype(dtype))
-    # within a chunk: (c_l . b_s) decay(l, s) dt_s x_s, summed over s <= l
-    cb = jnp.einsum("bnlgk,bnsgk->bngls", c, b,
-                    preferred_element_type=jnp.float32)
-    within = within.reshape(bsz, n, groups, per, chunk, chunk)
-    weights = (cb[:, :, :, None] * within).astype(dtype)
-    y = jnp.einsum("bngrls,bnsgrp->bnlgrp", weights, xdt,
-                   preferred_element_type=jnp.float32)
-    # what each chunk adds to the state by its end
-    added = jnp.einsum("bnsgrp,bnsgk->bngrpk", decayed, b,
-                       preferred_element_type=jnp.float32)
-    with islands.scope("ssm_scan"):
-        def carry(state, inputs):
-            decay, add = inputs
-            return state * decay[..., None, None] + add, state
-
-        added = added.reshape(bsz, n, heads, *added.shape[-2:])
-        _, before = lax.scan(carry, jnp.zeros_like(added[:, 0]),
-                             (chunk_decay.swapaxes(0, 1),
-                              added.swapaxes(0, 1)))
-        before = before.swapaxes(0, 1)                 # (B, n, H, P, N)
-    # the state carried into the chunk, read at each of its steps
-    before = before.reshape(bsz, n, groups, per, *before.shape[-2:])
-    read = jnp.einsum("bnlgk,bngrpk->bnlgrp", c, before.astype(dtype),
-                      preferred_element_type=jnp.float32)
-    with islands.scope("ssm_scan"):
-        y = y + read * from_start.reshape(bsz, n, chunk, groups, per, 1)
-    y = y.reshape(bsz, n * chunk, heads, -1)[:, :length]
-    return y.astype(dtype)
 
 
 class Mamba2Mixer(nn.Module):
@@ -299,9 +193,9 @@ class Mamba2Mixer(nn.Module):
                 a32 = -jnp.exp(a32)
             lead = x.shape[:2]
             x = x.reshape(*lead, heads, head_dim)
-            y = ssd_scan(x, dt32, a32,
-                         b.reshape(*lead, groups, state),
-                         c.reshape(*lead, groups, state), g.chunk_size)
+            y = state_space.ssd_scan(
+                x, dt32, a32, b.reshape(*lead, groups, state),
+                c.reshape(*lead, groups, state), g.chunk_size)
             y = y + x * d_skip.astype(dtype)[:, None]
             y = y.reshape(*lead, inner)
         with jax.named_scope("lm/mamba2/gate_norm"):
@@ -602,287 +496,6 @@ def route(x32, w_router, score_bias, top_k, scaling):
     return experts.astype(jnp.int32), weights
 
 
-def route_held(experts, weights, first, count, rows):
-    """The held experts' assignments, sorted by expert, in a buffer of
-    ``rows`` rows. Returns (token (rows,) int32: the token of each row;
-    weight (rows,) float32: its routing weight, 0 on rows no assignment
-    fills; valid (rows,) bool: the rows one fills; group_sizes (count,)
-    int32: rows of each held expert, as the buffer holds them; stats:
-    ``held_assignments``, ``overflow`` (held assignments the buffer has
-    no row for), ``load_max_over_mean`` over the held experts,
-    ``buffer_occupancy``). The order and the experts' counts carry the
-    name ``ROUTING_PLAN``: a block recomputed under a policy that keeps
-    the name sorts once a step."""
-    tokens, top_k = experts.shape
-    local = (experts - first).reshape(-1)
-    held = (local >= 0) & (local < count)
-    local = jnp.where(held, local, count)            # the others sort last
-    order = jnp.argsort(local, stable=True)[:rows].astype(jnp.int32)
-    # a comparison with each held expert, summed: a ``bincount`` is a
-    # scatter-add of ones, 0.57 ms for 65,536 assignments on a v5e where
-    # this is 0.002 (PERF.md, PR 40)
-    sizes = (local[:, None] == jnp.arange(count)).sum(0, dtype=jnp.int32)
-    order, sizes = checkpoint_name((order, sizes), ROUTING_PLAN)
-    n_held = sizes.sum()
-    # the held assignments sort first
-    valid = jnp.arange(order.shape[0]) < n_held
-    token = order // top_k
-    weight = jnp.where(valid, weights.reshape(-1)[order], 0.0)
-    ends = jnp.minimum(jnp.cumsum(sizes), rows)
-    group_sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
-    mean = jnp.maximum(n_held, 1) / count
-    stats = {
-        "held_assignments": n_held,
-        "overflow": jnp.maximum(n_held - rows, 0),
-        "load_max_over_mean": sizes.max() / mean,
-        "buffer_occupancy": n_held / rows,
-    }
-    stats = {k: lax.stop_gradient(v).astype(jnp.float32)
-             for k, v in stats.items()}
-    return token, weight, valid, group_sizes, stats
-
-
-# The rows a tier is moved by: a sixteenth of it, where that is
-# ``SEGMENT_FLOOR`` rows or more (under it a trip costs more than its
-# rows: PERF.md, PR 40), else the tier whole. The rows' sum into
-# (tokens, hidden) goes by segments where its float32 accumulator is
-# under ``SEGMENTED_SUM_BYTES``: on a v5e a loop that carries one of 64
-# or 84 MiB adds a row as fast as the whole tier's scatter-add does, and
-# one of 128 MiB pays a quarter of a millisecond more a trip and loses
-# (PERF.md, PR 40; the limit lies between what was measured); and in the
-# whole-buffer tier, which sets the step's peak memory: one scatter-add
-# stands the tier's rows in float32 beside it (1.07 GB in the widest
-# share, which then compiles 0.8 GB over what it compiled to).
-SEGMENTS = 16
-SEGMENT_FLOOR = 512
-SEGMENTED_SUM_BYTES = 100 * 2 ** 20
-
-
-def segment_rows(rows):
-    """The rows of one segment of a tier of ``rows`` rows."""
-    segment = rows // SEGMENTS
-    whole = rows % SEGMENTS or segment < SEGMENT_FLOOR
-    return rows if whole else segment
-
-
-def _over_filled(filled, rows, body, init):
-    """``body(at, keep, carry)`` over the segments of a tier of ``rows``
-    rows that start before its first ``filled`` rows end, ascending:
-    ``at`` is the segment's first row and ``keep`` (segment,) says which
-    of its rows are filled. No trip where nothing is filled."""
-    segment = segment_rows(rows)
-    filled = jnp.minimum(filled, rows)
-
-    def trip(i, carry):
-        at = i * segment
-        return body(at, at + jnp.arange(segment) < filled, carry)
-
-    return lax.fori_loop(0, (filled + segment - 1) // segment, trip, init)
-
-
-def gather_rows(x, token, filled):
-    """``x[token]`` on the first ``filled`` rows and zeros past them,
-    (rows, hidden) in ``x``'s dtype, a segment at a time: a segment that
-    starts past the filled rows is not gathered."""
-    rows, segment = token.shape[0], segment_rows(token.shape[0])
-
-    def body(at, keep, out):
-        index = lax.dynamic_slice(token, (at,), (segment,))
-        part = x.at[index].get(mode="promise_in_bounds")
-        return lax.dynamic_update_slice(
-            out, jnp.where(keep[:, None], part, 0), (at, 0))
-
-    return _over_filled(filled, rows, body,
-                        jnp.zeros((rows, x.shape[1]), x.dtype))
-
-
-def add_rows(values, token, filled, tokens, capacity, weight=None):
-    """The first ``filled`` rows of ``values`` (rows, hidden), each times
-    its ``weight`` where one is given, added to row ``token`` of a zero
-    (tokens, hidden); what stands past the filled rows is masked. In
-    float32 and a segment at a time, not reading the segments past the
-    filled rows, where the sum's accumulator is under
-    ``SEGMENTED_SUM_BYTES`` or the tier is the whole buffer of
-    ``capacity`` rows; else the whole tier in one scatter-add, in
-    float32 where weighted and in ``values``' dtype where not (the
-    layer's sum and the transpose of its gather as they stood before
-    ISSUE 40)."""
-    rows, hidden = values.shape
-    segment = segment_rows(rows)
-
-    def weighted(part, at, keep):
-        if weight is not None:
-            part = part.astype(jnp.float32) * lax.dynamic_slice(
-                weight, (at,), keep.shape)[:, None]
-        return jnp.where(keep[:, None], part, 0)
-
-    if tokens * hidden * 4 >= SEGMENTED_SUM_BYTES and rows < capacity:
-        part = weighted(values, 0, jnp.arange(rows) < filled)
-        return jnp.zeros((tokens, hidden), part.dtype).at[token].add(part)
-
-    def body(at, keep, total):
-        index = lax.dynamic_slice(token, (at,), (segment,))
-        part = lax.dynamic_slice(values, (at, 0), (segment, hidden))
-        return total.at[index].add(
-            weighted(part, at, keep).astype(jnp.float32),
-            mode="promise_in_bounds")
-
-    return _over_filled(filled, rows, body,
-                        jnp.zeros((tokens, hidden), jnp.float32))
-
-
-def held_products(buffer, kernels, group_sizes):
-    """The held experts' feed-forward on the buffer's rows, (rows, hidden)
-    to (rows, hidden). A row past the groups' end is not the grouped
-    products' to write, forward or backward: whatever stands there is
-    masked between the products (its gradient is the first product's);
-    on the way in and on the way out the movement masks it."""
-    mask = (jnp.arange(buffer.shape[0]) < group_sizes.sum())[:, None]
-    with jax.named_scope("lm/moe/experts"):
-        act = hidden_activation([
-            jnp.where(mask, grouped_matmul(buffer, w, group_sizes), 0)
-            for w in kernels[:-1]])
-        return grouped_matmul(act, kernels[-1], group_sizes)
-
-
-def held_experts_part(x, kernels, weight, token, group_sizes, rows):
-    """The held experts' part of the layer's result, computed on the first
-    ``rows`` rows of ``route_held``'s buffer: all of it where the step
-    holds no more than ``rows`` assignments. Rows are moved into the
-    buffer and out of it as far as the held ones reach (``group_sizes``'
-    sum), by segments; the rows past them read as zeros. ``x`` (T, hidden)
-    and ``kernels`` (gate where the expert is gated, up: (count, hidden,
-    width); down: (count, width, hidden)) in the compute dtype."""
-    capacity = weight.shape[0]
-    token, weight = token[:rows], weight[:rows]
-    filled = group_sizes.sum()
-    with jax.named_scope("lm/moe/dispatch"):
-        buffer = gather_rows(x, token, filled)
-    out = held_products(buffer, kernels, group_sizes)
-    with jax.named_scope("lm/moe/combine"):
-        return add_rows(out, token, filled, x.shape[0], capacity,
-                        weight).astype(x.dtype)
-
-
-def weighted_rows_bwd(ct, out, weight, token, filled):
-    """The transpose of ``add_rows`` with a weight, for the sum's
-    cotangent ``ct`` (T, hidden): a row's cotangent is its weight times
-    its token's, (rows, hidden) in ``out``'s dtype, and its weight's is
-    the two rows' product, (rows,) float32; zeros past the first
-    ``filled`` rows, where ``out`` is not read."""
-    rows, hidden = out.shape
-    segment = segment_rows(rows)
-
-    def body(at, keep, carry):
-        d_out, d_weight = carry
-        index = lax.dynamic_slice(token, (at,), (segment,))
-        ct_rows = ct.at[index].get(mode="promise_in_bounds").astype(
-            jnp.float32)
-        out_rows = lax.dynamic_slice(out, (at, 0), (segment, hidden))
-        to_weight = (ct_rows * out_rows.astype(jnp.float32)).sum(-1)
-        to_out = ct_rows * lax.dynamic_slice(weight, (at,),
-                                             (segment,))[:, None]
-        to_out = jnp.where(keep[:, None], to_out, 0).astype(out.dtype)
-        return (lax.dynamic_update_slice(d_out, to_out, (at, 0)),
-                lax.dynamic_update_slice(
-                    d_weight, jnp.where(keep, to_weight, 0), (at,)))
-
-    return _over_filled(filled, rows, body, (
-        jnp.zeros_like(out), jnp.zeros((rows,), jnp.float32)))
-
-
-def held_experts_part_bwd(ct, x, kernels, weight, token, group_sizes, rows):
-    """The gradients of ``held_experts_part`` to ``x``, ``kernels`` and
-    ``weight`` for the result's cotangent ``ct`` (T, hidden), written out:
-    a loop that ends at the step's own count has no transpose. The
-    buffer and the products are computed again (a block keeps neither),
-    the products' gradients are the kernels' own rules, and the rows
-    move by the same segments as forward."""
-    capacity = weight.shape[0]
-    token, weight = token[:rows], weight[:rows]
-    filled = group_sizes.sum()
-    with jax.named_scope("lm/moe/dispatch"):
-        buffer = gather_rows(x, token, filled)
-    out, products_vjp = jax.vjp(
-        functools.partial(held_products, group_sizes=group_sizes),
-        buffer, kernels)
-    with jax.named_scope("lm/moe/combine"):
-        d_out, d_weight = weighted_rows_bwd(ct, out, weight, token, filled)
-    d_buffer, d_kernels = products_vjp(d_out)
-    with jax.named_scope("lm/moe/dispatch"):
-        d_x = add_rows(d_buffer, token, filled, x.shape[0],
-                       capacity).astype(x.dtype)
-    return d_x, d_kernels, jnp.pad(d_weight,
-                                   (0, capacity - d_weight.shape[0]))
-
-
-def expert_tiers(tokens, g):
-    """The ascending rows a step may compute an expert layer on: a short
-    tier where the step's held assignments fit it, the whole buffer of
-    ``expert_buffer_rows`` otherwise. The short tier is a row a token;
-    where the held experts' even share of the ``tokens`` x top-k
-    assignments is more than half of that, the fewest whole rows a token
-    that hold twice the even share: a tier at the even share itself sends
-    every second step to the whole buffer. Where twice the even share is
-    the whole buffer (a share that holds half the experts, as the four
-    unit-test configurations do) the short tier stays a row a token."""
-    capacity = g.expert_buffer_rows
-    even = tokens * g.num_experts_per_tok * g.held_count // g.n_routed_experts
-    short = max(1, -(-2 * even // tokens)) * tokens
-    if short >= capacity:
-        short = tokens
-    return tuple(sorted({min(short, capacity), capacity}))
-
-
-def _tier(tiers, n_held):
-    """The first of the ascending ``tiers`` with ``n_held`` rows or more
-    (the last, if none has)."""
-    return sum((n_held > rows).astype(jnp.int32) for rows in tiers[:-1])
-
-
-def moved_rows(tiers, n_held):
-    """The rows a pass over the tier that holds ``n_held`` assignments
-    moves: its segments up to the one the held rows end in."""
-    each = [jnp.minimum(jnp.ceil(n_held / segment_rows(rows))
-                        * segment_rows(rows), rows) for rows in tiers]
-    return jnp.stack(each)[_tier(tiers, n_held)]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def on_filled_prefix(tiers, n_held, x, kernels, weight, token, group_sizes):
-    """``held_experts_part`` on the shortest of the static, ascending
-    ``tiers`` of rows that holds the step's ``n_held`` assignments, by
-    ``lax.switch``. Its gradient is each tier's own, recomputed inside
-    the backward branch: differentiating through the switch instead hands
-    every tier's intermediates from a forward conditional to a backward
-    one, and a step on the short tier would write the long tier's as
-    zeros (1.5 GB a layer at the published widths)."""
-    return lax.switch(
-        _tier(tiers, n_held),
-        [functools.partial(held_experts_part, rows=rows) for rows in tiers],
-        x, kernels, weight, token, group_sizes)
-
-
-def _on_filled_prefix_fwd(tiers, n_held, *operands):
-    return on_filled_prefix(tiers, n_held, *operands), (n_held, operands)
-
-
-def _on_filled_prefix_bwd(tiers, saved, ct):
-    n_held, operands = saved
-    grads = lax.switch(
-        _tier(tiers, n_held),
-        [functools.partial(held_experts_part_bwd, rows=rows)
-         for rows in tiers], ct, *operands)
-    # the kernels' gradients leave the switch in the compute dtype: left
-    # to itself the compiler moves their casts to float32 into the
-    # branches, and eight leaves of twice the size stand until the
-    # optimizer's pass (2 GB of temporaries at the published widths)
-    return (None, *lax.optimization_barrier(grads), None, None)
-
-
-on_filled_prefix.defvjp(_on_filled_prefix_fwd, _on_filled_prefix_bwd)
-
-
 class MoEMixer(nn.Module):
     cfg: Any
 
@@ -916,19 +529,21 @@ class MoEMixer(nn.Module):
         # the held assignments sort first, so they fill the buffer's
         # prefix: a step that holds no more than the short tier's rows
         # computes on that prefix, any other on the whole buffer
-        tiers = expert_tiers(x.shape[0], g)
+        tiers = held_experts.expert_tiers(
+            x.shape[0], g.num_experts_per_tok, g.held_count,
+            g.n_routed_experts, capacity)
         with jax.named_scope("lm/moe/dispatch"):
-            token, weight, _, group_sizes, stats = route_held(
+            token, weight, _, group_sizes, stats = held_experts.route_held(
                 experts, weights, g.held_first, g.held_count, capacity)
             n_held = stats["held_assignments"]
             stats["compact"] = (n_held <= tiers[0]).astype(jnp.float32)
-            stats["moved_rows"] = moved_rows(tiers, n_held)
+            stats["moved_rows"] = held_experts.moved_rows(tiers, n_held)
         # the switch stands under no scope: its branches' operations
         # carry their own
         with jax.named_scope("lm/moe/experts"):
             kernels = tuple(w.astype(dtype) for w in kernels)
-        routed = on_filled_prefix(tiers, n_held, x, kernels, weight,
-                                  token, group_sizes)
+        routed = held_experts.on_filled_prefix(
+            tiers, n_held, x, kernels, weight, token, group_sizes)
         if shared is None:
             return routed.reshape(*lead, hidden), stats
         with jax.named_scope("lm/moe/shared"):

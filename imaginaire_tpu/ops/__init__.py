@@ -56,6 +56,19 @@ the chunk and the length (``arm_of``), the two Pallas sweeps of
 ``jax.numpy``. Import it as a module,
 ``from imaginaire_tpu.ops import delta_rule``.
 
+state_space, held_experts, grouped_matmul
+-----------------------------------------
+The token model's other blocks of numerics, modules likewise and without
+an ``implementation``: ``ops/state_space.py`` (the Mamba-2 recurrence's
+chunked dual form, ``ssd_scan``, one arm), ``ops/held_experts.py`` (an
+expert layer's held share: the sort into the buffer, the tiers of its
+filled prefix, the rows moved by segments, the backward pass written
+out) and ``ops/grouped_matmul.py`` (the experts' grouped products: the
+Pallas kernels of ``ops/pallas/grouped_matmul_kernel.py`` or
+``lax.ragged_dot``, by ``arm_of``). They take arrays, shapes and sizes;
+nothing under ``ops/`` imports a model or a trainer
+(``tests/test_ops_imports.py``).
+
 auto pins
 ---------
 Every ``AUTO_IMPLEMENTATION`` is pinned to the XLA formulation; not

@@ -22,9 +22,10 @@ what it can observe (``arm_of``), with no option:
 Both take the compute dtype's operands, accumulate in float32 and return
 the compute dtype, forward and in both gradients. Neither writes the rows
 past the last group, forward or in the gradient to the rows: the caller
-masks them (``hybrid_lm.held_experts_part``). The kernel's tiles were
-chosen on a v5e chip by ``scripts/sweep_grouped_products.py`` (PERF.md,
-PR 38); they are not configuration.
+masks them (``ops/held_experts.py``'s ``held_experts_part``). The
+kernel's tiles were chosen on a v5e chip by
+``scripts/sweep_grouped_products.py`` (PERF.md, PR 38); they are not
+configuration.
 """
 
 from __future__ import annotations

@@ -21,7 +21,8 @@ import jax.numpy as jnp
 from imaginaire_tpu import telemetry
 from imaginaire_tpu.config import as_attrdict, cfg_get
 from imaginaire_tpu.models.generators import hybrid_lm
-from imaginaire_tpu.ops import attention, delta_rule, grouped_matmul
+from imaginaire_tpu.ops import (attention, delta_rule, grouped_matmul,
+                                held_experts)
 from imaginaire_tpu.optim.remat import resolve_policy
 from imaginaire_tpu.trainers.base import BaseTrainer
 
@@ -132,7 +133,9 @@ def moe_impl(gen_cfg, tokens_shape):
         return None
     bsz, length = (int(n) for n in tokens_shape)
     hidden, width = g.hidden_size, g.moe_intermediate_size
-    tiers = hybrid_lm.expert_tiers(bsz * length, g)
+    tiers = held_experts.expert_tiers(
+        bsz * length, g.num_experts_per_tok, g.held_count,
+        g.n_routed_experts, g.expert_buffer_rows)
     arms = list(dict.fromkeys(
         grouped_matmul.arm_of(rows, *shape) for rows in tiers
         for shape in ((hidden, width), (width, hidden))))

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """What an expert layer does around its grouped products, alone, on the
-chip: finding the held experts' rows (``hybrid_lm.route_held``'s sort) and
+chip: finding the held experts' rows (``held_experts.route_held``'s sort) and
 moving rows into the buffer and out of it (``held_experts_part`` and its
 backward), at the token cells' shapes.
 
@@ -58,7 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from imaginaire_tpu.models.generators import hybrid_lm
+from imaginaire_tpu.ops import held_experts
 
 CALLS, GROUPS = 5, 8
 SHARES = (0.21, 0.06, 0.17, 0.11, 0.02, 0.19, 0.09, 0.15)
@@ -178,30 +178,30 @@ def move_variants():
         def by_segments(which, segment, tokens=tokens, rows=rows):
             def fn(x, buf, weight, token, filled):
                 with mock.patch.object(
-                        hybrid_lm, "segment_rows", lambda rows: segment
-                ), mock.patch.object(hybrid_lm, "SEGMENTED_SUM_BYTES",
+                        held_experts, "segment_rows", lambda rows: segment
+                ), mock.patch.object(held_experts, "SEGMENTED_SUM_BYTES",
                                      float("inf")):
                     if which == "gather":
-                        return hybrid_lm.gather_rows(x, token, filled)
+                        return held_experts.gather_rows(x, token, filled)
                     if which == "gather_t":
-                        return hybrid_lm.add_rows(
+                        return held_experts.add_rows(
                             buf, token, filled, tokens,
                             2 * rows).astype(x.dtype)
                     if which == "add":
-                        return hybrid_lm.add_rows(
+                        return held_experts.add_rows(
                             buf, token, filled, tokens, 2 * rows,
                             weight).astype(x.dtype)
                     if which == "add_halves":
                         half = buf.shape[1] // 2
-                        return jnp.concatenate([hybrid_lm.add_rows(
+                        return jnp.concatenate([held_experts.add_rows(
                             part, token, filled, tokens, 2 * rows, weight)
                             for part in (buf[:, :half], buf[:, half:])],
                             axis=1).astype(x.dtype)
-                    return hybrid_lm.weighted_rows_bwd(
+                    return held_experts.weighted_rows_bwd(
                         x, buf, weight, token, filled)
             return fn
 
-        derived = hybrid_lm.segment_rows(rows)
+        derived = held_experts.segment_rows(rows)
         segments = sorted({s for s in SEGMENTS if s <= rows} | {derived})
         for which in ("gather", "add", "add_halves", "gather_t", "add_t"):
             if which != "add_halves":

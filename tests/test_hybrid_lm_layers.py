@@ -33,7 +33,7 @@ from hybrid_lm_util import PRESETS, layer_params, seeded, tiny_cfg
 
 from imaginaire_tpu.analysis import islands
 from imaginaire_tpu.models.generators import hybrid_lm
-from imaginaire_tpu.ops import delta_rule
+from imaginaire_tpu.ops import delta_rule, held_experts, state_space
 
 
 def _close(ours, theirs, tol=2e-5):
@@ -75,7 +75,7 @@ def test_ssd_scan_is_the_step_by_step_recurrence(length):
                                     maxval=2.5))
 
     def ours(x, dt, a, b, c):
-        return hybrid_lm.ssd_scan(x, dt, a, b, c, sizes["chunk_size"])
+        return state_space.ssd_scan(x, dt, a, b, c, sizes["chunk_size"])
 
     def theirs(x, dt, a, b, c):
         return jax.vmap(reference.recurrence,
@@ -376,10 +376,10 @@ def _plain_held_experts_part(x, kernels, weight, token, group_sizes, rows):
     token, weight = token[:rows], weight[:rows]
     mask = (jnp.arange(token.shape[0]) < group_sizes.sum())[:, None]
     filled = jnp.where(mask, x[token], 0)
-    act = hybrid_lm.hidden_activation([
-        jnp.where(mask, hybrid_lm.grouped_matmul(filled, w, group_sizes), 0)
+    act = held_experts.hidden_activation([
+        jnp.where(mask, held_experts.grouped_matmul(filled, w, group_sizes), 0)
         for w in kernels[:-1]])
-    out = hybrid_lm.grouped_matmul(act, kernels[-1], group_sizes)
+    out = held_experts.grouped_matmul(act, kernels[-1], group_sizes)
     out = jnp.where(mask, out, 0).astype(jnp.float32) * weight[:, None]
     routed = jnp.zeros(x.shape, jnp.float32).at[token].add(out)
     return routed.astype(x.dtype)
@@ -395,7 +395,7 @@ def test_skew_loses_no_assignment():
     """ISSUE 27 (d): every token on one held expert; the buffer holds
     them all and the counts say so."""
     experts, weights = _route_all_to(2, 128)
-    token, weight, valid, group_sizes, stats = hybrid_lm.route_held(
+    token, weight, valid, group_sizes, stats = held_experts.route_held(
         experts, weights, first=0, count=4, rows=256)
     assert [int(n) for n in group_sizes] == [0, 0, 128, 0]
     assert int(valid.sum()) == 128 and float(stats["overflow"]) == 0
@@ -408,7 +408,7 @@ def test_skew_loses_no_assignment():
 
 def test_an_overfull_buffer_is_counted_not_silent():
     experts, weights = _route_all_to(1, 128)
-    _, weight, valid, group_sizes, stats = hybrid_lm.route_held(
+    _, weight, valid, group_sizes, stats = held_experts.route_held(
         experts, weights, first=0, count=4, rows=96)
     assert float(stats["overflow"]) == 32
     assert int(group_sizes.sum()) == 96 and int(valid.sum()) == 96
@@ -471,8 +471,8 @@ def _prefix_and_whole_buffer(preset):
     buffers = layer_params(buffers, index)
     u = _inputs(cfg, 64)
     programs = []
-    for tier_rule in (hybrid_lm.on_filled_prefix, whole_buffer):
-        with mock.patch.object(hybrid_lm, "on_filled_prefix", tier_rule):
+    for tier_rule in (held_experts.on_filled_prefix, whole_buffer):
+        with mock.patch.object(held_experts, "on_filled_prefix", tier_rule):
             programs.append(jax.jit(jax.value_and_grad(
                 run, argnums=(0, 2), has_aux=True)).lower(
                     params, buffers, u).compile())
@@ -517,7 +517,8 @@ def test_the_filled_prefix_is_the_whole_buffer(held, compact, preset):
                                    1.0 / g.moe_intermediate_size),
         **{name: jnp.zeros_like(params[name]).at[:, 8].set(0.5)
            for name in ins})
-    factor = float(hybrid_lm.hidden_activation([jnp.float32(0.5)] * len(ins)))
+    factor = float(held_experts.hidden_activation(
+        [jnp.float32(0.5)] * len(ins)))
     (_, (out, _)), _ = tiered(probe, buffers, u)
     experts, weights = hybrid_lm.route(
         u.reshape(128, -1), router, buffers["score_bias"],
@@ -563,12 +564,12 @@ def test_the_kernel_arm_is_the_plain_arm_in_the_expert_layer(held):
         return jnp.sum(out.astype(jnp.float32) * weights), (out, stats)
 
     def both(arm):
-        with mock.patch.object(hybrid_lm, "grouped_matmul", arm):
+        with mock.patch.object(held_experts, "grouped_matmul", arm):
             return jax.jit(jax.value_and_grad(run, argnums=(0, 1),
                                               has_aux=True))(params, u)
 
     (_, (out, stats)), grads = both(_kernel_arm)
-    (_, (out_p, stats_p)), grads_p = both(hybrid_lm.grouped_matmul)
+    (_, (out_p, stats_p)), grads_p = both(held_experts.grouped_matmul)
     assert float(stats["held_assignments"]) == held
     assert float(stats["compact"]) == float(held <= 128)
     assert out.dtype == jnp.bfloat16
@@ -627,8 +628,8 @@ def test_route_held_is_the_stable_argsort(case):
     in `bincount`'s place, the filled rows from the counts, and one sort."""
     experts, first, count, rows = _assignments(case)
     weights = jax.random.uniform(jax.random.PRNGKey(1), experts.shape)
-    route = functools.partial(hybrid_lm.route_held, first=first, count=count,
-                              rows=rows)
+    route = functools.partial(held_experts.route_held, first=first,
+                              count=count, rows=rows)
     ours = jax.jit(route)(experts, weights)
     theirs = jax.jit(functools.partial(
         _route_held_before, first=first, count=count, rows=rows))(
@@ -655,7 +656,7 @@ def _segmented_case(held, gated):
     c = _SEGMENTED
     capacity = c["tokens"] * c["top_k"]
     rows = capacity // 2
-    assert hybrid_lm.segment_rows(rows) == 512
+    assert held_experts.segment_rows(rows) == 512
     rng = np.random.default_rng(held)
     experts = rng.integers(4, 8, capacity)
     experts[rng.choice(capacity, held, replace=False)] = rng.integers(
@@ -663,7 +664,7 @@ def _segmented_case(held, gated):
     experts = jnp.asarray(experts.reshape(c["tokens"], c["top_k"]), jnp.int32)
     keys = jax.random.split(jax.random.PRNGKey(held), 6)
     weights = jax.random.uniform(keys[0], experts.shape)
-    token, weight, _, group_sizes, stats = hybrid_lm.route_held(
+    token, weight, _, group_sizes, stats = held_experts.route_held(
         experts, weights, 0, c["count"], capacity)
     assert float(stats["held_assignments"]) == held
     x = jax.random.normal(keys[1], (c["tokens"], c["hidden"]))
@@ -681,8 +682,8 @@ def sums(request, monkeypatch):
     """Both forms of `add_rows` in a tier that is not the whole buffer:
     the limit as it stands (the tests' sums are far under it) and one
     that no sum is under."""
-    assert hybrid_lm.SEGMENTED_SUM_BYTES == 100 * 2 ** 20
-    monkeypatch.setattr(hybrid_lm, "SEGMENTED_SUM_BYTES", request.param)
+    assert held_experts.SEGMENTED_SUM_BYTES == 100 * 2 ** 20
+    monkeypatch.setattr(held_experts, "SEGMENTED_SUM_BYTES", request.param)
 
 
 @pytest.mark.parametrize("gated", [False, True], ids=["relu2", "gated"])
@@ -697,9 +698,9 @@ def test_segmented_movement_is_the_whole_tiers(held, gated, sums):
     segments and with the sums in one scatter-add."""
     operands, ct, rows = _segmented_case(held, gated)
     floats, placed = operands[:3], operands[3:]
-    out = jax.jit(functools.partial(hybrid_lm.held_experts_part, rows=rows))(
-        *operands)
-    grads = jax.jit(functools.partial(hybrid_lm.held_experts_part_bwd,
+    out = jax.jit(functools.partial(held_experts.held_experts_part,
+                                    rows=rows))(*operands)
+    grads = jax.jit(functools.partial(held_experts.held_experts_part_bwd,
                                       rows=rows))(ct, *operands)
     want, vjp = jax.vjp(lambda *floats: _plain_held_experts_part(
         *floats, *placed, rows=rows), *floats)
@@ -732,16 +733,16 @@ def test_rows_past_the_filled_ones_are_not_read(filled, sums):
     x_nan = x.at[tokens // 2:].set(jnp.nan)
     clean = [jnp.nan_to_num(a) for a in (values, weight)]
 
-    gathered = hybrid_lm.gather_rows(x_nan, token, filled)
+    gathered = held_experts.gather_rows(x_nan, token, filled)
     np.testing.assert_array_equal(
         gathered, jnp.where(past, 0, x[token]))
-    added = hybrid_lm.add_rows(values, token, filled, tokens, 2 * rows,
+    added = held_experts.add_rows(values, token, filled, tokens, 2 * rows,
                                weight)
     assert np.isfinite(np.asarray(added)).all()
     _close(added, jnp.zeros((tokens, hidden)).at[token].add(
         clean[0] * clean[1][:, None]), tol=1e-6)
     assert not np.asarray(added[tokens // 2:]).any()
-    d_out, d_weight = hybrid_lm.weighted_rows_bwd(x, values, weight, token,
+    d_out, d_weight = held_experts.weighted_rows_bwd(x, values, weight, token,
                                                   filled)
     assert np.isfinite(np.asarray(d_out)).all()
     assert not np.asarray(d_out[filled:]).any()
@@ -758,7 +759,7 @@ def test_a_tiers_segment_follows_from_its_rows(rows, segment):
     """A sixteenth of the tier where that is 512 rows or more: the
     unit-test configurations' 128 and 256 rows are one segment, the four
     cells' tiers sixteen."""
-    assert hybrid_lm.segment_rows(rows) == segment
+    assert held_experts.segment_rows(rows) == segment
 
 
 @pytest.mark.parametrize("held,moved", [
@@ -767,7 +768,7 @@ def test_a_tiers_segment_follows_from_its_rows(rows, segment):
 def test_moved_rows_counts_the_segments_that_ran(held, moved):
     """`moe/<layer>/moved_rows`: the held rows rounded up to the chosen
     tier's segments, never past the tier."""
-    assert float(hybrid_lm.moved_rows(
+    assert float(held_experts.moved_rows(
         (8192, 65536), jnp.float32(held))) == moved
 
 
@@ -786,7 +787,7 @@ def _loss_sorts_and_grads(remat, named=True, values=True):
         return model.apply({**rest, "params": params}, data)["loss"]
 
     plain = contextlib.nullcontext() if named else mock.patch.object(
-        hybrid_lm, "checkpoint_name", lambda tree, name: tree)
+        held_experts, "checkpoint_name", lambda tree, name: tree)
     with plain:
         sorts = _sorts(jax.make_jaxpr(jax.value_and_grad(loss))(
             variables["params"]).jaxpr)
@@ -837,7 +838,7 @@ def test_the_plain_arm_leaves_the_step_program_as_it_was():
             variables["params"], rest, data).as_text()
 
     ours = text()
-    with mock.patch.object(hybrid_lm, "grouped_matmul", jax.lax.ragged_dot):
+    with mock.patch.object(held_experts, "grouped_matmul", jax.lax.ragged_dot):
         plain = text()
     assert ours == plain
 
@@ -1076,6 +1077,7 @@ def test_the_accepted_models_hold_the_parameters_they_held(yaml):
     (8192, 4, 8, 64, 32768, (8192, 32768)),      # glm4_7_flash: half a row
     (8192, 8, 8, 320, 65536, (8192, 65536)),     # solar_open2_250b
     (16384, 4, 8, 32, 65536, (32768, 65536)),    # lfm2_8b_a1b: a row a token
+    (16384, 8, 16, 128, 131072, (32768, 131072)),  # trinity_mini: the same
     (16384, 4, 8, 32, 16384, (16384,)),          # a buffer under the tier
     (128, 2, 4, 8, 256, (128, 256)),             # the unit-test YAMLs' own
     (100, 3, 4, 16, 300, (200, 300))])
@@ -1085,11 +1087,35 @@ def test_the_short_tier_holds_twice_the_even_share(tokens, top_k, held, of,
     assignments is a row a token, a tier of a row a token would send
     every second step to the whole buffer; the accepted configurations,
     whose share is half a row a token or less, keep the tiers they had."""
-    from types import SimpleNamespace
+    assert held_experts.expert_tiers(tokens, top_k, held, of,
+                                     capacity) == tiers
 
-    g = SimpleNamespace(num_experts_per_tok=top_k, held_count=held,
-                        n_routed_experts=of, expert_buffer_rows=capacity)
-    assert hybrid_lm.expert_tiers(tokens, g) == tiers
+
+@pytest.mark.parametrize("yaml,tiers", [
+    ("nemotron_h/nano_30b_a3b_ep16_share.yaml", (8192, 49152)),
+    ("glm4_moe_lite/flash_ep8_share.yaml", (8192, 32768)),
+    ("solar_open2/250b_ep40_tp8_share.yaml", (8192, 65536)),
+    ("lfm2_moe/8b_a1b_ep4_share.yaml", (32768, 65536)),
+    ("afmoe/mini_ep8_share.yaml", (32768, 131072))])
+def test_the_project_yamls_keep_their_tiers(yaml, tiers):
+    """ISSUE 44: `expert_tiers` reads sizes and no `Settings`; what the
+    mixer and the trainer's `moe_impl` hand it from each shipped YAML at
+    the step it trains gives the tiers the function gave from `Settings`
+    (printed at the parent, PR 43)."""
+    import os
+
+    from hybrid_lm_util import ROOT
+
+    from imaginaire_tpu.config import Config
+    from imaginaire_tpu.trainers import lm
+
+    cfg = Config(os.path.join(ROOT, "configs", "projects", yaml))
+    g = hybrid_lm.model_settings(cfg.gen)
+    step = (int(cfg.data.train.batch_size), int(cfg.data.seq_len))
+    assert held_experts.expert_tiers(
+        step[0] * step[1], g.num_experts_per_tok, g.held_count,
+        g.n_routed_experts, g.expert_buffer_rows) == tiers
+    assert lm.moe_impl(cfg.gen, step)["tiers"] == list(tiers)
 
 
 def test_the_short_convolution_share_holds_what_the_issue_counted():
