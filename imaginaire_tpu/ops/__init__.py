@@ -28,14 +28,13 @@ won the race; this bit the memory autotuner once already). The rules:
 
 Each op has a pure-jnp implementation (differentiable; XLA autodiff turns
 the gather-style forward into the scatter-add backward the CUDA code does
-with atomicAdd). channelnorm and spade_modulation also have a Pallas TPU
-kernel reachable via ``implementation='pallas'``; both compile for a TPU
-v5e (tests/test_tpu_compile.py). ``implementation='auto'`` resolves to
-each module's ``AUTO_IMPLEMENTATION``: resample2d and channelnorm to the
-jnp/XLA path, correlation to the 'mxu' formulation — the cost volume
-recast as per-displacement-row matmuls plus a strided band-gather — with
-the scan path covering general kernel sizes, spade_modulation to 'fused'
-(the custom_vjp residual-trimming path).
+with atomicAdd). ``implementation='auto'`` resolves to each module's
+``AUTO_IMPLEMENTATION``: resample2d to the jnp/XLA path, correlation to
+the 'mxu' formulation — the cost volume recast as per-displacement-row
+matmuls plus a strided band-gather — with the scan path covering general
+kernel sizes, spade_modulation to 'fused' (the custom_vjp
+residual-trimming path). channelnorm has its jnp path and no
+``implementation``.
 
 attention
 ---------

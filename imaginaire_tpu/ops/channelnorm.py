@@ -6,37 +6,23 @@ with value ``(sum_c |x_c|^p)^(1/p)``; the reference hardcodes the sqrt for
 p=2 at channelnorm_kernel.cu:58. Used by FlowNet2 to normalize flow
 magnitudes.
 
-jnp forward is fully differentiable (the CUDA op ships a custom backward;
-XLA autodiff derives the same). The Pallas kernel fuses |x|^p, the channel
-reduction and the root in one VMEM pass.
+The jnp forward is fully differentiable (the CUDA op ships a custom
+backward; XLA autodiff derives the same) and is the only implementation:
+XLA already fuses square, reduce and sqrt, and a kernel over an (N, C)
+layout idles 128-wide lanes at the common C=2-3.
 """
 
 from __future__ import annotations
 
 import jax.numpy as jnp
 
-# pinned to the XLA formulation; not measured on this installation
+# the one implementation, under the name the other ops' tables use
 AUTO_IMPLEMENTATION = "jnp"
 
 
-def _channelnorm_jnp(x, p):
+def channelnorm(x, p=2):
+    """L-p norm over the trailing channel axis of an NHWC tensor -> (B,H,W,1)."""
     if p == 2:
         # small-eps-free: matches CUDA sqrt(sum x^2)
         return jnp.sqrt(jnp.sum(x * x, axis=-1, keepdims=True))
     return jnp.power(jnp.sum(jnp.abs(x) ** p, axis=-1, keepdims=True), 1.0 / p)
-
-
-def channelnorm(x, p=2, implementation="auto"):
-    """L-p norm over the trailing channel axis of an NHWC tensor -> (B,H,W,1)."""
-    if implementation == "auto":
-        # XLA already fuses square, reduce and sqrt, while the
-        # kernel's (N, C) layout idles 128-wide lanes at the common
-        # C=2-3
-        implementation = AUTO_IMPLEMENTATION
-    if implementation == "jnp":
-        return _channelnorm_jnp(x, p)
-    if implementation in ("pallas", "pallas_interpret"):
-        from imaginaire_tpu.ops.pallas.channelnorm_kernel import channelnorm_pallas
-
-        return channelnorm_pallas(x, p, interpret=(implementation == "pallas_interpret"))
-    raise ValueError(f"unknown implementation {implementation!r}")

@@ -29,10 +29,6 @@ implementations:
   'jnp'              plain jnp composition (autodiff reference)
   'fused'            same forward math under the custom_vjp (residual
                      trimming only; runs on every backend)
-  'pallas'           two-pass Pallas TPU kernel forward
-                     (ops/pallas/spade_modulation_kernel.py) + the same
-                     hand-written backward
-  'pallas_interpret' the kernel in interpret mode (CPU testing)
   'auto'             the pin, see AUTO_IMPLEMENTATION below
 """
 
@@ -81,31 +77,22 @@ def _spade_modulation_jnp(x, gammas, betas, eps):
     return _apply(x, mean, rstd, gammas, betas)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _spade_modulation_fused(x, gammas, betas, eps, kernel):
-    out, _ = _fused_fwd(x, gammas, betas, eps, kernel)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _spade_modulation_fused(x, gammas, betas, eps):
+    out, _ = _fused_fwd(x, gammas, betas, eps)
     return out
 
 
-def _fused_fwd(x, gammas, betas, eps, kernel):
-    if kernel is None:
-        mean, rstd = _stats(x.astype(jnp.float32), eps)
-        out = _apply(x, mean, rstd, gammas, betas)
-    else:
-        from imaginaire_tpu.ops.pallas.spade_modulation_kernel import (
-            spade_modulation_fwd_pallas,
-        )
-
-        out, mean, rstd = spade_modulation_fwd_pallas(
-            x, gammas, betas, eps=eps,
-            interpret=(kernel == "interpret"))
+def _fused_fwd(x, gammas, betas, eps):
+    mean, rstd = _stats(x.astype(jnp.float32), eps)
+    out = _apply(x, mean, rstd, gammas, betas)
     # scalar dtype tokens stand in for the betas: dβ_i is just g cast to
     # β_i's dtype, so the full β tensors need not survive as residuals
     beta_tokens = tuple(jnp.zeros((), b.dtype) for b in betas)
     return out, (x, gammas, beta_tokens, mean, rstd)
 
 
-def _fused_bwd(eps, kernel, res, g):
+def _fused_bwd(eps, res, g):
     x, gammas, beta_tokens, mean, rstd = res
     g32 = g.astype(jnp.float32)
     xhat = (x.astype(jnp.float32) - mean) * rstd
@@ -134,8 +121,7 @@ def spade_modulation(x, gammas, betas, *, eps=1e-5, implementation="auto"):
     x: (B, H, W, C); gammas/betas: equal-length sequences of tensors
     shaped exactly like x (one pair per SPADE condition input).
 
-    implementation: 'jnp' | 'fused' | 'pallas' | 'pallas_interpret'
-    | 'auto' (see module docstring).
+    implementation: 'jnp' | 'fused' | 'auto' (see module docstring).
     """
     gammas = tuple(gammas)
     betas = tuple(betas)
@@ -157,9 +143,5 @@ def spade_modulation(x, gammas, betas, *, eps=1e-5, implementation="auto"):
     if implementation == "jnp":
         return _spade_modulation_jnp(x, gammas, betas, eps)
     if implementation == "fused":
-        return _spade_modulation_fused(x, gammas, betas, eps, None)
-    if implementation == "pallas":
-        return _spade_modulation_fused(x, gammas, betas, eps, "mosaic")
-    if implementation == "pallas_interpret":
-        return _spade_modulation_fused(x, gammas, betas, eps, "interpret")
+        return _spade_modulation_fused(x, gammas, betas, eps)
     raise ValueError(f"unknown implementation {implementation!r}")

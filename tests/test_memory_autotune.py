@@ -58,7 +58,7 @@ class TestEnumeration:
     def test_bad_modulation_loud(self):
         with pytest.raises(ValueError, match="modulation"):
             ma.enumerate_candidates(["none"], ["float32"], [1],
-                                    modulations=["pallas"])
+                                    modulations=["kernel"])
 
 
 class TestFakeLedgerRows:

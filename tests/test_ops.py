@@ -74,13 +74,10 @@ def test_resample2d_grad_is_scatter_add(rng):
     np.testing.assert_allclose(np.asarray(g)[0, 0, :, 0], [2.0, 0.0, 1.0])
 
 
-@pytest.mark.parametrize("impl", ["jnp", "pallas_interpret"])
 @pytest.mark.parametrize("p", [1, 2])
-def test_channelnorm(rng, impl, p):
-    if impl == "pallas_interpret" and p == 1:
-        pytest.skip("pallas kernel parameterized test covered by p=2")
+def test_channelnorm(rng, p):
     x = rng.randn(2, 3, 4, 5).astype(np.float32)
-    got = np.asarray(channelnorm(jnp.asarray(x), p=p, implementation=impl))
+    got = np.asarray(channelnorm(jnp.asarray(x), p=p))
     want = (np.abs(x) ** p).sum(-1, keepdims=True) ** (1.0 / p)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 

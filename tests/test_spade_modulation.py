@@ -1,7 +1,7 @@
 """Goldens for the fused SPADE norm->modulate epilogue (ISSUE 16).
 
 The numpy reference below re-derives the epilogue independently of the
-jnp/fused/pallas implementations: biased instance-norm statistics over
+jnp/fused implementations: biased instance-norm statistics over
 the spatial axes in float64, then ``y = x_hat * (1 + sum(g)) + sum(b)``.
 Layer tests pin the integration contract: fused vs unfused is invisible
 to everything but the compiler — same outputs, same param tree, same
@@ -50,10 +50,8 @@ def _case(rng, shape, n_pairs, dtype=np.float32):
 
 
 @pytest.mark.parametrize("shape,n_pairs", SHAPES)
-@pytest.mark.parametrize("impl", ["jnp", "fused", "pallas_interpret"])
+@pytest.mark.parametrize("impl", ["jnp", "fused"])
 def test_forward_matches_reference(rng, impl, shape, n_pairs):
-    if impl == "pallas_interpret" and shape[1] > 32:
-        pytest.skip("interpret-mode grid too slow at the larger probe")
     x, gs, bs = _case(rng, shape, n_pairs)
     got = np.asarray(spade_modulation(
         jnp.asarray(x), [jnp.asarray(g) for g in gs],
@@ -63,11 +61,11 @@ def test_forward_matches_reference(rng, impl, shape, n_pairs):
 
 
 @pytest.mark.parametrize("shape,n_pairs", SHAPES[:2])
-@pytest.mark.parametrize("impl", ["fused", "pallas_interpret"])
+@pytest.mark.parametrize("impl", ["fused"])
 def test_grad_matches_jnp_autodiff(rng, impl, shape, n_pairs):
-    """The hand-written custom_vjp (incl. the kernel-forward variant)
-    must match XLA autodiff through the jnp composition, for dx and
-    every dgamma_i/dbeta_i of the multi-cond accumulation."""
+    """The hand-written custom_vjp must match XLA autodiff through the
+    jnp composition, for dx and every dgamma_i/dbeta_i of the multi-cond
+    accumulation."""
     x, gs, bs = _case(rng, shape, n_pairs)
     args = (jnp.asarray(x), tuple(jnp.asarray(g) for g in gs),
             tuple(jnp.asarray(b) for b in bs))
@@ -86,7 +84,7 @@ def test_grad_matches_jnp_autodiff(rng, impl, shape, n_pairs):
                                    rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("impl", ["jnp", "fused", "pallas_interpret"])
+@pytest.mark.parametrize("impl", ["jnp", "fused"])
 def test_bf16_inputs_fp32_stats(rng, impl):
     """bf16 compute dtype: stats still reduce in fp32 (the norm_stats
     island guard executes inside every implementation), the output stays
@@ -249,4 +247,4 @@ def test_auto_dispatch_resolves(rng):
                          [jnp.asarray(bs[0])],
                          implementation=AUTO_IMPLEMENTATION)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert AUTO_IMPLEMENTATION in ("jnp", "fused", "pallas")
+    assert AUTO_IMPLEMENTATION in ("jnp", "fused")

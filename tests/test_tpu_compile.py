@@ -64,31 +64,6 @@ def _compile(fn, *args):
 # ------------------------------------------------------- Pallas kernels
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_channelnorm_kernel_compiles(one_chip, dtype):
-    from imaginaire_tpu.ops.pallas.channelnorm_kernel import (
-        channelnorm_pallas,
-    )
-
-    compiled = _compile(channelnorm_pallas,
-                        _sds((2, 512, 1024, 2), dtype, one_chip))
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("shape", [(4, 32, 32, 1024), (4, 256, 256, 128)])
-def test_spade_modulation_kernel_compiles(one_chip, shape, dtype):
-    from imaginaire_tpu.ops.pallas.spade_modulation_kernel import (
-        spade_modulation_fwd_pallas,
-    )
-
-    x = _sds(shape, dtype, one_chip)
-    compiled = _compile(
-        lambda x, g, b: spade_modulation_fwd_pallas(x, (g,), (b,)),
-        x, x, x)
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 @pytest.mark.parametrize("policy,forward_calls", [("save_nothing", 2),
                                                   ("blocks", 1)])
 @pytest.mark.parametrize("q_heads,kv_heads,dim", [(32, 2, 128),
