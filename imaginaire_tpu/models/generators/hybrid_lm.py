@@ -382,10 +382,10 @@ class AttentionMixer(nn.Module):
                 q, k = rotary(q, g.rope_theta), rotary(k, g.rope_theta)
         if self.windowed:
             with jax.named_scope("lm/attn/window_scores"):
-                y = attention(q, k, v, g.attn_query_block, g.sliding_window)
+                y = attention(q, k, v, g.sliding_window)
         else:
             with jax.named_scope("lm/attn/scores"):
-                y = attention(q, k, v, g.attn_query_block)
+                y = attention(q, k, v)
         if w_gate is not None:
             # one value a head channel (``use_gqa_gate``)
             with jax.named_scope("lm/attn/gate"):
@@ -457,7 +457,7 @@ class LatentAttentionMixer(nn.Module):
             k = jnp.concatenate(
                 [k_nope, jnp.broadcast_to(k_rope, (*lead, heads, rope))], -1)
         with jax.named_scope("lm/attn/scores"):
-            y = attention(q, k, v, g.attn_query_block)
+            y = attention(q, k, v)
         with jax.named_scope("lm/attn/out"):
             return y @ w_o.astype(dtype)
 
@@ -572,12 +572,12 @@ _NEEDS = {
     "M": ("mamba_num_heads", "mamba_head_dim", "n_groups", "ssm_state_size",
           "conv_kernel", "chunk_size", "time_step_min", "time_step_max",
           "time_step_floor"),
-    "*": ("num_attention_heads", "attn_query_block"),
+    "*": ("num_attention_heads",),
     "K": ("kda_num_heads", "kda_head_dim", "kda_conv_kernel",
           "kda_chunk_size"),
     "C": ("conv_L_cache",),
-    "W": ("num_attention_heads", "attn_query_block", "num_key_value_heads",
-          "head_dim", "sliding_window", "rope_theta"),
+    "W": ("num_attention_heads", "num_key_value_heads", "head_dim",
+          "sliding_window", "rope_theta"),
     "-": ("intermediate_size",),
     "E": ("n_routed_experts", "num_experts_per_tok", "routed_scaling_factor",
           "moe_intermediate_size", "expert_buffer_rows"),
@@ -629,11 +629,18 @@ class Block(nn.Module):
         return h, stats
 
 
-def chunked_cross_entropy(h, w_head, targets, weights, chunk):
+# the tokens whose logits stand at a time in the loss: 1024 x the
+# vocabulary slice in float32 (0.1 GB at the widest slice shipped, 25,024)
+LOSS_CHUNK_TOKENS = 1024
+
+
+def chunked_cross_entropy(h, w_head, targets, weights, chunk=None):
     """Sum over tokens of ``weights`` times the cross-entropy of
     ``h W_head`` against ``targets``, in float32, ``chunk`` tokens at a
-    time under ``jax.checkpoint``: one chunk's logits stand at a time."""
+    time (``LOSS_CHUNK_TOKENS``, or all of them where there are fewer)
+    under ``jax.checkpoint``: one chunk's logits stand at a time."""
     tokens = h.shape[0]
+    chunk = chunk or min(LOSS_CHUNK_TOKENS, tokens)
     pad = (-tokens) % chunk
     if pad:
         h = jnp.pad(h, ((0, pad), (0, 0)))
@@ -669,7 +676,8 @@ class Settings:
     whose ``W`` layers turn take no turn), ``use_post_norm`` (a second
     norm, after each mixer) and ``embed_scale`` (the factor on the
     embedding; absent, none), and
-    the four bounds the program sets itself. A size the model has no layer
+    what the program sets itself (``expert_buffer_rows``, ``remat``,
+    ``compute_dtype``). A size the model has no layer
     for stays None (``_NEEDS``): no ``rope_theta`` is grouped-query
     attention without a position embedding, no
     ``moe_shared_expert_intermediate_size`` an expert layer without a
@@ -679,7 +687,6 @@ class Settings:
     hidden_size: int
     vocab_slice: int
     norm_eps: float
-    loss_chunk_tokens: int
     remat: str
     compute_dtype: str
     hidden_act: str = "relu2"
@@ -693,7 +700,6 @@ class Settings:
     time_step_max: float | None = None
     time_step_floor: float | None = None
     num_attention_heads: int | None = None
-    attn_query_block: int | None = None
     num_key_value_heads: int | None = None
     head_dim: int | None = None
     use_gqa_gate: bool = False
@@ -841,7 +847,7 @@ class Generator(nn.Module):
                 weights = weights.at[:, -last].set(0.0)
             total = chunked_cross_entropy(
                 h.reshape(-1, g.hidden_size), w_head.astype(dtype), targets,
-                weights.reshape(-1), g.loss_chunk_tokens)
+                weights.reshape(-1))
             return total / weights.sum(), h
 
         def embed(ids):

@@ -75,6 +75,10 @@ TILES = Tiles(fwd=(1024, 1024), bwd=(1024, 1024))
 # backward pass and only that kernel can rebuild
 KERNEL_RESIDUAL = "kernel_residual"
 BACKWARD_PRODUCTS = kernel.BACKWARD_PRODUCTS
+# the ``blocks`` arm's query rows a block: its (Hq, rows, keys) float32
+# scores stand one block at a time; a shorter sequence is one block. No
+# cell takes that arm on the chip, so the number was never tuned there.
+QUERY_BLOCK = 512
 
 
 def kernel_head_dim(head_dim):
@@ -116,14 +120,14 @@ def effective_window(window, length):
     return None if window is None or window >= length else int(window)
 
 
-def attention(q, k, v, block, window=None):
+def attention(q, k, v, window=None):
     """``q`` (B, L, Hq, d), ``k``, ``v`` (B, L, Hkv, d) to (B, L, Hq*d).
-    ``block`` is the plain arm's query block; the fused arm's tiles are
-    ``TILES``. ``window``: the keys a query sees, itself counted; None
-    for every key up to its own."""
+    The plain arm's query block is ``QUERY_BLOCK``, the fused arm's tiles
+    are ``TILES``. ``window``: the keys a query sees, itself counted;
+    None for every key up to its own."""
     if arm_of(q.shape[-1], q.shape[1]) == "fused":
         return fused_causal_attention(q, k, v, TILES, False, window)
-    return causal_attention(q, k, v, block, window)
+    return causal_attention(q, k, v, QUERY_BLOCK, window)
 
 
 def visited_tiles(length, window=None, tiles=TILES):

@@ -280,13 +280,17 @@ def test_the_rule(monkeypatch, backend, dim, length, arm):
 
 @pytest.mark.parametrize("length", [64, 50])
 def test_attention_takes_the_plain_arm_here(length):
-    """On the CPU ``attention`` is ``causal_attention``, to the bit, at a
-    length the block divides and at a ragged one."""
+    """On the CPU ``attention`` is ``causal_attention`` at its own
+    ``QUERY_BLOCK``, to the bit; several blocks of a length the block
+    divides and of a ragged one give what the one block gives."""
     q, k, v, _ = _inputs(seed=5, bsz=1, length=length, dim=16)
     assert attention.arm_of(16, length) == "blocks"
-    np.testing.assert_array_equal(
-        np.asarray(attention.attention(q, k, v, 32), np.float32),
-        np.asarray(attention.causal_attention(q, k, v, 32), np.float32))
+    ours = np.asarray(attention.attention(q, k, v), np.float32)
+    np.testing.assert_array_equal(ours, np.asarray(
+        attention.causal_attention(q, k, v, attention.QUERY_BLOCK),
+        np.float32))
+    np.testing.assert_allclose(ours, np.asarray(
+        attention.causal_attention(q, k, v, 32), np.float32), atol=2e-6)
 
 
 def test_tiles_are_lane_multiples_and_divide_the_cells_length():

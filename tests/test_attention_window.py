@@ -98,10 +98,11 @@ def test_a_window_that_hides_nothing_is_the_causal_call(arm, window):
 def test_attention_hands_the_window_to_the_plain_arm_here():
     q, k, v, _ = _inputs(2, 1, seed=3)
     np.testing.assert_array_equal(
-        np.asarray(attention.attention(q, k, v, 96, 40)),
-        np.asarray(attention.causal_attention(q, k, v, 96, 40)))
-    assert float(jnp.abs(attention.attention(q, k, v, 96, 40)
-                         - attention.attention(q, k, v, 96)).max()) > 1e-3
+        np.asarray(attention.attention(q, k, v, 40)),
+        np.asarray(attention.causal_attention(
+            q, k, v, attention.QUERY_BLOCK, 40)))
+    assert float(jnp.abs(attention.attention(q, k, v, 40)
+                         - attention.attention(q, k, v)).max()) > 1e-3
 
 
 # ---------------------------------------------------- the tiles visited

@@ -952,7 +952,7 @@ def test_no_rope_theta_and_no_head_norm_is_the_layer_it_was():
                for name, heads in (("q_proj", g.num_attention_heads),
                                    ("k_proj", g.num_key_value_heads),
                                    ("v_proj", g.num_key_value_heads)))
-    plain = hybrid_lm.attention(q, k, v, g.attn_query_block) @ params["o_proj"]
+    plain = hybrid_lm.attention(q, k, v) @ params["o_proj"]
     np.testing.assert_array_equal(np.asarray(ours), np.asarray(plain))
     turned = hybrid_lm.model_settings(tiny_cfg("lfm2_moe").gen)
     shapes = jax.eval_shape(hybrid_lm.AttentionMixer(turned).init,
@@ -973,9 +973,9 @@ def test_the_scores_see_normed_then_turned_heads():
     params["q_norm_scale"] = 1.0 + 0.1 * jnp.arange(g.head_dim)
     seen, attention = {}, hybrid_lm.attention
 
-    def scores(q, k, v, block):
+    def scores(q, k, v):
         seen.update(q=q, k=k, v=v)
-        return attention(q, k, v, block)
+        return attention(q, k, v)
 
     u = _inputs(cfg, 64)
     with mock.patch.object(hybrid_lm, "attention", scores):
@@ -1072,6 +1072,33 @@ def test_the_accepted_models_hold_the_parameters_they_held(yaml):
     assert tree_digest(yaml) == _ACCEPTED_TREES[yaml]
 
 
+@pytest.mark.parametrize("chunk", [16, 24, None])
+def test_the_loss_by_chunks_is_the_loss(chunk):
+    """ISSUE 44: the loss's chunk is a constant, so a unit-test step takes
+    one chunk; several chunks, also of a size that does not divide the
+    tokens, give the plain cross-entropy and its gradients all the same."""
+    keys = jax.random.split(jax.random.PRNGKey(4), 4)
+    h = jax.random.normal(keys[0], (50, 12))
+    w_head = jax.random.normal(keys[1], (12, 40)) / 3.0
+    targets = jax.random.randint(keys[2], (50,), 0, 40)
+    weights = (jax.random.uniform(keys[3], (50,)) > 0.2).astype(jnp.float32)
+
+    def plain(h, w_head):
+        logp = jax.nn.log_softmax(h @ w_head)
+        return -jnp.sum(weights * logp[jnp.arange(50), targets])
+
+    def ours(h, w_head):
+        return hybrid_lm.chunked_cross_entropy(h, w_head, targets, weights,
+                                               chunk)
+
+    assert hybrid_lm.LOSS_CHUNK_TOKENS == 1024
+    for a, b in zip(jax.tree_util.tree_leaves(
+            jax.value_and_grad(ours, argnums=(0, 1))(h, w_head)),
+            jax.tree_util.tree_leaves(
+            jax.value_and_grad(plain, argnums=(0, 1))(h, w_head))):
+        _close(a, b)
+
+
 @pytest.mark.parametrize("tokens,top_k,held,of,capacity,tiers", [
     (8192, 6, 8, 128, 49152, (8192, 49152)),     # nemotron3_nano_30b_a3b
     (8192, 4, 8, 64, 32768, (8192, 32768)),      # glm4_7_flash: half a row
@@ -1164,9 +1191,9 @@ def test_the_rotary_key_is_shared_by_all_heads():
     g = hybrid_lm.model_settings(cfg.gen)
     seen, attention = {}, hybrid_lm.attention
 
-    def scores(q, k, v, block):
+    def scores(q, k, v):
         seen.update(q=q, k=k, v=v)
-        return attention(q, k, v, block)
+        return attention(q, k, v)
 
     u = _inputs(cfg, 64)
     with mock.patch.object(hybrid_lm, "attention", scores):

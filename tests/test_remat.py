@@ -41,6 +41,9 @@ class TestRegistry:
         # every valid name is listed in the message
         with pytest.raises(ValueError, match="dots_saveable"):
             resolve_policy("nope")
+        # jax's own name of the keep-nothing policy is no second key
+        with pytest.raises(ValueError, match="save_nothing"):
+            resolve_policy("nothing_saveable")
 
     def test_wrapped_class_cached_per_policy(self):
         a = remat_block_cls(Res2dBlock, "blocks")
@@ -281,7 +284,7 @@ def test_an_attention_block_through_the_flax_lift(monkeypatch, policy):
     tiles = attention.Tiles(fwd=(128, 128), bwd=(128, 128))
     monkeypatch.setattr(
         hybrid_lm, "attention",
-        lambda q, k, v, block: attention.fused_causal_attention(
+        lambda q, k, v: attention.fused_causal_attention(
             q, k, v, tiles, True))
     module, (h,) = _lm_block("*", policy, head_dim=128, num_attention_heads=2,
                              num_key_value_heads=1, compute_dtype="bfloat16")
