@@ -37,7 +37,13 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from imaginaire_tpu.ops.grouped_matmul import grouped_matmul
-from imaginaire_tpu.optim.remat import ROUTING_PLAN
+
+# the ``checkpoint_name`` of what an expert layer's routing found where
+# the held experts' rows lie (``route_held``: the sorted order and the
+# experts' counts, integers of under a megabyte a layer): a block that
+# keeps it (``optim/remat.py``'s ``blocks``) sorts a step's assignments
+# once
+ROUTING_PLAN = "routing_plan"
 
 
 def relu2(x):

@@ -49,12 +49,7 @@ import jax
 from flax import linen as nn
 
 from imaginaire_tpu.ops.attention import KERNEL_RESIDUAL
-
-# the ``checkpoint_name`` of what an expert layer's routing found where
-# the held experts' rows lie (``ops/held_experts.py``'s ``route_held``:
-# the sorted order and the experts' counts, integers of under a megabyte
-# a layer): a block that keeps it sorts a step's assignments once
-ROUTING_PLAN = "routing_plan"
+from imaginaire_tpu.ops.held_experts import ROUTING_PLAN
 
 
 class RematPolicy(NamedTuple):

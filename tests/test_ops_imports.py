@@ -58,8 +58,8 @@ def test_resolved_implementations_uses_modules():
 PACKAGE = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "imaginaire_tpu")
 # what a block of numerics may not know: who calls it, who measures it
-ABOVE_OPS = ("models", "trainers", "telemetry", "serving", "resilience",
-             "data")
+ABOVE_OPS = ("models", "trainers", "optim", "telemetry", "serving",
+             "resilience", "data")
 
 
 def _op_files():
@@ -92,8 +92,10 @@ def _imported(path):
 @pytest.mark.parametrize("rel", _op_files())
 def test_an_op_imports_nothing_above_it(rel):
     """A module under ``ops/`` takes arrays, shapes and sizes: it imports
-    no model, trainer, telemetry, serving, resilience or data code, so a
-    model's edit re-keys no op and an op can be timed alone."""
+    no model, trainer, optimizer, telemetry, serving, resilience or data
+    code (``optim/remat.py`` reads the ops' checkpoint names, not the
+    other way round), so a model's edit re-keys no op and an op can be
+    timed alone."""
     refused = tuple(f"imaginaire_tpu.{name}" for name in ABOVE_OPS)
     above = sorted(name for name in _imported(os.path.join(PACKAGE, rel))
                    if name.startswith(refused))
@@ -102,7 +104,6 @@ def test_an_op_imports_nothing_above_it(rel):
 
 def test_the_layering_test_sees_every_op():
     files = _op_files()
-    assert len(files) >= 12
     for rel in ("ops/state_space.py", "ops/held_experts.py",
                 "ops/pallas/delta_rule_kernel.py"):
         assert rel in files
