@@ -116,15 +116,18 @@ def rms_norm(x, scale, eps, groups=1):
     return y.astype(dtype)
 
 
-# ``hidden_act`` -> whether a feed-forward has a gate beside its up-product
-GATED = {"relu2": False, "silu": True}
+# ``hidden_act`` -> whether a feed-forward has a gate beside its
+# up-product; a gated one's name is its gate's activation
+# (``held_experts.GATES``)
+GATED = {"relu2": False, "silu": True, "relu": True}
 
 
-def feed_forward(x, kernels):
+def feed_forward(x, kernels, gate="silu"):
     """``W_down act(...)`` of ``kernels`` (gate, up, down) or (up, down),
-    each cast to ``x``'s dtype where it is used."""
+    each cast to ``x``'s dtype where it is used; ``gate`` is the model's
+    ``hidden_act``, which a gated one reads."""
     hidden = held_experts.hidden_activation(
-        [x @ w.astype(x.dtype) for w in kernels[:-1]])
+        [x @ w.astype(x.dtype) for w in kernels[:-1]], gate)
     return hidden @ kernels[-1].astype(x.dtype)
 
 
@@ -475,32 +478,54 @@ class DenseMixer(nn.Module):
             self, g, "", (g.hidden_size, g.intermediate_size),
             (g.intermediate_size, g.hidden_size))
         with jax.named_scope("lm/mlp/dense"):
-            return feed_forward(u, kernels)
+            return feed_forward(u, kernels, g.hidden_act)
 
 
 # ------------------------------------------------------ mixture of experts
 
 
-def route(x32, w_router, score_bias, top_k, scaling):
-    """The router, in float32: ``s = sigmoid(x W_r)``; the ``top_k``
-    experts of ``s + score_bias``; their weights ``s_i / (sum of the
-    selected s + 1e-20) * scaling``. Returns (experts (T, k) int32,
-    weights (T, k) float32). No gradient reaches the choice or the bias."""
-    islands.guard("router_scores", x=x32, w=w_router, b=score_bias)
+def route(x32, w_router, score_bias, top_k, scaling=None,
+          softmax_of_chosen=False):
+    """The router, in float32, by one of two scorings. By sigmoid: ``s =
+    sigmoid(x W_r)``; the ``top_k`` experts of ``s + score_bias``; their
+    weights ``s_i / (sum of the selected s + 1e-20)``, times ``scaling``
+    where the model has such a factor. ``softmax_of_chosen`` (the
+    published ``moe_primary_router_apply_softmax``): the ``top_k``
+    experts by their logit ``z = x W_r``, with no bias (``score_bias``
+    None); their weights the softmax over the chosen logits alone, which
+    is the softmax over all the experts renormed over the chosen.
+    Returns (experts (T, k) int32, weights (T, k) float32). No gradient
+    reaches the choice or the bias."""
+    biased = {} if score_bias is None else {"b": score_bias}
+    islands.guard("router_scores", x=x32, w=w_router, **biased)
     with islands.scope("router_scores"):
-        scores = jax.nn.sigmoid(jnp.dot(x32, w_router,
-                                        precision=lax.Precision.HIGHEST))
-        _, experts = lax.top_k(lax.stop_gradient(scores + score_bias), top_k)
-        picked = jnp.take_along_axis(scores, experts, axis=-1)
-        weights = picked / (picked.sum(-1, keepdims=True) + 1e-20) * scaling
+        logits = jnp.dot(x32, w_router, precision=lax.Precision.HIGHEST)
+        if softmax_of_chosen:
+            _, experts = lax.top_k(lax.stop_gradient(logits), top_k)
+            weights = jax.nn.softmax(
+                jnp.take_along_axis(logits, experts, axis=-1), axis=-1)
+        else:
+            scores = jax.nn.sigmoid(logits)
+            _, experts = lax.top_k(
+                lax.stop_gradient(scores + score_bias), top_k)
+            picked = jnp.take_along_axis(scores, experts, axis=-1)
+            weights = picked / (picked.sum(-1, keepdims=True) + 1e-20)
+        if scaling is not None:
+            weights = weights * scaling
     return experts.astype(jnp.int32), weights
 
 
 class MoEMixer(nn.Module):
+    """The held experts' part of a mixture of experts, and the shared
+    expert where the model has one. The router reads ``u``, the layer's
+    own normed input, or ``router_input`` where one is given
+    (``use_early_router``: what the layer before it read); the experts
+    read ``u`` either way. A router scored by the softmax over its
+    chosen logits has no score-correction bias, and no buffer for one."""
     cfg: Any
 
     @nn.compact
-    def __call__(self, u):
+    def __call__(self, u, router_input=None):
         g = self.cfg
         dtype = u.dtype
         hidden, width = g.hidden_size, g.moe_intermediate_size
@@ -510,8 +535,10 @@ class MoEMixer(nn.Module):
         # the score-correction bias: a buffer, no gradient reaches it and
         # no optimizer moves it (the balancing rule that would is the
         # training recipe's, not the model's)
-        score_bias = self.variable("buffers", "score_bias", jnp.zeros,
-                                   (g.n_routed_experts,), jnp.float32).value
+        score_bias = (
+            None if g.moe_primary_router_apply_softmax else self.variable(
+                "buffers", "score_bias", jnp.zeros, (g.n_routed_experts,),
+                jnp.float32).value)
         kernels = _feed_forward_params(
             self, g, "experts_", (g.held_count, hidden, width),
             (g.held_count, width, hidden))
@@ -521,10 +548,12 @@ class MoEMixer(nn.Module):
             (shared_width, hidden)) if shared_width is not None else None)
         lead = u.shape[:2]
         x = u.reshape(-1, hidden)
+        read = x if router_input is None else router_input.reshape(x.shape)
         with jax.named_scope("lm/moe/router"):
             experts, weights = route(
-                x.astype(jnp.float32), w_router.astype(jnp.float32),
-                score_bias, g.num_experts_per_tok, g.routed_scaling_factor)
+                read.astype(jnp.float32), w_router.astype(jnp.float32),
+                score_bias, g.num_experts_per_tok, g.routed_scaling_factor,
+                g.moe_primary_router_apply_softmax)
         capacity = g.expert_buffer_rows
         # the held assignments sort first, so they fill the buffer's
         # prefix: a step that holds no more than the short tier's rows
@@ -543,11 +572,12 @@ class MoEMixer(nn.Module):
         with jax.named_scope("lm/moe/experts"):
             kernels = tuple(w.astype(dtype) for w in kernels)
         routed = held_experts.on_filled_prefix(
-            tiers, n_held, x, kernels, weight, token, group_sizes)
+            tiers, n_held, x, kernels, weight, token, group_sizes,
+            g.hidden_act)
         if shared is None:
             return routed.reshape(*lead, hidden), stats
         with jax.named_scope("lm/moe/shared"):
-            shared = feed_forward(x, shared)
+            shared = feed_forward(x, shared, g.hidden_act)
         return (routed + shared).reshape(*lead, hidden), stats
 
 
@@ -565,7 +595,8 @@ _LINEAR_ATTN = {"kda_num_heads": "num_heads", "kda_head_dim": "head_dim",
 # turns its heads where there is a ``rope_theta`` (none is demanded); ``W``
 # is grouped-query attention alone, under a window, and always turns; an
 # ``E`` layer has a shared expert where the config gives
-# ``moe_shared_expert_intermediate_size``
+# ``moe_shared_expert_intermediate_size`` and a factor on its routed sum
+# where it gives ``routed_scaling_factor``
 _LATENT = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
            "qk_rope_head_dim", "v_head_dim", "rope_theta")
 _NEEDS = {
@@ -579,7 +610,7 @@ _NEEDS = {
     "W": ("num_attention_heads", "num_key_value_heads", "head_dim",
           "sliding_window", "rope_theta"),
     "-": ("intermediate_size",),
-    "E": ("n_routed_experts", "num_experts_per_tok", "routed_scaling_factor",
+    "E": ("n_routed_experts", "num_experts_per_tok",
           "moe_intermediate_size", "expert_buffer_rows"),
 }
 
@@ -604,20 +635,33 @@ def layer_kinds(g):
     return g.pattern + (g.nextn_pattern or "")
 
 
+def feeds_early_router(g, kinds, at):
+    """Whether layer ``at`` of the letters ``kinds`` hands its normed
+    input to the router of the expert layer behind it
+    (``use_early_router``)."""
+    return g.use_early_router and kinds[at + 1:at + 2] == "E"
+
+
 class Block(nn.Module):
     """One layer of the pattern: ``h + Mixer(RMSNorm(h))`` or, where
     ``use_post_norm``, ``h + RMSNorm_post(Mixer(RMSNorm(h)))``. Returns
-    (h, stats): the expert layer's routing counts, {} for the others."""
+    (h, stats): the expert layer's routing counts, {} for the others.
+    Under ``use_early_router`` the attention layer before an expert layer
+    ``hands_on`` its normed input ``u``, returning (h, stats, u), and the
+    expert layer takes it as ``router_input``: its router reads what
+    attention read, its experts their own normed input."""
     cfg: Any
     kind: str
+    hands_on: bool = False
 
     @nn.compact
-    def __call__(self, h, training=False):
+    def __call__(self, h, router_input=None, training=False):
         scale = self.param("scale", nn.initializers.ones,
                            (self.cfg.hidden_size,))
         with jax.named_scope("lm/block/norm"):
             u = rms_norm(h, scale, self.cfg.norm_eps)
-        out = mixer_of(self.cfg, self.kind)(self.cfg, name="mixer")(u)
+        mixer = mixer_of(self.cfg, self.kind)(self.cfg, name="mixer")
+        out = mixer(u) if router_input is None else mixer(u, router_input)
         out, stats = out if self.kind == "E" else (out, {})
         if self.cfg.use_post_norm:
             post_scale = self.param("post_scale", nn.initializers.ones,
@@ -626,7 +670,7 @@ class Block(nn.Module):
                 out = rms_norm(out, post_scale, self.cfg.norm_eps)
         with jax.named_scope("lm/block/residual"):
             h = h + out
-        return h, stats
+        return (h, stats, u) if self.hands_on else (h, stats)
 
 
 # the tokens whose logits stand at a time in the loss: 1024 x the
@@ -681,7 +725,9 @@ class Settings:
     for stays None (``_NEEDS``): no ``rope_theta`` is grouped-query
     attention without a position embedding, no
     ``moe_shared_expert_intermediate_size`` an expert layer without a
-    shared expert. ``conv_L_cache`` is the taps of the sixth letter's
+    shared expert, no ``routed_scaling_factor`` a routed sum without a
+    factor. ``moe_primary_router_apply_softmax`` is the published key of
+    the second scoring (``route``). ``conv_L_cache`` is the taps of the sixth letter's
     (``C``) convolution; ``conv_bias`` has to be false."""
     pattern: str
     hidden_size: int
@@ -724,6 +770,8 @@ class Settings:
     n_routed_experts: int | None = None
     num_experts_per_tok: int | None = None
     routed_scaling_factor: float | None = None
+    moe_primary_router_apply_softmax: bool = False
+    use_early_router: bool = False
     moe_intermediate_size: int | None = None
     moe_shared_expert_intermediate_size: int | None = None
     held_first: int | None = None
@@ -798,6 +846,16 @@ def model_settings(gen_cfg):
             f"gen.v_head_dim {g.v_head_dim}")
     if g.nextn_pattern and g.nextn_loss_weight is None:
         raise ValueError("gen.nextn_pattern needs gen.nextn_loss_weight")
+    if g.use_early_router:
+        for letters in (g.pattern, g.nextn_pattern or ""):
+            orphans = [at for at, kind in enumerate(letters) if kind == "E"
+                       and letters[max(at - 1, 0):at] not in ("*", "W")]
+            if orphans:
+                raise ValueError(
+                    "gen.use_early_router: an expert layer's router reads "
+                    "the normed input of the attention layer ('*' or 'W') "
+                    f"before it; {letters!r} has none before the 'E' at "
+                    f"{orphans}")
     if g.conv_bias and "C" in kinds:
         raise ValueError(
             "gen.conv_bias true: the gated short convolution ('C') has no "
@@ -828,10 +886,16 @@ class Generator(nn.Module):
         out = {}
 
         def layers(h, kinds, first):
-            for index, kind in enumerate(kinds, first):
-                block = remat_block(Block, g.remat, where="gen.remat", cfg=g,
-                                    kind=kind, name=f"layer_{index}")
-                h, stats = block(h, training=training)
+            handed = ()
+            for at, kind in enumerate(kinds):
+                index = first + at
+                block = remat_block(
+                    Block, g.remat, where="gen.remat", cfg=g, kind=kind,
+                    hands_on=feeds_early_router(g, kinds, at),
+                    name=f"layer_{index}")
+                # what an early router reads goes from the layer that
+                # made it to the expert layer behind it, and no further
+                h, stats, *handed = block(h, *handed, training=training)
                 for key, value in stats.items():
                     out[f"moe/{index}/{key}"] = value
             return h

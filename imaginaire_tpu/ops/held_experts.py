@@ -50,13 +50,21 @@ def relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
-def hidden_activation(ups):
+# what a gated feed-forward does to its gate's product, by the name of
+# the model's ``hidden_act``
+GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def hidden_activation(ups, gate="silu"):
     """A feed-forward's hidden activations from its in-products: of one,
-    ``relu(up)^2``; of two, the gated ``silu(gate) * up``."""
+    ``relu(up)^2`` (``gate`` is not read); of two, the gated
+    ``GATES[gate](gate) * up`` (``silu``, or ``relu``: the product is
+    zero wherever the gate's is not positive, and so is its gradient,
+    the gate's at 0 included)."""
     if len(ups) == 1:
         return relu2(ups[0])
-    gate, up = ups
-    return jax.nn.silu(gate) * up
+    gate_product, up = ups
+    return GATES[gate](gate_product) * up
 
 
 def route_held(experts, weights, first, count, rows):
@@ -188,34 +196,37 @@ def add_rows(values, token, filled, tokens, capacity, weight=None):
                         jnp.zeros((tokens, hidden), jnp.float32))
 
 
-def held_products(buffer, kernels, group_sizes):
+def held_products(buffer, kernels, group_sizes, gate="silu"):
     """The held experts' feed-forward on the buffer's rows, (rows, hidden)
-    to (rows, hidden). A row past the groups' end is not the grouped
-    products' to write, forward or backward: whatever stands there is
-    masked between the products (its gradient is the first product's);
-    on the way in and on the way out the movement masks it."""
+    to (rows, hidden); ``gate`` is ``hidden_activation``'s. A row past the
+    groups' end is not the grouped products' to write, forward or
+    backward: whatever stands there is masked between the products (its
+    gradient is the first product's); on the way in and on the way out
+    the movement masks it."""
     mask = (jnp.arange(buffer.shape[0]) < group_sizes.sum())[:, None]
     with jax.named_scope("lm/moe/experts"):
         act = hidden_activation([
             jnp.where(mask, grouped_matmul(buffer, w, group_sizes), 0)
-            for w in kernels[:-1]])
+            for w in kernels[:-1]], gate)
         return grouped_matmul(act, kernels[-1], group_sizes)
 
 
-def held_experts_part(x, kernels, weight, token, group_sizes, rows):
+def held_experts_part(x, kernels, weight, token, group_sizes, rows,
+                      gate="silu"):
     """The held experts' part of the layer's result, computed on the first
     ``rows`` rows of ``route_held``'s buffer: all of it where the step
     holds no more than ``rows`` assignments. Rows are moved into the
     buffer and out of it as far as the held ones reach (``group_sizes``'
     sum), by segments; the rows past them read as zeros. ``x`` (T, hidden)
     and ``kernels`` (gate where the expert is gated, up: (count, hidden,
-    width); down: (count, width, hidden)) in the compute dtype."""
+    width); down: (count, width, hidden)) in the compute dtype; ``gate``
+    is ``hidden_activation``'s."""
     capacity = weight.shape[0]
     token, weight = token[:rows], weight[:rows]
     filled = group_sizes.sum()
     with jax.named_scope("lm/moe/dispatch"):
         buffer = gather_rows(x, token, filled)
-    out = held_products(buffer, kernels, group_sizes)
+    out = held_products(buffer, kernels, group_sizes, gate)
     with jax.named_scope("lm/moe/combine"):
         return add_rows(out, token, filled, x.shape[0], capacity,
                         weight).astype(x.dtype)
@@ -248,7 +259,8 @@ def weighted_rows_bwd(ct, out, weight, token, filled):
         jnp.zeros_like(out), jnp.zeros((rows,), jnp.float32)))
 
 
-def held_experts_part_bwd(ct, x, kernels, weight, token, group_sizes, rows):
+def held_experts_part_bwd(ct, x, kernels, weight, token, group_sizes, rows,
+                          gate="silu"):
     """The gradients of ``held_experts_part`` to ``x``, ``kernels`` and
     ``weight`` for the result's cotangent ``ct`` (T, hidden), written out:
     a loop that ends at the step's own count has no transpose. The
@@ -261,7 +273,7 @@ def held_experts_part_bwd(ct, x, kernels, weight, token, group_sizes, rows):
     with jax.named_scope("lm/moe/dispatch"):
         buffer = gather_rows(x, token, filled)
     out, products_vjp = jax.vjp(
-        functools.partial(held_products, group_sizes=group_sizes),
+        functools.partial(held_products, group_sizes=group_sizes, gate=gate),
         buffer, kernels)
     with jax.named_scope("lm/moe/combine"):
         d_out, d_weight = weighted_rows_bwd(ct, out, weight, token, filled)
@@ -305,30 +317,35 @@ def moved_rows(tiers, n_held):
     return jnp.stack(each)[_tier(tiers, n_held)]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def on_filled_prefix(tiers, n_held, x, kernels, weight, token, group_sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 7))
+def on_filled_prefix(tiers, n_held, x, kernels, weight, token, group_sizes,
+                     gate="silu"):
     """``held_experts_part`` on the shortest of the static, ascending
     ``tiers`` of rows that holds the step's ``n_held`` assignments, by
-    ``lax.switch``. Its gradient is each tier's own, recomputed inside
-    the backward branch: differentiating through the switch instead hands
-    every tier's intermediates from a forward conditional to a backward
-    one, and a step on the short tier would write the long tier's as
-    zeros (1.5 GB a layer at the published widths)."""
+    ``lax.switch``; ``gate`` is ``hidden_activation``'s. Its gradient is
+    each tier's own, recomputed inside the backward branch:
+    differentiating through the switch instead hands every tier's
+    intermediates from a forward conditional to a backward one, and a
+    step on the short tier would write the long tier's as zeros (1.5 GB a
+    layer at the published widths)."""
     return lax.switch(
         _tier(tiers, n_held),
-        [functools.partial(held_experts_part, rows=rows) for rows in tiers],
+        [functools.partial(held_experts_part, rows=rows, gate=gate)
+         for rows in tiers],
         x, kernels, weight, token, group_sizes)
 
 
 def _on_filled_prefix_fwd(tiers, n_held, *operands):
-    return on_filled_prefix(tiers, n_held, *operands), (n_held, operands)
+    *operands, gate = operands
+    return (on_filled_prefix(tiers, n_held, *operands, gate),
+            (n_held, operands))
 
 
-def _on_filled_prefix_bwd(tiers, saved, ct):
+def _on_filled_prefix_bwd(tiers, gate, saved, ct):
     n_held, operands = saved
     grads = lax.switch(
         _tier(tiers, n_held),
-        [functools.partial(held_experts_part_bwd, rows=rows)
+        [functools.partial(held_experts_part_bwd, rows=rows, gate=gate)
          for rows in tiers], ct, *operands)
     # the kernels' gradients leave the switch in the compute dtype: left
     # to itself the compiler moves their casts to float32 into the

@@ -1149,7 +1149,14 @@ def render_report(path_or_events):
             + "; ".join(
                 f"{way} " + ", ".join(f"{k} {'x'.join(map(str, v))}"
                                       for k, v in tiles.items())
-                for way, tiles in (moe.get("tiles") or {}).items()))
+                for way, tiles in (moe.get("tiles") or {}).items())
+            + ("; routers read " + ", ".join(
+                f"layer {i} {read}" for i, read in sorted(
+                    moe["router_input"].items(), key=lambda kv: int(kv[0])))
+               + f", scored by {moe.get('scoring')}; experts "
+               f"{moe.get('activation')}, a buffer of "
+               f"{moe.get('buffer_rows')} rows"
+               if "router_input" in moe else ""))
     lines.extend(_experts_section(s))
     lines.extend(_health_section(s))
     lines.extend(_xla_section(s))

@@ -124,7 +124,12 @@ def moe_impl(gen_cfg, tokens_shape):
     experts' hidden size and width, the tiers of rows a step computes a
     layer on, and the kernel's (rows, width) output tiles, forward (the
     weights' gradient's too) and in the gradient to the rows, for the
-    product up into the width and the one down out of it; None for a
+    product up into the width and the one down out of it; what each
+    layer's router reads (``own_norm``: the layer's own normed input;
+    ``attention_input``: the normed input of the attention layer before
+    it, ``use_early_router``), how it scores (``sigmoid``, or
+    ``softmax_of_chosen``), the experts' ``hidden_act`` and the rows of
+    the buffer a step's held assignments are sorted into; None for a
     model without such a layer."""
     g = hybrid_lm.model_settings(gen_cfg)
     layers = [i for i, kind in enumerate(hybrid_lm.layer_kinds(g))
@@ -139,9 +144,16 @@ def moe_impl(gen_cfg, tokens_shape):
     arms = list(dict.fromkeys(
         grouped_matmul.arm_of(rows, *shape) for rows in tiers
         for shape in ((hidden, width), (width, hidden))))
+    reads = "attention_input" if g.use_early_router else "own_norm"
     return dict(layers={str(i): "/".join(arms) for i in layers},
                 hidden=hidden, width=width, held=g.held_count,
                 tiers=list(tiers),
+                router_input={str(i): reads for i in layers},
+                scoring=("softmax_of_chosen"
+                         if g.moe_primary_router_apply_softmax
+                         else "sigmoid"),
+                activation=g.hidden_act,
+                buffer_rows=g.expert_buffer_rows,
                 tiles={"up": grouped_matmul.tiles_of(hidden, width)._asdict(),
                        "down": grouped_matmul.tiles_of(width,
                                                        hidden)._asdict()})

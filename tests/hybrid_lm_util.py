@@ -14,13 +14,15 @@ LATENT_YAML = os.path.join(ROOT, "configs", "unit_test",
 DELTA_YAML = os.path.join(ROOT, "configs", "unit_test", "solar_open2.yaml")
 SCONV_YAML = os.path.join(ROOT, "configs", "unit_test", "lfm2_moe.yaml")
 WINDOW_YAML = os.path.join(ROOT, "configs", "unit_test", "afmoe.yaml")
+EARLY_YAML = os.path.join(ROOT, "configs", "unit_test", "smallthinker.yaml")
 # tiny preset -> (its YAML, its plain reference under benchmark/reference,
 # the index of its first expert layer)
 PRESETS = {"nemotron_h": (TINY_YAML, "nemotron_h_train", 1),
            "glm4_moe_lite": (LATENT_YAML, "glm4_moe_lite_train", 3),
            "solar_open2": (DELTA_YAML, "solar_open2_train", 1),
            "lfm2_moe": (SCONV_YAML, "lfm2_moe_train", 3),
-           "afmoe": (WINDOW_YAML, "afmoe_train", 3)}
+           "afmoe": (WINDOW_YAML, "afmoe_train", 3),
+           "smallthinker": (EARLY_YAML, "smallthinker_train", 1)}
 
 
 def tiny_cfg(preset="nemotron_h", **gen):

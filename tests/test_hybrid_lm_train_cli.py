@@ -10,8 +10,8 @@ import sys
 
 import pytest
 
-from hybrid_lm_util import (DELTA_YAML, LATENT_YAML, ROOT, SCONV_YAML,
-                            TINY_YAML)
+from hybrid_lm_util import (DELTA_YAML, EARLY_YAML, LATENT_YAML, ROOT,
+                            SCONV_YAML, TINY_YAML)
 
 from imaginaire_tpu.parallel import mesh as mesh_mod
 from imaginaire_tpu.telemetry import core as tcore
@@ -115,6 +115,8 @@ def test_train_py_trains_checkpoints_and_resumes(entry_point_sandbox,
     assert ("- moe_impl: layer 1 ragged_dot, layer 4 ragged_dot; 4 held "
             "experts of 64 x 48 on tiers of 128, 256 rows; kernel tiles "
             "(rows x width) up fwd 128x128, dlhs 128x128; down fwd") in report
+    assert ("; routers read layer 1 own_norm, layer 4 own_norm, scored by "
+            "sigmoid; experts relu2, a buffer of 256 rows") in report
 
     # the resume leg: restores iteration 2 and trains on to 3
     capsys.readouterr()
@@ -245,3 +247,54 @@ def test_train_py_trains_the_short_convolution_preset(entry_point_sandbox,
     assert ("attn_impl at length 64, head size 16: layer 2 blocks; fused "
             "tiles") in report
     assert "| 5 |" in report.split("## experts")[1]
+
+
+def test_train_py_trains_the_early_router_preset(entry_point_sandbox,
+                                                 monkeypatch, tmp_path):
+    """ISSUE 45: `configs/unit_test/smallthinker.yaml` through
+    `train.main()`: one loss, the four expert layers' counters, the full
+    layer and the three window layers in the `attn_impl` meta, the
+    `moe_impl` meta saying what each router reads, how it scores and what
+    gates the experts, the report printing it, no recompile, a clean
+    graph audit (the softmax over the chosen logits is inside the
+    router's float32 island), and the router's scope named by
+    instructions of the compiled step."""
+    logdir = str(tmp_path / "log")
+    trainer = _train(monkeypatch, logdir, 2, config=EARLY_YAML)
+    assert trainer.current_iteration == 2
+    assert trainer.weights == {"lm": 1.0}
+    # the model has no buffer: no score-correction bias
+    assert set(trainer.state["vars_G"]) == {"params"}
+    events = _events(logdir)
+    counters = {e["name"]: e["value"] for e in events
+                if e["kind"] == "counter"}
+    assert counters["lm/main"] > 0 and "lm/mtp" not in counters
+    for layer in (1, 3, 5, 7):
+        assert counters[f"moe/{layer}/held_assignments"] > 0
+        assert 0 < counters[f"moe/{layer}/buffer_occupancy"] <= 1
+    assert counters["xla/recompiles"] == 0
+    assert counters["xla/graph_violations"] == 0
+    metas = {e["name"]: e for e in events if e["kind"] == "meta"}
+    attn = metas["attn_impl"]
+    assert attn["layers"] == dict.fromkeys("0246", "blocks")
+    assert attn["windows"] == dict.fromkeys("246", 24)
+    moe = metas["moe_impl"]
+    assert moe["layers"] == dict.fromkeys("1357", "ragged_dot")
+    assert moe["router_input"] == dict.fromkeys("1357", "attention_input")
+    assert (moe["scoring"], moe["activation"], moe["held"],
+            moe["buffer_rows"]) == ("softmax_of_chosen", "relu", 4, 256)
+    names = set(xla_obs.ledger().label_op_names["gen_step"].values())
+    for scope in ("lm/moe/router", "lm/attn/qkv", "lm/attn/window_scores",
+                  "lm/attn/scores", "lm/attn/rope", "lm/moe/experts"):
+        assert any(scope in name for name in names), scope
+    assert not any("lm/moe/shared" in name for name in names)
+
+    from imaginaire_tpu.telemetry.report import render_report
+
+    report = render_report(os.path.join(logdir, "telemetry.jsonl"))
+    assert ("; routers read layer 1 attention_input, layer 3 "
+            "attention_input, layer 5 attention_input, layer 7 "
+            "attention_input, scored by softmax_of_chosen; experts relu, a "
+            "buffer of 256 rows") in report
+    assert ("layer 0 blocks, layer 2 blocks (window 24), layer 4 blocks "
+            "(window 24), layer 6 blocks (window 24)") in report

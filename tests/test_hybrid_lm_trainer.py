@@ -173,7 +173,8 @@ def _stepped(preset, steps):
     for count in range(steps):
         loss, train, mu, nu = step(train, mu, nu, count)
         theirs.append((float(loss), host(train)))
-    after = host(program.flatten(trainer.state["vars_G"]["buffers"]))
+    after = host(program.flatten(
+        trainer.state["vars_G"].get("buffers", {})))
     return ours, theirs, after, host(buffers)
 
 
