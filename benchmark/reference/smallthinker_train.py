@@ -6,7 +6,8 @@ stands under `jax.checkpoint`, and attention works by query blocks, only
 so that the float32 step fits one chip at the published widths and
 16,384 tokens: the arithmetic is the same); imports nothing of the
 program (the norm, Adam and the rounding are `nemotron_h_train.py`'s, the
-rotary turn `glm4_moe_lite_train.py`'s, which are this model's too).
+rotary turn and the gated experts' count of work `glm4_moe_lite_train.py`'s,
+the band's count `afmoe_train.py`'s, which are this model's too).
 
 `h_0 = E[ids]`; one published layer `l`, with two norms of their own
 learned scales, is two letters of the pattern (`*E` the full layer of a
@@ -62,11 +63,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from benchmark.reference.glm4_moe_lite_train import rotary  # noqa: F401
+from benchmark.reference.afmoe_train import window_work  # noqa: F401
+from benchmark.reference.glm4_moe_lite_train import (  # noqa: F401
+    expert_work, rotary)
 from benchmark.reference.nemotron_h_train import (  # noqa: F401
     HIGHEST, QUERY_BLOCK, adam, attn_work, product, rms_norm, split)
 
 WINDOWED, FULL = "W", "*"
+HEAD_BLOCK = 2048     # positions whose float32 logits stand at once
 
 
 # ------------------------------------------------------------------ layers
@@ -208,15 +212,34 @@ def loss(train, buffers, sizes, tokens, precision="float32",
 
         h, aux[index + 1] = jax.checkpoint(layer)(h, p)
 
-    @jax.checkpoint
-    def head(h, p):
-        h = rms_norm(h, p["final_scale"], eps)
-        logits = product("blh,hv->blv", h, p["head"], precision)
-        picked = jnp.take_along_axis(logits[:, :-1],
-                                     tokens[:, 1:, None], -1)[..., 0]
-        return jnp.mean(jax.nn.logsumexp(logits[:, :-1], -1) - picked)
+    return head_loss(h, p, tokens, eps, precision), aux
 
-    return head(h, p), aux
+
+def head_loss(h, p, tokens, eps, precision):
+    """The mean cross-entropy of position t's logits `RMSNorm(h; w_f)
+    W_head` against token t + 1 over each sequence's L - 1 targets, by
+    blocks of `HEAD_BLOCK` positions under `jax.checkpoint`: at 16,384
+    positions and 37,984 ids the logits whole are 2.5e9 bytes in float32,
+    and the step keeps several such arrays (the first chip run did not
+    compile: 19.78 of 15.75 GiB). The sum is the same."""
+    h = rms_norm(h, p["final_scale"], eps)[:, :-1].reshape(-1, h.shape[-1])
+    targets = tokens[:, 1:].reshape(-1)
+    count = targets.shape[0]
+    block = min(HEAD_BLOCK, count)
+    pad = (-count) % block
+    weight = jnp.pad(jnp.ones((count,), h.dtype), (0, pad))
+
+    @jax.checkpoint
+    def rows(inputs):
+        hb, tb, wb = inputs
+        logits = product("th,hv->tv", hb, p["head"], precision)
+        picked = jnp.take_along_axis(logits, tb[:, None], -1)[:, 0]
+        return jnp.sum((jax.nn.logsumexp(logits, -1) - picked) * wb)
+
+    blocks = (jnp.pad(h, ((0, pad), (0, 0))).reshape(-1, block, h.shape[-1]),
+              jnp.pad(targets, (0, pad)).reshape(-1, block),
+              weight.reshape(-1, block))
+    return jnp.sum(lax.map(rows, blocks)) / count
 
 
 # ------------------------------------------------------------------- sizes
@@ -264,48 +287,16 @@ def parameter_count(sizes):
 # ------------------------------------------------ operations and bytes
 
 
-def window_work(sizes, batch, seq_len):
-    """(operations, bytes) of ONE window layer's scores and their product
-    with the values, under `lm/attn/window_scores`, forward and backward
-    by `attn_work`'s convention (three forward passes): the band and
-    nothing else, `sum_i min(i + 1, window)` query-key pairs a head
-    (58,722,304 at 16,384 under 4,096), two products of `2 d` operations
-    a pair. It is the same count whatever implements the scope: a kernel
-    that computes whole tiles reads what it wastes as a lower share.
-    Bytes as `attn_work`'s: q, k, v and the output once each way in
-    bfloat16."""
-    q_heads, kv_heads = (sizes["num_attention_heads"],
-                         sizes["num_key_value_heads"])
-    dim = sizes["head_dim"]
-    window = min(sizes["sliding_window"], seq_len)
-    pairs = window * (window + 1) // 2 + (seq_len - window) * window
-    forward = 2 * 2 * batch * q_heads * dim * pairs
-    io = 2 * batch * seq_len * dim * (2 * q_heads + 2 * kv_heads)
-    return 3 * forward, 3 * io
-
-
-def expert_work(sizes, held_assignments):
-    """(operations, bytes) of ONE expert layer's three grouped products
-    (gate, up, down: hidden x width each) over the rows that really landed
-    on the held experts, forward and backward; every column is counted,
-    the ones the gate's `relu` zeroes too (the program computes them).
-    Bytes: the rows in and out and the hidden activations once each way in
-    bfloat16, the held experts' weights read twice (forward, gradient to
-    the rows) and their gradient written."""
-    hidden, width = sizes["hidden_size"], sizes["moe_intermediate_size"]
-    held = sizes["experts_held"]["count"]
-    forward = 3 * 2 * held_assignments * hidden * width
-    rows = 2 * held_assignments * (2 * hidden + 2 * width)
-    weights = 3 * 2 * held * hidden * width
-    return 3 * forward, 3 * rows + 3 * weights
-
-
 def work(sizes, batch, seq_len, held_assignments):
     """{scope family: [operations, bytes]} of a whole step, every layer
     that runs under the scope: `attn_scores` the full layers' triangle
-    (under `lm/attn/scores`), `attn_window` the window layers' band
-    (under `lm/attn/window_scores`), `moe_experts` the held experts'
-    products. `held_assignments`: {layer index: rows that landed on the
+    (under `lm/attn/scores`), `attn_window` the window layers' band and
+    nothing else (under `lm/attn/window_scores`: `sum_i min(i + 1,
+    window)` query-key pairs a head, 58,722,304 at 16,384 under 4,096,
+    4 x 128 operations a pair forward, three passes), `moe_experts` the
+    held experts' three products of hidden x width over the rows that
+    landed, every column counted, those the gate's `relu` zeroes too (the
+    program computes them). `held_assignments`: {layer index: rows that landed on the
     held experts}, as the step itself reported them."""
     kinds = layer_kinds(sizes)
     out = {

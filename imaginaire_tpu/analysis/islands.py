@@ -108,9 +108,10 @@ register("loss_accumulation",
          "(tree_norm, audit guard) so the finite-check is trustworthy",
          where="imaginaire_tpu/diagnostics/audit.py")
 register("router_scores",
-         "an expert router's scores, its top-k choice and the selected "
-         "weights' normalization run in fp32; a bf16 score flips choices "
-         "between near-tied experts",
+         "an expert router's logits, its scores by either scoring (sigmoid "
+         "under a bias, or the softmax over the chosen logits), its top-k "
+         "choice and the selected weights' normalization run in fp32; a "
+         "bf16 score flips choices between near-tied experts",
          where="imaginaire_tpu/models/generators/hybrid_lm.py")
 register("ssm_scan",
          "a state-space scan's step sizes (softplus), decays exp(dt A), "

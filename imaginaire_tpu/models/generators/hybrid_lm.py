@@ -37,10 +37,18 @@ decay a key channel (``KDAMixer``; its sizes are the published
 of ``conv_L_cache`` taps between two elementwise gates
 (``ShortConvMixer``). An ``E`` layer has a shared expert beside the routed
 ones where the config gives ``moe_shared_expert_intermediate_size``, and
-none where it does not. ``hidden_act`` ``silu`` makes
-every feed-forward gated, ``W_down (silu(W_gate x) * W_up x)``; ``relu2``
-(the default: Nemotron-H's ``mlp_hidden_act``) is ``W_down relu(W_up
-x)^2``. ``nextn_pattern`` adds one multi-token-prediction module
+none where it does not; a factor on its routed sum where it gives
+``routed_scaling_factor``; its router scores by sigmoid under a fixed
+bias, or where ``moe_primary_router_apply_softmax`` says so by the
+softmax over the chosen logits with no bias (``route``); and where
+``use_early_router`` says so the router reads not the layer's own normed
+input but that of the attention layer before it (SmallThinker's
+``moe_enable_early_router``: the routing depends on nothing attention
+computes), which that layer's block hands on (``Block``). ``hidden_act``
+``silu`` makes every feed-forward gated, ``W_down (silu(W_gate x) * W_up
+x)``, ``relu`` gates by ``relu`` in its place; ``relu2`` (the default:
+Nemotron-H's ``mlp_hidden_act``) is ``W_down relu(W_up x)^2``.
+``nextn_pattern`` adds one multi-token-prediction module
 (DeepSeek-V3's): position ``i``'s final hidden state and token ``i+1``'s
 embedding, each normed, merged by one product, through the module's own
 layers to the logits for token ``i+2`` under the main model's embedding
@@ -117,9 +125,8 @@ def rms_norm(x, scale, eps, groups=1):
 
 
 # ``hidden_act`` -> whether a feed-forward has a gate beside its
-# up-product; a gated one's name is its gate's activation
-# (``held_experts.GATES``)
-GATED = {"relu2": False, "silu": True, "relu": True}
+# up-product (a gated one's name is its gate's: ``held_experts.GATES``)
+GATED = {"relu2": False, **dict.fromkeys(held_experts.GATES, True)}
 
 
 def feed_forward(x, kernels, gate="silu"):
@@ -517,11 +524,10 @@ def route(x32, w_router, score_bias, top_k, scaling=None,
 
 class MoEMixer(nn.Module):
     """The held experts' part of a mixture of experts, and the shared
-    expert where the model has one. The router reads ``u``, the layer's
-    own normed input, or ``router_input`` where one is given
-    (``use_early_router``: what the layer before it read); the experts
-    read ``u`` either way. A router scored by the softmax over its
-    chosen logits has no score-correction bias, and no buffer for one."""
+    expert where the model has one. The router reads ``router_input``
+    where one is given (``use_early_router``: what the layer before it
+    read), the experts ``u`` either way. A router scored by the softmax
+    over its chosen logits has no bias, and no buffer for one."""
     cfg: Any
 
     @nn.compact
@@ -635,13 +641,6 @@ def layer_kinds(g):
     return g.pattern + (g.nextn_pattern or "")
 
 
-def feeds_early_router(g, kinds, at):
-    """Whether layer ``at`` of the letters ``kinds`` hands its normed
-    input to the router of the expert layer behind it
-    (``use_early_router``)."""
-    return g.use_early_router and kinds[at + 1:at + 2] == "E"
-
-
 class Block(nn.Module):
     """One layer of the pattern: ``h + Mixer(RMSNorm(h))`` or, where
     ``use_post_norm``, ``h + RMSNorm_post(Mixer(RMSNorm(h)))``. Returns
@@ -674,7 +673,7 @@ class Block(nn.Module):
 
 
 # the tokens whose logits stand at a time in the loss: 1024 x the
-# vocabulary slice in float32 (0.1 GB at the widest slice shipped, 25,024)
+# vocabulary slice in float32 (0.16 GB at the widest slice shipped, 37,984)
 LOSS_CHUNK_TOKENS = 1024
 
 
@@ -727,7 +726,8 @@ class Settings:
     ``moe_shared_expert_intermediate_size`` an expert layer without a
     shared expert, no ``routed_scaling_factor`` a routed sum without a
     factor. ``moe_primary_router_apply_softmax`` is the published key of
-    the second scoring (``route``). ``conv_L_cache`` is the taps of the sixth letter's
+    the second scoring (``route``). ``conv_L_cache`` is the taps of the
+    sixth letter's
     (``C``) convolution; ``conv_bias`` has to be false."""
     pattern: str
     hidden_size: int
@@ -889,12 +889,12 @@ class Generator(nn.Module):
             handed = ()
             for at, kind in enumerate(kinds):
                 index = first + at
-                block = remat_block(
-                    Block, g.remat, where="gen.remat", cfg=g, kind=kind,
-                    hands_on=feeds_early_router(g, kinds, at),
-                    name=f"layer_{index}")
                 # what an early router reads goes from the layer that
                 # made it to the expert layer behind it, and no further
+                hands_on = g.use_early_router and kinds[at + 1:at + 2] == "E"
+                block = remat_block(
+                    Block, g.remat, where="gen.remat", cfg=g, kind=kind,
+                    hands_on=hands_on, name=f"layer_{index}")
                 h, stats, *handed = block(h, *handed, training=training)
                 for key, value in stats.items():
                     out[f"moe/{index}/{key}"] = value

@@ -336,9 +336,9 @@ def on_filled_prefix(tiers, n_held, x, kernels, weight, token, group_sizes,
 
 
 def _on_filled_prefix_fwd(tiers, n_held, *operands):
-    *operands, gate = operands
-    return (on_filled_prefix(tiers, n_held, *operands, gate),
-            (n_held, operands))
+    # the last is the gate's name, which the backward rule is handed too
+    return on_filled_prefix(tiers, n_held, *operands), (n_held,
+                                                        operands[:-1])
 
 
 def _on_filled_prefix_bwd(tiers, gate, saved, ct):

@@ -148,7 +148,7 @@ def moe_impl(gen_cfg, tokens_shape):
     return dict(layers={str(i): "/".join(arms) for i in layers},
                 hidden=hidden, width=width, held=g.held_count,
                 tiers=list(tiers),
-                router_input={str(i): reads for i in layers},
+                router_input=dict.fromkeys(map(str, layers), reads),
                 scoring=("softmax_of_chosen"
                          if g.moe_primary_router_apply_softmax
                          else "sigmoid"),

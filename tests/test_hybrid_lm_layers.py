@@ -461,7 +461,8 @@ def _prefix_and_whole_buffer(preset):
         return module.apply({"params": params, "buffers": buffers}, u)
 
     def whole_buffer(tiers, n_held, *operands):
-        return _plain_held_experts_part(*operands, rows=tiers[-1])
+        *operands, gate = operands
+        return _plain_held_experts_part(*operands, rows=tiers[-1], gate=gate)
 
     def run(params, buffers, u):
         out, stats = layer(params, buffers, u)

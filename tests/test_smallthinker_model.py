@@ -157,13 +157,14 @@ def test_the_experts_read_their_own_normed_input():
 def test_the_attention_block_hands_its_normed_input_on():
     """`Block`: the letter before an `E` returns `u` third under
     `use_early_router`, and no other block does."""
-    cfg, _, _, train = _model()
+    cfg, _, _, train = _model(remat="none")
     g = hybrid_lm.model_settings(cfg.gen)
-    assert [hybrid_lm.feeds_early_router(g, g.pattern, at)
-            for at in range(8)] == [True, False] * 4
-    late = hybrid_lm.model_settings(tiny_cfg("afmoe").gen)
-    assert not any(hybrid_lm.feeds_early_router(late, late.pattern, at)
-                   for at in range(len(late.pattern)))
+    tokens = jnp.asarray(through_trainer._tokens(cfg, seed=3))
+    _, state = _routed(cfg, train, tokens, intermediates=True)
+    outs = {name: len(found["__call__"][0])
+            for name, found in state["intermediates"].items()
+            if name.startswith("layer_")}
+    assert outs == {f"layer_{i}": 2 + (i % 2 == 0) for i in range(8)}
     h = jax.random.normal(jax.random.PRNGKey(1), (1, 64, 64))
     params = {"scale": 1.0 + 0.1 * jnp.arange(64.0) / 64,
               "mixer": layer_params(train, 0)}
