@@ -55,11 +55,21 @@ the chunk and the length (``arm_of``), the two Pallas sweeps of
 ``jax.numpy``. Import it as a module,
 ``from imaginaire_tpu.ops import delta_rule``.
 
-state_space, held_experts, grouped_matmul
------------------------------------------
+state_space
+-----------
+``ops/state_space.py`` (the token model's Mamba-2 recurrence in its
+chunked state-space dual form, ``ssd_scan``) is not a reference op either
+and takes no ``implementation``: it picks its own arm from the backend,
+the head size, the state, the chunk, the length and the heads a group
+(``arm_of``), the two Pallas sweeps of
+``ops/pallas/state_space_kernel.py`` or ``ssd_chunks`` in plain
+``jax.numpy``. Import it as a module,
+``from imaginaire_tpu.ops import state_space``.
+
+held_experts, grouped_matmul
+----------------------------
 The token model's other blocks of numerics, modules likewise and without
-an ``implementation``: ``ops/state_space.py`` (the Mamba-2 recurrence's
-chunked dual form, ``ssd_scan``, one arm), ``ops/held_experts.py`` (an
+an ``implementation``: ``ops/held_experts.py`` (an
 expert layer's held share: the sort into the buffer, the tiers of its
 filled prefix, the rows moved by segments, the backward pass written
 out) and ``ops/grouped_matmul.py`` (the experts' grouped products: the

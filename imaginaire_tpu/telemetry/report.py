@@ -1136,6 +1136,21 @@ def render_report(path_or_events):
                + f"; the blocks keep {sum(kda['kept_bytes'].values())} "
                "bytes of the kernel's forward sweeps for its backward "
                "sweeps" if "arm" in kda else ""))
+    ssd = s["meta"].get("ssd_impl")
+    if ssd:
+        lines.append(
+            f"- ssd_impl: layers {', '.join(map(str, ssd.get('layers')))}; "
+            f"{ssd.get('heads')} heads of {ssd.get('head_dim')} in "
+            f"{ssd.get('groups')} groups, state {ssd.get('state')}, chunks "
+            f"of {ssd.get('chunk')} steps; "
+            + ", ".join(f"layer {i} {arm}" for i, arm in sorted(
+                (ssd.get("arm") or {}).items(), key=lambda kv: int(kv[0])))
+            + "; fused tiles (chunks a grid step) "
+            + ", ".join(f"{k} {v}" for k, v in
+                        (ssd.get("tiles") or {}).items())
+            + "; the blocks keep "
+            f"{sum((ssd.get('kept_bytes') or {}).values())} bytes of the "
+            "kernel's forward sweeps for its backward sweeps")
     moe = s["meta"].get("moe_impl")
     if moe:
         lines.append(

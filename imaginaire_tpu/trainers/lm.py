@@ -22,7 +22,7 @@ from imaginaire_tpu import telemetry
 from imaginaire_tpu.config import as_attrdict, cfg_get
 from imaginaire_tpu.models.generators import hybrid_lm
 from imaginaire_tpu.ops import (attention, delta_rule, grouped_matmul,
-                                held_experts)
+                                held_experts, state_space)
 from imaginaire_tpu.optim.remat import resolve_policy
 from imaginaire_tpu.trainers.base import BaseTrainer
 
@@ -113,6 +113,36 @@ def kda_impl(gen_cfg, tokens_shape):
                 chunks_at_once=delta_rule.KDA_CHUNKS_AT_ONCE,
                 arm={str(i): arm for i in layers},
                 tiles=delta_rule.TILES._asdict(),
+                kept_bytes={str(i): kept for i in layers})
+
+
+def ssd_impl(gen_cfg, tokens_shape):
+    """The ``ssd_impl`` meta of a (batch, length) step: the Mamba-2 layers
+    of the pattern, their heads at their size, the groups that share
+    ``b`` and ``c``, the state's size, the chunk of the dual form, the arm
+    each layer's scan takes at this length on this backend
+    (``ops/state_space.py`` decides; nothing here does), the fused arm's
+    tiles (chunks a grid step of each sweep) and the bytes each layer's
+    block keeps of the kernel's forward sweep for its backward sweep (the
+    output and the chunks' entry states: ``gen.remat``'s policy decides;
+    0 on the ``chunks`` arm, which has no kernel); None for a model
+    without such a layer."""
+    g = hybrid_lm.model_settings(gen_cfg)
+    layers = [i for i, kind in enumerate(hybrid_lm.layer_kinds(g))
+              if kind == "M"]
+    if not layers:
+        return None
+    bsz, length = (int(n) for n in tokens_shape)
+    sizes = dict(heads=g.mamba_num_heads, head_dim=g.mamba_head_dim,
+                 state=g.ssm_state_size, chunk=g.chunk_size)
+    arm = state_space.arm_of(length=length, groups=g.n_groups, **sizes)
+    kept = (state_space.residual_bytes(
+        bsz, length, dtype=g.compute_dtype, **sizes)
+            if arm == "fused"
+            and resolve_policy(g.remat).keeps_kernel_residuals else 0)
+    return dict(layers=layers, groups=g.n_groups, **sizes,
+                arm={str(i): arm for i in layers},
+                tiles=state_space.TILES._asdict(),
                 kept_bytes={str(i): kept for i in layers})
 
 
@@ -229,13 +259,15 @@ class Trainer(BaseTrainer):
 
     def _note_attn_impl(self, tokens_shape):
         """One ``attn_impl`` meta as the step is first built, one
-        ``kda_impl`` where the model has delta-rule layers and one
-        ``moe_impl`` where it has expert layers."""
+        ``kda_impl`` where the model has delta-rule layers, one
+        ``ssd_impl`` where it has Mamba-2 layers and one ``moe_impl``
+        where it has expert layers."""
         tm = telemetry.get()
         if not tm.enabled:
             return
         tm.meta("attn_impl", **attn_impl(self.cfg.gen, tokens_shape))
-        for name, impl in (("kda_impl", kda_impl), ("moe_impl", moe_impl)):
+        for name, impl in (("kda_impl", kda_impl), ("ssd_impl", ssd_impl),
+                           ("moe_impl", moe_impl)):
             meta = impl(self.cfg.gen, tokens_shape)
             if meta:
                 tm.meta(name, **meta)

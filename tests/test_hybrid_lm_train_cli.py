@@ -84,6 +84,16 @@ def test_train_py_trains_checkpoints_and_resumes(entry_point_sandbox,
     metas = {e["name"] for e in events if e["kind"] == "meta"}
     assert "step_flops" in metas and "xla_compile/gen_step" in metas
     assert "kda_impl" not in metas      # no delta-rule layer, no meta
+    # the two Mamba-2 layers of 'MEM*E', on the CPU: the ``chunks`` arm,
+    # which has no kernel whose residuals a block could keep (ISSUE 46)
+    ssd = [e for e in events
+           if e["kind"] == "meta" and e["name"] == "ssd_impl"]
+    assert len(ssd) == 1
+    assert ssd[0]["layers"] == [0, 2]
+    assert ssd[0]["arm"] == {"0": "chunks", "2": "chunks"}
+    assert ssd[0]["kept_bytes"] == {"0": 0, "2": 0}
+    assert (ssd[0]["heads"], ssd[0]["head_dim"], ssd[0]["groups"],
+            ssd[0]["state"], ssd[0]["chunk"]) == (8, 16, 2, 16, 16)
     # the one attention layer of 'MEM*E', on the CPU: the plain arm
     attn = [e for e in events
             if e["kind"] == "meta" and e["name"] == "attn_impl"]
@@ -107,6 +117,8 @@ def test_train_py_trains_checkpoints_and_resumes(entry_point_sandbox,
 
     report = render_report(os.path.join(logdir, "telemetry.jsonl"))
     assert "## experts" in report and "perf/tokens_per_sec" in report
+    assert ("- ssd_impl: layers 0, 2; 8 heads of 16 in 2 groups, state 16, "
+            "chunks of 16 steps; layer 0 chunks, layer 2 chunks; ") in report
     assert "| moved over held |" in report
     assert "gen_step: 0 violation(s)" in report
     assert ("attn_impl at length 64, head size 16: layer 3 blocks; fused "
@@ -227,7 +239,7 @@ def test_train_py_trains_the_short_convolution_preset(entry_point_sandbox,
     assert counters["xla/recompiles"] == 0
     assert counters["xla/graph_violations"] == 0
     metas = {e["name"]: e for e in events if e["kind"] == "meta"}
-    assert "kda_impl" not in metas
+    assert "kda_impl" not in metas and "ssd_impl" not in metas
     attn = metas["attn_impl"]
     assert attn["layers"] == {"2": "blocks"} and attn["head_dim"] == 16
     assert attn["kernel_head_dim"] == 128 and attn["kept_bytes"] == {"2": 0}

@@ -105,7 +105,8 @@ def test_an_op_imports_nothing_above_it(rel):
 def test_the_layering_test_sees_every_op():
     files = _op_files()
     for rel in ("ops/state_space.py", "ops/held_experts.py",
-                "ops/pallas/delta_rule_kernel.py"):
+                "ops/pallas/delta_rule_kernel.py",
+                "ops/pallas/state_space_kernel.py"):
         assert rel in files
 
 

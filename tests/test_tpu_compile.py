@@ -8,6 +8,7 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU's library, and every worker imports this file.
 """
 
+import collections
 import os
 
 import jax
@@ -277,6 +278,177 @@ def test_the_chunks_arm_compiles_for_the_chip_at_a_ragged_length(one_chip):
     assert " while(" in text
     assert re.search(r"f32\[(\d+,)*16,16,128\]", text)
     assert not re.search(r"f32\[(\d+,)*64,64,128\]", text)
+
+
+def _ssd_operands(one_chip, length, heads, dim, groups, state):
+    lead = (1, length)
+    return (_sds(lead + (heads, dim), jnp.bfloat16, one_chip),
+            _sds(lead + (heads,), jnp.float32, one_chip),
+            _sds((heads,), jnp.float32, one_chip),
+            _sds(lead + (groups, state), jnp.bfloat16, one_chip),
+            _sds(lead + (groups, state), jnp.bfloat16, one_chip))
+
+
+def test_mamba2_layer_compiles_at_the_token_cells_shape(one_chip,
+                                                        monkeypatch):
+    """A Mamba-2 mixer of nemotron3_nano_30b_a3b (64 heads of 64 in 8
+    groups, state 128, chunks of 128, 8,192 positions, bfloat16 compute),
+    value and gradients under the block's checkpoint, on the arm the chip
+    takes (ISSUE 46): the chip's compiler takes the scan's two kernels,
+    one forward sweep (the block keeps its output and the chunks' entry
+    states, so the recompute holds no second one) and one backward sweep,
+    each a custom call on one line under ``lm/mamba2/ssd_scan``; no
+    ``while`` is left under that scope; nothing (..., 128, 128) of a
+    head's decays or weights and no (..., 64, 128) state a chunk and head
+    in the ``chunks`` arm's layout stands in HBM; the layer's temporaries
+    are 1.38 GB (1,383,805,952 bytes as this test compiles it), the kept
+    output and entry states in them, where the ``chunks`` arm's are 1.87.
+    Every instruction of the mixer names one of its scopes."""
+    import re
+
+    from imaginaire_tpu.config import Config
+    from imaginaire_tpu.models.generators import hybrid_lm
+    from imaginaire_tpu.ops import state_space
+    from imaginaire_tpu.optim.remat import POLICIES
+
+    gen = Config(os.path.join(ROOT, "configs", "projects", "nemotron_h",
+                              "nano_30b_a3b_ep16_share.yaml")).gen
+    gen["compute_dtype"] = "bfloat16"     # as the trainer sets it
+    g = hybrid_lm.model_settings(gen)
+    sizes = (g.mamba_head_dim, g.ssm_state_size, g.chunk_size, 8192,
+             g.mamba_num_heads, g.n_groups)
+    assert sizes == (64, 128, 128, 8192, 64, 8)
+    mixer = hybrid_lm.Mamba2Mixer(g)
+    params = jax.eval_shape(lambda: mixer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 128, g.hidden_size),
+                                         jnp.bfloat16)))
+    params = jax.tree_util.tree_map(
+        lambda leaf: _sds(leaf.shape, leaf.dtype, one_chip), params)
+    # the arm decides as it would on the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert state_space.arm_of(*sizes) == "fused"
+
+    def loss(params, u):
+        return jnp.sum(mixer.apply(params, u).astype(jnp.float32))
+
+    compiled = _compile(
+        jax.value_and_grad(
+            jax.checkpoint(loss, policy=POLICIES["blocks"].policy),
+            argnums=(0, 1)),
+        params, _sds((1, 8192, g.hidden_size), jnp.bfloat16, one_chip))
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert _kernel_calls(text) == ["ssd_scan_bwd", "ssd_scan_fwd"]
+    assert all('op_name="' in line and "lm/mamba2/ssd_scan" in line
+               for line in calls)
+    assert not [line for line in text.splitlines()
+                if " while(" in line and "lm/mamba2/ssd_scan" in line]
+    assert not re.search(r"(f32|bf16)\[(\d+,)*128,128\]", text)
+    assert not re.search(r"f32\[(\d+,)*64,64,128\]", text)
+    scopes = {scope for name in re.findall(r'op_name="([^"]*)"', text)
+              for scope in re.findall(r"lm/mamba2/\w+", name)[-1:]}
+    assert scopes == {"lm/mamba2/in_proj", "lm/mamba2/conv",
+                      "lm/mamba2/ssd_scan", "lm/mamba2/gate_norm",
+                      "lm/mamba2/out_proj"}
+
+
+@pytest.mark.parametrize("dim,state,chunk,heads,groups", [
+    (128, 128, 128, 32, 4), (64, 256, 256, 16, 1), (64, 128, 128, 24, 1),
+    (128, 256, 128, 8, 1)])
+def test_ssd_scan_kernels_compile_at_the_other_shapes_the_rule_sends_them(
+        one_chip, monkeypatch, dim, state, chunk, heads, groups):
+    """Heads of 128 (one to a lane tile), a state of 256, chunks of 256
+    and groups of sixteen and of twenty-four heads: what
+    ``state_space.arm_of`` lets through beside the token cell's shape."""
+    from imaginaire_tpu.ops import state_space
+
+    length = 2048
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert state_space.arm_of(dim, state, chunk, length, heads,
+                              groups) == "fused"
+
+    def loss(*xs):
+        return jnp.sum(state_space.ssd_scan(*xs, chunk).astype(jnp.float32))
+
+    text = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
+        *_ssd_operands(one_chip, length, heads, dim, groups, state)).as_text()
+    assert _kernel_calls(text) == ["ssd_scan_bwd", "ssd_scan_fwd"]
+
+
+def test_the_ssd_chunks_arm_compiles_for_the_chip_at_a_ragged_length(
+        one_chip, monkeypatch):
+    """A length the chunk does not divide takes ``ssd_chunks`` on a TPU
+    too (``arm_of``), so the chip's compiler still has to take the XLA
+    form and its gradient: the carry as a ``while`` loop, a head's
+    (128, 128) decays in float32, and no kernel."""
+    import re
+
+    from imaginaire_tpu.ops import state_space
+
+    length = 1000
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert state_space.arm_of(64, 128, 128, length, 16, 2) == "chunks"
+
+    def loss(*xs):
+        return jnp.sum(state_space.ssd_scan(*xs, 128).astype(jnp.float32))
+
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
+                    *_ssd_operands(one_chip, length, 16, 64, 2, 128)
+                    ).as_text()
+    assert _kernel_calls(text) == []
+    assert " while(" in text
+    assert re.search(r"f32\[(\d+,)*128,128\]", text)
+
+
+def test_the_mamba2_share_s_step_fits_one_chip(one_chip, monkeypatch):
+    """configs/projects/nemotron_h/nano_30b_a3b_ep16_share.yaml's whole
+    training step at its own shapes (one sequence of 8,192; from
+    ``jax.eval_shape`` shapes: no weight is materialized), lowered and
+    compiled as on the chip: the four Mamba-2 layers' scans on the fused
+    arm, a forward and a backward sweep each and no second forward sweep
+    in a block's recompute (each block keeps its sweep's bfloat16 output
+    and float32 entry states, 201 MB a layer), the attention layer's
+    scores on the fused arm, and state and temporaries together under one
+    chip's 16.9e9 bytes (ISSUE 46: 11.7e9 as it stands)."""
+    from imaginaire_tpu.config import Config
+    from imaginaire_tpu.registry import resolve
+    from imaginaire_tpu.trainers import lm
+
+    cfg = Config(os.path.join(ROOT, "configs", "projects", "nemotron_h",
+                              "nano_30b_a3b_ep16_share.yaml"))
+    shape = (int(cfg.data.train.batch_size), int(cfg.data.seq_len))
+    assert shape == (1, 8192)
+    trainer = resolve(cfg.trainer.type, "Trainer")(cfg)
+    # the arms decide as they would on the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    meta = lm.ssd_impl(trainer.cfg.gen, shape)
+    assert meta["arm"] == dict.fromkeys("0247", "fused")
+    assert sum(meta["kept_bytes"].values()) == 4 * 201_326_592
+    assert lm.attn_impl(trainer.cfg.gen, shape)["layers"] == {"5": "fused"}
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+
+    data = {"tokens": jax.ShapeDtypeStruct(shape, jnp.int32)}
+    state = jax.eval_shape(trainer._init_state,
+                           jax.ShapeDtypeStruct((2,), np.uint32), data)
+    compiled = trainer._jit_gen_step.lower(on_chip(state),
+                                           on_chip(data)).compile()
+    trainer.state = None
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert 11.0e9 < total < 12.5e9 < 16.9e9, total
+    text = compiled.as_text()
+    calls = collections.Counter(_kernel_calls(text))
+    assert (calls["ssd_scan_fwd"], calls["ssd_scan_bwd"]) == (4, 4)
+    assert (calls["causal_gqa_fwd"], calls["causal_gqa_bwd"]) == (1, 1)
+    assert not [line for line in text.splitlines()
+                if " while(" in line and "lm/mamba2/ssd_scan" in line]
 
 
 def test_the_short_convolution_share_s_step_fits_one_chip(one_chip,
